@@ -28,6 +28,9 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
 WORKDIR /app
 COPY pyproject.toml README.md ./
 COPY flyimg_tpu ./flyimg_tpu
+# the library is not in git and the loader would build it on first use,
+# but this stage has no toolchain: take the one built above (newer than
+# the sources just copied, so the loader keeps it)
 COPY --from=build /app/flyimg_tpu/codecs/native/libfastcodec.so \
      ./flyimg_tpu/codecs/native/libfastcodec.so
 

@@ -81,19 +81,18 @@ SERVER_DEFAULTS: Dict[str, Any] = {
     # static K-tap gather-contract (~30x fewer resample MACs at serving
     # scales); 'auto' = banded whenever the band is narrower than the
     # dense matrix. The FLYIMG_RESAMPLE_KERNEL env var seeds the default
-    # so offline A/B tools (bench.py, tools/chip_suite.py) flip the
-    # variant without config plumbing. Default dense until BENCH_r06
-    # confirms the on-chip win.
+    # so offline A/B tools (bench.py, tools/bench_http.py) flip the
+    # variant without config plumbing. Default dense until a chip
+    # measurement decides (ROADMAP S4/D2).
     "resample_kernel": os.environ.get("FLYIMG_RESAMPLE_KERNEL", "dense"),
     # face engine selection + optional blazeface checkpoint dir
     # (models/faces.py make_face_backend)
     "face_backend": "auto",
     "face_checkpoint": None,
-    # persistent XLA compilation cache dir ('' disables; service/app.py)
+    # persistent XLA compilation cache dir, relative to the checkout
+    # ('' disables; JAX_COMPILATION_CACHE_DIR wins over either;
+    # flyimg_tpu/compilecache.py)
     "compilation_cache_dir": "var/cache/xla",
-    # boot-time accelerator compute probe deadline (parallel/mesh.py
-    # ensure_live_backend; 0 trusts the selection and may hang)
-    "backend_probe_timeout_s": 75.0,
     # local-storage output-cache size budget + background prune cadence
     # (0 disables the budget; non-positive interval disables the loop)
     "cache_max_bytes": 0,
@@ -206,11 +205,10 @@ SERVER_DEFAULTS: Dict[str, Any] = {
     # inside this window — a slow trickle over hours is per-batch
     # retry's job, not a storm
     "device_storm_window_s": 30.0,
-    # background re-probe cadence while failed over (the probe itself is
-    # bounded by backend_probe_timeout_s, the same knob boot uses)
+    # background re-probe cadence while failed over
     "device_probe_interval_s": 5.0,
     # consecutive clean probes required before re-promotion (hysteresis:
-    # one lucky probe against a flapping tunnel must not re-promote)
+    # one lucky probe against a flapping backend must not re-promote)
     "device_probe_hysteresis": 2,
     # bound on the in-flight batch drain at failover/re-promotion;
     # leftovers are timeout-stamped like a shutdown drain

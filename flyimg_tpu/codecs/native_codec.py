@@ -1,17 +1,19 @@
 """ctypes bindings for the native fastcodec library.
 
-Builds libfastcodec.so on demand (make, g++, links libjpeg/libwebp) and
-exposes decode/encode entry points with numpy in/out. All calls release the
-GIL (plain ctypes calls do), so the fc_pool batch decode genuinely runs
-decodes in parallel on multi-core hosts.
+Builds libfastcodec.so from the tracked sources on first use (make, g++,
+links libjpeg/libpng/libwebp) and exposes decode/encode entry points with
+numpy in/out. All calls release the GIL (plain ctypes calls do), so the
+fc_pool batch decode genuinely runs decodes in parallel on multi-core hosts.
 
-Falls back cleanly: ``available()`` is False when the toolchain or libs are
-missing and callers (flyimg_tpu.codecs) keep using the PIL paths.
+``available()`` is False when the toolchain or libs are missing; callers
+(flyimg_tpu.codecs) then use the PIL paths, and the loader says so once at
+WARNING.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -88,14 +90,49 @@ class _EncodeItem(ctypes.Structure):
     ]
 
 
+_SOURCES = ("fastcodec.cpp", "webp_shim.h", "Makefile")
+
+
 def _build() -> bool:
     try:
         proc = subprocess.run(
-            ["make", "-C", _DIR], capture_output=True, timeout=120
+            ["make", "-B", "-C", _DIR], capture_output=True, timeout=120
         )
         return proc.returncode == 0 and os.path.exists(_LIB_PATH)
     except (OSError, subprocess.SubprocessError):
         return False
+
+
+def _stale() -> bool:
+    """The library is missing or older than a source it is built from."""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    return any(
+        os.path.getmtime(os.path.join(_DIR, name)) > built
+        for name in _SOURCES
+    )
+
+
+def _open_library():
+    """``ctypes.CDLL`` of the library built from the tracked sources, or
+    None. The binary is not in git: it is (re)built when missing or older
+    than its sources, and again when what is there will not load on this
+    machine (a copied-in binary linked against another installation's
+    sonames)."""
+    if _stale():
+        _build()
+    try:
+        return ctypes.CDLL(_LIB_PATH)
+    except OSError:
+        pass
+    if _build():
+        try:
+            return ctypes.CDLL(_LIB_PATH)
+        except OSError:
+            pass
+    return None
 
 
 def _load():
@@ -103,12 +140,12 @@ def _load():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build():
-            _lib = False
-            return _lib
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        lib = _open_library()
+        if lib is None:
+            logging.getLogger(__name__).warning(
+                "native host codec unavailable (could not build or load "
+                "%s); every decode/encode runs on PIL", _LIB_PATH,
+            )
             _lib = False
             return _lib
         lib.fc_jpeg_decode.restype = ctypes.c_void_p

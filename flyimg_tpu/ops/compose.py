@@ -260,22 +260,25 @@ class ProgramHandle:
     ``cost_analysis()``/``memory_analysis()``, which the call-time path
     discards — FLOPs, bytes accessed, peak memory, and the measured
     compile wall time are recorded in the ledger keyed by this handle's
-    program key. Any AOT-path failure (backend quirk) falls back to
-    calling the jitted function forever after, recording a ledger entry
-    with nulled cost fields — cost accounting must never fail a render
-    (tests/test_costledger.py pins the fallback).
+    program key. A program the compiler refuses raises to the caller —
+    its requests fail, nothing retries it down another path; only the
+    two analysis calls are guarded, because cost accounting must never
+    fail a render that compiled.
     """
 
     __slots__ = (
-        "_jitted", "_compiled", "_fallback", "_lock",
-        "ledger_key", "descriptor",
+        "_jitted", "_compiled", "_lock", "ledger_key", "descriptor",
+        "in_sharding",
     )
 
-    def __init__(self, jitted, key, descriptor: Dict[str, object]) -> None:
+    def __init__(self, jitted, key, descriptor: Dict[str, object],
+                 in_sharding=None) -> None:
         self._jitted = jitted
         self._compiled = None
-        self._fallback = False
         self._lock = threading.Lock()
+        # the sharding every argument of a mesh-sharded batched program
+        # takes (None: single device)
+        self.in_sharding = in_sharding
         if isinstance(key, str):
             self.ledger_key = key
         else:
@@ -285,10 +288,19 @@ class ProgramHandle:
 
     @property
     def is_compiled(self) -> bool:
-        """True once this handle holds a compiled program (or settled on
-        the jitted fallback) — the batcher's EXACT compile-hit signal,
-        replacing the old lru-miss-count inference."""
-        return self._compiled is not None or self._fallback
+        """True once this handle holds a compiled program — the batcher's
+        EXACT compile-hit signal, replacing the old lru-miss-count
+        inference."""
+        return self._compiled is not None
+
+    def stage(self, arrays):
+        """Host arrays -> device arrays laid out as this program takes
+        them. With an input sharding each device receives its slice of the
+        batch straight from the host — staged unsharded, the whole batch
+        would land on device 0 and be resharded at every launch."""
+        if self.in_sharding is None:
+            return [jnp.asarray(a) for a in arrays]
+        return jax.device_put(list(arrays), self.in_sharding)
 
     def precompile(self, args) -> None:
         """Compile (and ledger-record) for ``args``'s shapes WITHOUT
@@ -297,24 +309,19 @@ class ProgramHandle:
         for a geometry (e.g. the canonical 4k plan) that would be
         seconds-per-image to actually execute on a CPU host."""
         with self._lock:
-            if self._compiled is None and not self._fallback:
+            if self._compiled is None:
                 self._compile(args)
 
     def __call__(self, *args):
         compiled = self._compiled
-        if compiled is not None:
-            return compiled(*args)
-        if self._fallback:
-            return self._jitted(*args)
-        with self._lock:
-            # double-checked: a concurrent first call compiled while we
-            # waited — run it below, outside the lock
-            if self._compiled is None and not self._fallback:
-                self._compile(args)
-            compiled = self._compiled
-        if compiled is not None:
-            return compiled(*args)
-        return self._jitted(*args)
+        if compiled is None:
+            with self._lock:
+                # double-checked: a concurrent first call compiled while
+                # we waited — run it below, outside the lock
+                if self._compiled is None:
+                    self._compile(args)
+                compiled = self._compiled
+        return compiled(*args)
 
     def _compile(self, args) -> None:
         """AOT-compile for ``args``'s shapes and record the cost ledger
@@ -324,21 +331,7 @@ class ProgramHandle:
         ledger = _ledger()  # also populates the lazy module ref the
         # cost-normalization below reads
         t0 = time.perf_counter()
-        try:
-            compiled = self._jitted.lower(*args).compile()
-        except Exception:
-            # the jitted call path is the behavior of record; anything
-            # the AOT path cannot handle falls back to it, uncosted
-            self._fallback = True
-            ledger.record_compile(
-                self.ledger_key,
-                descriptor=self.descriptor,
-                compile_s=None,
-                cost=None,
-                peak_memory_bytes=None,
-                fallback=True,
-            )
-            return
+        compiled = self._jitted.lower(*args).compile()
         compile_s = time.perf_counter() - t0
         cost = None
         try:
@@ -365,7 +358,18 @@ class ProgramHandle:
             compile_s=compile_s,
             cost=cost,
             peak_memory_bytes=peak,
+            devices=_input_devices(compiled),
         )
+
+
+def _input_devices(compiled):
+    """Sorted ids of the devices a compiled program's first argument is
+    laid out over, or None where the executable does not say."""
+    try:
+        sharding = jax.tree_util.tree_leaves(compiled.input_shardings)[0]
+        return sorted(d.id for d in sharding.device_set)
+    except (AttributeError, IndexError, TypeError):
+        return None
 
 
 @lru_cache(maxsize=256)

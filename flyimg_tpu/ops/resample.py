@@ -67,7 +67,7 @@ FILTER_SUPPORT = {
 #: serving-wide resample formulation: 'dense' (the shipped [out, in]
 #: matrix einsums), 'banded' (static K-tap gather-contract), or 'auto'
 #: (banded whenever the band is narrower than the dense matrix). The env
-#: var seeds the default so offline tools (bench.py, chip_suite A/B legs)
+#: var seeds the default so offline tools (bench.py, bench_http A/B legs)
 #: can flip the variant without config plumbing; the ``resample_kernel``
 #: appconfig knob overrides it at app construction (service/app.py).
 KERNEL_MODES = ("dense", "banded", "auto")

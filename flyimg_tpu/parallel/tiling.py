@@ -32,22 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in later releases; this
-# image pins whichever home exists
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _axis_size(axis_name):
-    # jax.lax.axis_size is newer than this image's jax; psum(1) is the
-    # classic spelling and lowers to a compile-time constant
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
 from flyimg_tpu.ops.resample import resample_matrix
 
 
@@ -60,7 +44,7 @@ def _halo_exchange(
     ``"zero"`` (masked out of resample weights) or ``"edge"`` (replicate
     the boundary row — ImageMagick's edge virtual-pixel policy, matching
     ops.filters._separable_conv's mode='edge' padding)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     fwd = [(i, (i + 1) % n) for i in range(n)]
     bwd = [(i, (i - 1) % n) for i in range(n)]
     # my bottom rows -> next device's top halo; my top rows -> prev's bottom
@@ -192,7 +176,7 @@ def _build_tiled_program(
         # everywhere — the min() folds both limits into one clamp.
         top_valid = jnp.where(idx == 0, halo, 0)
         bottom_valid = jnp.where(
-            idx == _axis_size(axis) - 1, local_rows - halo, local_rows
+            idx == jax.lax.axis_size(axis) - 1, local_rows - halo, local_rows
         )
         bottom_valid = jnp.minimum(
             bottom_valid, jnp.float32(src_h) - local_offset
@@ -222,7 +206,7 @@ def _build_tiled_program(
             "ow,hwc->hoc", wx, tmp, precision=jax.lax.Precision.HIGHEST,
         )
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=P(axis, None, None),
@@ -307,7 +291,7 @@ def _build_tiled_filter(
         eff_threshold = threshold if op == "unsharp" else 0.0
         return unsharp_from_blurred(tile, blurred, eff_gain, eff_threshold)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         kernel_fn,
         mesh=mesh,
         in_specs=P(axis, None, None),
@@ -448,10 +432,7 @@ def _build_ring_rotate(
         acc = jnp.zeros((out_tile_h, out_w, tile.shape[-1]), jnp.float32)
         # the fresh zeros are unvaried over the mesh axis while the loop
         # output varies with it; align the carry's varying-axes type
-        # (jax versions without pcast have untyped varying axes — the
-        # alignment is a no-op there)
-        if hasattr(jax.lax, "pcast"):
-            acc = jax.lax.pcast(acc, (axis,), to="varying")
+        acc = jax.lax.pcast(acc, (axis,), to="varying")
         # n-1 permuted steps, then the last visiting tile outside the loop:
         # XLA can't DCE a collective in a uniform loop body, so a full-n
         # loop would pay one extra full-tile ICI hop per rotate
@@ -463,7 +444,7 @@ def _build_ring_rotate(
         )[..., None]
         return jnp.where(inside, acc, bg)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=P(axis, None, None),

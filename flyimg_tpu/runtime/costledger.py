@@ -16,9 +16,8 @@ This module is the accounting spine:
   (``jit(...).lower(...).compile()`` — ``ProgramHandle``) and records the
   compiled program's ``cost_analysis()`` (FLOPs, bytes accessed) and
   ``memory_analysis()`` (peak device memory estimate) here, along with
-  the measured compile wall time. Backends that return nothing (the CPU
-  fallback on some versions) or raise produce an entry with **nulled
-  cost fields** — the ledger never turns a cost-analysis quirk into a
+  the measured compile wall time. Backends whose analysis calls return
+  nothing or raise produce an entry with **nulled cost fields** — the ledger never turns a cost-analysis quirk into a
   serving failure (pinned by tests/test_costledger.py).
 - The batch runtime (``runtime/batcher.py``) and the single-image path
   (``ops/compose.py run_plan``) record every launch's device seconds and
@@ -101,7 +100,7 @@ class _Entry:
     __slots__ = (
         "key", "descriptor", "flops", "bytes_accessed", "transcendentals",
         "peak_memory_bytes", "compile_s", "compiled_at", "costed",
-        "fallback", "launches", "images", "device_s", "last_launch_at",
+        "devices", "launches", "images", "device_s", "last_launch_at",
     )
 
     def __init__(self, key: str, descriptor: Optional[Dict]) -> None:
@@ -114,7 +113,7 @@ class _Entry:
         self.compile_s: Optional[float] = None
         self.compiled_at: Optional[float] = None
         self.costed = False
-        self.fallback = False
+        self.devices: Optional[List[int]] = None
         self.launches = 0
         self.images = 0
         self.device_s = 0.0
@@ -133,7 +132,9 @@ class _Entry:
                 if self.compile_s is not None else None
             ),
             "costed": self.costed,
-            "fallback": self.fallback,
+            # ids of the devices the compiled program's inputs are laid
+            # out over (one id unless the batch is mesh-sharded)
+            "devices": self.devices,
             "launches": self.launches,
             "images": self.images,
             "device_s": round(self.device_s, 6),
@@ -183,7 +184,7 @@ class PlanCostLedger:
         compile_s: Optional[float] = None,
         cost: Optional[Dict[str, float]] = None,
         peak_memory_bytes: Optional[float] = None,
-        fallback: bool = False,
+        devices: Optional[List[int]] = None,
     ) -> str:
         """One program compiled (``cost`` already normalized; None =
         the backend reported nothing — the entry still exists, with
@@ -204,9 +205,9 @@ class PlanCostLedger:
             if not entry.costed:
                 self._total_uncosted += 1
             entry.peak_memory_bytes = peak_memory_bytes
+            entry.devices = devices
             entry.compile_s = compile_s
             entry.compiled_at = time.time()
-            entry.fallback = bool(fallback)
             self._total_compiles += 1
             if compile_s is not None:
                 self._total_compile_s += float(compile_s)

@@ -226,48 +226,31 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
     storage = make_storage(params, metrics=metrics)
     import jax
 
-    from flyimg_tpu.parallel.mesh import ensure_live_backend
+    from flyimg_tpu.compilecache import DEFAULT_DIR, enable_compile_cache
+    from flyimg_tpu.parallel.mesh import require_accelerator
 
-    # Backend selection BEFORE any device query. A cpu-only JAX_PLATFORMS
-    # pin boots instantly; ANY selection that includes an accelerator —
-    # pinned or default — must first pass a deadline-bounded compute probe
-    # in a subprocess, because the accelerator transport has a failure
-    # mode where client init succeeds and the first program hangs, which
-    # would wedge boot forever. Probe failure demotes the selection to
-    # CPU fallback, loudly, rather than not serving. Operators who prefer
-    # hanging to degrading set backend_probe_timeout_s: 0.
-    chosen = ensure_live_backend(
-        float(params.by_key("backend_probe_timeout_s", 75.0))
+    # Chip or fail: the backend initialises in process, and a CPU backend
+    # nobody pinned (JAX_PLATFORMS=cpu) is a boot error, not a degraded
+    # mode — JAX falls back to the CPU on its own when accelerator init
+    # fails, and every health surface would then read "ok".
+    device_info = require_accelerator()
+    import logging
+
+    from flyimg_tpu.codecs import native_codec
+
+    # what the native host codec loader found, once: PIL-only serving is
+    # a different host-stage cost under the same stage names
+    host_codec = "native" if native_codec.available() else "pil"
+    logging.getLogger("flyimg.boot").info(
+        "backend %s (%s) x%d, host codec %s",
+        device_info["platform"], device_info["device_kind"],
+        device_info["count"], host_codec,
+        extra={"event": "boot.backend", "host_codec": host_codec,
+               **device_info},
     )
-    if chosen == "cpu-fallback":
-        metrics.counter(
-            "flyimg_boot_backend_fallbacks_total",
-            "Boot-time compute probe failed; serving on CPU",
-        ).inc()
-
     # persistent XLA compilation cache: programs compiled once survive
-    # process restarts, so a redeployed server doesn't pay the 20-40 s
-    # first-compile for every shape bucket again (set to '' to disable).
-    # Best-effort: an unwritable location must not turn an optimization
-    # into a boot failure.
-    cache_dir = params.by_key("compilation_cache_dir", "var/cache/xla")
-    if cache_dir:
-        import logging
-        import os
-
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update(
-                "jax_compilation_cache_dir", os.path.abspath(cache_dir)
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.5
-            )
-        except OSError as exc:
-            logging.getLogger(__name__).warning(
-                "compilation cache disabled (%s unwritable: %s)",
-                cache_dir, exc,
-            )
+    # process restarts (flyimg_tpu/compilecache.py says where it lives)
+    enable_compile_cache(params.by_key("compilation_cache_dir", DEFAULT_DIR))
 
     # with more than one chip, shard every batch over a data-parallel mesh
     # (SPMD fan-out — the v4-8 serving story; parallel/mesh.py). Serving
@@ -1103,7 +1086,11 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
             import jax
 
             devices = [f"{d.platform}:{d.id}" for d in jax.devices()]
-            body = {"status": "ok", "app": app_name, "devices": devices}
+            body = {
+                "status": "ok", "app": app_name, "devices": devices,
+                "device_kind": device_info["device_kind"],
+                "host_codec": host_codec,
+            }
             status = 200
         except Exception as exc:  # device runtime down
             body = {"status": "error", "app": app_name, "error": str(exc)}

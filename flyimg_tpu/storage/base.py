@@ -14,7 +14,7 @@ class _DaemonPool:
     """Reusable daemon worker threads for hedged reads.
 
     Not a ThreadPoolExecutor: its workers are non-daemon and joined at
-    interpreter exit, so one tunnel-hung backend read would block
+    interpreter exit, so one hung backend read would block
     shutdown forever (the same reason the batcher drains on daemon
     threads). Workers here are daemons that park on a shared queue and
     exit after ``idle_timeout_s`` without work — steady-state hedged

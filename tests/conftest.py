@@ -1,8 +1,9 @@
 """Test harness config: force the CPU backend with a virtual 8-device mesh
 so sharding tests run anywhere (the standard fake-mesh trick; see SURVEY.md
-section 4). The order-sensitive recipe lives in one place —
+section 4). The recipe lives in one place —
 ``flyimg_tpu.parallel.mesh.force_cpu_platform`` — shared with the driver
-contract (``__graft_entry__.dryrun_multichip``) and the bench fallback.
+contract (``__graft_entry__.dryrun_multichip``); it also sets the explicit
+``JAX_PLATFORMS=cpu`` pin without which ``make_app`` refuses a CPU backend.
 
 Opt-in lock-order witness (docs/static-analysis.md "Lock-order witness"):
 ``FLYIMG_LOCK_WITNESS=1`` arms ``tools.flylint.witness`` BEFORE any

@@ -807,14 +807,30 @@ def test_bench_history_validate_exit_codes_and_repair(tmp_path):
     assert validate(str(clean)) == 0
 
 
-def test_bench_history_validates_the_real_trajectory():
-    """The repo's actual bench_history.jsonl passes the tolerant schema
-    (the acceptance bar: replay and dashboards can consume the WHOLE
-    trajectory)."""
-    from tools.bench_history import DEFAULT_PATH, validate
+def _bench_row(ts, value, platform="tpu"):
+    """A row in the shape bench.py prints today (stamped with the device
+    it ran on), plus the timestamp a trajectory file adds."""
+    return {
+        "metric": "images/sec/chip resize(300x250 crop-fill)+smart-crop",
+        "value": value, "unit": "images/sec", "vs_baseline": value / 1250.0,
+        "platform": platform, "device_kind": "TPU v5 lite",
+        "device_count": 1, "batch": 256, "scan_len": 10, "launches": 6,
+        "setup_compile_s": 0.5, "kernel": "dense",
+        "peak_rss_bytes": 1 << 30, "ts": ts,
+    }
 
-    assert os.path.exists(DEFAULT_PATH)
-    assert validate(DEFAULT_PATH) == 0
+
+def test_bench_history_validates_rows_in_todays_bench_shape(tmp_path):
+    """No trajectory is tracked any more (the old one held CPU runs under
+    the chip's metric name and went in PR 21); the tolerant schema must
+    still accept what bench.py prints now, device stamp included."""
+    from tools.bench_history import check_row, validate
+
+    rows = [_bench_row(1.0, 17000.0), _bench_row(2.0, 17100.5)]
+    assert all(check_row(row) == [] for row in rows)
+    path = tmp_path / "trajectory.jsonl"
+    path.write_text("".join(json.dumps(row) + "\n" for row in rows))
+    assert validate(str(path)) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -864,13 +880,20 @@ def test_replay_flight_recorder_window_math(tmp_path):
 
 
 def test_replay_e2e_on_real_repo_artifacts(tmp_path):
-    """The acceptance criterion verbatim: the replay tool on the repo's
-    real bench_history.jsonl emits a policy proposal + candidate
-    perf_gate baseline without error."""
+    """The replay tool on a bench trajectory (rows as bench.py prints
+    them) and the repo's real perf baseline emits a policy proposal +
+    candidate perf_gate baseline without error."""
     from tools.autotune_replay import main as replay_main
 
     out_dir = tmp_path / "autotune"
-    assert replay_main(["--out-dir", str(out_dir)]) == 0
+    history = tmp_path / "trajectory.jsonl"
+    history.write_text("".join(
+        json.dumps(_bench_row(float(i), 17000.0 + i)) + "\n"
+        for i in range(3)
+    ))
+    assert replay_main(
+        ["--history", str(history), "--out-dir", str(out_dir)]
+    ) == 0
     proposal = json.loads((out_dir / "proposal.json").read_text())
     assert "proposed_policy" in proposal and "decisions" in proposal
     assert "envelopes" in proposal

@@ -716,3 +716,77 @@ def test_native_cmyk_jpeg_decodes_like_pil():
         for o in outs:
             assert o is not None
             np.testing.assert_array_equal(o, oracle)
+
+
+# ---------------------------------------------------------------------------
+# the loader builds from tracked sources (the binary is not in git)
+
+
+def _private_native_dir(tmp_path, monkeypatch):
+    """A copy of the native sources the loader can build in, with the
+    process-wide load state reset and restored around the test."""
+    import os
+    import shutil
+
+    src = native_codec._DIR
+    dst = tmp_path / "native"
+    dst.mkdir()
+    for name in native_codec._SOURCES:
+        shutil.copy2(os.path.join(src, name), dst / name)
+    monkeypatch.setattr(native_codec, "_DIR", str(dst))
+    monkeypatch.setattr(
+        native_codec, "_LIB_PATH", str(dst / "libfastcodec.so")
+    )
+    monkeypatch.setattr(native_codec, "_lib", None)
+    return dst
+
+
+@pytest.mark.parametrize("found", ["missing", "unloadable", "stale"])
+def test_loader_builds_library_from_tracked_sources(
+    tmp_path, monkeypatch, found
+):
+    """Whatever is found where the library should be — nothing, another
+    installation's binary that will not load here, or a build older than
+    its sources — the loader ends up on a library built from the tracked
+    sources. (One directory per case: dlopen resolves a path it has
+    already loaded to the old handle.)"""
+    import os
+    import shutil
+
+    if not (shutil.which("make") and shutil.which("g++")):
+        pytest.skip("no toolchain")
+    assert native_codec.available()  # the checkout's own library exists
+    built_here = native_codec._LIB_PATH
+    dst = _private_native_dir(tmp_path, monkeypatch)
+    lib = dst / "libfastcodec.so"
+    if found == "unloadable":
+        lib.write_bytes(b"\x7fELF not a library this machine can load")
+        assert not native_codec._stale()  # newer than the sources
+    elif found == "stale":
+        shutil.copy2(built_here, lib)
+        os.utime(lib, (1, 1))
+    if found != "unloadable":
+        assert native_codec._stale()
+    assert native_codec._open_library() is not None
+    assert not native_codec._stale()
+    assert lib.stat().st_size > 10_000 and lib.stat().st_mtime > 1
+    assert native_codec.available()
+
+
+def test_loader_warns_once_when_it_ends_up_on_pil(
+    tmp_path, monkeypatch, caplog
+):
+    import logging
+
+    dst = _private_native_dir(tmp_path, monkeypatch)
+    (dst / "libfastcodec.so").write_bytes(b"not loadable")
+    monkeypatch.setattr(native_codec, "_build", lambda: False)
+    with caplog.at_level(logging.WARNING, logger=native_codec.__name__):
+        assert native_codec.available() is False
+        assert native_codec.available() is False
+    warnings = [
+        r for r in caplog.records if "native host codec unavailable" in
+        r.getMessage()
+    ]
+    assert len(warnings) == 1
+    assert "PIL" in warnings[0].getMessage()

@@ -95,7 +95,7 @@ class _FakeJitted:
 
     def lower(self, *args):
         if self._lower_raises:
-            raise RuntimeError("AOT lowering unsupported here")
+            raise RuntimeError("the compiler refused this program")
         outer = self
 
         class _Lowered:
@@ -111,7 +111,7 @@ def _fresh_handle(jitted, key="k"):
     handle = ProgramHandle.__new__(ProgramHandle)
     handle._jitted = jitted
     handle._compiled = None
-    handle._fallback = False
+    handle.in_sharding = None
     import threading
 
     handle._lock = threading.Lock()
@@ -155,16 +155,19 @@ def test_handle_cost_analysis_raises_yields_nulled_entry_no_crash():
     assert row["flops"] is None and not row["costed"]
 
 
-def test_handle_lowering_failure_falls_back_to_jitted_call():
+def test_handle_compile_failure_propagates():
+    """A program the compiler refuses fails its caller — every call, with
+    no jitted-path retry and no ledger row pretending it compiled."""
     jitted = _FakeJitted(lambda x: x - 1, lower_raises=True)
-    handle = _fresh_handle(jitted, key="fallback")
-    assert handle(10) == 9
-    assert handle.is_compiled  # settled (on the fallback)
-    assert jitted.plain_calls == 1
-    assert handle(11) == 10    # keeps using the jitted path
-    assert jitted.plain_calls == 2
-    row = _ledger_row(handle.ledger_key)
-    assert row["fallback"] is True and row["flops"] is None
+    handle = _fresh_handle(jitted, key="refused")
+    for _ in range(2):
+        with pytest.raises(RuntimeError):
+            handle(10)
+    assert not handle.is_compiled
+    assert jitted.plain_calls == 0
+    assert not [
+        r for r in get_ledger().entries() if r["key"] == handle.ledger_key
+    ]
 
 
 def _ledger_row(key):

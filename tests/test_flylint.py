@@ -995,12 +995,14 @@ def test_appconfig_declares_every_consumed_knob():
     for knob in (
         "decode_batch_max", "decode_deadline_ms", "face_backend",
         "face_checkpoint", "compilation_cache_dir",
-        "backend_probe_timeout_s", "cache_max_bytes",
+        "cache_max_bytes",
         "cache_prune_interval_s", "routes", "gcs", "fault_injector",
         "brownout_clock", "application_name",
     ):
         assert knob in SERVER_DEFAULTS, knob
+    # knobs that went with the code that read them
     assert "device_mesh" not in SERVER_DEFAULTS
+    assert "backend_probe_timeout_s" not in SERVER_DEFAULTS
 
 
 def test_healthz_reports_application_name(tmp_path):

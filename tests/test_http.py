@@ -11,6 +11,8 @@ Local file paths stand in for source URLs exactly as in the reference suite
 """
 
 import asyncio
+import contextlib
+import os
 
 import numpy as np
 import pytest
@@ -307,43 +309,87 @@ def test_route_patterns_config_overridable(tmp_path, source_png):
     assert status == 404
 
 
-def test_compilation_cache_configured(tmp_path):
-    """make_app arms the persistent XLA compilation cache so restarted
-    servers skip recompiles; the dir must be created and jax configured."""
+@contextlib.contextmanager
+def _restoring_jax_cache_config():
+    """make_app / enable_compile_cache mutate process-global jax config;
+    restore it so later tests don't write cache artifacts elsewhere."""
     import jax
 
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+    )
+    saved = {name: getattr(jax.config, name) for name in names}
+    try:
+        yield
+    finally:
+        for name, value in saved.items():
+            jax.config.update(name, value)
+
+
+def test_compilation_cache_env_var_wins_and_code_sets_no_dir(
+    tmp_path, monkeypatch
+):
+    """Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself: the
+    helper reports it and sets no directory in code — whatever the knob
+    says, '' included."""
+    import jax
+
+    from flyimg_tpu import compilecache
+
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, value: (updates.append(name), real_update(name, value)),
+    )
+    with _restoring_jax_cache_config():
+        for knob in ("var/cache/xla", str(tmp_path / "knob"), ""):
+            assert compilecache.compile_cache_dir(knob) == placed
+            assert compilecache.enable_compile_cache(knob) == placed
+        assert "jax_compilation_cache_dir" not in updates
+    assert not (tmp_path / "knob").exists()
+
+
+def test_compilation_cache_default_is_the_checkout_whatever_the_cwd(
+    tmp_path, monkeypatch
+):
+    """Unset, the cache is <checkout>/var/cache/xla resolved from the
+    package, not from the current directory; make_app arms exactly that.
+    An absolute knob is honored, '' disables."""
+    import jax
+
+    from flyimg_tpu import compilecache
     from flyimg_tpu.appconfig import AppParameters
     from flyimg_tpu.service.app import make_app
 
-    cache_dir = tmp_path / "xla-cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    expected = os.path.join(repo, "var", "cache", "xla")
+    assert compilecache.compile_cache_dir() == expected
+    assert compilecache.compile_cache_dir("") is None
+    elsewhere = str(tmp_path / "xla-cache")
+    assert compilecache.compile_cache_dir(elsewhere) == elsewhere
     params = AppParameters(
-        {
-            "upload_dir": str(tmp_path / "u"),
-            "tmp_dir": str(tmp_path / "t"),
-            "compilation_cache_dir": str(cache_dir),
-        }
+        {"upload_dir": str(tmp_path / "u"), "tmp_dir": str(tmp_path / "t")}
     )
-    # make_app mutates process-global jax config; restore it so later tests
-    # in this process don't silently write cache artifacts into tmp_path
-    saved = {
-        name: getattr(jax.config, name)
-        for name in (
-            "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-        )
-    }
-    app = make_app(params)
-    try:
-        assert cache_dir.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-    finally:
-        async def cleanup():
-            for cb in app.on_cleanup:
-                await cb(app)
+    with _restoring_jax_cache_config():
+        app = make_app(params)
+        try:
+            assert jax.config.jax_compilation_cache_dir == expected
+            assert not (tmp_path / "var").exists()
+        finally:
+            async def cleanup():
+                for cb in app.on_cleanup:
+                    await cb(app)
 
-        _run(cleanup())
-        for name, value in saved.items():
-            jax.config.update(name, value)
+            _run(cleanup())
+        assert compilecache.enable_compile_cache(elsewhere) == elsewhere
+        assert os.path.isdir(elsewhere)
+        assert jax.config.jax_compilation_cache_dir == elsewhere
 
 
 def test_refresh_mints_new_etag(tmp_path, source_png):

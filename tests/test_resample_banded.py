@@ -166,7 +166,7 @@ def test_banded_matches_dense_across_option_matrix(
 
 
 def test_dense_default_is_byte_stable_behind_the_knob():
-    """``resample_kernel: dense`` (the default until BENCH_r06 confirms)
+    """``resample_kernel: dense`` (the default until a chip measurement decides)
     reproduces the pre-banded outputs byte-for-byte: flipping the knob to
     banded and back must leave the dense render untouched."""
     assert AppParameters().by_key("resample_kernel") == "dense"

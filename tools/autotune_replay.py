@@ -7,17 +7,17 @@ online tuner runs (``flyimg_tpu/runtime/autotuner.py`` — pure,
 clock-free, deterministic), so the proposals here are exactly the
 adjustments a live process would have made on that traffic:
 
-    python -m tools.autotune_replay                       # bench history
+    python -m tools.autotune_replay --history rows.jsonl  # bench rows
     python -m tools.autotune_replay --flightrecorder dump.json
     python -m tools.autotune_replay --telemetry var/tmp/telemetry
     python -m tools.autotune_replay --out-dir /tmp/autotune
 
 Inputs:
 
-- ``benchmarks/bench_history.jsonl`` (default): rows are loaded through
-  the tolerant trajectory schema (``tools/bench_history.py`` — the
-  heterogeneous pre-PR-8/10/11 rows validate and repair instead of
-  crashing the replay). Rows that embed ``batch_efficiency`` columns
+- a bench trajectory (``--history``, one bench JSON row per line; none
+  is tracked in the repo, so the default path only exists if you keep
+  one there): rows are loaded through the tolerant trajectory schema
+  (``tools/bench_history.py``). Rows that embed ``batch_efficiency`` columns
   (bench_http rows, PR 7+) drive controller decisions directly;
   headline-only rows contribute to the throughput trend.
 - a flight-recorder dump (``--flightrecorder``): per-launch records are

@@ -1,12 +1,13 @@
 """bench_history.jsonl validation: a tolerant schema for a heterogeneous
 trajectory (ISSUE 14 satellite; docs/autotuning.md "Offline replay").
 
-``benchmarks/bench_history.jsonl`` accumulates one JSON line per bench
-run across the repo's whole history — which means rows from different
-eras carry different columns: pre-PR-8 rows have no ``kernel`` tag,
-pre-PR-10 rows no ``reuse_enable``, pre-PR-11 rows no decode-mode
-columns, and supervisor failure rows carry ``error`` with a null
-``value``. Anything consuming the WHOLE trajectory (the autotuner's
+A bench trajectory holds one JSON line per bench run (``bench.py`` /
+``tools/bench_http.py`` rows plus a ``ts``); none is tracked in the repo
+since PR 21, so pass ``--path``. Rows from different eras carry different
+columns: old rows have no ``kernel`` tag, no ``reuse_enable``, no
+decode-mode columns, ``backend`` where newer ones stamp ``platform`` /
+``device_kind`` / ``device_count``, and failure rows carry ``error``
+with a null ``value``. Anything consuming a WHOLE trajectory (the autotuner's
 offline replay, future dashboards) needs one contract for what a row
 may look like; this tool is that contract, machine-checked:
 

@@ -992,7 +992,7 @@ async def _fleet_ab(args) -> int:
 async def _scrape_observability(client: httpx.AsyncClient, base: str):
     """End-of-run attribution scrape: batch efficiency (/debug/perf),
     the per-plan cost ledger (/debug/plans), and the flight-recorder
-    summary (/debug/flightrecorder) — so BENCH_r06+ artifacts carry
+    summary (/debug/flightrecorder) — so bench artifacts carry
     per-plan FLOP/byte/occupancy attribution next to throughput, not
     just throughput. Returns None per section when the target serves
     404 (debug off — e.g. --base against a production config)."""
@@ -1595,7 +1595,7 @@ async def main() -> int:
 
             # end-of-run attribution: batch efficiency + per-plan cost +
             # flight-recorder summary embedded in every row (and the
-            # sweep artifact), so BENCH_r06+ carries attribution, not
+            # sweep artifact), so the artifact carries attribution, not
             # just throughput. None sections = target served 404
             # (debug off).
             # the control-plane timeline rides every row next to the

@@ -17,7 +17,8 @@ host-side rate of one core running both stages is 1/(1/dec + 1/enc) and
 host rate scales ~linearly with cores (the native pool decodes and
 encodes without the GIL).
 
-Usage: python tools/e2e_budget.py [--out benchmarks/e2e_budget_r5.json]
+Usage: python tools/e2e_budget.py --device-rate N --device-rate-source S
+           [--out benchmarks/e2e_budget_r5.json]
 """
 
 from __future__ import annotations
@@ -37,20 +38,21 @@ def load(rel):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="benchmarks/e2e_budget_r5.json")
+    ap.add_argument(
+        "--device-rate", type=float, required=True,
+        help="kernel-only img/s/chip of the flagship program, from a "
+             "PERF_LEDGER.jsonl row (no device record is tracked here)",
+    )
+    ap.add_argument(
+        "--device-rate-source", required=True,
+        help="where --device-rate comes from, e.g. 'ledger, PR 22'",
+    )
     args = ap.parse_args()
 
     codec = load("benchmarks/host_codec_r5.json")
     host = {r["op"]: r.get("images_per_sec") for r in codec["results"]}
-    # prefer a round-5 driver/manual device number when captured; fall back
-    # to the round-4 manual capture (same program, same methodology)
-    try:
-        device_rate = load("benchmarks/bench_tpu_r5_manual.json")[
-            "runs"][-1]["line"]["value"]
-        device_src = "bench_tpu_r5_manual.json"
-    except (OSError, KeyError):
-        device_rate = load("benchmarks/bench_tpu_r4_manual.json")[
-            "runs"][-1]["line"]["value"]
-        device_src = "bench_tpu_r4_manual.json"
+    device_rate = args.device_rate
+    device_src = args.device_rate_source
 
     # serving shape: decode the 512^2 source, encode the 300x250 output
     dec = host["jpeg_decode_512_1thread"]

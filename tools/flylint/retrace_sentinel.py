@@ -295,8 +295,8 @@ def install(budget: Optional[int] = None) -> RetraceSentinel:
     _REAL_INIT = ProgramHandle.__init__
     _REAL_COMPILE = ProgramHandle._compile
 
-    def __init__(self, jitted, key, descriptor):  # noqa: N807
-        _REAL_INIT(self, jitted, key, descriptor)
+    def __init__(self, jitted, key, descriptor, **kwargs):  # noqa: N807
+        _REAL_INIT(self, jitted, key, descriptor, **kwargs)
         sentinel.note_handle(self, key)
 
     def _compile(self, args):

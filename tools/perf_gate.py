@@ -158,10 +158,6 @@ def measure(repeats: int = 30, warmup: int = 3,
     programs coexist and each leg's cost snapshot diffs only its own
     newly-compiled programs. Import-heavy work happens here so --help
     stays instant."""
-    from flyimg_tpu.parallel.mesh import ensure_env_platform
-
-    ensure_env_platform()
-
     from flyimg_tpu.ops.resample import kernel_mode, set_kernel_mode
 
     prev_kernel = kernel_mode()

@@ -74,10 +74,10 @@ async def main() -> int:
         )
 
     # the scripted outage: while `storm` holds, every device readback
-    # raises a transient transport error (the dying-tunnel signature);
+    # raises a transient transport error (a dying device's signature);
     # while `dead` holds, every backend probe reports the device gone.
     # Clearing `storm` models "the device is unreachable, CPU serves";
-    # clearing `dead` models "tunnel restored".
+    # clearing `dead` models "the device is back".
     storm = {"on": False}
     dead = {"on": True}
     injector = faults.FaultInjector()
