@@ -15,11 +15,14 @@ touches the network. Everything it writes goes under
 ``chiprun_out/chip_smoke/`` next to this file (server logs, params,
 responses, ``report.json``).
 
-Exit code 0 and a last stdout line ``{"ok": true, "device": {...}, ...}``
-only when every check passed on an accelerator. Any failed check — the
-device check included, so also every run pinned with ``JAX_PLATFORMS=cpu``,
-which runs the same steps for debugging — exits 1, writes the report to
-stderr and ``report.json``, and prints the reason last. No other switch.
+Exit code 0 only when every check passed on an accelerator; stdout is then
+two JSON lines: the full report (versions, per-check results, programs
+compiled, set-up seconds, cache path), and last, exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Any failed check — the device check included, so also every run pinned with
+``JAX_PLATFORMS=cpu``, which runs the same steps for debugging — exits 1,
+writes the report to stderr and ``report.json``, and prints the reason last.
+No other switch.
 """
 
 from __future__ import annotations
@@ -511,6 +514,16 @@ def versions() -> dict:
     return found
 
 
+def result_line(device: dict) -> str:
+    """The last stdout line of a passing run: these keys and no others
+    (the full report is the line before it and ``report.json``)."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]),
+        "kind": str(device["kind"]),
+        "count": int(device["count"]),
+    }})
+
+
 def main() -> int:
     if not os.path.isdir(os.path.join(REPO, "flyimg_tpu")):
         print("chip_smoke: FAILED: the flyimg_tpu package is not next to "
@@ -571,11 +584,12 @@ def main() -> int:
                    "failures": report.reasons}, fh, indent=1)
         fh.write("\n")
     if ok:
-        print(json.dumps(line), flush=True)
+        print(json.dumps(line))
+        print(result_line(device), flush=True)
         return 0
     # no result on stdout: the report and the reason go to stderr
     print(json.dumps(line), file=sys.stderr)
-    print("chip_smoke: FAILED: " + "; ".join(report.reasons),
+    print("chip_smoke: FAILED: " + "; ".join(report.reasons).rstrip(),
           file=sys.stderr, flush=True)
     return 1
 

@@ -55,6 +55,26 @@ def test_chip_smoke_under_cpu_pin_fails_only_its_device_check():
     )
 
 
+def test_result_line_holds_the_contract_keys_and_no_others():
+    """The last stdout line of a passing run is read by the driver: exactly
+    ``ok`` and ``device`` with ``platform``, ``kind`` (text) and ``count``
+    (a whole number). The full report is the line before it."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    assert "jax" not in chip_smoke.__dict__  # the parent never imports jax
+    doc = json.loads(chip_smoke.result_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    ))
+    assert doc == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    assert isinstance(doc["device"]["count"], int)
+
+
 def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     """The script without the program next to it exits non-zero and
     prints no result."""
