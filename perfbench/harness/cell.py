@@ -1,0 +1,396 @@
+"""One run of one cell: set-up, pre-roll, the measured window, the drain,
+the comparison with the reference, and the result line."""
+
+from __future__ import annotations
+
+import copy
+import hashlib
+import json
+import os
+import re
+import resource
+import shutil
+import statistics
+import sys
+import tempfile
+import threading
+import time
+from typing import Any, Dict, List, Optional, Tuple
+
+from . import compare, corpus as corpus_mod, manifest as mf, trace as trace_mod, work
+from .traffic import ClosedLoop
+
+HOST_KEEP = re.compile(r"^flyimg:batch:")
+
+
+def log(*parts: Any) -> None:
+    print(*parts, file=sys.stderr, flush=True)
+
+
+def apply_toy(config: Dict[str, Any], mix: Optional[Dict[str, Any]] = None) -> None:
+    """The configuration's toy size, for rehearsals and tests on the CPU."""
+    toy = config["toy"]
+    config["frame"], config["corpus"] = toy["frame"], toy["corpus"]
+    config["options"] = toy["options"]
+    config["parameters"] = toy.get("parameters", config.get("parameters"))
+    for key in ("in_flight", "preroll_images", "warm_launch_sizes"):
+        if mix is not None:
+            mix[key] = toy[key]
+
+
+def rss_bytes() -> int:
+    """Resident set of this process now (``/proc/self/statm``)."""
+    try:
+        with open("/proc/self/statm", "r", encoding="ascii") as fh:
+            return int(fh.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except (OSError, ValueError, IndexError):
+        return 0
+
+
+class RssWatch(threading.Thread):
+    """Samples the resident set every few seconds; the cell holds tens of
+    GB of decoded frames and assembled launches on the host, and the machine
+    ends a process that passes its limit."""
+
+    def __init__(self, every: float = 2.0) -> None:
+        super().__init__(name="bench-rss", daemon=True)
+        self.every, self.samples, self._halt = every, [], threading.Event()
+        self._t0 = time.perf_counter()
+
+    def run(self) -> None:
+        while not self._halt.wait(self.every):
+            self.samples.append((round(time.perf_counter() - self._t0, 1),
+                                 round(rss_bytes() / 2**30, 2)))
+
+    def stop(self) -> None:
+        self._halt.set()
+
+
+def trace_one_launch(loop: ClosedLoop, t_burst: float, cycle: float, t_close: float,
+                     mix: Dict[str, Any]) -> Tuple[str, float]:
+    """Put the profiler around the execution of one full launch and keep it
+    off the launch's staging: with the profiler on, every second of staging a
+    4.7 GB launch costs 0.27 GiB of host memory that is not given back, and
+    a trace through a whole staging ends the process at the machine's limit
+    (PERF.md section 6). Placed by the harness's own clock alone: the callers
+    move in step with the launches, so the next launch is read back one
+    pre-roll cycle after the pre-roll's burst of answers (``t_burst``), and
+    ``trace_at_cycle_share`` of a cycle after that burst it is being staged.
+    ``start_trace`` called then returns only when the staging has ended; the
+    slice ends on the first answer after that (the launch has run and been
+    read back), or after half a cycle. Returns the trace's directory and the
+    slice's seconds."""
+    import jax
+
+    at = min(t_burst + float(mix["trace_at_cycle_share"]) * cycle, t_close - 2.0)
+    cap = 0.5 * cycle
+    time.sleep(max(at - time.perf_counter(), 0.0))
+    trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    t_call = time.perf_counter()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    t_on = time.perf_counter()
+    until = min(t_on + cap, max(t_close - 1.0, t_on))
+    answered = loop.wait_answer_after(t_on, until - t_on)
+    if answered is not None:
+        time.sleep(min(0.5, max(until - time.perf_counter(), 0.0)))  # the read-back's own tail
+    slice_s = time.perf_counter() - t_on
+    jax.profiler.stop_trace()
+    log(f"# trace: cycle of the pre-roll {cycle:.2f} s; start_trace called {t_call - t_burst:.1f} s after its burst, "
+        f"returned after {t_on - t_call:.1f} s; {slice_s:.2f} s traced until "
+        f"{'the first answer after it' if answered is not None else 'the cap (no answer came)'}; "
+        f"stopped in {time.perf_counter() - t_on - slice_s:.1f} s")
+    return trace_dir, slice_s
+
+
+def trim_heap() -> None:
+    """Hand the allocator's free memory back to the machine. Seven compiles
+    at once leave several GB in glibc's arenas, which a cold run would
+    otherwise carry through its window beside 64 decoded 24 MP frames."""
+    try:
+        import ctypes
+
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except (OSError, AttributeError):
+        pass
+
+
+def cpu_seconds() -> float:
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return usage.ru_utime + usage.ru_stime
+
+
+def launch_sizes(before: Dict[str, float], after: Dict[str, float]) -> Dict[str, int]:
+    """Launches of each padded size in the window, from the program's
+    ``flyimg_batch_bucket_size`` histogram of the device controller."""
+    rx = re.compile(r'^flyimg_batch_bucket_size_bucket\{(.*)\}$')
+    cumulative: List[Tuple[float, float]] = []
+    for key, value in after.items():
+        m = rx.match(key)
+        if not m or 'controller="device"' not in m.group(1):
+            continue
+        le = re.search(r'le="([^"]+)"', m.group(1))
+        if le and le.group(1) != "+Inf":
+            cumulative.append((float(le.group(1)), value - before.get(key, 0.0)))
+    sizes: Dict[str, int] = {}
+    last = 0.0
+    for bound, count in sorted(cumulative):
+        if count - last > 0:
+            sizes[str(int(bound))] = int(round(count - last))
+        last = count
+    return sizes
+
+
+def window_timers(before: Dict[str, float], after: Dict[str, float]) -> Dict[str, List[float]]:
+    """Every histogram of seconds the program keeps, as ``[count, summed
+    seconds]`` over the window."""
+    out: Dict[str, List[float]] = {}
+    for key, value in after.items():
+        if "_seconds_sum" not in key:
+            continue
+        count_key = key.replace("_sum", "_count", 1)
+        n = after.get(count_key, 0.0) - before.get(count_key, 0.0)
+        if n > 0:
+            out[key.replace("_sum", "", 1)] = [int(n), value - before.get(key, 0.0)]
+    return out
+
+
+def device_report(planes: List[Dict[str, Any]], slice_s: float, window_s: float,
+                  timers: Dict[str, List[float]]) -> Tuple[float, Dict[str, Any]]:
+    """``busy_s`` and the ``breakdown`` of a traced run. The profiler ran for
+    a slice of the window around one launch's execution
+    (``trace_one_launch``), so the busy seconds are those of the slice: a
+    lower bound on the window's where it holds a second launch. The idle
+    seconds are the window's: the part the profiler was off for, the slice's
+    own gaps, and then what the host was doing meanwhile, by the program's
+    own timers summed over the window."""
+    dev_planes = trace_mod.device_planes(planes)
+    busy = sum(trace_mod.busy_seconds(p) for p in dev_planes) / len(dev_planes)
+    host_marks = [e for p in planes if p not in dev_planes
+                  for line in p["lines"] for e in line["events"]]
+    in_slice = trace_mod.attribute_gaps(
+        trace_mod.idle_gaps(dev_planes[0]), host_marks,
+        "traced slice, between device ops: host inside a flyimg:batch dispatch",
+        "traced slice, between device ops: host between dispatches")[:2]
+    between = sum(seconds for _, seconds in in_slice)
+    idle = [["window outside the traced slice (profiler off, PERF.md section 5)",
+             window_s - slice_s],
+            ["traced slice, before the first and after the last device op",
+             slice_s - busy - between]] + in_slice
+    idle += [[f"program timer over the window's {n} call(s): {key}", seconds]
+             for key, (n, seconds) in sorted(timers.items(), key=lambda kv: -kv[1][1])]
+    return busy, {"device_ops": trace_mod.top_ops(dev_planes), "idle_gaps": idle[:10]}
+
+
+def percentile(values: List[float], q: float) -> float:
+    ordered = sorted(values)
+    if not ordered:
+        raise ValueError("no values")
+    pos = q * (len(ordered) - 1)
+    lo = int(pos)
+    hi = min(lo + 1, len(ordered) - 1)
+    return ordered[lo] + (ordered[hi] - ordered[lo]) * (pos - lo)
+
+
+def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
+             traced: bool, *, t_process: float, toy: bool = False,
+             require_chip: bool = True) -> Dict[str, Any]:
+    from . import system as system_mod
+
+    cell = mf.workload(manifest, name)
+    config = copy.deepcopy(mf.load_config(manifest, cell["config"]))
+    mix = copy.deepcopy(mf.load_traffic(cell["traffic"]))
+    if toy:
+        apply_toy(config, mix)
+    device = system_mod.device_info(cell["chips"], require_chip)
+    on_chip = device["platform"] != "cpu"
+    phases: Dict[str, float] = {"import_and_backend": time.perf_counter() - t_process}
+
+    # -- set-up: corpus on threads while the programs compile or load -------
+    frame = config["frame"]
+    made: Dict[str, Any] = {}
+    watch = RssWatch()
+    watch.start()
+
+    def build_corpus() -> None:
+        t = time.perf_counter()
+        made["corpus"] = corpus_mod.make_corpus(
+            seed, frame, int(config["corpus"]["images"]))
+        made["seconds"] = time.perf_counter() - t
+
+    builder = threading.Thread(target=build_corpus, name="bench-corpus")
+    builder.start()
+    t = time.perf_counter()
+    sut = system_mod.System(config)
+    warmed = sut.warm_programs(frame["width"], frame["height"], mix["warm_launch_sizes"])
+    phases["warm_programs"] = time.perf_counter() - t
+    trim_heap()
+    compiles_in_warm = sut.compiles.count
+    builder.join()
+    corpus = made["corpus"]
+    phases["corpus"] = made["seconds"]
+    log(f"# set-up: corpus {len(corpus)} x {frame['width']}x{frame['height']} "
+        f"({sum(map(len, corpus)) / 1e6:.1f} MB) in {made['seconds']:.1f} s; programs "
+        f"{warmed['in_shape']}->{warmed['resample_out']} sizes {mix['warm_launch_sizes']} in "
+        f"{phases['warm_programs']:.1f} s ({compiles_in_warm} built, {sut.compiles.hits} of them read "
+        f"from the cache {sut.cache_dir})")
+    log("# warm seconds by launch size:", json.dumps(warmed["seconds"]))
+
+    # -- the loop ---------------------------------------------------------------
+    answers: Dict[Tuple[int, str], bytes] = {}
+    answers_lock = threading.Lock()
+
+    def call(item: int):
+        out, timings = sut.transform(corpus[item])
+        digest = hashlib.blake2b(out, digest_size=8).hexdigest()
+        with answers_lock:
+            answers.setdefault((item, digest), out)
+        return timings, digest
+
+    loop = ClosedLoop(mix, len(corpus), seed, call)
+    t = time.perf_counter()
+    loop.start()
+    # the pre-roll ends on the answer that completes it: the same amount of
+    # work from the seed in every run. The callers move in step with a launch,
+    # so that answer is one of a launch's burst; the window opens a fixed
+    # share of the cycle later, between bursts, so that no burst (and no
+    # straggler of the pre-roll's) straddles the window's edge
+    t_burst = loop.wait_completed(int(mix["preroll_images"]),
+                                  float(mix.get("preroll_timeout_seconds", 900)))
+    cycle = t_burst - t
+    t_open = t_burst + float(mix["window_opens_cycle_share"]) * cycle
+    time.sleep(max(t_open - time.perf_counter(), 0.0))
+    t_open = time.perf_counter()
+    phases["preroll"] = t_open - t
+    compiles_in_preroll = sut.compiles.count - compiles_in_warm
+    counters_before, cpu_before = sut.counters(), cpu_seconds()
+    compiles_before = sut.compiles.count
+    setup_s = t_open - t_process
+    trace_dir = slice_s = None
+    if traced:
+        trace_dir, slice_s = trace_one_launch(loop, t_burst, cycle, t_open + seconds, mix)
+    time.sleep(max(t_open + seconds - time.perf_counter(), 0.0))
+    t_close = time.perf_counter()
+    counters_after, cpu_after = sut.counters(), cpu_seconds()
+    compiles_in_window = sut.compiles.count - compiles_before
+    loop.stop()
+    unanswered = loop.drain(float(mix["drain_seconds"]))
+    answered = loop.window(t_open, t_close)
+    peak = system_mod.memory_peak_bytes()
+    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    late = [r for r in loop.all_records() if r.done > t_close]
+    sut.close()
+    watch.stop()
+    log("# host RSS GiB by second since set-up began:",
+        " ".join(f"{t:.0f}:{g}" for t, g in watch.samples))
+
+    done = [r for r in answered if r.ok]
+    failed = [r for r in answered if not r.ok]
+    for r in failed[:5]:
+        log("# failed:", r.error)
+    sizes = launch_sizes(counters_before, counters_after)
+    wedged = counters_after.get("flyimg_wedged_fallbacks_total", 0.0) - \
+        counters_before.get("flyimg_wedged_fallbacks_total", 0.0)
+    log(f"# launches in the window by padded size: {json.dumps(sizes)}; programs built in warm-up "
+        f"{compiles_in_warm}, pre-roll {compiles_in_preroll}, window {compiles_in_window}; "
+        f"wedged fallbacks in window {wedged:.0f}")
+    log(f"# process peak RSS {rss / 2**30:.2f} GiB; device peak "
+        f"{(peak or 0) / 2**30:.2f} GiB; answered after the window closed: {len(late)}; "
+        f"never answered: {unanswered}; set-up phases {json.dumps({k: round(v, 2) for k, v in phases.items()})}")
+
+    timers = window_timers(counters_before, counters_after)
+    log("# program timers in the window [count, summed seconds]:",
+        json.dumps({k: [n, round(v, 3)] for k, (n, v) in timers.items()}))
+    if done:
+        keys = sorted({k for r in done for k in r.info[0]})
+        log("# mean timings per image in the window, ms:", json.dumps({
+            k: round(1000 * statistics.fmean(r.info[0][k] for r in done if k in r.info[0]), 1)
+            for k in keys}))
+
+    # -- metrics ------------------------------------------------------------------
+    values: Dict[str, Optional[float]] = {}
+    breakdown = None
+    device_out = {"platform": device["platform"], "kind": device["kind"],
+                  "count": device["count"], "memory_peak_bytes": peak}
+    if not traced:
+        # what the harness can time itself; a cell reports those of them
+        # that the manifest lists for it
+        measured = {
+            "images_per_s": len(done) / seconds,
+            "latency_p95_ms": (1000.0 * percentile([r.done - r.sent for r in done], 0.95)
+                               if done else None),
+            "setup_s": setup_s,
+        }
+        for metric in mf.metrics_for(manifest, name, "end_to_end"):
+            values[metric["name"]] = measured[metric["name"]]
+        log(f"# in the window: {len(done)} images answered, "
+            f"{measured['images_per_s']:.3f} img/s (not an end-to-end metric: PERF.md section 2)")
+    else:
+        planes: List[Dict[str, Any]] = []
+        xplane = trace_mod.find_xplane(trace_dir) if trace_dir else None
+        if xplane:
+            planes = trace_mod.load_xplane(xplane, HOST_KEEP)
+        if trace_dir:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+        from . import reference
+
+        opts = reference.parse_options(config["options"]["url"])
+        geo = reference.geometry(opts, frame["width"], frame["height"])
+        rw, rh = geo["resize"]
+        out_w, out_h = geo["cols"][1] - geo["cols"][0], geo["rows"][1] - geo["rows"][0]
+        ctx: Dict[str, Any] = {
+            "counters_before": counters_before, "counters_after": counters_after,
+            "timings": [r.info[0] for r in done], "images": len(done),
+            "cpu_before": cpu_before, "cpu_after": cpu_after,
+            "trace_planes": planes, "trace_slice_s": slice_s, "launch_sizes": sizes,
+            "device": device,
+            "work_per_image": work.resize_work(
+                frame["width"], frame["height"], frame["width"] * out_w / rw,
+                frame["height"] * out_h / rh, out_w, out_h),
+        }
+        for metric in mf.metrics_for(manifest, name, "per_layer"):
+            spec = mf.load_metric(metric["name"])
+            if metric["source"] == "device_trace" and not on_chip:
+                continue  # a CPU run never prints a device metric
+            values[metric["name"]] = mf.load_reader(spec["reader"])(ctx, **spec["args"])
+        dev_planes = trace_mod.device_planes(planes)
+        if dev_planes and on_chip:
+            window_s = t_close - t_open
+            busy, breakdown = device_report(planes, slice_s, window_s, timers)
+            device_out["busy_s"], device_out["window_s"] = busy, window_s
+            log(f"# trace: device busy {busy:.4f} s of the {slice_s:.2f} s slice; "
+                f"window {window_s:.2f} s")
+        if ctx.get("notes"):
+            log("# notes:", json.dumps(ctx["notes"]))
+
+    units = {m["name"]: m["unit"] for kind in ("end_to_end", "per_layer") for m in manifest[kind]}
+    metrics = {k: {"value": v, "unit": units[k]} for k, v in values.items() if v is not None}
+
+    # -- correct: after the window, the memory reading and the program's close --
+    t = time.perf_counter()
+    verdict = compare.Judge(config, corpus).judge(answers, unanswered)
+    numbers = verdict["numbers"]
+    numbers["compiles_in_window"] = {"value": float(compiles_in_window), "limit": 0.0}
+    numbers["wedged_fallbacks"] = {"value": float(wedged), "limit": 0.0}
+    correct = all(n["value"] <= n["limit"] for n in numbers.values())
+    log(f"# reference: {verdict['answers']} distinct answers of {len(corpus)} originals "
+        f"judged in {time.perf_counter() - t:.1f} s; rms_err {verdict['rms_err_not_compared']:.4g} (not compared)")
+    log("# compared:", compare.format_numbers(numbers))
+
+    result: Dict[str, Any] = {
+        "correct": bool(correct),
+        "attempted": len(done) + len(failed),
+        "failed": len(failed),
+        "metrics": metrics,
+        "device": device_out,
+    }
+    if breakdown is not None:
+        result["breakdown"] = breakdown
+    result["workload"] = name
+    result["seed"] = seed
+    result["launch_sizes"] = sizes
+    result["host_peak_rss_bytes"] = rss
+    result["compared"] = numbers
+    return result

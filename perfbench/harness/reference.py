@@ -1,0 +1,164 @@
+"""The plain reference: the same semantics, written down independently.
+
+It imports nothing of the program and takes nothing the program has made.
+Pillow decodes and encodes; everything between is numpy in float32 (weights
+are worked out in float64). The semantics are those of the flyimg URL options
+the configurations use (docs/url-options.md, after ImageMagick):
+
+``w_,h_,c_1``  ``-thumbnail WxH^ -gravity Center -extent WxH``: scale both axes
+               so the frame covers the box (each rounded to the nearest pixel),
+               then cut the box out of the middle.
+
+Resizing is ImageMagick's Lanczos: output pixel ``i`` samples the source at
+``(i + 0.5) * scale - 0.5``, the three-lobe kernel is stretched by the
+downscale factor, taps outside the frame are dropped and the rest renormalised.
+
+``operands`` lowers the precision for the control: the pixels, the weights and
+the intermediate between the two passes are rounded to that type before each
+multiplication, as a kernel with operands of that type would hold them, and
+sums stay in float32.
+"""
+
+from __future__ import annotations
+
+import io
+import math
+from typing import Any, Callable, Dict, Optional, Tuple
+
+import numpy as np
+from PIL import Image
+
+def _quantiser(operands: str) -> Callable[[np.ndarray, bool], np.ndarray]:
+    """``q(array, is_weight)``: the array as a kernel with operands of this
+    type would hold it, returned as float32."""
+    if operands == "float32":
+        return lambda a, is_weight=False: a
+    if operands == "int8":
+        # symmetric per-tensor scaling, as an int8 matmul would be fed: the
+        # weights scaled to +-127 at their largest, the pixels centred on 128
+        def q_int8(a: np.ndarray, is_weight: bool = False) -> np.ndarray:
+            if is_weight:
+                scale = 127.0 / max(float(np.abs(a).max()), 1e-30)
+                return (np.round(a * scale) / scale).astype(np.float32)
+            return np.clip(np.round(a - 128.0), -128, 127).astype(np.float32) + 128.0
+        return q_int8
+    import ml_dtypes
+
+    low = {"bfloat16": ml_dtypes.bfloat16,
+           "float8_e4m3fn": ml_dtypes.float8_e4m3fn}[operands]
+    return lambda a, is_weight=False: a.astype(low).astype(np.float32)
+
+
+def decode(data: bytes) -> np.ndarray:
+    with Image.open(io.BytesIO(data)) as im:
+        return np.asarray(im.convert("RGB"))
+
+
+def encode_jpeg(rgb: np.ndarray, quality: int = 90) -> bytes:
+    """The reference's own encoder (libjpeg through Pillow, 4:4:4)."""
+    buf = io.BytesIO()
+    Image.fromarray(rgb).save(buf, format="JPEG", quality=quality, subsampling=0)
+    return buf.getvalue()
+
+
+def _lanczos3(x: np.ndarray) -> np.ndarray:
+    return np.where(np.abs(x) < 3.0, np.sinc(x) * np.sinc(x / 3.0), 0.0)
+
+
+def axis_taps(in_size: int, out_size: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Tap indices ``[out, K]`` and float64 weights ``[out, K]`` that take an
+    axis of ``in_size`` samples to ``out_size`` (the whole axis to the whole
+    axis; a crop takes rows of the result)."""
+    scale = in_size / out_size
+    stretch = max(scale, 1.0)
+    x = (np.arange(out_size, dtype=np.float64) + 0.5) * scale - 0.5
+    x = np.clip(x, 0.0, in_size - 1.0)
+    taps = 2 * int(math.ceil(3.0 * stretch)) + 2
+    first = np.floor(x).astype(np.int64) - taps // 2 + 1
+    idx = first[:, None] + np.arange(taps)[None, :]
+    w = _lanczos3((idx - x[:, None]) / stretch)
+    w[(idx < 0) | (idx >= in_size)] = 0.0
+    w /= w.sum(axis=1, keepdims=True)
+    return np.clip(idx, 0, in_size - 1), w
+
+
+def _apply(idx: np.ndarray, w: np.ndarray, a: np.ndarray, q, block: int = 64) -> np.ndarray:
+    """``out[i] = sum_k w[i, k] * a[idx[i, k]]`` over the first axis of the
+    2-D ``a``, a block of output rows at a time as one dense product."""
+    out = np.empty((idx.shape[0], a.shape[1]), dtype=np.float32)
+    for start in range(0, idx.shape[0], block):
+        rows = slice(start, min(start + block, idx.shape[0]))
+        lo, hi = int(idx[rows].min()), int(idx[rows].max()) + 1
+        dense = np.zeros((rows.stop - rows.start, hi - lo), dtype=np.float64)
+        np.add.at(dense, (np.arange(rows.stop - rows.start)[:, None], idx[rows] - lo), w[rows])
+        out[rows] = q(dense.astype(np.float32), True) @ a[lo:hi]
+    return out
+
+
+def resize(rgb: np.ndarray, out_w: int, out_h: int,
+           rows: Optional[Tuple[int, int]] = None,
+           cols: Optional[Tuple[int, int]] = None,
+           operands: str = "float32") -> np.ndarray:
+    """Lanczos-resize ``[h, w, 3]`` uint8 to ``out_w x out_h`` and return the
+    rows ``rows`` and columns ``cols`` of it as float32, unrounded."""
+    q = _quantiser(operands)
+    h, w = rgb.shape[:2]
+    iy, wy = axis_taps(h, out_h)
+    ix, wx = axis_taps(w, out_w)
+    if rows is not None:
+        iy, wy = iy[rows[0]:rows[1]], wy[rows[0]:rows[1]]
+    if cols is not None:
+        ix, wx = ix[cols[0]:cols[1]], wx[cols[0]:cols[1]]
+    a = q(rgb.reshape(h, w * 3).astype(np.float32), False)
+    tmp = _apply(iy, wy, a, q)                               # [oh, w*3]
+    oh = tmp.shape[0]
+    tmp = q(tmp, False).reshape(oh, w, 3).transpose(1, 0, 2).reshape(w, oh * 3)
+    out = _apply(ix, wx, np.ascontiguousarray(tmp), q)       # [ow, oh*3]
+    return np.ascontiguousarray(out.reshape(-1, oh, 3).transpose(1, 0, 2))
+
+
+def _round_half_up(x: float) -> int:
+    return int(math.floor(x + 0.5))
+
+
+def geometry(options: Dict[str, Any], src_w: int, src_h: int) -> Dict[str, Any]:
+    """What the options make of a ``src_w x src_h`` frame: the size the whole
+    frame is resized to, and the window of that which is kept."""
+    tw, th = int(options["width"]), int(options["height"])
+    scale = max(tw / src_w, th / src_h)
+    rw = max(_round_half_up(src_w * scale), 1)
+    rh = max(_round_half_up(src_h * scale), 1)
+    x0, y0 = max((rw - tw) // 2, 0), max((rh - th) // 2, 0)
+    return {"resize": (rw, rh), "rows": (y0, min(y0 + th, rh)),
+            "cols": (x0, min(x0 + tw, rw))}
+
+
+def parse_options(url: str) -> Dict[str, Any]:
+    """The reference's own reading of a flyimg options string: the keys the
+    configurations use (``w_``, ``h_``, ``c_1``); any other is an error here,
+    since the reference would not be rendering it."""
+    parts = url.split(",")
+    out: Dict[str, Any] = {}
+    for part in parts:
+        key, _, value = part.partition("_")
+        if key == "w":
+            out["width"] = int(value)
+        elif key == "h":
+            out["height"] = int(value)
+        elif part != "c_1":
+            raise ValueError(f"the reference does not render option {part!r}")
+    if "c_1" not in parts or set(out) != {"width", "height"}:
+        raise ValueError(f"the reference renders w_,h_,c_1 together, not {url!r}")
+    return out
+
+
+def render(data: bytes, options: Dict[str, Any], operands: str = "float32") -> np.ndarray:
+    """Encoded original -> the resized, cut frame as float32 ``[h, w, 3]``."""
+    rgb = decode(data)
+    geo = geometry(options, rgb.shape[1], rgb.shape[0])
+    return resize(rgb, geo["resize"][0], geo["resize"][1], geo["rows"],
+                  geo["cols"], operands)
+
+
+def to_u8(frame: np.ndarray) -> np.ndarray:
+    return np.clip(np.floor(frame + 0.5), 0.0, 255.0).astype(np.uint8)
