@@ -13,7 +13,13 @@ produces the same bytes in both.
 
 Usage:
     python -m flyimg_tpu.bulk --src photos/ --out thumbs/ \
-        --options w_256,h_256,c_1 [--format jpg] [--workers 8]
+        --options w_256,h_256,c_1 [--format jpg] [--workers 8] \
+        [--trace-out spans.jsonl]
+
+``--trace-out`` runs every file under its own trace and writes one JSON
+line per file (the span tree of docs/observability.md "Tracing": decode
+with its codec queue and run, the fill wait, the shared launch span with
+its phases, encode): the operator's view of a job's launches.
 
 Prints one JSON line: {images, failed, images_per_sec, batches,
 mean_occupancy, padding_waste, queue_wait_share}. Library surface:
@@ -43,6 +49,7 @@ def bulk_process(
     workers: int = 8,
     batcher=None,
     quality: Optional[int] = None,
+    trace_out: Optional[str] = None,
 ) -> Dict[str, float]:
     """Transform every image under ``src_dir`` (non-recursive) with the
     URL-DSL ``options_str``; outputs land in ``out_dir`` as
@@ -57,8 +64,15 @@ def bulk_process(
     ``--format`` governs the output container (there is no Accept header
     to negotiate against); an ``o_`` key in ``options_str`` is ignored.
     ``quality`` overrides the encode quality unless the options string
-    itself carries an explicit ``q_``."""
+    itself carries an explicit ``q_``.
+
+    ``trace_out``: a path; each file's ``transform_bytes`` then runs
+    under a trace of its own (runtime/tracing.py, every trace kept) and
+    the job ends by writing one JSON line per file there
+    (``Trace.as_dict()`` plus the file's name). Without it no trace is
+    ever created and the pipeline's spans stay no-ops."""
     from flyimg_tpu.appconfig import AppParameters
+    from flyimg_tpu.runtime import tracing
     from flyimg_tpu.runtime.batcher import BatchController
     from flyimg_tpu.service.handler import ImageHandler
     from flyimg_tpu.service.output_image import EXT_TO_MIME, OutputSpec
@@ -117,9 +131,27 @@ def bulk_process(
         seg.startswith("q_") for seg in options_str.split(separator)
     )
     failed = 0
+    # every trace is kept (no tail sampling offline) in a ring as long as
+    # the job, and written out once the job is done
+    tracer = (
+        tracing.Tracer(buffer_size=max(len(names), 1), sample_rate=1.0)
+        if trace_out else None
+    )
     t0 = time.perf_counter()
 
     def run_one(name: str) -> None:
+        trace = tracer.start(name=name) if tracer is not None else None
+        try:
+            with tracing.activate(trace):
+                transform_one(name)
+        except BaseException:
+            if tracer is not None:
+                tracer.finish(trace, "error")
+            raise
+        if tracer is not None:
+            tracer.finish(trace)
+
+    def transform_one(name: str) -> None:
         src = os.path.join(src_dir, name)
         with open(src, "rb") as fh:
             data = fh.read()
@@ -179,6 +211,12 @@ def bulk_process(
                           file=sys.stderr)
         elapsed = time.perf_counter() - t0
         stats = batcher.stats()
+        if tracer is not None:
+            with open(trace_out, "w", encoding="utf-8") as fh:
+                for summary in reversed(tracer.list(limit=len(names))):
+                    doc = tracer.get(summary["trace_id"]).as_dict()
+                    doc["name"] = summary["name"]
+                    fh.write(json.dumps(doc) + "\n")
     finally:
         codec_batcher.close()
         if own_batcher:
@@ -208,11 +246,14 @@ def main(argv=None) -> int:
                     choices=("jpg", "png", "webp", "gif"))
     ap.add_argument("--workers", type=int, default=8)
     ap.add_argument("--quality", type=int, default=None)
+    ap.add_argument("--trace-out", default=None,
+                    help="write one JSON line of spans per file here")
     ns = ap.parse_args(argv)
 
     summary = bulk_process(
         ns.src, ns.out, ns.options,
         out_format=ns.format, workers=ns.workers, quality=ns.quality,
+        trace_out=ns.trace_out,
     )
     print(json.dumps(summary))
     return 1 if summary["failed"] else 0

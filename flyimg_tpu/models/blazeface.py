@@ -143,10 +143,11 @@ def decode_boxes(raw: jnp.ndarray) -> jnp.ndarray:
 
 @partial(jax.jit, static_argnames=("score_threshold",))
 def _forward(params, images, score_threshold: float = 0.5):
-    scores, raw = BlazeFace().apply(params, images)
-    probs = jax.nn.sigmoid(scores)
-    boxes = decode_boxes(raw)
-    return probs, boxes
+    with jax.named_scope("flyimg.blazeface"):
+        scores, raw = BlazeFace().apply(params, images)
+        probs = jax.nn.sigmoid(scores)
+        boxes = decode_boxes(raw)
+        return probs, boxes
 
 
 def _network_input(rgb: np.ndarray) -> np.ndarray:

@@ -171,7 +171,8 @@ def _batched_face_masks(
         m = erode(m)                  # close complete
         return (m > 0.5) & valid
 
-    return jax.vmap(one)(images, in_true, thresholds)
+    with jax.named_scope("flyimg.face_masks"):
+        return jax.vmap(one)(images, in_true, thresholds)
 
 
 def detect_faces_batched(items: List[FaceWork]) -> List[List[Box]]:

@@ -453,7 +453,8 @@ def _batched_weighted(images: jnp.ndarray, in_true: jnp.ndarray) -> jnp.ndarray:
         )
         return jnp.where(valid, wf, 0.0)
 
-    return jax.vmap(one)(images, in_true)
+    with jax.named_scope("flyimg.smartcrop_features"):
+        return jax.vmap(one)(images, in_true)
 
 
 @partial(jax.jit, static_argnames=("stride",))
@@ -463,9 +464,12 @@ def _batched_scores(weighted: jnp.ndarray, kernels: jnp.ndarray, stride: int):
     scale-c importance kernel, channel S+c its box-sum ones mask; both are
     zero-padded to the (khm, kwm) bucket, which contributes exactly nothing
     to a VALID correlation over a field that is itself zero-padded."""
-    grids = jax.vmap(partial(_window_scores, stride=stride))(weighted, kernels)
-    totals = jnp.sum(weighted, axis=(1, 2))
-    return grids, totals
+    with jax.named_scope("flyimg.smartcrop_scores"):
+        grids = jax.vmap(partial(_window_scores, stride=stride))(
+            weighted, kernels
+        )
+        totals = jnp.sum(weighted, axis=(1, 2))
+        return grids, totals
 
 
 def _crop_from_best(best, item: WorkItem) -> Dict[str, int]:

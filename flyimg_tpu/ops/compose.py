@@ -128,6 +128,11 @@ def make_program_fn(
     cache key (docs/kernels.md)."""
 
     def program(img_u8, in_true, span_y, span_x, out_true):
+        # every stage runs under a jax.named_scope: the names reach the
+        # HLO's op metadata, so a profile's device ops can be told apart
+        # by stage whatever the compiler numbers its fusions
+        # (flyimg.resample_rows / flyimg.resample_cols / flyimg.weights
+        # are opened inside ops/resample.py)
         x = img_u8.astype(jnp.float32)
         cur_true = in_true[:2]
         if resample_out is not None:
@@ -143,35 +148,40 @@ def make_program_fn(
                 )
             cur_true = out_true
         if pad_canvas is not None:
-            x = extent_pad(x, pad_canvas, pad_offset, plan.background)
+            with jax.named_scope("flyimg.pad"):
+                x = extent_pad(x, pad_canvas, pad_offset, plan.background)
             cur_true = jnp.array(
                 (pad_canvas[1], pad_canvas[0]), jnp.float32
             )
-        if plan.colorspace == "gray":
-            x = to_grayscale(x)
-        elif plan.colorspace == "gray601":
-            from flyimg_tpu.ops.color import LUMA_WEIGHTS_601
+        with jax.named_scope("flyimg.pixel_ops"):
+            if plan.colorspace == "gray":
+                x = to_grayscale(x)
+            elif plan.colorspace == "gray601":
+                from flyimg_tpu.ops.color import LUMA_WEIGHTS_601
 
-            x = to_grayscale(x, LUMA_WEIGHTS_601)
-        if plan.monochrome:
-            x = monochrome_dither(x)
+                x = to_grayscale(x, LUMA_WEIGHTS_601)
+            if plan.monochrome:
+                x = monochrome_dither(x)
         if plan.rotate is not None:
-            if rotate_dynamic:
-                x = rotate_image_dynamic(
-                    x, plan.rotate, plan.background, cur_true, in_true[2:4]
-                )
-            else:
-                x = rotate_image(x, plan.rotate, plan.background)
-        if plan.unsharp is not None:
-            r, s, gain, thr = plan.unsharp
-            x = unsharp_mask(x, r, s, gain, thr)
-        if plan.sharpen is not None:
-            r, s, _, _ = plan.sharpen
-            x = sharpen_op(x, r, s)
-        if plan.blur is not None:
-            r, s = plan.blur
-            x = gaussian_blur(x, r, s)
-        return jnp.clip(jnp.round(x), 0.0, 255.0).astype(jnp.uint8)
+            with jax.named_scope("flyimg.rotate"):
+                if rotate_dynamic:
+                    x = rotate_image_dynamic(
+                        x, plan.rotate, plan.background, cur_true,
+                        in_true[2:4],
+                    )
+                else:
+                    x = rotate_image(x, plan.rotate, plan.background)
+        with jax.named_scope("flyimg.pixel_ops"):
+            if plan.unsharp is not None:
+                r, s, gain, thr = plan.unsharp
+                x = unsharp_mask(x, r, s, gain, thr)
+            if plan.sharpen is not None:
+                r, s, _, _ = plan.sharpen
+                x = sharpen_op(x, r, s)
+            if plan.blur is not None:
+                r, s = plan.blur
+                x = gaussian_blur(x, r, s)
+            return jnp.clip(jnp.round(x), 0.0, 255.0).astype(jnp.uint8)
 
     return program
 

@@ -261,20 +261,29 @@ def resample_image(
     """
     in_h, in_w = image.shape[0], image.shape[1]
     out_h, out_w = out_hw
-    wy = resample_matrix(
-        in_h, out_h, span_y[0], span_y[1], out_true_hw[0], in_true_hw[0], method
-    )
-    wx = resample_matrix(
-        in_w, out_w, span_x[0], span_x[1], out_true_hw[1], in_true_hw[1], method
-    )
+    with jax.named_scope("flyimg.weights"):
+        wy = resample_matrix(
+            in_h, out_h, span_y[0], span_y[1], out_true_hw[0], in_true_hw[0],
+            method,
+        )
+        wx = resample_matrix(
+            in_w, out_w, span_x[0], span_x[1], out_true_hw[1], in_true_hw[1],
+            method,
+        )
     if RESAMPLE_FORM == "fold2d_bf16":
         return _apply_fold2d_bf16(image, wy, wx, out_h, out_w)
     # DEFAULT precision = bf16 multiplies with f32 accumulation on TPU: 2.3x
     # the throughput of the f32 path, worst-case error well under one uint8
     # level for 8-bit imagery (bf16 has 8 mantissa bits). On CPU this is
     # plain f32, so conformance tests are unaffected.
-    tmp = jnp.einsum("oh,hwc->owc", wy, image, precision=jax.lax.Precision.DEFAULT)
-    return jnp.einsum("ow,hwc->hoc", wx, tmp, precision=jax.lax.Precision.DEFAULT)
+    with jax.named_scope("flyimg.resample_rows"):
+        tmp = jnp.einsum(
+            "oh,hwc->owc", wy, image, precision=jax.lax.Precision.DEFAULT
+        )
+    with jax.named_scope("flyimg.resample_cols"):
+        return jnp.einsum(
+            "ow,hwc->hoc", wx, tmp, precision=jax.lax.Precision.DEFAULT
+        )
 
 
 def _band_axis(
@@ -362,22 +371,25 @@ def resample_image_banded(
     contributing taps; docs/kernels.md)."""
     in_h, in_w = image.shape[0], image.shape[1]
     out_h, out_w = out_hw
-    iy, wy = _band_axis(
-        in_h, out_h, int(taps_hw[0]), span_y[0], span_y[1],
-        out_true_hw[0], in_true_hw[0], method,
-    )
-    ix, wx = _band_axis(
-        in_w, out_w, int(taps_hw[1]), span_x[0], span_x[1],
-        out_true_hw[1], in_true_hw[1], method,
-    )
-    rows = jnp.take(image, iy, axis=0)            # [oh, Ky, w, c]
-    tmp = jnp.einsum(
-        "ok,okwc->owc", wy, rows, precision=jax.lax.Precision.DEFAULT
-    )
-    cols = jnp.take(tmp, ix, axis=1)              # [oh, ow, Kx, c]
-    return jnp.einsum(
-        "ok,hokc->hoc", wx, cols, precision=jax.lax.Precision.DEFAULT
-    )
+    with jax.named_scope("flyimg.weights"):
+        iy, wy = _band_axis(
+            in_h, out_h, int(taps_hw[0]), span_y[0], span_y[1],
+            out_true_hw[0], in_true_hw[0], method,
+        )
+        ix, wx = _band_axis(
+            in_w, out_w, int(taps_hw[1]), span_x[0], span_x[1],
+            out_true_hw[1], in_true_hw[1], method,
+        )
+    with jax.named_scope("flyimg.resample_rows"):
+        rows = jnp.take(image, iy, axis=0)            # [oh, Ky, w, c]
+        tmp = jnp.einsum(
+            "ok,okwc->owc", wy, rows, precision=jax.lax.Precision.DEFAULT
+        )
+    with jax.named_scope("flyimg.resample_cols"):
+        cols = jnp.take(tmp, ix, axis=1)              # [oh, ow, Kx, c]
+        return jnp.einsum(
+            "ok,hokc->hoc", wx, cols, precision=jax.lax.Precision.DEFAULT
+        )
 
 
 #: Weight-application formulation. 'einsum' is the shipped two-einsum

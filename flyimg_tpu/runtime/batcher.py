@@ -21,7 +21,11 @@ behind a bounded in-flight window (``pipeline_depth``, default 2 = classic
 double buffering): jax dispatch is asynchronous, so the executor can assemble and
 launch batch N+1 while batch N's device->host read is still in flight,
 overlapping the D2H copy with compute (not yet measured on the chip:
-ROADMAP S3/D5).
+ROADMAP S3/D5). Every launch keeps ONE account of its phases (``_Launch``:
+fill, assemble, slot wait, h2d, dispatch, run, d2h, resolve) and hands it
+whole to the span, the histograms, the efficiency window and the flight
+recorder; every member's future carries its own queued / popped / ready
+instants (``launch_times``).
 
 Failure containment (docs/resilience.md): sharing a batch must not mean
 sharing its failures. A failed launch is classified
@@ -46,6 +50,7 @@ import itertools
 import threading
 import time
 from concurrent.futures import Future
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import lru_cache
 from typing import Dict, List, Optional, Tuple
@@ -186,7 +191,7 @@ class _Pending:       # ndarray fields ("truth value is ambiguous" in any
     image: np.ndarray               # [h, w, 3] uint8 (or aux payload)
     plan: Optional[TransformPlan]
     future: Future
-    enqueued_at: float
+    enqueued_at: float              # time.monotonic(): the flush policy's clock
     final_true: Tuple[int, int]     # final valid (h, w) of the output
     needs_slice: bool = False       # output is bucket-padded; slice final_true
     # trace fan-in: the submitting request's trace + the span that was
@@ -200,6 +205,172 @@ class _Pending:       # ndarray fields ("truth value is ambiguous" in any
     # the plan's source starting at this (x, y) offset; _assemble shifts
     # the member's TRACED spans by it — program identity is untouched
     src_window: Optional[Tuple[int, int]] = None
+    # the same instant as enqueued_at on the clock spans and launch phases
+    # use (time.perf_counter()): the start of this member's queue wait
+    enqueued_pc: float = field(default_factory=time.perf_counter)
+
+
+class _Launch:
+    """The account of ONE launch, made when the launch is popped and handed
+    whole to every sink: the shared batch span, the registry's histograms
+    and batch-efficiency window (``MetricsRegistry.record_launch``), and
+    the flight recorder. Phases, in the order a transform launch runs them
+    (docs/observability.md "Launch phases"):
+
+    ``fill``       oldest member's enqueue -> pop (``queue_wait_s``)
+    ``assemble``   ``_assemble``: the padded host batch, executor thread
+    ``slot_wait``  the wait for a pipeline slot (``pipeline_depth``)
+    ``h2d``        start of ``fn.stage`` -> the staged inputs are ON the
+                   device (``jax.block_until_ready``, waited on the drain
+                   thread: the executor never waits on the transfer)
+    ``dispatch``   the ``fn(*dev_args)`` call (enqueue; compile on a miss)
+    ``run``        inputs on the device -> output ready
+    ``d2h``        output ready -> ``np.asarray`` returned (the read-back)
+    ``resolve``    ``_resolve_members``: slice, copy, set each future
+
+    ``device_s`` keeps the meaning of ``flyimg_device_seconds``: start of
+    the dispatch call -> completed read-back, which is the part of h2d
+    after the dispatch, plus run, plus d2h, exactly (the three laps share
+    their end points). An aux launch has ``fill``, ``run`` (the runner
+    call) and ``resolve``. ``marks`` holds ``time.perf_counter()`` pairs;
+    ``cpu_s`` the process CPU seconds (``time.process_time()``, all
+    threads) spent during ``assemble`` and ``h2d``: where the phases of a
+    cycle are serial, ``cpu_s / seconds`` says whether the host computed
+    through a phase or waited through it. Every phase is also opened as a
+    ``jax.profiler.TraceAnnotation`` named ``flyimg:batch:<seq>:<phase>``
+    on the thread that runs it (``h2d`` is two: ``h2d`` around the staging
+    call, ``h2d_wait`` around the wait), so a profiler trace carries the
+    same intervals on the device trace's clock."""
+
+    __slots__ = (
+        "seq", "kind", "aux", "images", "capacity", "popped",
+        "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
+        "_cursor", "_opened",
+    )
+
+    def __init__(self, seq: int, members: List[_Pending], *,
+                 kind: str = "primary", aux: bool = False) -> None:
+        self.seq = seq
+        self.kind = kind
+        self.aux = aux
+        self.images = self.capacity = len(members)
+        self.popped = time.perf_counter()
+        self.queue_wait_s = time.monotonic() - min(
+            m.enqueued_at for m in members
+        )
+        self.marks: Dict[str, Tuple[float, float]] = {}
+        self.cpu_s: Dict[str, float] = {}
+        self.compile_hit: Optional[bool] = None
+        # the staged inputs, held only until the h2d wait returns: the
+        # drain thread must not keep a launch's inputs alive through the
+        # read-back (a Thread keeps its args until run() returns)
+        self.dev_args = None
+        self._cursor = self.popped
+        self._opened = None
+
+    def annotate(self, label: str):
+        if self.aux:
+            # aux launches number their own controller's sequence: keep
+            # them apart from the device launches' names
+            return jax.profiler.TraceAnnotation(
+                f"flyimg:aux:{self.seq}:{label}"
+            )
+        return jax.profiler.TraceAnnotation(
+            f"flyimg:batch:{self.seq}:{label}"
+        )
+
+    @contextmanager
+    def phase(self, name: str, *, cpu: bool = False):
+        """Time (and annotate) a phase that starts and ends on this
+        thread."""
+        with self.annotate(name):
+            cpu0 = time.process_time() if cpu else 0.0
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.marks[name] = (t0, time.perf_counter())
+                if cpu:
+                    self.cpu_s[name] = time.process_time() - cpu0
+
+    def open(self, name: str) -> None:
+        """Start a phase that another thread will ``close`` (h2d: staged
+        on the executor, waited for on the drain thread)."""
+        self._opened = (name, time.perf_counter(), time.process_time())
+
+    def close(self) -> None:
+        """End the ``open`` phase now; ``lap`` continues from here."""
+        name, t0, cpu0 = self._opened
+        self._cursor = time.perf_counter()
+        self.marks[name] = (t0, self._cursor)
+        self.cpu_s[name] = time.process_time() - cpu0
+
+    def lap(self, name: str) -> None:
+        """End phase ``name`` now; it began where the last lap (or
+        ``close``) ended, so consecutive laps leave no gap between them."""
+        now = time.perf_counter()
+        self.marks[name] = (self._cursor, now)
+        self._cursor = now
+
+    def seconds(self, name: str) -> Optional[float]:
+        mark = self.marks.get(name)
+        return mark[1] - mark[0] if mark is not None else None
+
+    @property
+    def device_s(self) -> Optional[float]:
+        """Dispatch -> completed read-back (``flyimg_device_seconds``);
+        for an aux launch, the runner call."""
+        if self.aux:
+            return self.seconds("run")
+        start, end = self.marks.get("dispatch"), self.marks.get("d2h")
+        if start is None or end is None:
+            return None
+        return end[1] - start[0]
+
+    def fields(self) -> Dict[str, Optional[float]]:
+        """The flight recorder's view (``FlightRecorder.record``
+        ``phases``): seconds by the recorder's field names."""
+        out: Dict[str, Optional[float]] = {
+            "queue_wait_s": self.queue_wait_s,
+            "device_s": self.device_s,
+        }
+        for name, (_, field_name) in _PHASE_NAMES.items():
+            out[field_name] = self.seconds(name)
+        for name, cpu in self.cpu_s.items():
+            out[f"{name}_cpu_s"] = cpu
+        return out
+
+    def annotate_span(self, span_obj) -> None:
+        """The shared batch span's view: ``batch.*`` for the phases the
+        batcher spends on the host, ``device.*`` for the launch's hold on
+        the device."""
+        for name, (attr, _) in _PHASE_NAMES.items():
+            seconds = self.seconds(name)
+            if seconds is not None:
+                if self.aux and name == "run":
+                    attr = "batch.run_s"  # a host runner call, not the device's
+                span_obj.set_attribute(attr, round(seconds, 6))
+        for name, cpu in self.cpu_s.items():
+            span_obj.set_attribute(
+                _PHASE_NAMES[name][0][:-2] + "_cpu_s", round(cpu, 6)
+            )
+        device_s = self.device_s
+        if device_s is not None and not self.aux:
+            span_obj.set_attribute("device.seconds", round(device_s, 6))
+
+
+# phase -> (shared span attribute, flight-recorder field): ``batch.*`` is
+# the batcher's own work on the host, ``device.*`` the launch's hold on the
+# device; the read-back kept its first name, ``sync``
+_PHASE_NAMES = {
+    "assemble": ("batch.assemble_s", "assemble_s"),
+    "slot_wait": ("batch.slot_wait_s", "slot_wait_s"),
+    "h2d": ("device.h2d_s", "h2d_s"),
+    "dispatch": ("device.dispatch_s", "dispatch_s"),
+    "run": ("device.run_s", "run_s"),
+    "d2h": ("device.sync_s", "sync_s"),
+    "resolve": ("batch.resolve_s", "resolve_s"),
+}
 
 
 @dataclass
@@ -1138,37 +1309,62 @@ class BatchController:
     # ------------------------------------------------------------------
 
     @staticmethod
-    def _attach_batch_span(members: List[_Pending], span_obj) -> None:
+    def _attach_batch_span(members: List[_Pending], span_obj) -> List:
         """Fan the SHARED batch span back into every member request's
         trace (same span id everywhere), re-parented under the span each
-        member had active at submit time."""
-        for member in members:
-            if member.trace is not None:
-                member.trace.attach_shared(span_obj, member.parent_span_id)
+        member had active at submit time. Returns the attached copies:
+        the attach has to precede the members' resolution (a resolved
+        request may finish its trace at once), so the one phase that
+        follows it, ``resolve``, is written onto the copies afterwards
+        (``_publish_resolve``)."""
+        return [
+            member.trace.attach_shared(span_obj, member.parent_span_id)
+            for member in members
+            if member.trace is not None
+        ]
 
     def _start_batch_span(self, name: str, n: int, batch: int,
                           members: List[_Pending],
-                          seq: Optional[int] = None):
+                          launch: Optional[_Launch] = None):
         """Mint the shared span for one batch launch — only when at least
-        one member is traced (the untraced path must stay free). ``seq``
-        is the launch's captured batch id; concurrent recovery launches
-        share the counter, so reading it live could name the wrong
-        launch."""
+        one member is traced (the untraced path must stay free).
+        ``launch`` carries the launch's captured batch id and its fill
+        wait; concurrent recovery launches share the counter, so reading
+        it live could name the wrong launch."""
         if not any(m.trace is not None for m in members):
             return None
         span_obj = tracing.Span(name)
         span_obj.set_attribute(
-            "batch.id", seq if seq is not None else self._batch_seq
+            "batch.id", launch.seq if launch is not None else self._batch_seq
         )
         span_obj.set_attribute("batch.controller", self.name)
         span_obj.set_attribute("batch.occupancy", n)
         span_obj.set_attribute("batch.size", batch)
         span_obj.set_attribute("batch.padded_slots", batch - n)
-        oldest = min(m.enqueued_at for m in members)
-        span_obj.set_attribute(
-            "batch.queue_wait_s", round(time.monotonic() - oldest, 6)
-        )
+        if launch is not None:
+            queue_wait_s = launch.queue_wait_s
+        else:
+            queue_wait_s = time.monotonic() - min(
+                m.enqueued_at for m in members
+            )
+        span_obj.set_attribute("batch.queue_wait_s", round(queue_wait_s, 6))
         return span_obj
+
+    def _end_batch_span(self, span_obj, members: List[_Pending],
+                        launch: Optional[_Launch] = None,
+                        exc: Optional[BaseException] = None) -> List:
+        """End the shared span (as an error when ``exc`` is given), put
+        the launch's phases on it and attach it to every member trace."""
+        if span_obj is None:
+            return []
+        if exc is not None:
+            span_obj.add_event(
+                "exception", type=type(exc).__name__, message=str(exc)
+            )
+        span_obj.end("error" if exc is not None else None)
+        if launch is not None:
+            launch.annotate_span(span_obj)
+        return self._attach_batch_span(members, span_obj)
 
     @staticmethod
     def _flight_plan_key(group: _Group, fn=None) -> Optional[str]:
@@ -1179,27 +1375,22 @@ class BatchController:
             return f"aux:{getattr(group.runner, '__name__', 'aux')}"
         return fn.ledger_key if fn is not None else None
 
-    def _record_flight(self, group: _Group, members: List[_Pending], *,
-                       n: int, batch: int, seq: Optional[int],
-                       queue_wait_s: float, fn=None,
-                       h2d_s: Optional[float] = None,
-                       dispatch_s: Optional[float] = None,
-                       sync_s: Optional[float] = None,
-                       device_s: Optional[float] = None,
-                       compile_hit: Optional[bool] = None,
-                       kind: str = "primary",
+    def _record_flight(self, group: _Group, members: List[_Pending],
+                       launch: _Launch, *, fn=None,
                        error: Optional[str] = None,
-                       mem_event: Optional[str] = None) -> None:
+                       mem_event: Optional[str] = None) -> Optional[Dict]:
         """One flight-recorder entry per launch resolution (primary,
-        recovery, aux, and failures alike). No recorder wired -> one
-        None check; the record itself is a dict append. With a memory
-        governor attached, every device-launch record also carries the
-        predicted peak HBM vs the configured budget, and ``mem_event``
-        tags governor interventions (``presplit``/``ceiling`` launches,
-        ``oversize`` failures) so post-incident triage can replay the
-        admission decisions from the flight alone."""
+        recovery, aux, and failures alike), from the launch's one record.
+        No recorder wired -> one None check; the record itself is a dict
+        append. With a memory governor attached, every device-launch
+        record also carries the predicted peak HBM vs the configured
+        budget, and ``mem_event`` tags governor interventions
+        (``presplit``/``ceiling`` launches, ``oversize`` failures) so
+        post-incident triage can replay the admission decisions from the
+        flight alone. Returns the recorder's row (``_publish_resolve``
+        fills its ``resolve_s`` once the members are resolved)."""
         if self.flight_recorder is None:
-            return
+            return None
         predicted_bytes = budget_bytes = None
         if (
             self.governor is not None
@@ -1207,30 +1398,43 @@ class BatchController:
             and group.runner is None
         ):
             predicted_bytes = self.governor.predict_bytes(
-                group.base_key or group.key, batch, group.in_shape
+                group.base_key or group.key, launch.capacity, group.in_shape
             )
             budget_bytes = self.governor.device_budget_bytes or None
         if mem_event is None and group.mem_cap is not None:
             mem_event = "presplit"
-        self.flight_recorder.record(
+        return self.flight_recorder.record(
             controller=self.name,
-            batch_id=seq,
+            batch_id=launch.seq,
             plan_key=self._flight_plan_key(group, fn),
-            occupancy=n,
-            capacity=batch,
-            queue_wait_s=queue_wait_s,
-            h2d_s=h2d_s,
-            dispatch_s=dispatch_s,
-            sync_s=sync_s,
-            device_s=device_s,
-            compile_hit=compile_hit,
-            kind=kind,
+            occupancy=launch.images,
+            capacity=launch.capacity,
+            phases=launch.fields(),
+            compile_hit=launch.compile_hit,
+            kind=launch.kind,
             trace_id=self._member_trace_id(members),
             error=error,
             predicted_bytes=predicted_bytes,
             budget_bytes=budget_bytes,
             mem_event=mem_event,
         )
+
+    def _publish_resolve(self, launch: _Launch, row: Optional[Dict],
+                         span_copies: List) -> None:
+        """The ``resolve`` phase is over only after every sink had to be
+        fed (a resolved request may read the counters, the flight ring or
+        its own trace at once), so it is written late: its histogram,
+        the flight row's ``resolve_s`` (the key is there from the start:
+        only the value changes) and the attached span copies."""
+        seconds = launch.seconds("resolve")
+        if seconds is None:
+            return
+        if not launch.aux:
+            self.metrics.record_launch_resolve(seconds)
+        if row is not None:
+            row["resolve_s"] = round(seconds, 6)
+        for copy in span_copies:
+            copy.set_attribute("batch.resolve_s", round(seconds, 6))
 
     def _execute(self, group: _Group):
         """Run one popped group. Returns True when the batch was handed
@@ -1257,93 +1461,36 @@ class BatchController:
         except Exception as exc:
             self._recover(group, members, exc)
             return
-        # queue wait of the oldest member at launch time — the
+        # the launch's one record (_Launch): the fill wait ends here, at
+        # the pop, BEFORE the batch is assembled — queue_wait_s is the
         # batch-efficiency record's "how long did batching cost" half
         # (the other half is device_s, measured at readback)
-        queue_wait_s = time.monotonic() - min(
-            m.enqueued_at for m in members
-        )
         if group.runner is not None:
-            # the wedge clock keeps running across the aux runner call
-            # (deliberate: aux batches are sub-second host codec work, so
-            # a long silence there IS the hung-native-pool wedge worth
-            # re-homing the queue over)
-            span_obj = self._start_batch_span(
-                "aux_execute", n, n, members, seq=seq
+            self._execute_aux(
+                group, members, _Launch(seq, members, kind="aux", aux=True)
             )
-            if span_obj is not None:
-                span_obj.set_attribute(
-                    "batch.runner", getattr(group.runner, "__name__", "aux")
-                )
-            try:
-                t_aux = time.perf_counter()
-                outputs = group.runner([m.image for m in members])
-                aux_s = time.perf_counter() - t_aux
-                if len(outputs) != n:
-                    raise RuntimeError(
-                        f"aux runner returned {len(outputs)} results for "
-                        f"{n} payloads"
-                    )
-                # aux items are requests already counted by their transform
-                # batch — separate counters so images_processed/occupancy
-                # keep meaning "images through the transform pipeline"
-                self.metrics.counter(
-                    "flyimg_aux_batches_total",
-                    "Batched auxiliary (scoring/detection) launches",
-                ).inc()
-                self.metrics.counter(
-                    "flyimg_aux_items_total",
-                    "Items through batched auxiliary programs",
-                ).inc(n)
-                # efficiency window only (aux=True skips the transform
-                # counters): aux launches have no padding or compile step
-                self.metrics.record_batch_launch(
-                    self.name, images=n, capacity=n,
-                    queue_wait_s=queue_wait_s, device_s=aux_s,
-                    compile_hit=None,
-                    trace_id=self._member_trace_id(members), aux=True,
-                )
-                self._record_flight(
-                    group, members, n=n, batch=n, seq=seq,
-                    queue_wait_s=queue_wait_s, device_s=aux_s, kind="aux",
-                )
-                if span_obj is not None:
-                    span_obj.end()
-                    self._attach_batch_span(members, span_obj)
-                for member, result in zip(members, outputs):
-                    if not member.future.done():
-                        member.future.set_result(result)
-            except Exception as exc:
-                if span_obj is not None:
-                    span_obj.add_event(
-                        "exception", type=type(exc).__name__, message=str(exc)
-                    )
-                    span_obj.end("error")
-                    self._attach_batch_span(members, span_obj)
-                self._record_flight(
-                    group, members, n=n, batch=n, seq=seq,
-                    queue_wait_s=queue_wait_s, kind="aux",
-                    error=type(exc).__name__,
-                )
-                self._recover(group, members, exc)
             return
+        launch = _Launch(seq, members)
         span_obj = None
-        batch, fn, compile_hit = n, None, None
+        fn = None
         profiler_poked = False
         try:
-            batch, arrays = self._assemble(group, members)
-            fn, compile_hit = self._program(group, batch)
+            with launch.phase("assemble", cpu=True):
+                launch.capacity, arrays = self._assemble(group, members)
+            batch = launch.capacity
+            fn, launch.compile_hit = self._program(group, batch)
             # fault hook: a plan raising an XLA-style RESOURCE_EXHAUSTED
             # here models device OOM at dispatch — the failure routes
             # through _recover's OVERSIZE branch (cap the family
             # ceiling, re-launch smaller), never through quarantine
             faults.fire("batcher.oom", key=group.key, n=n, batch=batch)
             span_obj = self._start_batch_span(
-                "device_execute", n, batch, members, seq=seq
+                "device_execute", n, batch, members, launch
             )
             if span_obj is not None:
                 span_obj.set_attribute(
-                    "program.compile_cache", "hit" if compile_hit else "miss"
+                    "program.compile_cache",
+                    "hit" if launch.compile_hit else "miss",
                 )
                 span_obj.set_attribute("program.in_shape", str(group.in_shape))
                 if group.mem_cap is not None:
@@ -1361,45 +1508,43 @@ class BatchController:
             # clock so slow-but-alive drains (long recoveries, compiles)
             # holding both slots cannot trigger a spurious restart
             self._suspend_busy()
-            inflight.acquire()
+            with launch.phase("slot_wait"):
+                inflight.acquire()
             self._touch_busy()
             try:
-                # split device accounting (satellite of the performance
-                # observatory): host->device transfer, asynchronous
-                # dispatch (returns once the launch is enqueued; pixels
-                # land later, read on a drain thread), and the
-                # readback-side sync measured in _drain. The
-                # TraceAnnotation labels the launch in jax.profiler
+                # the device side of the launch's record: the staging
+                # call here returns before the copy has happened, so the
+                # h2d phase is closed on the drain thread, which waits
+                # for the staged inputs first (the executor never does),
+                # then for the output, then reads it back. The
+                # TraceAnnotations label the launch in jax.profiler
                 # device traces (/debug/trace, /debug/profile) so
                 # profiler timelines and request traces share batch ids.
                 if self.profiler is not None:
                     self.profiler.on_batch_start()
                     profiler_poked = True
-                t_h2d = time.perf_counter()
-                dev_args = fn.stage(arrays)
-                t_dispatch = time.perf_counter()
-                h2d_s = t_dispatch - t_h2d
-                if not compile_hit:
+                launch.open("h2d")  # closed by the drain thread's wait
+                with launch.annotate("h2d"):
+                    launch.dev_args = fn.stage(arrays)
+                if not launch.compile_hit:
                     self._suspend_busy()  # synchronous XLA compile ahead
                 with jax.profiler.TraceAnnotation(f"flyimg:batch:{seq}"):
-                    dev_out = fn(*dev_args)
-                dispatch_s = time.perf_counter() - t_dispatch
+                    with launch.phase("dispatch"):
+                        dev_out = fn(*launch.dev_args)
                 self._touch_busy()  # dispatch returned: progress
                 # the batch was registered in _inflight_batches by _run
                 # BEFORE dispatch (close()-drain visibility); ownership
                 # now passes to the drain thread, whose finally removes it
                 threading.Thread(
                     target=self._drain,
-                    args=(
-                        group, members, dev_out, n, batch, t_dispatch,
-                        span_obj, inflight, queue_wait_s, compile_hit,
-                        fn, seq, h2d_s, dispatch_s,
-                    ),
+                    args=(group, members, dev_out, launch, span_obj,
+                          inflight, fn),
                     name="flyimg-batcher-drain",
                     daemon=True,
                 ).start()
                 return True
             except BaseException:
+                launch.dev_args = None
                 inflight.release()
                 raise
         except Exception as exc:
@@ -1412,19 +1557,63 @@ class BatchController:
                 # dispatch failed after the span was minted: the errored
                 # span must still reach the member traces (tail sampling
                 # keeps exactly these), mirroring the aux/drain paths
-                span_obj.add_event(
-                    "exception", type=type(exc).__name__, message=str(exc)
-                )
-                span_obj.end("error")
-                self._attach_batch_span(members, span_obj)
+                self._end_batch_span(span_obj, members, launch, exc)
             self._record_flight(
-                group, members, n=n, batch=batch, seq=seq,
-                queue_wait_s=queue_wait_s, fn=fn, compile_hit=compile_hit,
-                error=type(exc).__name__,
+                group, members, launch, fn=fn, error=type(exc).__name__,
                 mem_event=(
                     "oversize"
                     if classify_batch_error(exc) == OVERSIZE else None
                 ),
+            )
+            self._recover(group, members, exc)
+
+    def _execute_aux(self, group: _Group, members: List[_Pending],
+                     launch: _Launch) -> None:
+        """One aux launch (host codec batches, smart-crop scoring, face
+        detection): ``runner(payloads)`` on this thread. The wedge clock
+        keeps running across the runner call (deliberate: aux batches are
+        sub-second host codec work, so a long silence there IS the
+        hung-native-pool wedge worth re-homing the queue over)."""
+        n = len(members)
+        span_obj = self._start_batch_span("aux_execute", n, n, members, launch)
+        if span_obj is not None:
+            span_obj.set_attribute(
+                "batch.runner", getattr(group.runner, "__name__", "aux")
+            )
+        try:
+            with launch.phase("run"):
+                outputs = group.runner([m.image for m in members])
+            if len(outputs) != n:
+                raise RuntimeError(
+                    f"aux runner returned {len(outputs)} results for "
+                    f"{n} payloads"
+                )
+            # aux items are requests already counted by their transform
+            # batch — separate counters so images_processed/occupancy
+            # keep meaning "images through the transform pipeline"
+            self.metrics.counter(
+                "flyimg_aux_batches_total",
+                "Batched auxiliary (scoring/detection) launches",
+            ).inc()
+            self.metrics.counter(
+                "flyimg_aux_items_total",
+                "Items through batched auxiliary programs",
+            ).inc(n)
+            # efficiency window only (an aux record skips the transform
+            # counters): aux launches have no padding or compile step
+            self.metrics.record_launch(
+                self.name, launch, trace_id=self._member_trace_id(members)
+            )
+            row = self._record_flight(group, members, launch)
+            copies = self._end_batch_span(span_obj, members, launch)
+            with launch.phase("resolve"):
+                self._resolve_members(group, members, outputs, launch)
+            self._publish_resolve(launch, row, copies)
+        except Exception as exc:
+            if span_obj is not None and span_obj.duration_s is None:
+                self._end_batch_span(span_obj, members, launch, exc)
+            self._record_flight(
+                group, members, launch, error=type(exc).__name__
             )
             self._recover(group, members, exc)
 
@@ -1520,125 +1709,122 @@ class BatchController:
         return fn, compile_hit
 
     def _resolve_members(self, group: _Group, members: List[_Pending],
-                         outputs) -> None:
+                         outputs, launch: _Launch) -> None:
         """Resolve every member future from one launch's outputs.
         done()-guarded THROUGHOUT: one already-settled/cancelled future
         (client gone, shutdown race, a superseded executor finishing
         late) must skip, not raise InvalidStateError mid-loop — which
         previously diverted to the except path and wrongly failed every
-        remaining member of the batch."""
-        if group.runner is not None:
-            for member, result in zip(members, outputs):
-                if not member.future.done():
-                    member.future.set_result(result)
-            return
+        remaining member of the batch. Each future first gets the
+        member's own three instants (``launch_times``: queued, its launch
+        popped, its result ready; ``time.perf_counter()``), from which
+        the handler fills the request's ``*_queue`` / ``*_run`` stages."""
+        ready = time.perf_counter()
         for i, member in enumerate(members):
             result = outputs[i]
-            if member.needs_slice:
-                th, tw = member.final_true
-                result = result[: int(th), : int(tw)]
+            if group.runner is None:
+                if member.needs_slice:
+                    th, tw = member.final_true
+                    result = result[: int(th), : int(tw)]
+                result = np.ascontiguousarray(result)
             if not member.future.done():
-                member.future.set_result(np.ascontiguousarray(result))
+                member.future.launch_times = (
+                    member.enqueued_pc, launch.popped, ready
+                )
+                member.future.set_result(result)
 
-    def _drain(self, group: _Group, members, dev_out, n: int, batch: int,
-               t_dispatch: Optional[float] = None, span_obj=None,
+    def _await_launch(self, launch: _Launch, dev_out):
+        """The device side of one launch after its dispatch, in three
+        laps that share their end points: the staged inputs are on the
+        device (``h2d`` ends; the inputs are let go at once, so that a
+        launch's 4 GiB of them are not held through its read-back), the
+        output is ready (``run``), the output is on the host (``d2h``).
+        The inputs are not donated, so waiting on them after the dispatch
+        is legal. Returns the output as a host array."""
+        with launch.annotate("h2d_wait"):
+            jax.block_until_ready(launch.dev_args)
+        launch.dev_args = None
+        launch.close()
+        with launch.annotate("run"):
+            jax.block_until_ready(dev_out)
+        launch.lap("run")
+        with launch.annotate("d2h"):
+            out = np.asarray(dev_out)
+        launch.lap("d2h")
+        return out
+
+    def _launch_done(self, group: _Group, members: List[_Pending],
+                     launch: _Launch, fn, span_obj=None,
+                     mem_event: Optional[str] = None):
+        """Feed every sink from one completed launch's record — cost
+        ledger, memory governor, shared span, histograms and efficiency
+        window, flight recorder, backend supervisor — all BEFORE the
+        members resolve. Returns what ``_publish_resolve`` needs."""
+        n, batch = launch.images, launch.capacity
+        trace_id = self._member_trace_id(members)
+        # per-plan attribution: cumulative device seconds against the
+        # program key the cost ledger costed at compile time
+        self._ledger.record_launch(
+            fn.ledger_key, device_s=launch.device_s, images=n
+        )
+        if self.governor is not None:
+            # governor feedback: a completed readback is the "this batch
+            # size fits" signal — and the ledger's compile-time peak
+            # estimate (if the family ever compiled) refines the
+            # per-member prediction
+            family = group.base_key or group.key
+            self.governor.observe(
+                family, batch, self._ledger.peak_memory(fn.ledger_key)
+            )
+            self.governor.record_success(family, n)
+            if launch.kind == "recovery" and self.governor.has_ceiling(
+                family
+            ):
+                # a clean launch at a live ceiling counts toward the
+                # additive-raise probe
+                mem_event = "ceiling"
+        # the phases ride the SHARED span into every member trace (and
+        # the Server-Timing header derives from it)
+        copies = self._end_batch_span(span_obj, members, launch)
+        self.metrics.record_launch(self.name, launch, trace_id=trace_id)
+        row = self._record_flight(
+            group, members, launch, fn=fn, mem_event=mem_event
+        )
+        if self.supervisor is not None:
+            # backend evidence for the device supervisor: a completed
+            # readback means the backend answered, so any failure storm
+            # in progress resets
+            self.supervisor.record_batch_success()
+        return row, copies
+
+    def _drain(self, group: _Group, members, dev_out, launch: _Launch,
+               span_obj=None,
                inflight: Optional[threading.Semaphore] = None,
-               queue_wait_s: float = 0.0,
-               compile_hit: Optional[bool] = None,
-               fn=None, seq: Optional[int] = None,
-               h2d_s: Optional[float] = None,
-               dispatch_s: Optional[float] = None) -> None:
+               fn=None) -> None:
         """Blocking device->host read + future resolution for one
         dispatched batch (runs on a daemon drain thread). ``inflight`` is
         the pipeline semaphore instance this batch acquired from (the
-        live one unless wedge self-healing swapped it since).
-        ``h2d_s``/``dispatch_s`` are the launch-side halves of the device
-        split measured in ``_execute``; the readback sync is timed here,
-        and ``flyimg_device_seconds`` keeps its meaning as the total."""
+        live one unless wedge self-healing swapped it since). ``launch``
+        carries the executor's half of the record (fill, assemble, slot
+        wait, the start of h2d, dispatch); the waits are timed here."""
+        n, batch = launch.images, launch.capacity
         try:
             faults.fire("batcher.drain", key=group.key, n=n, batch=batch)
-            t_sync = time.perf_counter()
-            out = np.asarray(dev_out)
-            sync_s = time.perf_counter() - t_sync
-            trace_id = self._member_trace_id(members)
-            device_s = (
-                time.perf_counter() - t_dispatch
-                if t_dispatch is not None else None
+            out = self._await_launch(launch, dev_out)
+            row, copies = self._launch_done(
+                group, members, launch, fn, span_obj
             )
-            if device_s is not None:
-                # dispatch -> completed readback: what the batch actually
-                # held the device (and its members) for; the exemplar
-                # links this bucket to one member's retrievable trace
-                self.metrics.record_device_batch_seconds(
-                    device_s, trace_id=trace_id
-                )
-            self.metrics.record_device_split(
-                h2d_s=h2d_s, dispatch_s=dispatch_s, sync_s=sync_s,
-                trace_id=trace_id,
-            )
-            if self.governor is not None and fn is not None:
-                # governor feedback on the drain side: a completed
-                # readback is the "this batch size fits" signal — and the
-                # ledger's compile-time peak estimate (if the family ever
-                # compiled) refines the per-member prediction
-                family = group.base_key or group.key
-                self.governor.observe(
-                    family, batch, self._ledger.peak_memory(fn.ledger_key)
-                )
-                self.governor.record_success(family, n)
-            if fn is not None and device_s is not None:
-                # per-plan attribution: cumulative device seconds against
-                # the program key the cost ledger costed at compile time
-                self._ledger.record_launch(
-                    fn.ledger_key, device_s=device_s, images=n
-                )
-            if span_obj is not None:
-                span_obj.end()
-                if device_s is not None:
-                    span_obj.set_attribute(
-                        "device.seconds", round(device_s, 6)
-                    )
-                # the split rides the SHARED span into every member
-                # trace (and the Server-Timing header derives from it)
-                if h2d_s is not None:
-                    span_obj.set_attribute("device.h2d_s", round(h2d_s, 6))
-                if dispatch_s is not None:
-                    span_obj.set_attribute(
-                        "device.dispatch_s", round(dispatch_s, 6)
-                    )
-                span_obj.set_attribute("device.sync_s", round(sync_s, 6))
-                self._attach_batch_span(members, span_obj)
-            self.metrics.record_batch_launch(
-                self.name, images=n, capacity=batch,
-                queue_wait_s=queue_wait_s, device_s=device_s,
-                compile_hit=compile_hit, trace_id=trace_id,
-            )
-            self._record_flight(
-                group, members, n=n, batch=batch, seq=seq,
-                queue_wait_s=queue_wait_s, fn=fn, h2d_s=h2d_s,
-                dispatch_s=dispatch_s, sync_s=sync_s, device_s=device_s,
-                compile_hit=compile_hit,
-            )
-            if self.supervisor is not None:
-                # backend evidence for the device supervisor: a
-                # completed readback means the backend answered, so any
-                # failure storm in progress resets
-                self.supervisor.record_batch_success()
-            self._resolve_members(group, members, out)
+            with launch.phase("resolve"):
+                self._resolve_members(group, members, out, launch)
+            self._publish_resolve(launch, row, copies)
         except Exception as exc:
+            launch.dev_args = None
             if span_obj is not None and span_obj.duration_s is None:
                 # not yet ended -> the failure happened before the attach
                 # above; record and attach the errored span instead
-                span_obj.add_event(
-                    "exception", type=type(exc).__name__, message=str(exc)
-                )
-                span_obj.end("error")
-                self._attach_batch_span(members, span_obj)
+                self._end_batch_span(span_obj, members, launch, exc)
             self._record_flight(
-                group, members, n=n, batch=batch, seq=seq,
-                queue_wait_s=queue_wait_s, fn=fn, h2d_s=h2d_s,
-                dispatch_s=dispatch_s, compile_hit=compile_hit,
-                error=type(exc).__name__,
+                group, members, launch, fn=fn, error=type(exc).__name__,
                 mem_event=(
                     "oversize"
                     if classify_batch_error(exc) == OVERSIZE else None
@@ -1725,7 +1911,7 @@ class BatchController:
             if delay > 0:
                 self._retry_policy.sleep(delay)
             try:
-                outputs = self._run_members(group, members)
+                self._run_members(group, members)
             except Exception as exc:
                 last = exc
                 retry_kind = classify_batch_error(exc)
@@ -1737,7 +1923,6 @@ class BatchController:
                 if retry_kind != TRANSIENT:
                     return exc
                 continue
-            self._resolve_members(group, members, outputs)
             return None
         return last
 
@@ -1800,7 +1985,7 @@ class BatchController:
             if not live:
                 continue
             try:
-                outputs = self._run_members(group, live)
+                self._run_members(group, live)
             except Exception as sub_exc:
                 kind = classify_batch_error(sub_exc)
                 if kind == OVERSIZE:
@@ -1830,8 +2015,6 @@ class BatchController:
                         self._fail_poison(group, live[0], sub_exc, span_obj)
                     continue
                 self._fail_members(live, sub_exc)
-                continue
-            self._resolve_members(group, live, outputs)
 
     def _bisect(self, group: _Group, members: List[_Pending],
                 span_obj, depth: int = 0) -> None:
@@ -1848,7 +2031,7 @@ class BatchController:
             if not live:
                 continue
             try:
-                outputs = self._run_members(group, live)
+                self._run_members(group, live)
             except Exception as exc:
                 if len(live) > 1:
                     self._bisect(group, live, span_obj, depth + 1)
@@ -1864,8 +2047,6 @@ class BatchController:
                     if exc is None:
                         continue
                 self._fail_poison(group, live[0], exc, span_obj)
-                continue
-            self._resolve_members(group, live, outputs)
 
     def _fail_poison(self, group: _Group, member: _Pending,
                      exc: Exception, span_obj) -> None:
@@ -1900,18 +2081,19 @@ class BatchController:
         )
         return member.fp_digest
 
-    def _run_members(self, group: _Group, members: List[_Pending]):
+    def _run_members(self, group: _Group, members: List[_Pending]) -> None:
         """ONE synchronous launch (assemble -> dispatch -> blocking
-        readback) for the recovery paths; raises on failure, returns the
-        outputs for ``_resolve_members``. Successful recovery launches
-        count in the batch/occupancy metrics like primary launches do."""
+        readback -> resolve) for the recovery paths; raises on failure.
+        Successful recovery launches count in the batch/occupancy metrics
+        like primary launches do, with the same phases (no slot wait: a
+        recovery launch runs inside its failed launch's slot)."""
         with self._lock:  # drain-thread recoveries race the executor
             self._batch_seq += 1
             seq = self._batch_seq
         self._touch_busy()  # each recovery launch is wedge-clock progress
         n = len(members)
-        queue_wait_s = time.monotonic() - min(
-            m.enqueued_at for m in members
+        launch = _Launch(
+            seq, members, kind="recovery", aux=group.runner is not None
         )
         if group.runner is not None:
             for i, member in enumerate(members):
@@ -1921,84 +2103,46 @@ class BatchController:
                     index=i,
                     image=member.image,
                 )
-            t_aux = time.perf_counter()
-            outputs = group.runner([m.image for m in members])
-            aux_s = time.perf_counter() - t_aux
+            with launch.phase("run"):
+                outputs = group.runner([m.image for m in members])
             if len(outputs) != n:
                 raise RuntimeError(
                     f"aux runner returned {len(outputs)} results for "
                     f"{n} payloads"
                 )
             faults.fire("batcher.drain", key=group.key, n=n, batch=n)
-            self.metrics.record_batch_launch(
-                self.name, images=n, capacity=n, queue_wait_s=queue_wait_s,
-                device_s=aux_s, compile_hit=None,
-                trace_id=self._member_trace_id(members), aux=True,
+            self.metrics.record_launch(
+                self.name, launch, trace_id=self._member_trace_id(members)
             )
-            self._record_flight(
-                group, members, n=n, batch=n, seq=seq,
-                queue_wait_s=queue_wait_s, device_s=aux_s, kind="recovery",
-            )
-            return outputs
-        batch, arrays = self._assemble(group, members)
-        fn, compile_hit = self._program(group, batch)
-        # same OOM fault hook as the primary path: recovery sub-launches
-        # can hit device memory exhaustion too, and must route through
-        # the same OVERSIZE handling in their caller
-        faults.fire("batcher.oom", key=group.key, n=n, batch=batch)
-        if not compile_hit:
-            self._suspend_busy()  # synchronous XLA compile ahead
-        if self.profiler is not None:
-            self.profiler.on_batch_start()
-        t_h2d = time.perf_counter()
-        dev_args = fn.stage(arrays)
-        t_dispatch = time.perf_counter()
-        h2d_s = t_dispatch - t_h2d
-        with jax.profiler.TraceAnnotation(f"flyimg:batch:{seq}"):
-            dev_out = fn(*dev_args)
-        dispatch_s = time.perf_counter() - t_dispatch
-        self._touch_busy()  # dispatch returned: progress
-        try:
-            faults.fire("batcher.drain", key=group.key, n=n, batch=batch)
-            t_sync = time.perf_counter()
-            out = np.asarray(dev_out)
-            sync_s = time.perf_counter() - t_sync
-        finally:
+            row, copies = self._record_flight(group, members, launch), []
+        else:
+            with launch.phase("assemble", cpu=True):
+                launch.capacity, arrays = self._assemble(group, members)
+            batch = launch.capacity
+            fn, launch.compile_hit = self._program(group, batch)
+            # same OOM fault hook as the primary path: recovery
+            # sub-launches can hit device memory exhaustion too, and must
+            # route through the same OVERSIZE handling in their caller
+            faults.fire("batcher.oom", key=group.key, n=n, batch=batch)
+            if not launch.compile_hit:
+                self._suspend_busy()  # synchronous XLA compile ahead
             if self.profiler is not None:
-                self.profiler.on_batch_end()
-        device_s = time.perf_counter() - t_dispatch
-        trace_id = self._member_trace_id(members)
-        self.metrics.record_device_split(
-            h2d_s=h2d_s, dispatch_s=dispatch_s, sync_s=sync_s,
-            trace_id=trace_id,
-        )
-        self._ledger.record_launch(
-            fn.ledger_key, device_s=device_s, images=n
-        )
-        mem_event = None
-        if self.governor is not None:
-            # governor feedback: the ledger's compile-time peak estimate
-            # refines the per-member prediction, and a clean launch at a
-            # live ceiling counts toward the additive-raise probe
-            family = group.base_key or group.key
-            self.governor.observe(
-                family, batch, self._ledger.peak_memory(fn.ledger_key)
-            )
-            self.governor.record_success(family, n)
-            if self.governor.has_ceiling(family):
-                mem_event = "ceiling"
-        self.metrics.record_batch_launch(
-            self.name, images=n, capacity=batch, queue_wait_s=queue_wait_s,
-            device_s=device_s, compile_hit=compile_hit, trace_id=trace_id,
-        )
-        self._record_flight(
-            group, members, n=n, batch=batch, seq=seq,
-            queue_wait_s=queue_wait_s, fn=fn, h2d_s=h2d_s,
-            dispatch_s=dispatch_s, sync_s=sync_s, device_s=device_s,
-            compile_hit=compile_hit, kind="recovery", mem_event=mem_event,
-        )
-        if self.supervisor is not None:
-            # a completed recovery launch is backend evidence exactly
-            # like a primary readback
-            self.supervisor.record_batch_success()
-        return out
+                self.profiler.on_batch_start()
+            try:
+                launch.open("h2d")
+                with launch.annotate("h2d"):
+                    launch.dev_args = fn.stage(arrays)
+                with jax.profiler.TraceAnnotation(f"flyimg:batch:{seq}"):
+                    with launch.phase("dispatch"):
+                        dev_out = fn(*launch.dev_args)
+                self._touch_busy()  # dispatch returned: progress
+                faults.fire("batcher.drain", key=group.key, n=n, batch=batch)
+                outputs = self._await_launch(launch, dev_out)
+            finally:
+                launch.dev_args = None
+                if self.profiler is not None:
+                    self.profiler.on_batch_end()
+            row, copies = self._launch_done(group, members, launch, fn)
+        with launch.phase("resolve"):
+            self._resolve_members(group, members, outputs, launch)
+        self._publish_resolve(launch, row, copies)

@@ -11,10 +11,11 @@ keeps the last N launches verbatim:
 - ``record()`` is called by ``runtime/batcher.py`` at every launch
   resolution — primary drains, recovery launches, aux batches, and
   failures — with the batch id, controller, plan-key digest (joining the
-  per-plan cost ledger), occupancy, queue wait, the h2d / dispatch /
-  readback-sync device-time split, compile hit/miss, brownout level, and
-  a member trace id. A record is one dict append under one lock —
-  nanoseconds against a millisecond launch.
+  per-plan cost ledger), occupancy, the launch's phases (queue wait,
+  assemble, slot wait, h2d, dispatch, run, read-back, resolve: the
+  batcher's one record per launch, ``phases``), compile hit/miss,
+  brownout level, and a member trace id. A record is one dict append
+  under one lock — nanoseconds against a millisecond launch.
 - ``dump(reason)`` snapshots the ring into a JSON artifact under
   ``dump_dir``. The serving wiring (service/app.py) dumps automatically
   on **SLO breach** (the PR-4 breach event) and **brownout escalation**
@@ -36,11 +37,20 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Mapping, Optional
 
-__all__ = ["FlightRecorder"]
+__all__ = ["FlightRecorder", "PHASE_FIELDS"]
 
 RECORDER_LOGGER = "flyimg.flightrecorder"
+
+#: the seconds every row carries (None where the launch has no such
+#: phase): the device split of old (h2d, dispatch, read-back ``sync``,
+#: and their total ``device_s``) and the rest of the launch cycle around
+#: it, with the process CPU seconds of the two host-heavy phases
+PHASE_FIELDS = (
+    "assemble_s", "slot_wait_s", "h2d_s", "dispatch_s", "run_s", "sync_s",
+    "resolve_s", "device_s", "assemble_cpu_s", "h2d_cpu_s",
+)
 
 
 class FlightRecorder:
@@ -101,11 +111,8 @@ class FlightRecorder:
         plan_key: Optional[str],
         occupancy: int,
         capacity: int,
-        queue_wait_s: float,
-        h2d_s: Optional[float] = None,
-        dispatch_s: Optional[float] = None,
-        sync_s: Optional[float] = None,
-        device_s: Optional[float] = None,
+        queue_wait_s: Optional[float] = None,
+        phases: Optional[Mapping[str, Optional[float]]] = None,
         compile_hit: Optional[bool] = None,
         kind: str = "primary",
         trace_id: Optional[str] = None,
@@ -114,10 +121,17 @@ class FlightRecorder:
         predicted_bytes: Optional[float] = None,
         budget_bytes: Optional[int] = None,
         mem_event: Optional[str] = None,
-    ) -> None:
+    ) -> Dict[str, object]:
         """One launch outcome. Runs on the batcher's executor/drain
         threads — the body is one level sample plus a deque append.
-        ``stage`` is set on host-pipeline ``host_stage`` records
+        ``phases`` is the launch's one record as seconds by field name
+        (runtime/batcher.py ``_Launch.fields()``: ``queue_wait_s``,
+        ``assemble_s``, ``slot_wait_s``, ``h2d_s``, ``dispatch_s``,
+        ``run_s``, ``sync_s``, ``resolve_s``, ``device_s`` and the CPU
+        seconds of the assembly and the staging); a phase the launch did
+        not reach stays None. The row is returned so that the batcher can
+        fill ``resolve_s``, which ends after the record had to be
+        visible. ``stage`` is set on host-pipeline ``host_stage`` records
         (runtime/hostpipeline.py): the per-stage queue-wait joins the
         device launches' h2d/dispatch/sync split in the same ring, so an
         incident dump shows where requests queued — host stage pools or
@@ -144,10 +158,6 @@ class FlightRecorder:
             "occupancy": int(occupancy),
             "capacity": int(capacity),
             "queue_wait_s": _r(queue_wait_s),
-            "h2d_s": _r(h2d_s),
-            "dispatch_s": _r(dispatch_s),
-            "sync_s": _r(sync_s),
-            "device_s": _r(device_s),
             "compile_hit": compile_hit,
             "brownout_level": level,
             "kind": kind,
@@ -160,10 +170,16 @@ class FlightRecorder:
             "budget_bytes": budget_bytes,
             "mem_event": mem_event,
         }
+        for name in PHASE_FIELDS:
+            rec[name] = None
+        if phases:
+            for name, seconds in phases.items():
+                rec[name] = _r(seconds)
         with self._lock:
             self._seq += 1
             rec["seq"] = self._seq
             self._ring.append(rec)
+        return rec
 
     # -- dumping -----------------------------------------------------------
 

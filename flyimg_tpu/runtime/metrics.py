@@ -483,39 +483,44 @@ class MetricsRegistry:
             trace_id=trace_id if self.exemplars_enabled else None,
         )
 
-    def record_device_split(
-        self,
-        *,
-        h2d_s: Optional[float] = None,
-        dispatch_s: Optional[float] = None,
-        sync_s: Optional[float] = None,
-        trace_id: Optional[str] = None,
-    ) -> None:
-        """The per-launch device-time split (runtime/batcher.py): host->
-        device transfer and device->host readback sync land in
-        ``flyimg_device_transfer_seconds{direction=}``, the asynchronous
-        dispatch (enqueue; includes the synchronous XLA compile on a
-        miss) in ``flyimg_device_dispatch_seconds``.
-        ``flyimg_device_seconds`` keeps its meaning as the total —
-        these are its components, recorded per launch so the round-4
-        dispatch/readback transport constants stay visible separately."""
+    def record_launch(self, controller: str, launch,
+                      trace_id: Optional[str] = None) -> None:
+        """THE histogram sink of one completed launch
+        (runtime/batcher.py ``_Launch``: the launch's one record, read
+        here by ``seconds(phase)``, ``device_s``, ``queue_wait_s``,
+        ``images``, ``capacity``, ``compile_hit``, ``aux``). A transform
+        launch observes ``flyimg_device_seconds`` (dispatch to completed
+        read-back, as ever) and one histogram per phase; every launch,
+        aux included, feeds the per-controller efficiency record
+        (``record_batch_launch``). ``resolve`` ends after the sinks are
+        fed and arrives through ``record_launch_resolve``."""
         exemplar = trace_id if self.exemplars_enabled else None
-        if h2d_s is not None:
-            self.histogram(
-                'flyimg_device_transfer_seconds{direction="h2d"}',
-                "Host<->device transfer time per batch launch, by direction",
-            ).observe(max(float(h2d_s), 0.0), trace_id=exemplar)
-        if dispatch_s is not None:
-            self.histogram(
-                "flyimg_device_dispatch_seconds",
-                "Asynchronous dispatch (launch enqueue) time per batch; "
-                "includes the synchronous XLA compile on a miss",
-            ).observe(max(float(dispatch_s), 0.0), trace_id=exemplar)
-        if sync_s is not None:
-            self.histogram(
-                'flyimg_device_transfer_seconds{direction="d2h"}',
-                "Host<->device transfer time per batch launch, by direction",
-            ).observe(max(float(sync_s), 0.0), trace_id=exemplar)
+        if not launch.aux:
+            device_s = launch.device_s
+            if device_s is not None:
+                # dispatch -> completed readback: what the batch actually
+                # held the device (and its members) for; the exemplar
+                # links this bucket to one member's retrievable trace
+                self.record_device_batch_seconds(device_s, trace_id=trace_id)
+            for phase, name, help_text in _LAUNCH_PHASE_HISTOGRAMS:
+                seconds = launch.seconds(phase)
+                if seconds is not None:
+                    self.histogram(name, help_text).observe(
+                        max(float(seconds), 0.0), trace_id=exemplar
+                    )
+        self.record_batch_launch(
+            controller, images=launch.images, capacity=launch.capacity,
+            queue_wait_s=launch.queue_wait_s, device_s=launch.device_s,
+            compile_hit=launch.compile_hit, trace_id=trace_id,
+            aux=launch.aux,
+        )
+
+    def record_launch_resolve(self, seconds: float) -> None:
+        self.histogram(
+            "flyimg_batch_resolve_seconds",
+            "Per transform launch: slicing and copying each member's "
+            "output out of the batch and resolving its future",
+        ).observe(max(float(seconds), 0.0))
 
     def record_compile_event(self, cache_hit: bool) -> None:
         """Batched-program compile cache outcome per device batch."""
@@ -746,13 +751,6 @@ class MetricsRegistry:
         for name, h in histograms.items():
             out[f"{name}:p50"] = h.quantile(0.5)
             out[f"{name}:p99"] = h.quantile(0.99)
-        slots = out.get("flyimg_batch_slots_total", 0.0)
-        if slots:
-            occupancy = (
-                out.get("flyimg_images_processed_total", 0.0) / slots
-            )
-            out["flyimg_batch_occupancy"] = occupancy
-            out["flyimg_batch_padding_waste"] = 1.0 - occupancy
         for name, eff in batch_eff.items():
             stats = eff.stats()
             for key in (
@@ -821,6 +819,34 @@ class MetricsRegistry:
             "stages": stages,
             "device": device_doc,
         }
+
+
+# one histogram per phase of a transform launch (runtime/batcher.py
+# _Launch; docs/observability.md "Launch phases"). The fill wait is
+# flyimg_batch_queue_wait_seconds{controller=} (record_batch_launch).
+_TRANSFER_HELP = (
+    "Host<->device transfer time per batch launch, by direction: h2d "
+    "from the start of the staging call until the staged inputs are on "
+    "the device, d2h from the output being ready until it is on the host"
+)
+_LAUNCH_PHASE_HISTOGRAMS = (
+    ("assemble", "flyimg_batch_assemble_seconds",
+     "Per transform launch: building the padded host batch (_assemble, "
+     "executor thread)"),
+    ("slot_wait", "flyimg_batch_slot_wait_seconds",
+     "Per transform launch: waiting for a pipeline slot "
+     "(batch_pipeline_depth launches between dispatch and read-back)"),
+    ("h2d", 'flyimg_device_transfer_seconds{direction="h2d"}',
+     _TRANSFER_HELP),
+    ("dispatch", "flyimg_device_dispatch_seconds",
+     "Asynchronous dispatch (launch enqueue) time per batch; "
+     "includes the synchronous XLA compile on a miss"),
+    ("run", "flyimg_device_run_seconds",
+     "Per transform launch: staged inputs on the device until the output "
+     "is ready"),
+    ("d2h", 'flyimg_device_transfer_seconds{direction="d2h"}',
+     _TRANSFER_HELP),
+)
 
 
 def _families(metrics: Iterable) -> List[List]:

@@ -92,6 +92,8 @@ RECORD_SCHEMAS: Dict[str, Tuple[str, ...]] = {
         "schema", "kind", "at_s", "replica",
         "controller", "batch_id", "plan_key", "occupancy", "capacity",
         "queue_wait_s", "h2d_s", "dispatch_s", "sync_s", "device_s",
+        "assemble_s", "slot_wait_s", "run_s", "resolve_s",
+        "assemble_cpu_s", "h2d_cpu_s",
         "compile_hit", "brownout_level", "launch_kind", "stage",
         "trace_id", "error", "launch_seq",
         "predicted_bytes", "budget_bytes", "mem_event",

@@ -47,6 +47,8 @@ __all__ = [
     "current_trace",
     "current_span",
     "span",
+    "stage",
+    "stage_interval",
     "add_event",
     "parse_traceparent",
     "format_traceparent",
@@ -92,10 +94,15 @@ def format_traceparent(trace_id: str, span_id: str, sampled: bool = True) -> str
 
 class Span:
     """One timed operation in a trace. Wall-clock anchored at ``start_s``
-    (epoch, for display); durations measured on the monotonic clock."""
+    (epoch, for display); durations measured on the monotonic clock, whose
+    own reading at the start is kept as ``start_mono_ns``
+    (``time.perf_counter_ns()``): the batcher's launch phases are opened as
+    ``jax.profiler.TraceAnnotation``s over the same intervals, so one
+    (span, annotation) pair places every span of the process on a device
+    trace's timeline (docs/observability.md "Launch phases")."""
 
     __slots__ = (
-        "name", "span_id", "parent_id", "start_s", "_t0",
+        "name", "span_id", "parent_id", "start_s", "start_mono_ns",
         "duration_s", "attributes", "events", "status",
     )
 
@@ -105,7 +112,7 @@ class Span:
         self.span_id = span_id or _new_span_id()
         self.parent_id = parent_id
         self.start_s = time.time()
-        self._t0 = time.perf_counter()
+        self.start_mono_ns = time.perf_counter_ns()
         self.duration_s: Optional[float] = None
         self.attributes: Dict[str, object] = {}
         self.events: List[Dict[str, object]] = []
@@ -124,9 +131,19 @@ class Span:
 
     def end(self, status: Optional[str] = None) -> None:
         if self.duration_s is None:
-            self.duration_s = time.perf_counter() - self._t0
+            self.duration_s = (
+                time.perf_counter_ns() - self.start_mono_ns
+            ) * 1e-9
         if status is not None:
             self.status = status
+
+    def set_interval(self, start: float, end: float) -> None:
+        """Make this the span of an interval timed elsewhere (both ends
+        ``time.perf_counter()`` readings) and end it."""
+        now_mono, now_epoch = time.perf_counter(), time.time()
+        self.start_s = now_epoch - (now_mono - start)
+        self.start_mono_ns = int(start * 1e9)
+        self.duration_s = max(end - start, 0.0)
 
     def as_dict(self) -> Dict[str, object]:
         return {
@@ -134,6 +151,7 @@ class Span:
             "span_id": self.span_id,
             "parent_id": self.parent_id,
             "start_s": self.start_s,
+            "start_mono_ns": self.start_mono_ns,
             "duration_s": self.duration_s,
             "status": self.status,
             "attributes": dict(self.attributes),
@@ -186,18 +204,20 @@ class Trace:
             self.spans.append(span_obj)
             return True
 
-    def attach_shared(self, shared: Span, parent_id: Optional[str]) -> None:
+    def attach_shared(self, shared: Span, parent_id: Optional[str]) -> Span:
         """Attach a span SHARED with other traces (the device batch): same
         span id and timing everywhere, re-parented under this trace's own
-        submitting span."""
+        submitting span. Returns this trace's copy."""
         copy = Span(shared.name, parent_id=parent_id or self.root.span_id,
                     span_id=shared.span_id)
         copy.start_s = shared.start_s
+        copy.start_mono_ns = shared.start_mono_ns
         copy.duration_s = shared.duration_s
         copy.status = shared.status
         copy.attributes = dict(shared.attributes)
         copy.events = list(shared.events)
         self._append(copy)
+        return copy
 
     def add_event(self, name: str, span_obj: Optional[Span] = None, **attrs):
         target = span_obj or self.root
@@ -295,6 +315,32 @@ def activate(trace: Optional[Trace]):
         _local.stack = prev_stack
 
 
+def _open_child(trace: Trace, name: str, attrs: Dict[str, object]) -> Span:
+    """Start a child of this thread's current span and make it current."""
+    parent = current_span()
+    child = trace.start_span(
+        name, parent_id=parent.span_id if parent else None
+    )
+    if attrs:
+        child.attributes.update(attrs)
+    _local.stack.append(child)
+    return child
+
+
+def _close_child(child: Span, exc: Optional[BaseException]) -> None:
+    """End ``child`` (as an error when ``exc`` is given) and unwind it."""
+    if exc is not None:
+        child.add_event(
+            "exception", type=type(exc).__name__, message=str(exc)
+        )
+        child.end("error")
+    elif child.duration_s is None:
+        child.end()
+    stack = getattr(_local, "stack", None)
+    if stack and stack[-1] is child:
+        stack.pop()
+
+
 @contextmanager
 def span(name: str, **attrs):
     """Open a child span under the current one; no active trace -> a
@@ -303,25 +349,79 @@ def span(name: str, **attrs):
     if trace is None:
         yield None
         return
-    parent = current_span()
-    child = trace.start_span(
-        name, parent_id=parent.span_id if parent else None
-    )
-    if attrs:
-        child.attributes.update(attrs)
-    _local.stack.append(child)
+    child = _open_child(trace, name, attrs)
     try:
         yield child
     except BaseException as exc:
-        child.add_event("exception", type=type(exc).__name__, message=str(exc))
-        child.end("error")
+        _close_child(child, exc)
         raise
-    finally:
-        if child.duration_s is None:
-            child.end()
-        stack = getattr(_local, "stack", None)
-        if stack and stack[-1] is child:
-            stack.pop()
+    else:
+        _close_child(child, None)
+
+
+class stage:
+    """One pipeline stage, timed once for all three of its readers: on a
+    clean exit the seconds land in ``timings[name]``, in
+    ``flyimg_stage_seconds{stage=name}`` when a registry is given, and in
+    a child span (``span_name``, default ``name``) when a trace is active
+    on this thread. The served path and ``transform_bytes`` both time
+    their stages through this one helper, so they record the same series.
+    With no trace active no ``Span`` is allocated and ``with`` yields None;
+    a stage that raises ends its span as an error and records nothing else
+    (the request failed; its partial stage is no latency sample)."""
+
+    __slots__ = ("name", "timings", "metrics", "span_name", "attrs",
+                 "_span", "_t0")
+
+    def __init__(self, name: str, timings: Dict[str, float], metrics=None,
+                 *, span_name: Optional[str] = None, **attrs) -> None:
+        self.name = name
+        self.timings = timings
+        self.metrics = metrics
+        self.span_name = span_name or name
+        self.attrs = attrs
+        self._span: Optional[Span] = None
+
+    def __enter__(self) -> Optional[Span]:
+        trace = current_trace()
+        if trace is not None:
+            self._span = _open_child(trace, self.span_name, self.attrs)
+        self._t0 = time.perf_counter()
+        return self._span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        seconds = time.perf_counter() - self._t0
+        if self._span is not None:
+            _close_child(self._span, exc)
+        if exc is None:
+            _record_stage(self.name, seconds, self.timings, self.metrics)
+        return False
+
+
+def _record_stage(name: str, seconds: float, timings: Dict[str, float],
+                  metrics) -> None:
+    timings[name] = seconds
+    if metrics is not None:
+        metrics.record_stage(name, seconds)
+
+
+def stage_interval(name: str, start: float, end: float,
+                   timings: Dict[str, float], metrics=None, *,
+                   span_name: Optional[str] = None) -> None:
+    """``stage`` for an interval that was timed elsewhere: the batcher
+    stamps each member with when it was queued, when its launch was popped
+    and when its result was ready (``time.perf_counter()`` readings), and
+    the handler turns those into the ``*_queue`` / ``*_run`` stages of the
+    request that waited. Same three readers; the span (when a trace is
+    active) is a finished child of the current one over that interval."""
+    trace = current_trace()
+    if trace is not None:
+        parent = current_span()
+        child = trace.start_span(
+            span_name or name, parent_id=parent.span_id if parent else None
+        )
+        child.set_interval(start, end)
+    _record_stage(name, max(end - start, 0.0), timings, metrics)
 
 
 def add_event(name: str, **attrs) -> None:
@@ -370,12 +470,14 @@ def server_timing(trace: Trace, max_entries: int = 16) -> str:
         name = _ST_NAME_RE.sub("_", name)
         _add(name, span_obj.duration_s)
         if span_obj.name == "device_execute":
-            # the batcher's h2d / dispatch / readback-sync split rides
-            # the shared span as attributes; surface it next to the
-            # total so a bare curl shows where device time went
+            # the batcher's launch phases (completed h2d, dispatch
+            # call, run, read-back) ride the shared span as attributes;
+            # surface them next to the total so a bare curl shows where
+            # device time went
             for attr, st_name in (
                 ("device.h2d_s", "device_h2d"),
                 ("device.dispatch_s", "device_dispatch"),
+                ("device.run_s", "device_run"),
                 ("device.sync_s", "device_sync"),
             ):
                 value = span_obj.attributes.get(attr)

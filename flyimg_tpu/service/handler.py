@@ -674,8 +674,13 @@ class ImageHandler:
             timings["reuse_hit"] = timings["total"]
         if self.metrics is not None:
             self.metrics.record_cache(hit=False)
-            for stage, seconds in timings.items():
-                self.metrics.record_stage(stage, seconds)
+            # the pipeline's stages (fetch, decode, device, encode, ...)
+            # recorded themselves where they ran (tracing.stage), on this
+            # path and under transform_bytes alike; only the request's
+            # own totals are recorded here
+            self.metrics.record_stage("total", timings["total"])
+            if reused is not None:
+                self.metrics.record_stage("reuse_hit", timings["reuse_hit"])
         self._record_mix(
             options, image_src, source_key,
             "degraded" if modes
@@ -722,10 +727,12 @@ class ImageHandler:
         stale refresh. With the stage DAG on it runs on the bounded
         fetch I/O pool (a saturated/wedged pool sheds 503 instead of
         silently stacking origin connections on request threads)."""
-        t = time.perf_counter()
-
         def _fetch():
-            with tracing.span("fetch") as fetch_span:
+            # timed where it runs (a fetch-pool worker when the stage DAG
+            # is on: the pool's own queue wait is
+            # flyimg_host_pool_queue_wait_seconds), so that retry and
+            # breaker events land on this span
+            with tracing.stage("fetch", timings, self.metrics) as fetch_span:
                 source = load_source(
                     image_src,
                     options,
@@ -741,9 +748,7 @@ class ImageHandler:
                     fetch_span.set_attribute("source.mime", source.info.mime)
             return source
 
-        source = self._stage("fetch", _fetch, deadline, inline_fallback=False)
-        timings["fetch"] = time.perf_counter() - t
-        return source
+        return self._stage("fetch", _fetch, deadline, inline_fallback=False)
 
     def _schedule_refresh(self, spec: OutputSpec, options: OptionsBag,
                           data: Optional[bytes], image_src: str,
@@ -1143,6 +1148,29 @@ class ImageHandler:
                 "single-image path instead",
             ).inc()
 
+    def _aux_result(self, future: Future, stage: str,
+                    timings: Dict[str, float],
+                    deadline: Optional[Deadline]):
+        """Wait for one codec-controller member and split the wait by the
+        member's own instants (runtime/batcher.py ``launch_times``):
+        ``<stage>_queue`` is enqueue -> its launch popped, ``<stage>_run``
+        the runner call that carried it. What is left of the enclosing
+        stage is the handler's own work around the codec (probe, ROI
+        window, the copy out of the pool's buffer, the wake-up)."""
+        result = future.result(timeout=self._device_wait_s(deadline))
+        times = getattr(future, "launch_times", None)
+        if times is not None:
+            queued, popped, ready = times
+            tracing.stage_interval(
+                f"{stage}_queue", queued, popped, timings, self.metrics,
+                span_name=f"{stage}.queue",
+            )
+            tracing.stage_interval(
+                f"{stage}_run", popped, ready, timings, self.metrics,
+                span_name=f"{stage}.run",
+            )
+        return result
+
     def _await_transform(
         self,
         future: Future,
@@ -1322,6 +1350,7 @@ class ImageHandler:
         deadline: Optional[Deadline] = None,
         quality_cap: Optional[int] = None,
         degraded_out: Optional[List[str]] = None,
+        timings: Dict[str, float],
     ) -> bytes:
         """Encode a finished frame. JPEG outputs ride the native encode
         pool through the host-codec controller when available, so
@@ -1375,11 +1404,15 @@ class ImageHandler:
             # thread (typed 400), not inside the shared pool runner
             sampling = parse_sampling_factor(sampling_factor)
             try:
-                blob = self.codec_batcher.submit_aux(
-                    ("jpegenc", quality, sampling, mozjpeg),
-                    (np.ascontiguousarray(frame), quality, sampling, mozjpeg),
-                    batch_jpeg_encode,
-                ).result(timeout=self._device_wait_s(deadline))
+                blob = self._aux_result(
+                    self.codec_batcher.submit_aux(
+                        ("jpegenc", quality, sampling, mozjpeg),
+                        (np.ascontiguousarray(frame), quality, sampling,
+                         mozjpeg),
+                        batch_jpeg_encode,
+                    ),
+                    "encode", timings, deadline,
+                )
             except FutureTimeout:
                 if deadline is not None:
                     deadline.check("encode")
@@ -1451,6 +1484,7 @@ class ImageHandler:
         return "full"
 
     def _decode_batched(self, data: bytes, hint, info,
+                        timings: Dict[str, float],
                         deadline: Optional[Deadline] = None,
                         roi=None):
         """JPEG fast path through the native DecodePool: concurrent misses
@@ -1479,9 +1513,12 @@ class ImageHandler:
             roi = None
         scale = jpeg_batch_scale_num(info, hint)
         try:
-            result = self.codec_batcher.submit_aux(
-                ("jpegdec", scale), (data, scale, roi), batch_jpeg_decode
-            ).result(timeout=self._device_wait_s(deadline))
+            result = self._aux_result(
+                self.codec_batcher.submit_aux(
+                    ("jpegdec", scale), (data, scale, roi), batch_jpeg_decode
+                ),
+                "decode", timings, deadline,
+            )
         except FutureTimeout:
             if deadline is not None:
                 deadline.check("decode")
@@ -1576,7 +1613,6 @@ class ImageHandler:
         clamped to ``brownout_quality`` — appending the applied mode
         names to ``degraded_out`` (docs/degradation.md). None = the
         byte-for-byte normal pipeline."""
-        t = time.perf_counter()
         if deadline is not None:
             deadline.check("decode")
 
@@ -1603,7 +1639,7 @@ class ImageHandler:
         hint = decode_target_hint(options)
 
         gif_frame = options.int_option("gif-frame", 0) or 0
-        with tracing.span("decode") as decode_span:
+        with tracing.stage("decode", timings, self.metrics) as decode_span:
             data_info = media_info(data)  # one probe, shared by both paths
             # ROI decode (docs/host-pipeline.md): for crop/extract-
             # dominant plans, decode only the source window the plan's
@@ -1615,7 +1651,7 @@ class ImageHandler:
                 if self.decode_roi else None
             )
             decoded = self._decode_batched(
-                data, hint, data_info, deadline, roi=roi
+                data, hint, data_info, timings, deadline, roi=roi
             )
             batched_decode = decoded is not None
             if decoded is None:
@@ -1632,13 +1668,16 @@ class ImageHandler:
                 decode_span.set_attribute("decode.mime", data_info.mime)
                 decode_span.set_attribute("decode.batched", batched_decode)
                 decode_span.set_attribute("decode.mode", decode_mode)
-        timings["decode"] = time.perf_counter() - t
         # the per-mode stage series feeds the perf-gate's decode-mode
         # legs (tools/perf_gate.py schema 5) and bench_http's
         # decode-split reporting without disturbing the aggregate
-        # `decode` stage every dashboard already reads
+        # `decode` stage every dashboard already reads: the same seconds
+        # under the mode's name
         timings[f"decode_{decode_mode}"] = timings["decode"]
         if self.metrics is not None:
+            self.metrics.record_stage(
+                f"decode_{decode_mode}", timings["decode"]
+            )
             # host-codec throughput accounting (the codec-overhaul
             # baseline, ROADMAP item 4): compressed bytes in, next to
             # the decode-pool busy-ratio gauge
@@ -1727,7 +1766,6 @@ class ImageHandler:
                 ).astype(np.uint8)
             ]
 
-        t = time.perf_counter()
         # submit every frame before waiting on any: coalesced GIF frames
         # share one program identity, so the batcher runs them as a single
         # vmapped launch instead of n_frames serial device round-trips
@@ -1736,7 +1774,8 @@ class ImageHandler:
             if anim is not None and anim.alphas is not None
             else None
         )
-        with tracing.span("batch_wait", frames=len(frames)):
+        with tracing.stage("device", timings, self.metrics,
+                           span_name="batch_wait", frames=len(frames)):
             # submissions happen INSIDE this span so the batcher records
             # it as the parent of the shared device_execute span it fans
             # back into this trace (runtime/batcher.py)
@@ -1801,7 +1840,18 @@ class ImageHandler:
                 if isinstance(s, Future) else s
                 for s, frame, frame_plan, window in staged
             ]
-        timings["device"] = time.perf_counter() - t
+            # the fill wait as this request saw it: its own enqueue ->
+            # its launch popped (of an animation's frames, the longest)
+            waits = [
+                s.launch_times for s, _, _, _ in staged
+                if isinstance(s, Future) and hasattr(s, "launch_times")
+            ]
+            if waits:
+                queued, popped, _ = max(waits, key=lambda w: w[1] - w[0])
+                tracing.stage_interval(
+                    "device_queue", queued, popped, timings, self.metrics,
+                    span_name="device.queue",
+                )
 
         # post-passes on the transformed output, in reference order:
         # smart-crop, then face blur, then face crop — all skipped for GIF
@@ -1812,17 +1862,15 @@ class ImageHandler:
                 # BROWNOUT: the deterministic host entropy crop stands in
                 # for the batched device scoring pass — same square
                 # output contract, zero device work (docs/degradation.md)
-                t = time.perf_counter()
-                with tracing.span("smartcrop", degraded=True):
+                with tracing.stage("smartcrop", timings, self.metrics,
+                                   degraded=True):
                     from flyimg_tpu.models import smartcrop as sc_mod
 
                     out = sc_mod.entropy_crop_image(out)
                 if degraded_out is not None:
                     degraded_out.append("smartcrop")
-                timings["smartcrop"] = time.perf_counter() - t
             elif plan.smart_crop:
-                t = time.perf_counter()
-                with tracing.span("smartcrop"):
+                with tracing.stage("smartcrop", timings, self.metrics):
                     sc = self._smartcrop()
                     if self.batcher is not None and hasattr(
                         sc, "prepare_work"
@@ -1850,10 +1898,8 @@ class ImageHandler:
                             out = sc.apply_crop(out, crop)
                     else:
                         out = sc.smart_crop_image(out)
-                timings["smartcrop"] = time.perf_counter() - t
             if plan.face_blur or plan.face_crop:
-                t = time.perf_counter()
-                with tracing.span("faces"):
+                with tracing.stage("faces", timings, self.metrics):
                     ff = self._faces()
                     if self.batcher is not None and hasattr(
                         ff, "prepare_face_work"
@@ -1877,13 +1923,12 @@ class ImageHandler:
                         out = ff.blur_faces(out, faces)
                     if plan.face_crop:
                         out = ff.crop_face(out, faces, plan.face_crop_position)
-                timings["faces"] = time.perf_counter() - t
             out_frames = [out]
 
-        t = time.perf_counter()
         if deadline is not None:
             deadline.check("encode")
-        with tracing.span("encode", format=spec.extension) as encode_span:
+        with tracing.stage("encode", timings, self.metrics,
+                           format=spec.extension) as encode_span:
             # attach-time decision mirrors keeps_alpha (the flatten
             # decision): attaching alpha to rgb that was already flattened
             # over bg would double-composite semi-transparent pixels
@@ -1912,7 +1957,7 @@ class ImageHandler:
                 content = self._encode_one(
                     out_frames[0], spec, options, alpha=alpha,
                     deadline=deadline, quality_cap=quality_cap,
-                    degraded_out=degraded_out,
+                    degraded_out=degraded_out, timings=timings,
                 )
             # st_0: the reference preserves ALL source metadata when -strip
             # is off (ImageProcessor.php:97-99) — EXIF, ICC profile, XMP. A
@@ -1939,7 +1984,6 @@ class ImageHandler:
                     content = meta_mod.inject(content, spec.extension, meta)
             if encode_span is not None:
                 encode_span.set_attribute("encode.bytes", len(content))
-        timings["encode"] = time.perf_counter() - t
         if self.metrics is not None:
             self.metrics.counter(
                 "flyimg_encode_bytes_total",

@@ -240,8 +240,17 @@ def test_ledger_bound_evicts_least_recently_launched():
 def _record(rec, i=0, **kw):
     defaults = dict(
         controller="device", batch_id=i, plan_key=f"p{i}", occupancy=6,
-        capacity=8, queue_wait_s=0.004, h2d_s=0.001, dispatch_s=0.01,
-        sync_s=0.002, device_s=0.013, compile_hit=True, kind="primary",
+        capacity=8,
+        # the launch's one record as the batcher hands it over
+        # (_Launch.fields()): h2d is the completed transfer, run the
+        # program, sync the read-back alone; device_s = the part of h2d
+        # after the dispatch + run + sync
+        phases=dict(
+            queue_wait_s=0.004, assemble_s=0.003, slot_wait_s=0.0,
+            h2d_s=0.005, dispatch_s=0.001, run_s=0.006, sync_s=0.002,
+            device_s=0.013,
+        ),
+        compile_hit=True, kind="primary",
         trace_id="t" * 32,
     )
     defaults.update(kw)
@@ -276,7 +285,12 @@ def test_flightrecorder_dump_writes_artifact_and_rate_limits(tmp_path):
     assert doc["summary"]["records"] == 2
     assert doc["summary"]["recovery_launches"] == 1
     assert doc["summary"]["compile_misses"] == 1
-    assert doc["records"][0]["h2d_s"] == pytest.approx(0.001)
+    row = doc["records"][0]
+    assert row["h2d_s"] == pytest.approx(0.005)
+    assert row["run_s"] == pytest.approx(0.006)
+    # every row carries every phase field; one the launch did not reach
+    # (resolve ends after the row is written) is null, not missing
+    assert row["resolve_s"] is None and row["h2d_cpu_s"] is None
     # rate limit: a second dump inside the interval is suppressed
     assert rec.dump("slo_breach") is None
     clock[0] += 31.0
@@ -576,15 +590,23 @@ def test_debug_flightrecorder_launch_joins_plans_and_split(
     ]
     assert launches
     launch = launches[0]
-    for field in ("h2d_s", "dispatch_s", "sync_s", "device_s"):
-        assert launch[field] is not None and launch[field] >= 0.0
+    for field in ("assemble_s", "slot_wait_s", "h2d_s", "dispatch_s",
+                  "run_s", "sync_s", "device_s", "assemble_cpu_s",
+                  "h2d_cpu_s"):
+        assert launch[field] is not None and launch[field] >= 0.0, field
+    # device_s is dispatch -> completed read-back: h2d's part after the
+    # dispatch call began (at most all of it), the run and the read-back
+    assert launch["run_s"] + launch["sync_s"] <= launch["device_s"] + 1e-6
+    assert launch["device_s"] <= (
+        launch["h2d_s"] + launch["run_s"] + launch["sync_s"] + 1e-6
+    )
     assert launch["compile_hit"] in (True, False)
     assert launch["occupancy"] >= 1 and launch["capacity"] >= 1
     # the record's plan key joins the cost ledger
     assert launch["plan_key"] in {row["key"] for row in plans["plans"]}
     # and the split reaches the response's Server-Timing header
     for entry in ("device_h2d;dur=", "device_dispatch;dur=",
-                  "device_sync;dur="):
+                  "device_run;dur=", "device_sync;dur="):
         assert entry in server_timing, server_timing
 
 
@@ -648,6 +670,10 @@ def test_metrics_carry_observatory_families(tmp_path, source_png):
         "flyimg_program_cache_entries",
         "flyimg_device_transfer_seconds_bucket",
         "flyimg_device_dispatch_seconds_bucket",
+        "flyimg_device_run_seconds_bucket",
+        "flyimg_batch_assemble_seconds_bucket",
+        "flyimg_batch_slot_wait_seconds_bucket",
+        "flyimg_batch_resolve_seconds_bucket",
         "flyimg_host_pool_busy_ratio",
         "flyimg_decode_bytes_total",
         "flyimg_encode_bytes_total",

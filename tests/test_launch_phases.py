@@ -1,0 +1,662 @@
+"""The launch cycle as the program itself accounts for it (PR 27): the one
+stage helper on the request path, the per-member queue/run split, the one
+phase record per launch and its four sinks, the phases' profiler
+annotations, ``bulk_process(trace_out=)``, and the benchmark files that
+read them. Nothing here asserts an upper bound on a time: sleeps give lower
+bounds, identities hold by construction."""
+
+import io
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+from PIL import Image
+
+from flyimg_tpu.appconfig import AppParameters
+from flyimg_tpu.runtime import batcher as batcher_mod
+from flyimg_tpu.runtime import tracing
+from flyimg_tpu.runtime.batcher import BatchController
+from flyimg_tpu.runtime.flightrecorder import PHASE_FIELDS, FlightRecorder
+from flyimg_tpu.runtime.metrics import MetricsRegistry
+from flyimg_tpu.service.handler import ImageHandler
+from flyimg_tpu.service.output_image import EXT_TO_MIME, OutputSpec
+from flyimg_tpu.spec.options import OptionsBag
+from flyimg_tpu.spec.plan import build_plan
+from flyimg_tpu.storage import make_storage
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+# ---------------------------------------------------------------------------
+# helpers
+
+
+def _jpeg(w=320, h=240, seed=0) -> bytes:
+    rng = np.random.default_rng(seed)
+    ramp = np.linspace(0, 255, w, dtype=np.float32)[None, :, None]
+    img = np.clip(ramp + rng.normal(0, 12, (h, w, 3)), 0, 255).astype(np.uint8)
+    buf = io.BytesIO()
+    Image.fromarray(img).save(buf, format="JPEG", quality=90)
+    return buf.getvalue()
+
+
+def _options(params, text="w_120,h_90,c_1"):
+    return OptionsBag(
+        text, options_keys=params.by_key("options_keys"),
+        default_options=params.by_key("default_options"),
+        separator=params.by_key("options_separator", ","),
+    )
+
+
+class _System:
+    """Handler + device controller + codec controller, the way bulk.py and
+    the benchmark build them."""
+
+    def __init__(self, flight_recorder=None):
+        self.params = AppParameters()
+        self.metrics = MetricsRegistry()
+        self.batcher = BatchController(
+            deadline_ms=1.0, metrics=self.metrics,
+            flight_recorder=flight_recorder,
+        )
+        self.codec = BatchController(deadline_ms=1.0, name="codec")
+        self.handler = ImageHandler(
+            storage=None, params=self.params, batcher=self.batcher,
+            codec_batcher=self.codec, metrics=self.metrics,
+        )
+
+    def transform(self, data, timings=None):
+        spec = OutputSpec(name="t.jpg", extension="jpg",
+                          mime=EXT_TO_MIME["jpg"])
+        return self.handler.transform_bytes(
+            data, _options(self.params), spec, timings
+        )
+
+    def close(self):
+        self.codec.close()
+        self.batcher.close()
+
+
+@pytest.fixture()
+def system():
+    sut = _System(flight_recorder=FlightRecorder(size=32, dump_dir="/nonexistent"))
+    yield sut
+    sut.close()
+
+
+@pytest.fixture()
+def span_count(monkeypatch):
+    """Counts every ``Span`` the program constructs."""
+    made = []
+
+    class CountingSpan(tracing.Span):
+        __slots__ = ()
+
+        def __init__(self, *args, **kwargs):
+            made.append(args[0] if args else kwargs.get("name"))
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(tracing, "Span", CountingSpan)
+    return made
+
+
+def _stage_count(metrics, stage):
+    text = metrics.render_prometheus()
+    key = f'flyimg_stage_seconds_count{{stage="{stage}"}}'
+    for line in text.splitlines():
+        if line.startswith(key):
+            return int(float(line.split()[-1]))
+    return 0
+
+
+def _tree(node, out=None):
+    """{span name: [child names]} over a Trace.as_dict() span tree."""
+    out = {} if out is None else out
+    out.setdefault(node["name"], []).extend(c["name"] for c in node["children"])
+    for child in node["children"]:
+        _tree(child, out)
+    return out
+
+
+def _find(node, name):
+    if node["name"] == name:
+        return node
+    for child in node["children"]:
+        hit = _find(child, name)
+        if hit is not None:
+            return hit
+    return None
+
+
+# ---------------------------------------------------------------------------
+# 1. the stage helper
+
+
+def test_stage_without_a_trace_allocates_no_span(span_count):
+    timings, metrics = {}, MetricsRegistry()
+    with tracing.stage("decode", timings, metrics) as span_obj:
+        assert span_obj is None
+    tracing.stage_interval("decode_queue", 1.0, 1.25, timings, metrics,
+                           span_name="decode.queue")
+    assert span_count == []
+    assert timings["decode"] >= 0.0
+    assert timings["decode_queue"] == pytest.approx(0.25)
+    assert _stage_count(metrics, "decode") == 1
+    assert _stage_count(metrics, "decode_queue") == 1
+
+
+def test_stage_with_a_trace_feeds_span_timings_and_histogram():
+    timings, metrics = {}, MetricsRegistry()
+    trace = tracing.Trace(name="job")
+    with tracing.activate(trace):
+        with tracing.stage("device", timings, metrics,
+                           span_name="batch_wait", frames=1) as span_obj:
+            assert span_obj is not None and span_obj.name == "batch_wait"
+            assert tracing.current_span() is span_obj
+            now = time.perf_counter()
+            tracing.stage_interval("device_queue", now - 0.5, now - 0.25,
+                                   timings, metrics, span_name="device.queue")
+        assert tracing.current_span() is trace.root
+    tree = _tree(trace.as_dict()["spans"][0])
+    assert tree["job"] == ["batch_wait"]
+    assert tree["batch_wait"] == ["device.queue"]
+    wait = _find(trace.as_dict()["spans"][0], "batch_wait")
+    assert wait["attributes"]["frames"] == 1
+    assert wait["duration_s"] == pytest.approx(timings["device"], abs=1e-3)
+    queue = _find(wait, "device.queue")
+    # an interval timed elsewhere: placed on both clocks where it happened
+    assert queue["duration_s"] == pytest.approx(0.25)
+    assert queue["start_mono_ns"] == pytest.approx((now - 0.5) * 1e9, rel=1e-9)
+    assert queue["start_s"] == pytest.approx(time.time() - 0.5, abs=0.2)
+    assert _stage_count(metrics, "device") == 1
+    assert _stage_count(metrics, "device_queue") == 1
+
+
+def test_stage_that_raises_ends_its_span_as_error_and_records_nothing():
+    timings, metrics = {}, MetricsRegistry()
+    trace = tracing.Trace()
+    with tracing.activate(trace):
+        with pytest.raises(ValueError):
+            with tracing.stage("encode", timings, metrics):
+                raise ValueError("boom")
+        assert tracing.current_span() is trace.root  # stack unwound
+    span_obj = _find(trace.as_dict()["spans"][0], "encode")
+    assert span_obj["status"] == "error"
+    assert span_obj["events"][0]["name"] == "exception"
+    assert "encode" not in timings
+    assert _stage_count(metrics, "encode") == 0
+
+
+def test_span_carries_its_monotonic_start():
+    before = time.perf_counter_ns()
+    span_obj = tracing.Span("x")
+    after = time.perf_counter_ns()
+    assert before <= span_obj.start_mono_ns <= after
+    span_obj.end()
+    assert span_obj.as_dict()["start_mono_ns"] == span_obj.start_mono_ns
+    trace = tracing.Trace()
+    copy = trace.attach_shared(span_obj, None)
+    assert copy.start_mono_ns == span_obj.start_mono_ns
+    assert copy.duration_s == span_obj.duration_s
+
+
+# ---------------------------------------------------------------------------
+# 2. transform_bytes: the same stages with and without a trace
+
+NEW_KEYS = ("decode_queue", "decode_run", "device_queue", "encode_queue",
+            "encode_run")
+
+
+def test_transform_bytes_under_a_trace_yields_the_span_tree(system):
+    trace = tracing.Trace(name="img0.jpg")
+    timings = {}
+    with tracing.activate(trace):
+        out = system.transform(_jpeg(), timings)
+    trace.finish()
+    assert Image.open(io.BytesIO(out)).size == (120, 90)
+    root = trace.as_dict()["spans"][0]
+    tree = _tree(root)
+    assert tree["img0.jpg"] == ["decode", "batch_wait", "encode"]
+    # the codec controller's shared aux span rides under the stage too
+    assert [n for n in tree["decode"] if n != "aux_execute"] == [
+        "decode.queue", "decode.run"]
+    assert sorted(tree["batch_wait"]) == ["device.queue", "device_execute"]
+    assert [n for n in tree["encode"] if n != "aux_execute"] == [
+        "encode.queue", "encode.run"]
+    # the launch's phases ride the shared span
+    attrs = _find(root, "device_execute")["attributes"]
+    for key in ("batch.queue_wait_s", "batch.assemble_s", "batch.slot_wait_s",
+                "device.h2d_s", "device.dispatch_s", "device.run_s",
+                "device.sync_s", "device.seconds", "batch.assemble_cpu_s",
+                "device.h2d_cpu_s"):
+        assert attrs[key] >= 0.0, key
+    # each stage's seconds are its span's
+    for stage, span_name in (("decode", "decode"), ("device", "batch_wait"),
+                             ("encode", "encode"),
+                             ("decode_queue", "decode.queue"),
+                             ("encode_run", "encode.run")):
+        assert _find(root, span_name)["duration_s"] == pytest.approx(
+            timings[stage], abs=2e-3), stage
+    # the parts of a stage never exceed it
+    assert timings["decode_queue"] + timings["decode_run"] <= timings["decode"] + 1e-3
+    assert timings["encode_queue"] + timings["encode_run"] <= timings["encode"] + 1e-3
+    assert timings["device_queue"] <= timings["device"] + 1e-3
+
+
+def test_resolve_phase_reaches_the_attached_span_and_the_flight_row(system):
+    trace = tracing.Trace()
+    with tracing.activate(trace):
+        system.transform(_jpeg(seed=3))
+    # resolve ends after the member was resolved: written late, so wait
+    deadline = time.monotonic() + 5.0
+    attrs = {}
+    while time.monotonic() < deadline:
+        attrs = _find(trace.as_dict()["spans"][0], "device_execute")["attributes"]
+        rows = [r for r in system.batcher.flight_recorder.snapshot()["records"]
+                if r["kind"] == "primary"]
+        if "batch.resolve_s" in attrs and rows and rows[0]["resolve_s"] is not None:
+            break
+        time.sleep(0.01)
+    assert attrs["batch.resolve_s"] >= 0.0
+    assert rows[0]["resolve_s"] == pytest.approx(attrs["batch.resolve_s"])
+    assert set(PHASE_FIELDS) <= set(rows[0])
+
+
+def test_transform_bytes_without_a_trace_creates_no_span_and_fills_the_same(
+        system, span_count):
+    timings = {}
+    system.transform(_jpeg(seed=1), timings)
+    assert span_count == []
+    for key in ("decode", "decode_full", "device", "encode") + NEW_KEYS:
+        assert timings[key] >= 0.0, key
+        assert _stage_count(system.metrics, key) == 1, key
+
+
+def test_process_image_records_each_stage_exactly_once(tmp_path):
+    src = tmp_path / "src.jpg"
+    src.write_bytes(_jpeg(seed=2))
+    params = AppParameters({
+        "upload_dir": str(tmp_path / "up"), "tmp_dir": str(tmp_path / "tmp"),
+    })
+    metrics = MetricsRegistry()
+    device = BatchController(deadline_ms=1.0, metrics=metrics)
+    codec = BatchController(deadline_ms=1.0, name="codec")
+    try:
+        handler = ImageHandler(
+            storage=make_storage(params), params=params, batcher=device,
+            codec_batcher=codec, metrics=metrics,
+        )
+        result = handler.process_image("w_100,h_70,c_1,o_jpg", str(src))
+        for key in ("fetch", "decode", "device", "encode", "total") + NEW_KEYS:
+            assert key in result.timings, key
+            assert _stage_count(metrics, key) == 1, key
+        # a hit records its own stage and none of the pipeline's again
+        handler.process_image("w_100,h_70,c_1,o_jpg", str(src))
+        assert _stage_count(metrics, "cache_hit") == 1
+        assert _stage_count(metrics, "decode") == 1
+        assert _stage_count(metrics, "total") == 1
+    finally:
+        codec.close()
+        device.close()
+
+
+# ---------------------------------------------------------------------------
+# 3. the phase record, with a program whose staging, run and read-back each
+#    take a known time
+
+
+class _Staged:
+    """A staged input: the staging call returned at once, the copy takes
+    ``seconds`` more."""
+
+    def __init__(self, done_at):
+        self.done_at = done_at
+
+    def block_until_ready(self):
+        time.sleep(max(self.done_at - time.perf_counter(), 0.0))
+        return self
+
+
+class _Output:
+    def __init__(self, program, batch, ready_at):
+        self.program, self.batch, self.ready_at = program, batch, ready_at
+
+    def block_until_ready(self):
+        time.sleep(max(self.ready_at - time.perf_counter(), 0.0))
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.block_until_ready()
+        self.program.inputs_alive_at_readback.append(
+            self.program.launch().dev_args is not None)
+        time.sleep(self.program.d2h_s)
+        return np.zeros((self.batch, 24, 32, 3), np.uint8)
+
+
+class _FakeProgram:
+    ledger_key = "fake-program"
+    is_compiled = True
+
+    def __init__(self, h2d_s=0.06, run_s=0.04, d2h_s=0.05):
+        self.h2d_s, self.run_s, self.d2h_s = h2d_s, run_s, d2h_s
+        self.stage_call_s = []
+        self.inputs_alive_at_readback = []
+        self.launches = []
+
+    def launch(self):
+        return self.launches[-1]
+
+    def stage(self, arrays):
+        t = time.perf_counter()
+        staged = [_Staged(t + self.h2d_s) for _ in arrays]
+        self.stage_call_s.append(time.perf_counter() - t)
+        return staged
+
+    def __call__(self, *dev_args):
+        # runs once its inputs are there, as the device does
+        ready = max(a.done_at for a in dev_args) + self.run_s
+        return _Output(self, int(self.batch), ready)
+
+
+@pytest.fixture()
+def fake_launches(monkeypatch):
+    """A device controller whose program is ``_FakeProgram``; yields
+    (controller, program, recorder, registry)."""
+    program = _FakeProgram()
+    metrics = MetricsRegistry()
+    recorder = FlightRecorder(size=64, dump_dir="/nonexistent")
+    ctl = BatchController(deadline_ms=1.0, metrics=metrics,
+                          flight_recorder=recorder, batch_retries=1)
+    ctl._retry_policy.sleep = lambda _s: None
+
+    def program_for(self, group, batch):
+        program.batch = batch
+        return program, True
+
+    monkeypatch.setattr(BatchController, "_program", program_for)
+    real_init = batcher_mod._Launch.__init__
+
+    def remember(self, *args, **kwargs):
+        real_init(self, *args, **kwargs)
+        if not self.aux:
+            program.launches.append(self)
+
+    monkeypatch.setattr(batcher_mod._Launch, "__init__", remember)
+    yield ctl, program, recorder, metrics
+    ctl.close()
+
+
+def _submit_one(ctl, w=32, h=24):
+    image = np.zeros((h, w, 3), np.uint8)
+    plan = build_plan(OptionsBag(f"w_{w},h_{h},c_1"), w, h)
+    return ctl.submit(image, plan)
+
+
+def _rows(recorder, kind):
+    return [r for r in recorder.snapshot()["records"] if r["kind"] == kind]
+
+
+def _check_identity(row):
+    """h2d's part after the dispatch began + run + read-back = device_s,
+    and every phase is >= 0."""
+    for field in ("queue_wait_s", "h2d_s", "dispatch_s", "run_s", "sync_s",
+                  "device_s"):
+        assert row[field] is not None and row[field] >= 0.0, field
+    after_dispatch = row["device_s"] - row["run_s"] - row["sync_s"]
+    assert -1e-5 <= after_dispatch <= row["h2d_s"] + 1e-5
+
+
+def _slow_aux(payloads):
+    time.sleep(0.03)
+    return [p * 2 for p in payloads]
+
+
+@pytest.mark.parametrize("path", ["primary", "recovery", "aux"])
+def test_phase_record_adds_up_on_every_path(fake_launches, path):
+    ctl, program, recorder, metrics = fake_launches
+    if path == "aux":
+        future = ctl.submit_aux(("k",), 21, _slow_aux)
+        assert future.result(timeout=30) == 42
+        row = _rows(recorder, "aux")[0]
+        assert row["run_s"] >= 0.03 and row["device_s"] == row["run_s"]
+        assert row["queue_wait_s"] >= 0.0 and row["h2d_s"] is None
+        queued, popped, ready = future.launch_times
+        assert queued <= popped and ready - popped >= 0.03
+        return
+    if path == "recovery":
+        from flyimg_tpu.testing import faults
+
+        faults.install(faults.FaultInjector()).plan(
+            "batcher.drain", faults.fail_n_then_succeed(
+                1, lambda: ConnectionError("transient device hiccup")))
+        try:
+            future = _submit_one(ctl)
+            future.result(timeout=30)
+        finally:
+            faults.clear()
+        assert _rows(recorder, "primary")[0]["error"] == "ConnectionError"
+    else:
+        future = _submit_one(ctl)
+        future.result(timeout=30)
+    row = _rows(recorder, path)[0]
+    assert row["error"] is None
+    _check_identity(row)
+    launch = program.launches[-1]
+    assert launch.kind == path
+    # exactly: the three laps share their end points
+    h2d, run, d2h = (launch.marks[k] for k in ("h2d", "run", "d2h"))
+    assert h2d[1] == run[0] and run[1] == d2h[0]
+    assert launch.device_s == pytest.approx(
+        (h2d[1] - launch.marks["dispatch"][0]) + launch.seconds("run")
+        + launch.seconds("d2h"), rel=0.01)
+    assert row["assemble_s"] >= 0.0 and row["assemble_cpu_s"] >= 0.0
+    assert (row["slot_wait_s"] is None) == (path == "recovery")
+    queued, popped, ready = future.launch_times
+    assert queued <= popped <= ready
+
+
+def test_h2d_times_the_completed_transfer_not_the_call(fake_launches):
+    ctl, program, recorder, metrics = fake_launches
+    _submit_one(ctl).result(timeout=30)
+    row = _rows(recorder, "primary")[0]
+    # the staging call returned at once; the copy took 60 ms more
+    assert program.stage_call_s[0] < program.h2d_s / 2
+    assert row["h2d_s"] >= program.h2d_s
+    assert row["run_s"] >= program.run_s * 0.5
+    # the read-back no longer swallows the staging and the run
+    assert row["sync_s"] >= program.d2h_s
+    assert row["device_s"] >= program.h2d_s + program.run_s + program.d2h_s - 0.01
+    assert row["sync_s"] <= row["device_s"] - row["run_s"] + 1e-6
+    text = metrics.render_prometheus()
+    for series in ('flyimg_device_transfer_seconds_count{direction="h2d"} 1',
+                   'flyimg_device_transfer_seconds_count{direction="d2h"} 1',
+                   "flyimg_device_run_seconds_count 1",
+                   "flyimg_batch_assemble_seconds_count 1",
+                   "flyimg_batch_slot_wait_seconds_count 1",
+                   "flyimg_device_seconds_count 1"):
+        assert series in text, series
+
+
+def test_drain_lets_go_of_the_inputs_before_the_readback(fake_launches):
+    ctl, program, recorder, metrics = fake_launches
+    _submit_one(ctl).result(timeout=30)
+    assert program.inputs_alive_at_readback == [False]
+    assert program.launches[-1].dev_args is None
+
+
+def test_every_phase_is_annotated_for_the_profiler(fake_launches, monkeypatch):
+    ctl, program, recorder, metrics = fake_launches
+    names = []
+    lock = threading.Lock()
+
+    class Recording:
+        def __init__(self, name, **_):
+            with lock:
+                names.append((name, threading.current_thread().name))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(batcher_mod.jax.profiler, "TraceAnnotation", Recording)
+    _submit_one(ctl).result(timeout=30)
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline and not any(
+            n.endswith(":resolve") for n, _ in names):
+        time.sleep(0.01)
+    seq = program.launches[-1].seq
+    by_name = dict(names)
+    executor = {"assemble", "slot_wait", "h2d", "dispatch"}
+    drain = {"h2d_wait", "run", "d2h", "resolve"}
+    for phase in executor | drain:
+        assert f"flyimg:batch:{seq}:{phase}" in by_name, (phase, names)
+    assert f"flyimg:batch:{seq}" in by_name  # the dispatch's first name stays
+    assert {by_name[f"flyimg:batch:{seq}:{p}"] for p in executor} == {"flyimg-batcher"}
+    assert {by_name[f"flyimg:batch:{seq}:{p}"] for p in drain} == {
+        "flyimg-batcher-drain"}
+
+
+# ---------------------------------------------------------------------------
+# 4. bulk_process(trace_out=)
+
+
+def test_bulk_trace_out_writes_one_parseable_line_per_image(tmp_path):
+    from flyimg_tpu.bulk import bulk_process
+
+    src = tmp_path / "src"
+    src.mkdir()
+    for i in range(4):
+        (src / f"img{i}.jpg").write_bytes(_jpeg(seed=i))
+    path = tmp_path / "spans.jsonl"
+    summary = bulk_process(str(src), str(tmp_path / "out"), "w_100,h_75,c_1",
+                           workers=4, trace_out=str(path))
+    assert summary["images"] == 4 and summary["failed"] == 0
+    docs = [json.loads(line) for line in path.read_text().splitlines()]
+    assert sorted(d["name"] for d in docs) == [f"img{i}.jpg" for i in range(4)]
+    for doc in docs:
+        root = doc["spans"][0]
+        assert root["name"] == doc["name"] and doc["status"] == "ok"
+        names = [c["name"] for c in root["children"]]
+        assert names == ["decode", "batch_wait", "encode"]
+        assert _find(root, "device_execute")["attributes"]["device.run_s"] >= 0
+
+
+def test_bulk_without_trace_out_creates_no_trace(tmp_path, span_count):
+    from flyimg_tpu.bulk import bulk_process
+
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "a.jpg").write_bytes(_jpeg(seed=9))
+    summary = bulk_process(str(src), str(tmp_path / "out"), "w_64", workers=1)
+    assert summary["images"] == 1
+    assert span_count == []
+
+
+# ---------------------------------------------------------------------------
+# 5. the benchmark's new files
+
+# two launches of 64 in the window; the program's timers as Prometheus
+# renders them
+_BEFORE = {
+    "flyimg_images_processed_total": 64.0,
+    "flyimg_batch_assemble_seconds_sum": 3.0,
+    "flyimg_batch_resolve_seconds_sum": 0.5,
+    'flyimg_device_transfer_seconds_sum{direction="h2d"}': 24.0,
+    'flyimg_device_transfer_seconds_sum{direction="d2h"}': 0.25,
+    "flyimg_device_run_seconds_sum": 0.25,
+}
+_AFTER = {
+    "flyimg_images_processed_total": 192.0,
+    "flyimg_batch_assemble_seconds_sum": 9.4,
+    "flyimg_batch_resolve_seconds_sum": 1.14,
+    'flyimg_device_transfer_seconds_sum{direction="h2d"}': 72.0,
+    'flyimg_device_transfer_seconds_sum{direction="d2h"}': 0.89,
+    "flyimg_device_run_seconds_sum": 0.762,
+}
+_TIMINGS = [
+    {"decode": 4.0, "decode_queue": 2.0, "decode_run": 1.5,
+     "encode": 1.0, "encode_queue": 0.25, "encode_run": 0.5},
+    {"decode": 5.0, "decode_queue": 3.0, "decode_run": 1.0,
+     "encode": 2.0, "encode_queue": 0.75, "encode_run": 1.0},
+    {"decode": 1.0},  # a fallback decode: no codec launch carried it
+]
+
+
+@pytest.mark.parametrize("metric,expected", [
+    ("decode_queue_ms", 2500.0),
+    ("decode_run_ms", 1250.0),
+    ("encode_queue_ms", 500.0),
+    ("encode_run_ms", 750.0),
+    ("assemble_ms_per_image", 50.0),
+    ("resolve_ms_per_image", 5.0),
+    ("h2d_ms_per_image", 375.0),
+    ("d2h_ms_per_image", 5.0),
+    ("device_run_ms_per_image", 4.0),
+    ("readback_gap_ms", 150.0),
+])
+def test_new_metric_files_read_the_recorded_fixture(metric, expected):
+    from perfbench.harness import manifest
+
+    doc = manifest.load_manifest()
+    entry = next(m for m in doc["per_layer"] if m["name"] == metric)
+    assert entry["moves"] == "latency_p95_ms"
+    spec = manifest.load_metric(metric)
+    read = manifest.load_reader(spec["reader"])
+    planes = manifest.load_json(
+        os.path.join(ROOT, "perfbench", "fixtures", "phase_trace.json"))
+    ctx = {"counters_before": _BEFORE, "counters_after": _AFTER,
+           "timings": _TIMINGS, "images": 3, "trace_planes": planes}
+    assert read(ctx, **spec["args"]) == pytest.approx(expected)
+    # a program that has no such counter, key or annotation (the parent of
+    # this PR): nothing read, nothing raised
+    empty = {"counters_before": {}, "counters_after": {},
+             "timings": [{"decode": 1.0}], "images": 1, "trace_planes": []}
+    assert read(empty, **spec["args"]) is None
+
+
+def test_trace_phase_gap_pairs_each_readback_with_the_module_before_it():
+    from perfbench.harness import manifest
+
+    read = manifest.load_reader("trace_phase_gap")
+    planes = manifest.load_json(
+        os.path.join(ROOT, "perfbench", "fixtures", "phase_trace.json"))
+    # module ends at 1.206 s, the d2h annotation at 1.356 s
+    assert read({"trace_planes": planes}, "^jit_program",
+                r"^flyimg:batch:\d+:d2h$") == pytest.approx(150.0)
+    # the resolve annotation ends 30 ms later
+    assert read({"trace_planes": planes}, "^jit_program",
+                r"^flyimg:batch:\d+:resolve$") == pytest.approx(180.0)
+    # an annotation with no module before its end pairs with nothing
+    assert read({"trace_planes": planes}, "^jit_program",
+                r"^flyimg:batch:\d+:dispatch$") is None
+    # the older fixture's program annotates no phases
+    old = manifest.load_json(
+        os.path.join(ROOT, "perfbench", "fixtures", "small_trace.json"))
+    assert read({"trace_planes": old}, "^jit_program",
+                r"^flyimg:batch:\d+:d2h$") is None
+
+
+def test_manifest_with_the_new_metrics_keeps_the_rules():
+    from perfbench.harness import manifest
+
+    doc = manifest.load_manifest()
+    assert manifest.validate(doc) == []
+    names = [m["name"] for m in doc["per_layer"]]
+    # appended after what was there, nothing reordered
+    assert names[:8] == ["decode_ms", "encode_ms", "host_cpu_ms_per_image",
+                         "images_per_launch", "padded_slot_share",
+                         "roundtrip_ms_per_image", "resample_roofline",
+                         "device_idle_share"]
+    assert len(names) == 18 and len(set(names)) == 18
+    for metric in doc["per_layer"][8:]:
+        assert metric["workloads"] == ["dslr-backfill-saturated"]
+        assert callable(manifest.load_reader(
+            manifest.load_metric(metric["name"])["reader"]))

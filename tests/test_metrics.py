@@ -134,7 +134,17 @@ def test_batch_occupancy_summary():
     summary = reg.summary()
     assert summary["flyimg_images_processed_total"] == 7
     assert summary["flyimg_batches_total"] == 2
-    assert abs(summary["flyimg_batch_occupancy"] - 7 / 8) < 1e-9
+    # occupancy is images over padded slots, from the two counters (the
+    # benchmark's padded_slot_share and the batcher's stats() read them)
+    assert summary["flyimg_batch_slots_total"] == 8
+    occupancy = (
+        summary["flyimg_images_processed_total"]
+        / summary["flyimg_batch_slots_total"]
+    )
+    assert abs(occupancy - 7 / 8) < 1e-9
+    # the derived copies nobody read are gone (PERF.md, series audit)
+    assert "flyimg_batch_occupancy" not in summary
+    assert "flyimg_batch_padding_waste" not in summary
 
 
 def test_handler_records_cache_and_stages(tmp_path):

@@ -437,7 +437,7 @@ def test_summary_carries_slo_and_efficiency_fields(tmp_path, source_png):
     assert "slo:error_budget_remaining" in summary
     assert "batch_efficiency:device:padding_waste" in summary
     assert "batch_efficiency:device:queue_wait_share" in summary
-    assert summary["flyimg_batch_padding_waste"] == pytest.approx(
-        1.0 - summary["flyimg_batch_occupancy"]
+    assert summary["batch_efficiency:device:padding_waste"] == pytest.approx(
+        1.0 - summary["batch_efficiency:device:mean_occupancy"]
     )
     assert not math.isnan(summary["slo:burn_rate_fast"])

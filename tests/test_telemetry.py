@@ -348,7 +348,7 @@ def test_schema_doc_and_code_agree_on_field_count():
     # runtime canary so a schema edit that skips the docs fails HERE too
     pairs = {(kind, field) for kind, fields in RECORD_SCHEMAS.items()
              for field in fields}
-    assert len(pairs) == 57
+    assert len(pairs) == 63  # 57 + the six launch-phase fields of PR 27
     for kind in ("boot", "window", "launch"):
         assert {"schema", "kind", "at_s"} <= set(RECORD_SCHEMAS[kind])
 
