@@ -256,6 +256,70 @@ def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
     return desc
 
 
+#: the most bytes one host-to-device transfer of a launch's images carries.
+#: The runtime copies every transfer through a host buffer it maps for the
+#: device once, at start (4 GiB on a v5e host), and the bytes its transfer
+#: threads have in flight must fit it: one transfer of a 4.73 GB launch (64
+#: frames of 24 MP) takes 4-26 s, the same bytes as 64 transfers of 73.9 MB
+#: 0.35 s, as 32 of 148 MB 0.37 s, as 16 of 296 MB 0.4-3.2 s, as 8 of 591
+#: MB 2.9-3.0 s (PERF.md section 6, PR 28). Half the largest piece that
+#: was steady, for a second launch staging beside the first and for hosts
+#: with more transfer threads. Small launches (every launch of served
+#: thumbnails) stay one transfer.
+STAGE_PIECE_BYTES = 128 << 20
+
+
+def stage_pieces(batch: int, in_shape: Tuple[int, int]) -> int:
+    """How many pieces a batched program takes its images in: equal runs
+    of whole frames along the batch axis, a power of two of them to a
+    piece, each piece at most ``STAGE_PIECE_BYTES`` (one frame where a
+    single frame is larger). A function of the program's static shape
+    alone, so it is settled when the program is built."""
+    frames = max(STAGE_PIECE_BYTES // (in_shape[0] * in_shape[1] * 3), 1)
+    frames = 1 << (frames.bit_length() - 1)
+    while batch % frames:
+        frames //= 2
+    return batch // frames
+
+
+def flat_pieces(images, pieces: int):
+    """The staged form of a batched program's image argument: ``u8[n, h,
+    w, 3]`` as the batcher assembles it -> ``pieces`` arrays ``u8[n /
+    pieces, h, w * 3]``, the same bytes in the same order. Views of a
+    C-contiguous host array (nothing is copied), the matching
+    ``ShapeDtypeStruct``s of an abstract value.
+
+    Flat, because the device keeps ``[n, h, w, 3]`` planar and tiled (w
+    minor, then h, then c), so the runtime's transfer threads de-interleave
+    every 3-byte pixel on the host; the flat shape's device layout is
+    row-major, which the host side copies in long runs (1.4-1.8 times
+    faster at any size, a third of the host CPU). In pieces, because the
+    bytes in flight have to fit the runtime's transfer buffer
+    (``STAGE_PIECE_BYTES``). The program un-flattens each piece on the
+    device (``unflatten_images``). docs/architecture.md "The transform
+    program's input contract"."""
+    n, h, w, c = images.shape
+    frames = n // pieces
+    if isinstance(images, jax.ShapeDtypeStruct):
+        piece = jax.ShapeDtypeStruct(
+            (frames, h, w * c), images.dtype, sharding=images.sharding
+        )
+        return (piece,) * pieces
+    flat = images.reshape(n, h, w * c)
+    return tuple(
+        flat[k * frames:(k + 1) * frames] for k in range(pieces)
+    )
+
+
+def unflatten_images(flat, in_shape: Tuple[int, int]):
+    """One piece of ``flat_pieces`` back to ``u8[frames, h, w, 3]`` inside
+    the device program: the first operation on every piece. A reshape of
+    the same bytes; the device's own re-layout behind it is the
+    compiler's."""
+    with jax.named_scope("flyimg.unflatten"):
+        return flat.reshape(flat.shape[0], *in_shape, 3)
+
+
 class ProgramHandle:
     """One device program: callable like the jitted function it wraps,
     but compiled through the AOT API so its XLA cost analysis feeds the
@@ -274,21 +338,30 @@ class ProgramHandle:
     its requests fail, nothing retries it down another path; only the
     two analysis calls are guarded, because cost accounting must never
     fail a render that compiled.
+
+    A batched program (``pieces`` >= 1) takes its image argument in the
+    staged form (``flat_pieces``) and the handle owns that form: callers
+    assemble and describe images as ``[n, h, w, 3]``; ``stage`` and
+    ``precompile`` map them, so the executable a handle is warmed with is
+    the one its staged arrays run.
     """
 
     __slots__ = (
         "_jitted", "_compiled", "_lock", "ledger_key", "descriptor",
-        "in_sharding",
+        "in_sharding", "pieces",
     )
 
     def __init__(self, jitted, key, descriptor: Dict[str, object],
-                 in_sharding=None) -> None:
+                 in_sharding=None, pieces: int = 0) -> None:
         self._jitted = jitted
         self._compiled = None
         self._lock = threading.Lock()
         # the sharding every argument of a mesh-sharded batched program
         # takes (None: single device)
         self.in_sharding = in_sharding
+        # the program takes its first argument as flat_pieces leaves it,
+        # in this many pieces (0: a single-image program, images as given)
+        self.pieces = pieces
         if isinstance(key, str):
             self.ledger_key = key
         else:
@@ -303,24 +376,40 @@ class ProgramHandle:
         inference."""
         return self._compiled is not None
 
+    def _staged(self, args):
+        """``args`` as the program takes them: the image argument of a
+        batched program as its flat pieces, everything else as it is."""
+        if not self.pieces:
+            return list(args)
+        return [flat_pieces(args[0], self.pieces), *args[1:]]
+
     def stage(self, arrays):
-        """Host arrays -> device arrays laid out as this program takes
-        them. With an input sharding each device receives its slice of the
-        batch straight from the host — staged unsharded, the whole batch
-        would land on device 0 and be resharded at every launch."""
-        if self.in_sharding is None:
-            return [jnp.asarray(a) for a in arrays]
-        return jax.device_put(list(arrays), self.in_sharding)
+        """Host arrays as the batcher assembles them (images ``[n, h, w,
+        3]``, C-contiguous) -> device arrays as this program takes them:
+        the images as their flat pieces, views, so the one copy made is
+        the transfer's own, a piece a transfer. With an input sharding each
+        device receives its slice of the batch straight from the host —
+        staged unsharded, the whole batch would land on device 0 and be
+        resharded at every launch (a sharded program takes one piece: the
+        runtime already moves one transfer a device, and the flat form
+        keeps the batch axis the sharding splits). Returns before the
+        copies have happened (the caller waits with
+        ``jax.block_until_ready``); ``__call__`` takes what this returns."""
+        return jax.device_put(self._staged(arrays), self.in_sharding)
 
     def precompile(self, args) -> None:
         """Compile (and ledger-record) for ``args``'s shapes WITHOUT
         executing — ``args`` may be ``jax.ShapeDtypeStruct`` abstract
-        values. Lets cost A/B tooling and tests obtain the ledger entry
-        for a geometry (e.g. the canonical 4k plan) that would be
+        values, and describe what ``stage`` is given, images as ``[n, h,
+        w, 3]``: the same mapping is applied to them, so the executable
+        held afterwards is exactly the one staged arrays run (a warmed
+        handle never compiles at its first launch). Lets warm-up, cost A/B
+        tooling and tests obtain the executable and its ledger entry for a
+        geometry (e.g. the canonical 4k plan) that would be
         seconds-per-image to actually execute on a CPU host."""
         with self._lock:
             if self._compiled is None:
-                self._compile(args)
+                self._compile(self._staged(args))
 
     def __call__(self, *args):
         compiled = self._compiled

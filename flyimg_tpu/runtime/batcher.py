@@ -56,6 +56,7 @@ from functools import lru_cache
 from typing import Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from flyimg_tpu.ops.compose import (
@@ -66,6 +67,8 @@ from flyimg_tpu.ops.compose import (
     make_program_fn,
     plan_descriptor,
     plan_layout,
+    stage_pieces,
+    unflatten_images,
 )
 from flyimg_tpu.ops.resample import kernel_mode, select_band_taps
 from flyimg_tpu.runtime import costledger, tracing
@@ -136,23 +139,68 @@ def build_batched_program(
     as a ``ProgramHandle``: the first call AOT-compiles and records XLA
     cost analysis in the per-plan ledger; ``handle.is_compiled`` is the
     batcher's exact compile-hit signal. One cache entry = one (batch,
-    shape) program = one compiled executable. ``band_taps`` (the banded
+    shape) program = one compiled executable. The program takes the
+    images flat and in pieces (``stage_pieces`` of them, each ``u8[batch /
+    pieces, h, w * 3]``; one piece for every launch of small frames) and
+    un-flattens and transforms them piece by piece, in a loop over one
+    traced body; callers keep assembling and describing ``[batch, h, w,
+    3]`` and go through the handle's ``stage`` / ``precompile``, which
+    own the mapping (ops/compose.py ``flat_pieces``). ``band_taps`` (the
+    banded
     resample's static per-axis K; docs/kernels.md) is part of the cache
     key AND the ledger key — dense and banded variants of one plan must
     never collide in either."""
-    inner = make_program_fn(
+    batched = jax.vmap(make_program_fn(
         resample_out, pad_canvas, pad_offset, plan,
         rotate_dynamic=rotate_dynamic, band_taps=band_taps,
-    )
+    ))
+
+    # a sharded program takes the images in one piece: the runtime moves
+    # one transfer a device, and a piece must not straddle the mesh
+    pieces = stage_pieces(batch_size, in_shape) if mesh is None else 1
+    frames = batch_size // pieces
+
+    def program(flat_pieces_u8, in_true, span_y, span_x, out_true):
+        # the images arrive as ProgramHandle.stage leaves them (flat, in
+        # pieces: the form the host copies straight through) and every
+        # piece is un-flattened and transformed here, inside the one
+        # jit_program module, so the device time of the re-layout counts
+        # with the program's
+        def one(piece, *scalars):
+            return batched(unflatten_images(piece, in_shape), *scalars)
+
+        scalars = (in_true, span_y, span_x, out_true)
+        if pieces == 1:
+            return one(flat_pieces_u8[0], *scalars)
+
+        # several pieces: ONE traced body in a loop over them, not the
+        # body unrolled `pieces` times (unrolled, the 64-piece program of
+        # a 24 MP launch compiled for 104-112 s, took 23 s to read back
+        # from the compile cache and outgrew it; PERF.md section 6, PR
+        # 28). The step picks its piece among the program's parameters (a
+        # copy of one piece, at HBM speed), so the temporaries are one
+        # piece's whatever the launch's size.
+        picks = [lambda ps, k=k: ps[k] for k in range(pieces)]
+
+        def step(xs):
+            k, rows = xs
+            return one(jax.lax.switch(k, picks, flat_pieces_u8), *rows)
+
+        out = jax.lax.map(step, (
+            jnp.arange(pieces),
+            tuple(a.reshape(pieces, frames, *a.shape[1:]) for a in scalars),
+        ))
+        return out.reshape(batch_size, *out.shape[2:])
+
     sharding = None
     if mesh is None:
-        jitted = jax.jit(jax.vmap(inner))
+        jitted = jax.jit(program)
     else:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         sharding = NamedSharding(mesh, P("data"))
         jitted = jax.jit(
-            jax.vmap(inner),
+            program,
             in_shardings=(sharding,) * 5,
             out_shardings=sharding,
         )
@@ -182,6 +230,7 @@ def build_batched_program(
             band_taps=band_taps,
         ),
         in_sharding=sharding,
+        pieces=pieces,
     )
 
 
@@ -236,7 +285,8 @@ class _Launch:
     ``cpu_s`` the process CPU seconds (``time.process_time()``, all
     threads) spent during ``assemble`` and ``h2d``: where the phases of a
     cycle are serial, ``cpu_s / seconds`` says whether the host computed
-    through a phase or waited through it. Every phase is also opened as a
+    through a phase or waited through it; ``transfer_bytes`` the bytes
+    staged (``h2d``) and read back (``d2h``). Every phase is also opened as a
     ``jax.profiler.TraceAnnotation`` named ``flyimg:batch:<seq>:<phase>``
     on the thread that runs it (``h2d`` is two: ``h2d`` around the staging
     call, ``h2d_wait`` around the wait), so a profiler trace carries the
@@ -245,7 +295,7 @@ class _Launch:
     __slots__ = (
         "seq", "kind", "aux", "images", "capacity", "popped",
         "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
-        "_cursor", "_opened",
+        "transfer_bytes", "_cursor", "_opened",
     )
 
     def __init__(self, seq: int, members: List[_Pending], *,
@@ -260,6 +310,10 @@ class _Launch:
         )
         self.marks: Dict[str, Tuple[float, float]] = {}
         self.cpu_s: Dict[str, float] = {}
+        # bytes over the link by direction ("h2d": every staged array,
+        # "d2h": the output read back): with the two transfer phases'
+        # seconds, the rate an operator reads
+        self.transfer_bytes: Dict[str, int] = {}
         self.compile_hit: Optional[bool] = None
         # the staged inputs, held only until the h2d wait returns: the
         # drain thread must not keep a launch's inputs alive through the
@@ -354,6 +408,8 @@ class _Launch:
             span_obj.set_attribute(
                 _PHASE_NAMES[name][0][:-2] + "_cpu_s", round(cpu, 6)
             )
+        for direction, nbytes in self.transfer_bytes.items():
+            span_obj.set_attribute(f"device.{direction}_bytes", nbytes)
         device_s = self.device_s
         if device_s is not None and not self.aux:
             span_obj.set_attribute("device.seconds", round(device_s, 6))
@@ -1523,9 +1579,7 @@ class BatchController:
                 if self.profiler is not None:
                     self.profiler.on_batch_start()
                     profiler_poked = True
-                launch.open("h2d")  # closed by the drain thread's wait
-                with launch.annotate("h2d"):
-                    launch.dev_args = fn.stage(arrays)
+                self._stage(launch, fn, arrays)
                 if not launch.compile_hit:
                     self._suspend_busy()  # synchronous XLA compile ahead
                 with jax.profiler.TraceAnnotation(f"flyimg:batch:{seq}"):
@@ -1708,6 +1762,18 @@ class BatchController:
         self.metrics.record_compile_event(compile_hit)
         return fn, compile_hit
 
+    @staticmethod
+    def _stage(launch: _Launch, fn, arrays) -> None:
+        """Start the launch's ``h2d`` phase: hand the assembled arrays to
+        the program's handle, which stages them in the form the program
+        takes (``ProgramHandle.stage``: the images flat and in pieces,
+        views of ``_assemble``'s array). The call returns before the
+        copies have happened; ``_await_launch`` closes the phase."""
+        launch.open("h2d")
+        with launch.annotate("h2d"):
+            launch.dev_args = fn.stage(arrays)
+        launch.transfer_bytes["h2d"] = sum(a.nbytes for a in arrays)
+
     def _resolve_members(self, group: _Group, members: List[_Pending],
                          outputs, launch: _Launch) -> None:
         """Resolve every member future from one launch's outputs.
@@ -1751,6 +1817,7 @@ class BatchController:
         with launch.annotate("d2h"):
             out = np.asarray(dev_out)
         launch.lap("d2h")
+        launch.transfer_bytes["d2h"] = out.nbytes
         return out
 
     def _launch_done(self, group: _Group, members: List[_Pending],
@@ -2129,9 +2196,7 @@ class BatchController:
             if self.profiler is not None:
                 self.profiler.on_batch_start()
             try:
-                launch.open("h2d")
-                with launch.annotate("h2d"):
-                    launch.dev_args = fn.stage(arrays)
+                self._stage(launch, fn, arrays)
                 with jax.profiler.TraceAnnotation(f"flyimg:batch:{seq}"):
                     with launch.phase("dispatch"):
                         dev_out = fn(*launch.dev_args)

@@ -488,9 +488,11 @@ class MetricsRegistry:
         """THE histogram sink of one completed launch
         (runtime/batcher.py ``_Launch``: the launch's one record, read
         here by ``seconds(phase)``, ``device_s``, ``queue_wait_s``,
-        ``images``, ``capacity``, ``compile_hit``, ``aux``). A transform
-        launch observes ``flyimg_device_seconds`` (dispatch to completed
-        read-back, as ever) and one histogram per phase; every launch,
+        ``images``, ``capacity``, ``compile_hit``, ``aux``,
+        ``transfer_bytes``). A transform launch observes
+        ``flyimg_device_seconds`` (dispatch to completed read-back, as
+        ever), one histogram per phase and the bytes it moved each way
+        (``flyimg_device_transfer_bytes_total``); every launch,
         aux included, feeds the per-controller efficiency record
         (``record_batch_launch``). ``resolve`` ends after the sinks are
         fed and arrives through ``record_launch_resolve``."""
@@ -508,6 +510,15 @@ class MetricsRegistry:
                     self.histogram(name, help_text).observe(
                         max(float(seconds), 0.0), trace_id=exemplar
                     )
+            for direction, nbytes in launch.transfer_bytes.items():
+                self.counter(
+                    "flyimg_device_transfer_bytes_total"
+                    f'{{direction="{direction}"}}',
+                    "Bytes transform launches staged to the device (h2d: "
+                    "every argument of the launch) and read back from it "
+                    "(d2h: the output); over the sum of "
+                    "flyimg_device_transfer_seconds, the link's rate",
+                ).inc(nbytes)
         self.record_batch_launch(
             controller, images=launch.images, capacity=launch.capacity,
             queue_wait_s=launch.queue_wait_s, device_s=launch.device_s,
