@@ -17,7 +17,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from . import compare, corpus as corpus_mod, manifest as mf, trace as trace_mod, work
+from . import compare, corpus as corpus_mod, manifest as mf, trace as trace_mod
 from .traffic import ClosedLoop
 
 HOST_KEEP = re.compile(r"^flyimg:batch:")
@@ -25,17 +25,6 @@ HOST_KEEP = re.compile(r"^flyimg:batch:")
 
 def log(*parts: Any) -> None:
     print(*parts, file=sys.stderr, flush=True)
-
-
-def apply_toy(config: Dict[str, Any], mix: Optional[Dict[str, Any]] = None) -> None:
-    """The configuration's toy size, for rehearsals and tests on the CPU."""
-    toy = config["toy"]
-    config["frame"], config["corpus"] = toy["frame"], toy["corpus"]
-    config["options"] = toy["options"]
-    config["parameters"] = toy.get("parameters", config.get("parameters"))
-    for key in ("in_flight", "preroll_images", "warm_launch_sizes"):
-        if mix is not None:
-            mix[key] = toy[key]
 
 
 def rss_bytes() -> int:
@@ -68,18 +57,22 @@ class RssWatch(threading.Thread):
 
 def trace_one_launch(loop: ClosedLoop, t_burst: float, cycle: float, t_close: float,
                      mix: Dict[str, Any]) -> Tuple[str, float]:
-    """Put the profiler around the execution of one full launch and keep it
-    off the launch's staging: with the profiler on, every second of staging a
-    4.7 GB launch costs 0.27 GiB of host memory that is not given back, and
-    a trace through a whole staging ends the process at the machine's limit
-    (PERF.md section 6). Placed by the harness's own clock alone: the callers
-    move in step with the launches, so the next launch is read back one
-    pre-roll cycle after the pre-roll's burst of answers (``t_burst``), and
-    ``trace_at_cycle_share`` of a cycle after that burst it is being staged.
-    ``start_trace`` called then returns only when the staging has ended; the
-    slice ends on the first answer after that (the launch has run and been
-    read back), or after half a cycle. Returns the trace's directory and the
-    slice's seconds."""
+    """Put the profiler around one cycle's device work: every launch between
+    two bursts of answers, whatever programs it runs. Placed by the harness's
+    own clock alone: the callers move in step with the launches, so the
+    window's first burst comes one cycle after the pre-roll's (``t_burst``),
+    and the slice opens ``trace_at_cycle_share`` of the pre-roll's cycle after
+    that burst, while the launch is still filling or being assembled on the
+    host. It ends on the first answer after that (the cycle's launches have
+    run and been read back), or after half a cycle. The share is set early,
+    with the cap, so that the slice holds the device work where the pre-roll's
+    cycle is anything from a tenth shorter to twice as long as the window's
+    (a checkout's first run builds the host codec in its pre-roll); the
+    program has no signal to go by, since it feeds its launch timers only
+    once a launch has been read back (PERF.md section 6, PR 29). ``start_trace`` called
+    while a launch is being staged returns only when the staging has ended
+    (0.37 s since PR 28). Returns the trace's directory and the slice's
+    seconds."""
     import jax
 
     at = min(t_burst + float(mix["trace_at_cycle_share"]) * cycle, t_close - 2.0)
@@ -157,31 +150,51 @@ def window_timers(before: Dict[str, float], after: Dict[str, float]) -> Dict[str
     return out
 
 
+PER_IMAGE_TIMER = "flyimg_stage_seconds"
+LAUNCH_TIMERS_KEPT = 7
+
+
 def device_report(planes: List[Dict[str, Any]], slice_s: float, window_s: float,
                   timers: Dict[str, List[float]]) -> Tuple[float, Dict[str, Any]]:
     """``busy_s`` and the ``breakdown`` of a traced run. The profiler ran for
-    a slice of the window around one launch's execution
+    a slice of the window around one cycle's device work
     (``trace_one_launch``), so the busy seconds are those of the slice: a
-    lower bound on the window's where it holds a second launch. The idle
+    lower bound on the window's where it holds a second cycle. The idle
     seconds are the window's: the part the profiler was off for, the slice's
-    own gaps, and then what the host was doing meanwhile, by the program's
-    own timers summed over the window."""
+    own, and then what the host was doing meanwhile, by the program's own
+    timers summed over the window (``<series> x<calls>``): the per-launch
+    ones first, by seconds, then the per-image stage series in what room is
+    left, so that the ten entries name the launch's phases and the heaviest
+    stages both. Every name is whole within the 64 characters the ledger
+    keeps of it."""
     dev_planes = trace_mod.device_planes(planes)
     busy = sum(trace_mod.busy_seconds(p) for p in dev_planes) / len(dev_planes)
     host_marks = [e for p in planes if p not in dev_planes
                   for line in p["lines"] for e in line["events"]]
-    in_slice = trace_mod.attribute_gaps(
+    log("# trace: gaps between the slice's device ops, seconds:", json.dumps(trace_mod.attribute_gaps(
         trace_mod.idle_gaps(dev_planes[0]), host_marks,
-        "traced slice, between device ops: host inside a flyimg:batch dispatch",
-        "traced slice, between device ops: host between dispatches")[:2]
-    between = sum(seconds for _, seconds in in_slice)
-    idle = [["window outside the traced slice (profiler off, PERF.md section 5)",
-             window_s - slice_s],
-            ["traced slice, before the first and after the last device op",
-             slice_s - busy - between]] + in_slice
-    idle += [[f"program timer over the window's {n} call(s): {key}", seconds]
-             for key, (n, seconds) in sorted(timers.items(), key=lambda kv: -kv[1][1])]
-    return busy, {"device_ops": trace_mod.top_ops(dev_planes), "idle_gaps": idle[:10]}
+        "host inside a flyimg:batch dispatch", "host between dispatches")[:4]))
+    idle = [["window outside the traced slice (profiler off)", window_s - slice_s],
+            ["traced slice, no device op running", slice_s - busy]]
+    ranked = sorted(timers.items(), key=lambda kv: -kv[1][1])
+    per_launch = [kv for kv in ranked if not kv[0].startswith(PER_IMAGE_TIMER)][:LAUNCH_TIMERS_KEPT]
+    per_image = [kv for kv in ranked if kv[0].startswith(PER_IMAGE_TIMER)]
+    idle += [[f"{key} x{n}", seconds]
+             for key, (n, seconds) in (per_launch + per_image)[:10 - len(idle)]]
+    return busy, {"device_ops": trace_mod.top_ops(dev_planes), "idle_gaps": idle}
+
+
+def bursts(records: List[Any], t_open: float, gap: float) -> List[List[float]]:
+    """The answers of the window grouped where the gap between two reaches
+    ``gap``: the closing rule's own reading of a burst."""
+    out: List[List[float]] = []
+    last = None
+    for done in sorted(r.done for r in records):
+        if last is None or done - last >= gap:
+            out.append([round(done - t_open, 2), 0.0, 0])
+        out[-1][1], out[-1][2] = round(done - t_open - out[-1][0], 2), out[-1][2] + 1
+        last = done
+    return out
 
 
 def percentile(values: List[float], q: float) -> float:
@@ -203,7 +216,8 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     config = copy.deepcopy(mf.load_config(manifest, cell["config"]))
     mix = copy.deepcopy(mf.load_traffic(cell["traffic"]))
     if toy:
-        apply_toy(config, mix)
+        mf.apply_toy(config, mix)
+    bound = mf.bind(manifest, cell["config"], config)
     device = system_mod.device_info(cell["chips"], require_chip)
     on_chip = device["platform"] != "cpu"
     phases: Dict[str, float] = {"import_and_backend": time.perf_counter() - t_process}
@@ -217,14 +231,14 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     def build_corpus() -> None:
         t = time.perf_counter()
         made["corpus"] = corpus_mod.make_corpus(
-            seed, frame, int(config["corpus"]["images"]))
+            bound.make_image, seed, frame, int(config["corpus"]["images"]))
         made["seconds"] = time.perf_counter() - t
 
     builder = threading.Thread(target=build_corpus, name="bench-corpus")
     builder.start()
     t = time.perf_counter()
     sut = system_mod.System(config)
-    warmed = sut.warm_programs(frame["width"], frame["height"], mix["warm_launch_sizes"])
+    warmed = {warmer: warm(sut, config, mix) for warmer, warm in bound.warmers}
     phases["warm_programs"] = time.perf_counter() - t
     trim_heap()
     compiles_in_warm = sut.compiles.count
@@ -232,11 +246,11 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     corpus = made["corpus"]
     phases["corpus"] = made["seconds"]
     log(f"# set-up: corpus {len(corpus)} x {frame['width']}x{frame['height']} "
-        f"({sum(map(len, corpus)) / 1e6:.1f} MB) in {made['seconds']:.1f} s; programs "
-        f"{warmed['in_shape']}->{warmed['resample_out']} sizes {mix['warm_launch_sizes']} in "
-        f"{phases['warm_programs']:.1f} s ({compiles_in_warm} built, {sut.compiles.hits} of them read "
-        f"from the cache {sut.cache_dir})")
-    log("# warm seconds by launch size:", json.dumps(warmed["seconds"]))
+        f"({sum(map(len, corpus)) / 1e6:.1f} MB, {config['corpus']['kind']}) in {made['seconds']:.1f} s; "
+        f"programs of {list(warmed)} in {phases['warm_programs']:.1f} s ({compiles_in_warm} built, "
+        f"{sut.compiles.hits} of them read from the cache {sut.cache_dir})")
+    for warmer, info in warmed.items():
+        log(f"# warmer {warmer}:", json.dumps(info))
 
     # -- the loop ---------------------------------------------------------------
     answers: Dict[Tuple[int, str], bytes] = {}
@@ -252,35 +266,38 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     loop = ClosedLoop(mix, len(corpus), seed, call)
     t = time.perf_counter()
     loop.start()
-    # the pre-roll ends on the answer that completes it: the same amount of
-    # work from the seed in every run. The callers move in step with a launch,
-    # so that answer is one of a launch's burst; the window opens a fixed
-    # share of the cycle later, between bursts, so that no burst (and no
-    # straggler of the pre-roll's) straddles the window's edge
-    t_burst = loop.wait_completed(int(mix["preroll_images"]),
-                                  float(mix.get("preroll_timeout_seconds", 900)))
+    # the pre-roll ends when the first wave of calls has all answered: the
+    # same amount of work from the seed in every run. The callers move in
+    # step with a launch, so that is the end of a burst of answers, and the
+    # window opens there as it closes: on the last answer of a burst, so
+    # that it holds whole cycles
+    t_burst = loop.wait_first_sent(int(mix["preroll_images"]),
+                                   float(mix.get("preroll_timeout_seconds", 900)))
     cycle = t_burst - t
-    t_open = t_burst + float(mix["window_opens_cycle_share"]) * cycle
-    time.sleep(max(t_open - time.perf_counter(), 0.0))
     t_open = time.perf_counter()
     phases["preroll"] = t_open - t
     compiles_in_preroll = sut.compiles.count - compiles_in_warm
     counters_before, cpu_before = sut.counters(), cpu_seconds()
     compiles_before = sut.compiles.count
     setup_s = t_open - t_process
+    # when the time is up nothing more is sent; all that was sent is waited
+    # for and counts, and the clock is read after that wait (traffic.py)
+    t_up = t_open + seconds
+    burst_gap = float(mix["burst_gap_cycle_share"]) * cycle
+    loop.close_at(t_up, burst_gap, float(mix["burst_cap_cycle_share"]) * cycle)
     trace_dir = slice_s = None
     if traced:
-        trace_dir, slice_s = trace_one_launch(loop, t_burst, cycle, t_open + seconds, mix)
-    time.sleep(max(t_open + seconds - time.perf_counter(), 0.0))
+        trace_dir, slice_s = trace_one_launch(loop, t_burst, cycle, t_up, mix)
+    time.sleep(max(t_up - time.perf_counter(), 0.0))
+    unanswered = loop.drain(float(mix["drain_seconds"]))
     t_close = time.perf_counter()
+    loop.stop()
     counters_after, cpu_after = sut.counters(), cpu_seconds()
     compiles_in_window = sut.compiles.count - compiles_before
-    loop.stop()
-    unanswered = loop.drain(float(mix["drain_seconds"]))
     answered = loop.window(t_open, t_close)
     peak = system_mod.memory_peak_bytes()
     rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-    late = [r for r in loop.all_records() if r.done > t_close]
+    after_up = sum(r.sent > t_up for r in answered)
     sut.close()
     watch.stop()
     log("# host RSS GiB by second since set-up began:",
@@ -297,9 +314,12 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
         f"{compiles_in_warm}, pre-roll {compiles_in_preroll}, window {compiles_in_window}; "
         f"wedged fallbacks in window {wedged:.0f}")
     log(f"# process peak RSS {rss / 2**30:.2f} GiB; device peak "
-        f"{(peak or 0) / 2**30:.2f} GiB; answered after the window closed: {len(late)}; "
-        f"never answered: {unanswered}; set-up phases {json.dumps({k: round(v, 2) for k, v in phases.items()})}")
+        f"{(peak or 0) / 2**30:.2f} GiB; sent after the time was up (a burst that straddled it): "
+        f"{after_up}; never answered: {unanswered}; set-up phases {json.dumps({k: round(v, 2) for k, v in phases.items()})}")
 
+    window_s = t_close - t_open
+    log(f"# window: {window_s:.2f} s, of which {t_close - t_up:.2f} s after the time was up; bursts of answers "
+        f"[seconds after the opening, seconds long, answers]: {json.dumps(bursts(answered, t_open, burst_gap))}")
     timers = window_timers(counters_before, counters_after)
     log("# program timers in the window [count, summed seconds]:",
         json.dumps({k: [n, round(v, 3)] for k, (n, v) in timers.items()}))
@@ -317,16 +337,17 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     if not traced:
         # what the harness can time itself; a cell reports those of them
         # that the manifest lists for it
+        lat = [r.done - r.sent for r in done]
         measured = {
-            "images_per_s": len(done) / seconds,
-            "latency_p95_ms": (1000.0 * percentile([r.done - r.sent for r in done], 0.95)
-                               if done else None),
+            "latency_p95_ms": 1000.0 * percentile(lat, 0.95) if lat else None,
+            "images_per_s": len(done) / window_s,
             "setup_s": setup_s,
         }
         for metric in mf.metrics_for(manifest, name, "end_to_end"):
             values[metric["name"]] = measured[metric["name"]]
-        log(f"# in the window: {len(done)} images answered, "
-            f"{measured['images_per_s']:.3f} img/s (not an end-to-end metric: PERF.md section 2)")
+        if lat:
+            log(f"# in the window: {len(done)} images answered in {window_s:.2f} s; latency p50 "
+                f"{1000 * percentile(lat, 0.5):.0f} ms, p95 {1000 * percentile(lat, 0.95):.0f} ms")
     else:
         planes: List[Dict[str, Any]] = []
         xplane = trace_mod.find_xplane(trace_dir) if trace_dir else None
@@ -334,21 +355,13 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
             planes = trace_mod.load_xplane(xplane, HOST_KEEP)
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        from . import reference
-
-        opts = reference.parse_options(config["options"]["url"])
-        geo = reference.geometry(opts, frame["width"], frame["height"])
-        rw, rh = geo["resize"]
-        out_w, out_h = geo["cols"][1] - geo["cols"][0], geo["rows"][1] - geo["rows"][0]
         ctx: Dict[str, Any] = {
             "counters_before": counters_before, "counters_after": counters_after,
             "timings": [r.info[0] for r in done], "images": len(done),
             "cpu_before": cpu_before, "cpu_after": cpu_after,
             "trace_planes": planes, "trace_slice_s": slice_s, "launch_sizes": sizes,
             "device": device,
-            "work_per_image": work.resize_work(
-                frame["width"], frame["height"], frame["width"] * out_w / rw,
-                frame["height"] * out_h / rh, out_w, out_h),
+            "work_per_image": bound.reference.work(config),
         }
         for metric in mf.metrics_for(manifest, name, "per_layer"):
             spec = mf.load_metric(metric["name"])
@@ -357,7 +370,6 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
             values[metric["name"]] = mf.load_reader(spec["reader"])(ctx, **spec["args"])
         dev_planes = trace_mod.device_planes(planes)
         if dev_planes and on_chip:
-            window_s = t_close - t_open
             busy, breakdown = device_report(planes, slice_s, window_s, timers)
             device_out["busy_s"], device_out["window_s"] = busy, window_s
             log(f"# trace: device busy {busy:.4f} s of the {slice_s:.2f} s slice; "
@@ -370,7 +382,7 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
 
     # -- correct: after the window, the memory reading and the program's close --
     t = time.perf_counter()
-    verdict = compare.Judge(config, corpus).judge(answers, unanswered)
+    verdict = compare.Judge(bound, corpus).judge(answers, unanswered)
     numbers = verdict["numbers"]
     numbers["compiles_in_window"] = {"value": float(compiles_in_window), "limit": 0.0}
     numbers["wedged_fallbacks"] = {"value": float(wedged), "limit": 0.0}

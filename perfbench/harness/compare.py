@@ -1,97 +1,73 @@
-"""The comparison that decides ``correct``.
+"""The comparison that decides ``correct``: what is common to every
+configuration. The render and the numbers are the configuration's reference's
+(``references/<name>.py``, handed in as a module).
 
 Every distinct answer the window produced (one per source image and output
 digest, so that identical bytes are judged once and no answer is skipped) is
-decoded and held against the plain reference's render of the same original.
-The numbers compared, each the worst over the answers, each with a limit of
-its own from the configuration's file:
+decoded and handed, with the other answers to the same original, to the
+reference's ``judge_original``, which renders that original once and returns
+each answer's numbers. Each number compared is the worst over the answers and
+has a limit of its own from the configuration's file; a number that an answer
+could not be read for fails its limit. Beside the reference's numbers:
 
-``dims_gap``   |width| + |height| by which an answer's size misses the
-               reference's. Exact: limit 0.
-``block_err``  largest |mean over a 32x32 block and channel| of answer minus
-               reference, in uint8 levels. A JPEG's own quantisation noise
-               averages out over a block; a shifted window, a swapped image,
-               a damaged patch or operands of too few bits do not.
 ``unanswered`` calls that never answered, and answers that would not decode.
 
-The root mean square of answer minus reference is printed beside them and not
-compared: it is mostly the output JPEG's own quantisation, and the control
-reads under twice the program there (PERF.md section 2).
+``rms_err``, where the reference returns it, is printed beside them and not
+compared.
 """
 
 from __future__ import annotations
 
-import io
 from concurrent.futures import ThreadPoolExecutor
+from types import SimpleNamespace
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
-from PIL import Image
 
-from . import reference
+from . import plain
 
-BLOCK = 32
 MISSING = 1.0e9   # a number that could not be read fails its limit
 
 
-def block_and_rms(answer: np.ndarray, ref: np.ndarray) -> Tuple[float, float]:
-    diff = answer.astype(np.float32) - ref
-    rms = float(np.sqrt(np.mean(diff * diff)))
-    h, w = (diff.shape[0] // BLOCK) * BLOCK, (diff.shape[1] // BLOCK) * BLOCK
-    if h == 0 or w == 0:
-        return float(np.abs(diff.mean(axis=(0, 1))).max()), rms
-    blocks = diff[:h, :w].reshape(h // BLOCK, BLOCK, w // BLOCK, BLOCK, 3).mean(axis=(1, 3))
-    return float(np.abs(blocks).max()), rms
-
-
 class Judge:
-    """Holds the reference's render of each original, made once, and judges
-    answers against it."""
+    """Judges a run's answers against the reference's render of each
+    original, made once."""
 
-    def __init__(self, config: Dict[str, Any], corpus: List[bytes]) -> None:
-        self.options = reference.parse_options(config["options"]["url"])
-        self.limits = dict(config["limits"])
+    def __init__(self, bound: SimpleNamespace, corpus: List[bytes]) -> None:
+        """``bound``: what the configuration names, as ``manifest.bind`` loads it."""
+        self.reference, self.options, self.limits = bound.reference, bound.options, bound.limits
         self._corpus = corpus
-        self._refs: Dict[int, np.ndarray] = {}
 
-    def _ref(self, item: int) -> np.ndarray:
-        if item not in self._refs:
-            self._refs[item] = reference.render(self._corpus[item], self.options)
-        return self._refs[item]
-
-    def judge_one(self, item: int, answer_bytes: bytes) -> Dict[str, float]:
-        try:
-            with Image.open(io.BytesIO(answer_bytes)) as im:
-                answer = np.asarray(im.convert("RGB"))
-        except Exception:
-            return {"dims_gap": MISSING, "block_err": MISSING, "rms_err": MISSING,
-                    "unanswered": 1.0}
-        frame = self._ref(item)
-        gap = abs(answer.shape[1] - frame.shape[1]) + abs(answer.shape[0] - frame.shape[0])
-        if gap:
-            return {"dims_gap": float(gap), "block_err": MISSING, "rms_err": MISSING}
-        block, rms = block_and_rms(answer, frame)
-        return {"dims_gap": 0.0, "block_err": block, "rms_err": rms}
+    def _judge_original(self, item: int, answers: List[bytes]) -> Tuple[List[Dict[str, float]], int]:
+        decoded: List[np.ndarray] = []
+        for data in answers:
+            try:
+                decoded.append(plain.decode(data))
+            except Exception:   # whatever Pillow makes of damaged bytes: no answer
+                pass
+        verdicts = self.reference.judge_original(self._corpus[item], decoded, self.options)
+        return verdicts, len(answers) - len(decoded)
 
     def judge(self, answers: Dict[Tuple[int, str], bytes], unanswered: int = 0,
               threads: int = 4) -> Dict[str, Any]:
         """All distinct answers -> ``{"correct", "numbers": {name: {value, limit}}}``."""
-        keys = sorted(answers)
+        by_item: Dict[int, List[bytes]] = {}
+        for key in sorted(answers):
+            by_item.setdefault(key[0], []).append(answers[key])
         with ThreadPoolExecutor(max_workers=threads) as pool:
-            # one render per original, in parallel, then the answers
-            list(pool.map(self._ref, sorted({k[0] for k in keys})))
-            verdicts = list(pool.map(lambda k: self.judge_one(k[0], answers[k]), keys))
+            judged = list(pool.map(lambda kv: self._judge_original(*kv), by_item.items()))
+        verdicts = [v for vs, _ in judged for v in vs]
         numbers: Dict[str, Dict[str, float]] = {}
-        for name in ("dims_gap", "block_err"):
-            values = [v.get(name, 0.0) for v in verdicts] or [MISSING]
+        for name in self.reference.NUMBERS:
+            values = [v.get(name, MISSING) for v in verdicts] or [MISSING]
             numbers[name] = {"value": float(max(values)), "limit": float(self.limits[name])}
-        lost = float(unanswered) + sum(v.get("unanswered", 0.0) for v in verdicts)
-        if not keys:
+        lost = float(unanswered) + sum(n for _, n in judged)
+        if not answers:
             lost += 1.0  # a window with no answer at all proves nothing
         numbers["unanswered"] = {"value": lost, "limit": 0.0}
         correct = all(n["value"] <= n["limit"] for n in numbers.values())
-        rms = max([v.get("rms_err", 0.0) for v in verdicts] or [MISSING])
-        return {"correct": bool(correct), "numbers": numbers, "answers": len(keys),
+        rms = max([v.get("rms_err", MISSING) for v in verdicts] or [MISSING])
+        return {"correct": bool(correct), "numbers": numbers, "answers": len(answers),
                 "rms_err_not_compared": float(rms)}
 
 

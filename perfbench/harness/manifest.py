@@ -1,15 +1,26 @@
-"""BENCHMARK.json and the data files it names.
+"""BENCHMARK.json and the files it names.
 
 Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file of its own, found by the name in the manifest:
+per-layer metric is a file of its own, found by the name in the manifest or
+in the configuration's file:
 
-    configs/<config>.json     sizes, options, parameters, limits of `correct`
+    configs/<config>.json     sizes, options, parameters, limits of `correct`,
+                              and, by name, its reference, corpus kind and warmers
     traffic/<traffic>.json    parameters of the one general load generator
     metrics/<metric>.json     which reader reads the metric, and its arguments
     readers/<reader>.py       the reader's code (``read(ctx, **args)``)
+    references/<name>.py      a configuration's ``"reference"``: the plain
+                              render of its semantics and the numbers that
+                              decide `correct` (``REFERENCE_INTERFACE``)
+    corpora/<kind>.py         its ``"corpus": {"kind"}``: ``make_image``
+    warmers/<name>.py         each of its ``"warm"``: ``warm(sut, config, mix)``
 
-Adding a cell or a metric whose reader exists adds files and entries and
-edits nothing that is there.
+A plug is a Python file loaded by its name (``load_plug``); no file of the
+harness imports one. A configuration's plugs are looked for beside its own
+file first (``<dir of configs/>/references/`` ...), then here, so that a
+deployment kept elsewhere (``tests/fixtures/``) brings its own. Adding a
+cell, a metric whose reader exists, or a configuration of other semantics
+adds files and entries and edits nothing that is there.
 """
 
 from __future__ import annotations
@@ -18,7 +29,8 @@ import importlib.util
 import json
 import os
 import re
-from typing import Any, Callable, Dict, List, Optional
+from types import ModuleType, SimpleNamespace
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -27,6 +39,7 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TRAFFIC_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
+REFERENCE_INTERFACE = ("NUMBERS", "parse", "render", "judge_original", "work")
 
 
 class ManifestError(ValueError):
@@ -75,17 +88,75 @@ def load_metric(name: str) -> Dict[str, Any]:
     return load_json(path)
 
 
-def load_reader(name: str) -> Callable[..., Optional[float]]:
-    """``readers/<name>.py`` -> its ``read(ctx, **args)`` function."""
-    if not NAME_RE.match(name):
-        raise ManifestError(f"bad reader name {name!r}")
-    path = os.path.join(BENCH_DIR, "readers", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"perfbench_reader_{name}", path)
-    if spec is None or spec.loader is None or not os.path.exists(path):
-        raise ManifestError(f"no reader {path}")
+def load_plug(kind: str, name: str, needs: Sequence[str],
+              roots: Sequence[str] = (BENCH_DIR,)) -> ModuleType:
+    """``<root>/<kind>/<name>.py`` of the first root that has it, as a module
+    that has every attribute of ``needs``."""
+    if not isinstance(name, str) or not NAME_RE.match(name):
+        raise ManifestError(f"bad name {name!r} under {kind}/")
+    for root in roots:
+        path = os.path.join(root, kind, name + ".py")
+        if os.path.exists(path):
+            break
+    else:
+        raise ManifestError(f"no {kind}/{name}.py under {' or '.join(roots)}")
+    spec = importlib.util.spec_from_file_location(f"perfbench_{kind}_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    missing = [attr for attr in needs if not hasattr(module, attr)]
+    if missing:
+        raise ManifestError(f"{path} lacks {', '.join(missing)}")
+    return module
+
+
+def load_reader(name: str) -> Callable[..., Optional[float]]:
+    """``readers/<name>.py`` -> its ``read(ctx, **args)`` function."""
+    return load_plug("readers", name, ("read",)).read
+
+
+def plug_roots(manifest: Dict[str, Any], name: str) -> Sequence[str]:
+    """Where the plugs of configuration ``name`` are looked for: beside its
+    own file (``configs/`` and ``references/`` ... are siblings), then here."""
+    return (os.path.dirname(os.path.dirname(config_file(manifest, name))), BENCH_DIR)
+
+
+def bind(manifest: Dict[str, Any], name: str, config: Dict[str, Any]) -> SimpleNamespace:
+    """What the configuration ``name`` names, loaded and held to its rules
+    before anything of the program or of JAX is imported (a warmer imports
+    the program inside ``warm``, not as it is loaded): its reference with its
+    reading of the options, its corpus kind and its warmers. An option the
+    reference does not render, a judged number without a limit and a limit
+    without a number are errors here, not after a run. ``config`` is the
+    configuration as it will be run (at its toy size, where that is what
+    runs)."""
+    roots = plug_roots(manifest, name)
+    for key in ("reference", "corpus", "warm", "limits"):
+        if key not in config:
+            raise ManifestError(f"config {name}: names no {key!r}; no default stands in")
+    reference = load_plug("references", config["reference"], REFERENCE_INTERFACE, roots)
+    try:
+        options = reference.parse(config)
+    except (KeyError, ValueError) as exc:
+        raise ManifestError(f"config {name}, reference {config['reference']}: {exc}") from exc
+    if set(reference.NUMBERS) != set(config["limits"]):
+        raise ManifestError(
+            f"config {name}: its reference judges {sorted(reference.NUMBERS)} and its limits "
+            f"are for {sorted(config['limits'])}; every number has a limit and every limit a number")
+    kind = load_plug("corpora", config["corpus"].get("kind"), ("make_image",), roots)
+    warmers = [(w, load_plug("warmers", w, ("warm",), roots).warm) for w in config["warm"]]
+    return SimpleNamespace(reference=reference, options=options, limits=dict(config["limits"]),
+                           make_image=kind.make_image, warmers=warmers)
+
+
+def apply_toy(config: Dict[str, Any], mix: Optional[Dict[str, Any]] = None) -> None:
+    """The configuration's toy size, for rehearsals and tests on the CPU."""
+    toy = config["toy"]
+    config["frame"], config["options"] = toy["frame"], toy["options"]
+    config["corpus"] = dict(config["corpus"], **toy["corpus"])
+    config["parameters"] = toy.get("parameters", config.get("parameters"))
+    for key in ("in_flight", "preroll_images", "warm_launch_sizes"):
+        if mix is not None:
+            mix[key] = toy[key]
 
 
 def cells_of(manifest: Dict[str, Any], metric: Dict[str, Any]) -> List[str]:

@@ -1,13 +1,8 @@
-"""The plain reference: the same semantics, written down independently.
-
-It imports nothing of the program and takes nothing the program has made.
-Pillow decodes and encodes; everything between is numpy in float32 (weights
-are worked out in float64). The semantics are those of the flyimg URL options
-the configurations use (docs/url-options.md, after ImageMagick):
-
-``w_,h_,c_1``  ``-thumbnail WxH^ -gravity Center -extent WxH``: scale both axes
-               so the frame covers the box (each rounded to the nearest pixel),
-               then cut the box out of the middle.
+"""The plain reference's shared pieces: what every reference of
+``references/`` renders and judges with, written down independently of the
+program. It imports nothing of the program and takes nothing the program has
+made. Pillow decodes and encodes; everything between is numpy in float32
+(weights are worked out in float64).
 
 Resizing is ImageMagick's Lanczos: output pixel ``i`` samples the source at
 ``(i + 0.5) * scale - 0.5``, the three-lobe kernel is stretched by the
@@ -23,10 +18,12 @@ from __future__ import annotations
 
 import io
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import numpy as np
 from PIL import Image
+
+BLOCK = 32
 
 def _quantiser(operands: str) -> Callable[[np.ndarray, bool], np.ndarray]:
     """``q(array, is_weight)``: the array as a kernel with operands of this
@@ -117,48 +114,34 @@ def resize(rgb: np.ndarray, out_w: int, out_h: int,
     return np.ascontiguousarray(out.reshape(-1, oh, 3).transpose(1, 0, 2))
 
 
-def _round_half_up(x: float) -> int:
+def round_half_up(x: float) -> int:
     return int(math.floor(x + 0.5))
-
-
-def geometry(options: Dict[str, Any], src_w: int, src_h: int) -> Dict[str, Any]:
-    """What the options make of a ``src_w x src_h`` frame: the size the whole
-    frame is resized to, and the window of that which is kept."""
-    tw, th = int(options["width"]), int(options["height"])
-    scale = max(tw / src_w, th / src_h)
-    rw = max(_round_half_up(src_w * scale), 1)
-    rh = max(_round_half_up(src_h * scale), 1)
-    x0, y0 = max((rw - tw) // 2, 0), max((rh - th) // 2, 0)
-    return {"resize": (rw, rh), "rows": (y0, min(y0 + th, rh)),
-            "cols": (x0, min(x0 + tw, rw))}
-
-
-def parse_options(url: str) -> Dict[str, Any]:
-    """The reference's own reading of a flyimg options string: the keys the
-    configurations use (``w_``, ``h_``, ``c_1``); any other is an error here,
-    since the reference would not be rendering it."""
-    parts = url.split(",")
-    out: Dict[str, Any] = {}
-    for part in parts:
-        key, _, value = part.partition("_")
-        if key == "w":
-            out["width"] = int(value)
-        elif key == "h":
-            out["height"] = int(value)
-        elif part != "c_1":
-            raise ValueError(f"the reference does not render option {part!r}")
-    if "c_1" not in parts or set(out) != {"width", "height"}:
-        raise ValueError(f"the reference renders w_,h_,c_1 together, not {url!r}")
-    return out
-
-
-def render(data: bytes, options: Dict[str, Any], operands: str = "float32") -> np.ndarray:
-    """Encoded original -> the resized, cut frame as float32 ``[h, w, 3]``."""
-    rgb = decode(data)
-    geo = geometry(options, rgb.shape[1], rgb.shape[0])
-    return resize(rgb, geo["resize"][0], geo["resize"][1], geo["rows"],
-                  geo["cols"], operands)
 
 
 def to_u8(frame: np.ndarray) -> np.ndarray:
     return np.clip(np.floor(frame + 0.5), 0.0, 255.0).astype(np.uint8)
+
+
+def against_frame(answer: np.ndarray, frame: np.ndarray) -> dict:
+    """What every reference judges a decoded answer by, against its render:
+    ``dims_gap`` (|width| + |height| by which the sizes miss) and, where that
+    is 0, ``block_err`` and ``rms_err`` (``block_and_rms``)."""
+    gap = abs(answer.shape[1] - frame.shape[1]) + abs(answer.shape[0] - frame.shape[0])
+    if gap:
+        return {"dims_gap": float(gap)}
+    block, rms = block_and_rms(answer, frame)
+    return {"dims_gap": 0.0, "block_err": block, "rms_err": rms}
+
+
+def block_and_rms(answer: np.ndarray, ref: np.ndarray) -> Tuple[float, float]:
+    """Largest |mean over a 32x32 block and channel| of answer minus
+    reference, and the root mean square of it, in uint8 levels. A JPEG's own
+    quantisation noise averages out over a block; a shifted window, a swapped
+    image, a damaged patch or operands of too few bits do not."""
+    diff = answer.astype(np.float32) - ref
+    rms = float(np.sqrt(np.mean(diff * diff)))
+    h, w = (diff.shape[0] // BLOCK) * BLOCK, (diff.shape[1] // BLOCK) * BLOCK
+    if h == 0 or w == 0:
+        return float(np.abs(diff.mean(axis=(0, 1))).max()), rms
+    blocks = diff[:h, :w].reshape(h // BLOCK, BLOCK, w // BLOCK, BLOCK, 3).mean(axis=(1, 3))
+    return float(np.abs(blocks).max()), rms

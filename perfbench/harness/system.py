@@ -1,11 +1,12 @@
 """The system under test: ``ImageHandler.transform_bytes`` behind the two
 controllers, built the way ``flyimg_tpu/bulk.py`` builds them.
 
-This is the only module of the benchmark that imports the program. From it
-the benchmark takes the entry point, the program's counters (as Prometheus
-text), its per-image ``timings`` and the names of its launches; every
-yardstick (traffic, reference, comparison, trace reduction, peaks) is the
-benchmark's own.
+This module and the warmers (``warmers/<name>.py``, which build the programs
+a configuration's traffic will use before the first request) are the two
+places of the benchmark that import the program. From it the benchmark takes
+the entry point, the program's counters (as Prometheus text), its per-image
+``timings`` and the names of its launches; every yardstick (traffic,
+reference, comparison, trace reduction, peaks) is the benchmark's own.
 """
 
 from __future__ import annotations
@@ -13,10 +14,7 @@ from __future__ import annotations
 import re
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import Any, Dict, Optional, Tuple
 
 _SAMPLE = re.compile(r"^([A-Za-z_:][A-Za-z0-9_:]*)(\{[^}]*\})?\s+(\S+)$")
 
@@ -93,6 +91,18 @@ class CompileCounter:
                 self.hits += 1
 
 
+def options_bag(config: Dict[str, Any], params: Any):
+    """The program's own reading of the configuration's options string, made
+    anew for each call as a request's is."""
+    from flyimg_tpu.spec.options import OptionsBag
+
+    return OptionsBag(
+        str(config["options"]["url"]), options_keys=params.by_key("options_keys"),
+        default_options=params.by_key("default_options"),
+        separator=params.by_key("options_separator", ","),
+    )
+
+
 class System:
     """Handler + device controller + host-codec controller for one
     configuration. ``transform`` is the timed entry."""
@@ -130,86 +140,18 @@ class System:
             storage=None, params=params, batcher=self.batcher,
             codec_batcher=self.codec_batcher, metrics=self.metrics,
         )
-        self._options_keys = params.by_key("options_keys")
-        self._default_options = params.by_key("default_options")
-        self._separator = params.by_key("options_separator", ",")
-        self.options_str = str(config["options"]["url"])
         self.extension = str(config["output"]["extension"])
 
     # -- the timed entry ------------------------------------------------
 
     def transform(self, data: bytes) -> Tuple[bytes, Dict[str, float]]:
         from flyimg_tpu.service.output_image import EXT_TO_MIME, OutputSpec
-        from flyimg_tpu.spec.options import OptionsBag
 
-        options = OptionsBag(
-            self.options_str, options_keys=self._options_keys,
-            default_options=self._default_options, separator=self._separator,
-        )
+        options = options_bag(self.config, self.params)
         spec = OutputSpec(name=f"bench.{self.extension}", extension=self.extension,
                           mime=EXT_TO_MIME[self.extension])
         timings: Dict[str, float] = {}
         return self.handler.transform_bytes(data, options, spec, timings), timings
-
-    # -- set-up ---------------------------------------------------------
-
-    def _group_args(self, width: int, height: int):
-        """The arguments ``BatchController.submit`` derives for a full
-        ``width x height`` frame under this configuration's options: what
-        keys the batched program. Mirrors ``submit`` with the program's own
-        helpers; if it ever drifts, the pre-roll compiles and the run says
-        so (``compiles_in_preroll``)."""
-        from flyimg_tpu.ops.compose import _bucket_dim, plan_layout
-        from flyimg_tpu.ops.resample import kernel_mode, select_band_taps
-        from flyimg_tpu.spec.options import OptionsBag
-        from flyimg_tpu.spec.plan import build_plan
-
-        options = OptionsBag(
-            self.options_str, options_keys=self._options_keys,
-            default_options=self._default_options, separator=self._separator,
-        )
-        plan = build_plan(options, width, height)
-        layout = plan_layout(plan)
-        in_shape = (_bucket_dim(height), _bucket_dim(width))
-        if plan.extent is not None:
-            resample_out = layout.resample_out
-        else:
-            resample_out = (_bucket_dim(layout.resample_out[0], 64),
-                            _bucket_dim(layout.resample_out[1], 64))
-        band = select_band_taps(kernel_mode(), plan.filter_method, in_shape,
-                                layout.span_y, layout.span_x, layout.out_true)
-        return plan, layout, in_shape, resample_out, band
-
-    def warm_programs(self, width: int, height: int, sizes: Sequence[int]) -> Dict[str, Any]:
-        """Compile (or read from the cache) the batched program of every
-        launch size in ``sizes`` without running it, several at once: a
-        first request must never wait on a compile longer than the program's
-        own time limits."""
-        import jax
-        from flyimg_tpu.runtime.batcher import build_batched_program
-
-        plan, layout, in_shape, resample_out, band = self._group_args(width, height)
-
-        def one(batch: int) -> float:
-            t = time.perf_counter()
-            handle = build_batched_program(
-                batch, in_shape, resample_out, layout.pad_canvas,
-                layout.pad_offset, plan.device_plan(), None, False, band,
-            )
-            f32 = np.float32
-            handle.precompile((
-                jax.ShapeDtypeStruct((batch,) + in_shape + (3,), np.uint8),
-                jax.ShapeDtypeStruct((batch, 2), f32),
-                jax.ShapeDtypeStruct((batch, 2), f32),
-                jax.ShapeDtypeStruct((batch, 2), f32),
-                jax.ShapeDtypeStruct((batch, 2), f32),
-            ))
-            return time.perf_counter() - t
-
-        with ThreadPoolExecutor(max_workers=max(len(sizes), 1)) as pool:
-            seconds = list(pool.map(one, sizes))
-        return {"in_shape": list(in_shape), "resample_out": list(resample_out),
-                "seconds": dict(zip(map(str, sizes), seconds))}
 
     # -- what the benchmark reads from the program ------------------------
 

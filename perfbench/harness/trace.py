@@ -24,6 +24,7 @@ Interval = Tuple[float, float]
 DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
 def find_xplane(log_dir: str) -> Optional[str]:
@@ -101,8 +102,9 @@ def top_ops(planes: Sequence[Plane], n: int = 10) -> List[List[Any]]:
     for plane in planes:
         for name, _, dur in op_events(plane):
             # an HLO op's event is named by its whole instruction; its name
-            # and result type are enough to find it again
-            name = name.split(" fusion(")[0].split(" copy(")[0][:96]
+            # and result type are enough to find it again, and without the
+            # layouts both are whole within what the ledger keeps of a name
+            name = LAYOUT.sub("", name.split(" fusion(")[0].split(" copy(")[0])[:96]
             total[name] = total.get(name, 0.0) + dur / 1e9
     ranked = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[name, seconds] for name, seconds in ranked]

@@ -6,12 +6,22 @@ parameters (``traffic/<name>.json``):
 ``in_flight``       how many callers
 ``order``           ``shuffled_cycle``: the corpus in a seeded order, cycled,
                     so every seed offers the same set of sizes in another order
-``preroll_images``  completions that end the pre-roll (set-up); the time they
-                    take is the harness's measure of a cycle
-``window_opens_cycle_share``  the window opens this share of a cycle after the
-                    pre-roll's last answer: between bursts, where the callers
-                    move in step, so that no burst straddles the window's edge
-``drain_seconds``   how long to wait, after the window, for answers still due
+``preroll_images``  the pre-roll (set-up) ends when the first so many calls
+                    sent have all answered; the time they take is the
+                    harness's measure of a cycle. The window opens on the
+                    last of those answers: at the end of a burst, as it closes
+``burst_gap_cycle_share``, ``burst_cap_cycle_share``  how the window closes
+                    (``close_at``): when the time is up nothing more is sent,
+                    all that was sent is waited for, and the clock is read
+                    after that wait. The callers move in step with a launch,
+                    so a burst of answers that straddles the time-up is let
+                    through whole (its callers send again, and the last launch
+                    is a full one, not a part of one that waits out the
+                    program's deadline): a caller answered after the time-up
+                    sends again only while no gap between answers since before
+                    it has reached the first share of a cycle, and not later
+                    than the second share of a cycle after it
+``drain_seconds``   how long to wait, after the time-up, for answers still due
 ``warm_launch_sizes``  padded launch sizes the loop can produce, to be warmed
 ``trace_at_cycle_share``  a traced run calls the profiler this share of a
                     cycle after the pre-roll's last answer, while the window's
@@ -34,6 +44,7 @@ import numpy as np
 
 @dataclass
 class Record:
+    seq: int
     item: int
     sent: float
     done: float
@@ -59,6 +70,8 @@ class ClosedLoop:
         self._records: List[Record] = []
         self._sent = 0
         self._stop = False
+        self._close: Optional[tuple] = None  # (time-up, gap, cap), seconds
+        self._last_done = float("-inf")
         self._threads: List[threading.Thread] = []
 
     def _next_item(self) -> int:
@@ -72,16 +85,22 @@ class ClosedLoop:
                 if self._stop:
                     return
                 item = self._next_item()
+                seq = self._sent
                 self._sent += 1
             sent = time.perf_counter()
             try:
                 info = self._call(item)
-                rec = Record(item, sent, time.perf_counter(), True, info)
+                rec = Record(seq, item, sent, time.perf_counter(), True, info)
             except Exception as exc:  # a failed request is a result, not a crash
-                rec = Record(item, sent, time.perf_counter(), False,
+                rec = Record(seq, item, sent, time.perf_counter(), False,
                              error=f"{type(exc).__name__}: {exc}")
             with self._lock:
                 self._records.append(rec)
+                if self._close is not None and rec.done >= self._close[0]:
+                    up, gap, cap = self._close
+                    if rec.done - self._last_done >= gap or rec.done >= up + cap:
+                        self._stop = True
+                self._last_done = max(self._last_done, rec.done)
                 self._lock.notify_all()
 
     def start(self) -> None:
@@ -90,18 +109,21 @@ class ClosedLoop:
             self._threads.append(t)
             t.start()
 
-    def wait_completed(self, count: int, timeout: float) -> float:
-        """Block until ``count`` calls have answered; returns the clock at the
-        answer that made it so (the window then opens on a completion)."""
+    def wait_first_sent(self, count: int, timeout: float) -> float:
+        """Block until the first ``count`` calls sent have all answered;
+        returns the clock at the last of their answers (the window then opens
+        on a completion, and no answer of the pre-roll's falls into it)."""
         deadline = time.perf_counter() + timeout
         with self._lock:
-            while len(self._records) < count:
+            while True:
+                first = [r.done for r in self._records if r.seq < count]
+                if len(first) >= count:
+                    return max(first)
                 left = deadline - time.perf_counter()
                 if left <= 0:
                     raise TimeoutError(
-                        f"pre-roll: {len(self._records)} of {count} answers in {timeout:.0f} s")
+                        f"pre-roll: {len(first)} of the first {count} calls answered in {timeout:.0f} s")
                 self._lock.wait(timeout=left)
-            return self._records[count - 1].done
 
     def wait_answer_after(self, start: float, timeout: float) -> Optional[float]:
         """Block until a call answers later than ``start``; returns the clock
@@ -116,6 +138,11 @@ class ClosedLoop:
                 if left <= 0:
                     return None
                 self._lock.wait(timeout=left)
+
+    def close_at(self, up: float, gap: float, cap: float) -> None:
+        """The time is up at ``up``: see ``burst_gap_cycle_share`` above."""
+        with self._lock:
+            self._close = (up, gap, cap)
 
     def stop(self) -> None:
         with self._lock:

@@ -4,13 +4,16 @@ the profiler around one full launch's execution: ``cell.trace_one_launch``).
 ``what``:
   ``roofline``     least time the chip could take for the needed work of the
                    launches traced, over the device time of the XLA modules
-                   matching ``module``. Needed work is ``harness/work.py``'s,
-                   from the configuration's true sizes. The traced launches
-                   are the window's largest (the profiler is put on a full
-                   one); their images are the window's images less the
-                   padded sizes of its other launches (program counters),
-                   which is exact where those hold 1 or 2 images and never
-                   too many.
+                   matching ``module``. Needed work is that of the kernel
+                   ``work`` as the configuration's reference gives it for
+                   one image (``references/<name>.py`` ``work``, through
+                   ``harness/work.py``, from the configuration's true
+                   sizes); ``images`` names the program's counter of the
+                   images that kernel has done. The traced launches are the
+                   window's largest (the profiler is put on a full one);
+                   their images are the window's images less the padded
+                   sizes of its other launches (program counters), which is
+                   exact where those hold 1 or 2 images and never too many.
   ``launch_idle``  of the seconds the program held its launches between
                    dispatch and completed read-back (the histogram ``timer``
                    of the program, summed over the window), the share in
@@ -21,10 +24,10 @@ the profiler around one full launch's execution: ``cell.trace_one_launch``).
 No device plane in the trace (a CPU run): nothing read. Never 0 for a share
 of a roofline."""
 
-from perfbench.harness import trace, work
+from perfbench.harness import trace, work as work_mod
 
 
-def read(ctx, what, module, timer=None):
+def read(ctx, what, module, timer=None, work=None, images=None):
     planes = trace.device_planes(ctx.get("trace_planes") or [])
     seconds = count = 0
     for plane in planes:
@@ -40,12 +43,13 @@ def read(ctx, what, module, timer=None):
     if what == "roofline":
         sizes = sorted((int(size) for size, n in ctx["launch_sizes"].items() for _ in range(n)),
                        reverse=True)
-        images = delta("flyimg_images_processed_total") - sum(sizes[count:])
-        if images <= 0:
+        done = delta(images) - sum(sizes[count:])
+        if done <= 0 or work not in ctx["work_per_image"]:
             return None
-        least = work.least_seconds(ctx["work_per_image"], work.peaks(ctx["device"]["kind"]))
-        ctx.setdefault("notes", {})["roofline_bound"] = least["bound"]
-        return 100.0 * least["seconds"] * images / seconds
+        least = work_mod.least_seconds(ctx["work_per_image"][work],
+                                       work_mod.peaks(ctx["device"]["kind"]))
+        ctx.setdefault("notes", {})[f"{work}_roofline_bound"] = least["bound"]
+        return 100.0 * least["seconds"] * done / seconds
     if what == "launch_idle":
         held = delta(timer + "_sum")
         if held <= seconds:

@@ -34,7 +34,10 @@ def main(argv=None) -> int:
 
     try:
         doc = manifest.load_manifest()
-        manifest.workload(doc, ns.workload)
+        config = manifest.workload(doc, ns.workload)["config"]
+        # what the configuration names is loaded and held to its rules
+        # before the program or the backend is
+        manifest.bind(doc, config, manifest.load_config(doc, config))
         import flyimg_tpu  # noqa: F401  the system under test
     except (OSError, ImportError, manifest.ManifestError) as exc:
         print(f"perfbench: {exc}", file=sys.stderr)

@@ -3,7 +3,8 @@
 
     JAX_PLATFORMS=cpu python3 perfbench/selfcheck.py [--no-run]
 
-1. loads the manifest and every file it names, and holds every name, unit
+1. loads the manifest and every file it names (each configuration's
+   reference, corpus kind and warmers with it), and holds every name, unit
    and layer to the contract's character rules;
 2. checks the needed-work function on hand-computed shapes, and that an
    unknown device kind is an error;
@@ -47,9 +48,27 @@ def files() -> None:
               f"config {cfg['name']}: file and manifest agree on name and source")
         check(sorted(config["reduced"]) == sorted(cfg["reduced"]),
               f"config {cfg['name']}: reduced agrees")
-        for key in ("frame", "corpus", "options", "output", "guarantees", "parameters",
-                    "source_defines", "assumed", "limits", "toy"):
+        for key in ("frame", "corpus", "options", "reference", "warm", "output", "guarantees",
+                    "parameters", "source_defines", "assumed", "limits", "toy"):
             check(key in config, f"config {cfg['name']}: has {key}")
+        for toy in (False, True):
+            sized = json.loads(json.dumps(config))
+            if toy:
+                manifest.apply_toy(sized)
+            try:
+                bound = manifest.bind(doc, cfg["name"], sized)
+                kernels = bound.reference.work(sized)
+                check(all(set(w) == {"flops", "bytes"} for w in kernels.values()),
+                      f"config {cfg['name']}{' (toy)' if toy else ''}: reference {sized['reference']}, corpus kind "
+                      f"{sized['corpus']['kind']} and warmers {sized['warm']} load; needed work for {sorted(kernels)}")
+            except manifest.ManifestError as exc:
+                check(False, f"config {cfg['name']}: {exc}")
+        refused = dict(config, options={"url": config["options"]["url"] + ",zz_1"})
+        try:
+            manifest.bind(doc, cfg["name"], refused)
+            check(False, f"config {cfg['name']}: an option its reference does not render is refused at load")
+        except manifest.ManifestError:
+            check(True, f"config {cfg['name']}: an option its reference does not render is refused at load")
     for cell in doc["workloads"]:
         mix = manifest.load_traffic(cell["traffic"])
         check(mix["loop"] == "closed" and mix["in_flight"] > 0,
@@ -108,30 +127,40 @@ def trace_reduction() -> None:
           "gap attribution: 0.3 s inside a dispatch, 0.2 s between")
     from perfbench.harness import cell
 
-    busy, breakdown = cell.device_report(
-        planes, 3.0, 51.0, {"flyimg_device_seconds": [1, 25.0], "flyimg_x_seconds": [2, 7.0]})
-    idle = dict((n, s) for n, s in breakdown["idle_gaps"])
-    check(math.isclose(busy, 1.1) and len(breakdown["idle_gaps"]) == 6
-          and math.isclose(idle["window outside the traced slice (profiler off, PERF.md section 5)"], 48.0)
-          and math.isclose(idle["traced slice, before the first and after the last device op"], 1.4)
-          and breakdown["idle_gaps"][4] == ["program timer over the window's 1 call(s): flyimg_device_seconds", 25.0],
-          "traced run's report: busy 1.1 s of a 3 s slice in a 51 s window, idle by where and by program timer")
+    timers = {"flyimg_device_seconds": [1, 25.0], "flyimg_x_seconds": [2, 7.0],
+              'flyimg_stage_seconds{stage="device"}': [64, 1700.0]}
+    timers.update({f"flyimg_phase_{i}_seconds": [1, float(i)] for i in range(7)})
+    timers.update({f'flyimg_stage_seconds{{stage="s{i}"}}': [64, 100.0 * i] for i in range(4)})
+    busy, breakdown = cell.device_report(planes, 3.0, 51.0, timers)
+    idle = breakdown["idle_gaps"]
+    check(math.isclose(busy, 1.1) and len(idle) == 10
+          and idle[0] == ["window outside the traced slice (profiler off)", 48.0]
+          and idle[1][0] == "traced slice, no device op running" and math.isclose(idle[1][1], 1.9)
+          and idle[2] == ["flyimg_device_seconds x1", 25.0] and idle[3] == ["flyimg_x_seconds x2", 7.0]
+          and [n for n, _ in idle[4:9]] == [f"flyimg_phase_{i}_seconds x1" for i in (6, 5, 4, 3, 2)]
+          and idle[9] == ['flyimg_stage_seconds{stage="device"} x64', 1700.0]
+          and all(len(n) <= 64 for n, _ in idle),
+          "traced run's report: busy 1.1 s of a 3 s slice in a 51 s window; seven per-launch timers by "
+          "seconds, then the heaviest per-image stage, every name within 64 characters")
     read = manifest.load_reader("trace_share")
     ctx = {"trace_planes": planes, "counters_before": {"flyimg_device_seconds_sum": 2.0},
            "counters_after": {"flyimg_device_seconds_sum": 12.0, "flyimg_batches_total": 2.0,
                               "flyimg_images_processed_total": 4.0},
            "launch_sizes": {"2": 2}, "device": {"kind": "TPU v5 lite"},
-           "work_per_image": {"flops": 0.0, "bytes": 819e9 / 100}}
+           "work_per_image": {"resample": {"flops": 0.0, "bytes": 819e9 / 100}}}
     check(math.isclose(read(ctx, "launch_idle", "^jit_program", timer="flyimg_device_seconds"), 90.0),
           "launch_idle: modules ran 1.0 s of the 10 s the launches were held: 90%")
-    check(math.isclose(read(ctx, "roofline", "^jit_program"), 100.0 * 0.01 * 2 * 2 / 1.0),
+    kernel = {"work": "resample", "images": "flyimg_images_processed_total"}
+    check(math.isclose(read(ctx, "roofline", "^jit_program", **kernel), 100.0 * 0.01 * 2 * 2 / 1.0),
           "roofline: 2 launches of 2 images needing 10 ms each in 1.0 s of module time: 4%")
     lone = dict(ctx, launch_sizes={"1": 1, "2": 2},
                 counters_after=dict(ctx["counters_after"], flyimg_images_processed_total=5.0))
-    check(math.isclose(read(lone, "roofline", "^jit_program"), 4.0),
+    check(math.isclose(read(lone, "roofline", "^jit_program", **kernel), 4.0),
           "roofline: a lone launch of 1 outside the trace does not change it")
-    check(read(dict(ctx, trace_planes=[]), "roofline", "^jit_program") is None,
+    check(read(dict(ctx, trace_planes=[]), "roofline", "^jit_program", **kernel) is None,
           "no device plane: the reader reads nothing")
+    check(read(ctx, "roofline", "^jit_program", work="scores", images="flyimg_aux_items_total") is None,
+          "a kernel the configuration's reference gives no work for: the reader reads nothing")
     recorded = os.path.join(HERE, "fixtures", "recorded_trace.json")
     if os.path.exists(recorded):
         doc = manifest.load_json(recorded)

@@ -2,31 +2,33 @@
 underneath: ``correct`` has to come out false. Of the faults the contract
 lists, these cells can have one kind, an answer altered where it is produced
 (they keep no state across steps, take no mean over a batch and use one
-chip); it is planted three ways."""
+chip); it is planted three ways. The cells are those of the benchmark and the
+one of the fixture deployment (``fixtures/``: semantics other than crop-fill,
+with a reference, a configuration and a manifest of its own and nothing
+else), which the same harness has to run end to end unedited."""
 
 import io
 import time
 
 import numpy as np
 import pytest
+from conftest import MANIFESTS, every
 from PIL import Image
 
 from perfbench.harness import cell, system
 
-CELLS = ["dslr-backfill-saturated"]
+
+def _run(which, name, seed=77, traced=False):
+    return cell.run_cell(MANIFESTS[which], name, seed, 3.0, traced,
+                         t_process=time.perf_counter(), toy=True, require_chip=False)
 
 
-def _run(doc, name):
-    return cell.run_cell(doc, name, 77, 3.0, False, t_process=time.perf_counter(),
-                         toy=True, require_chip=False)
-
-
-@pytest.mark.parametrize("name", CELLS)
-def test_sound_run_is_correct_and_prints_no_device_metric(doc, name):
-    result = cell.run_cell(doc, name, 78, 3.0, True,
-                           t_process=time.perf_counter(), toy=True, require_chip=False)
+@pytest.mark.parametrize("which,name", every("workloads"))
+def test_sound_run_is_correct_and_prints_no_device_metric(which, name):
+    result = _run(which, name, 78, traced=True)
     assert result["correct"], result["compared"]
     assert result["failed"] == 0 and result["attempted"] > 0
+    assert {"decode_ms", "encode_ms", "images_per_launch"} <= set(result["metrics"])
     assert "resample_roofline" not in result["metrics"]
     assert "busy_s" not in result["device"]
     assert list(result)[-1] == "compared"
@@ -53,8 +55,8 @@ def _shift(rgb):
 
 
 @pytest.mark.parametrize("fault", ["patch_brightened", "shifted_two_pixels", "another_images_answer"])
-@pytest.mark.parametrize("name", CELLS)
-def test_altered_answer_is_not_correct(monkeypatch, doc, fault, name):
+@pytest.mark.parametrize("which,name", every("workloads"))
+def test_altered_answer_is_not_correct(monkeypatch, fault, which, name):
     sound = system.System.transform
     first = {}
 
@@ -69,5 +71,5 @@ def test_altered_answer_is_not_correct(monkeypatch, doc, fault, name):
         return first["out"], timings
 
     monkeypatch.setattr(system.System, "transform", broken)
-    result = _run(doc, name)
+    result = _run(which, name)
     assert not result["correct"], result["compared"]
