@@ -10,7 +10,9 @@ traced geometry scalars, so a group executes as ONE jitted vmapped program:
 Flush policy (reference-free; this subsystem has no analog in the
 per-request reference): a group flushes when it reaches ``max_batch`` or
 when its oldest member has waited ``deadline_ms`` — the standard
-throughput/latency dial for dynamic batching. Batch sizes are bucketed to
+throughput/latency dial for dynamic batching; a lone request, and an aux
+group whatever else is pending, flushes at once when the executor is idle
+(``_group_ready``). Batch sizes are bucketed to
 powers of two (padding repeats the last image) so XLA compiles a handful of
 batch shapes per program, not one per occupancy.
 
@@ -84,6 +86,9 @@ from flyimg_tpu.spec.plan import TransformPlan
 from flyimg_tpu.testing import faults
 
 MAX_BATCH_BUCKET = 64
+# the name (and `controller` label) of the controller that runs transform
+# launches: ``BatchController``'s default
+TRANSFORM_CONTROLLER = "device"
 
 
 def containment_params(params) -> dict:
@@ -472,7 +477,7 @@ class BatchController:
         pipeline_depth: int = 2,
         max_queue_depth: int = 0,
         shed_retry_after_s: float = 1.0,
-        name: str = "device",
+        name: str = TRANSFORM_CONTROLLER,
         batch_retries: int = 2,
         bisect_enable: bool = True,
         quarantine_ttl_s: float = 0.0,
@@ -489,6 +494,15 @@ class BatchController:
         from flyimg_tpu.runtime.resilience import AdmissionGate
 
         self.name = name
+        # the `controller` label this controller's AUX launches are
+        # observed under (flyimg_batch_* histograms, the efficiency
+        # window): the series of the transform controller hold transform
+        # launches alone, so that occupancy, launch sizes and the fill wait
+        # read what they say; a controller that runs aux work only (the
+        # codec controller) has one kind of launch and keeps its name
+        self.aux_name = (
+            f"{name}_aux" if name == TRANSFORM_CONTROLLER else name
+        )
         # the LIVE flush policy as ONE atomic (max_batch, deadline_s)
         # tuple: every flush decision reads the pair through a single
         # reference load, so an online policy update (apply_policy — the
@@ -824,7 +838,9 @@ class BatchController:
         """Queue one item for a batched AUXILIARY program (smart-crop
         scoring, face detection, ...): concurrent submissions sharing
         ``(runner, key)`` execute as ONE ``runner(payloads)`` call on the
-        executor thread, under the same flush policy as transform groups.
+        executor thread; an aux group flushes when it is full, when its
+        oldest member has waited the deadline, or at once when the executor
+        is idle (``_group_ready``).
         ``runner`` must be a stable module-level callable (it is part of
         the group key) returning one result per payload, in order."""
         future: Future = Future()
@@ -1257,12 +1273,17 @@ class BatchController:
                      policy: Tuple[int, float]) -> bool:
         """The ONE flush-readiness predicate (used by both the wait loop and
         the pop — drift between two copies would make _run busy-spin):
-        batch full, deadline expired, or the lone-request fast path. The
+        batch full, deadline expired, or the idle-executor fast path. The
         fast path: the executor thread IS the device owner, so evaluating
         this means the chip is idle — holding a single request for the
         deadline buys no batching (any later arrival lands in the next
         batch, which forms while this one executes). Cuts sparse-traffic
-        p99 by deadline_ms (SURVEY.md section 7 hard part 2).
+        p99 by deadline_ms (SURVEY.md section 7 hard part 2). An AUX group
+        takes the fast path whatever else is pending: its runner holds this
+        thread to the end, so what arrives meanwhile forms the next aux
+        launch, up the power-of-two ladder, and the deadline (which a bulk
+        deployment sets to seconds so that a transform launch fills) would
+        only hold a post-pass back while the executor has nothing to run.
         ``policy`` is the caller's one-shot read of ``self._policy``: one
         decision pass must judge every group against ONE (size, timeout)
         pair even if apply_policy lands mid-pass."""
@@ -1271,7 +1292,9 @@ class BatchController:
             return True
         if now - group.members[0].enqueued_at >= deadline_s:
             return True
-        return self.lone_flush and total_pending == 1
+        return self.lone_flush and (
+            total_pending == 1 or group.runner is not None
+        )
 
     def _ready_group(self) -> bool:
         now = time.monotonic()
@@ -1653,10 +1676,12 @@ class BatchController:
                 "flyimg_aux_items_total",
                 "Items through batched auxiliary programs",
             ).inc(n)
-            # efficiency window only (an aux record skips the transform
+            # efficiency window and launch histograms under the aux
+            # label (``aux_name``; an aux record skips the transform
             # counters): aux launches have no padding or compile step
             self.metrics.record_launch(
-                self.name, launch, trace_id=self._member_trace_id(members)
+                self.aux_name, launch,
+                trace_id=self._member_trace_id(members),
             )
             row = self._record_flight(group, members, launch)
             copies = self._end_batch_span(span_obj, members, launch)
@@ -2179,7 +2204,8 @@ class BatchController:
                 )
             faults.fire("batcher.drain", key=group.key, n=n, batch=n)
             self.metrics.record_launch(
-                self.name, launch, trace_id=self._member_trace_id(members)
+                self.aux_name, launch,
+                trace_id=self._member_trace_id(members),
             )
             row, copies = self._record_flight(group, members, launch), []
         else:

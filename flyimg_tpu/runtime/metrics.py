@@ -494,7 +494,11 @@ class MetricsRegistry:
         ever), one histogram per phase and the bytes it moved each way
         (``flyimg_device_transfer_bytes_total``); every launch,
         aux included, feeds the per-controller efficiency record
-        (``record_batch_launch``). ``resolve`` ends after the sinks are
+        (``record_batch_launch``) under the label its controller gives
+        it: the transform controller's aux launches go under
+        ``<name>_aux`` (``BatchController.aux_name``), so the series of
+        ``controller="device"`` hold transform launches alone.
+        ``resolve`` ends after the sinks are
         fed and arrives through ``record_launch_resolve``."""
         exemplar = trace_id if self.exemplars_enabled else None
         if not launch.aux:

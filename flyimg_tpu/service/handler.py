@@ -1151,12 +1151,14 @@ class ImageHandler:
     def _aux_result(self, future: Future, stage: str,
                     timings: Dict[str, float],
                     deadline: Optional[Deadline]):
-        """Wait for one codec-controller member and split the wait by the
+        """Wait for one aux member (a codec launch's, or the smart-crop
+        scorer's on the device controller) and split the wait by the
         member's own instants (runtime/batcher.py ``launch_times``):
         ``<stage>_queue`` is enqueue -> its launch popped, ``<stage>_run``
         the runner call that carried it. What is left of the enclosing
-        stage is the handler's own work around the codec (probe, ROI
-        window, the copy out of the pool's buffer, the wake-up)."""
+        stage is the handler's own work around the launch (probe, ROI
+        window, the copy out of the pool's buffer, the prescale and the
+        cut, the wake-up)."""
         result = future.result(timeout=self._device_wait_s(deadline))
         times = getattr(future, "launch_times", None)
         if times is not None:
@@ -1880,13 +1882,21 @@ class ImageHandler:
                         # program shape bench.py measures; the per-image
                         # path would recompile analyse_features for every
                         # distinct post-resize size
-                        item = sc.prepare_work(out)
+                        # the host's share before the wait: the prescale
+                        # to the scorer's work size and its bookkeeping
+                        with tracing.stage("smartcrop_prepare", timings,
+                                           self.metrics,
+                                           span_name="smartcrop.prepare"):
+                            item = sc.prepare_work(out)
                         try:
-                            crop = self.batcher.submit_aux(
-                                ("smc", item.bucket, item.step),
-                                item,
-                                sc.find_best_crops_batched,
-                            ).result(timeout=self._device_wait_s(deadline))
+                            crop = self._aux_result(
+                                self.batcher.submit_aux(
+                                    ("smc", item.bucket, item.step),
+                                    item,
+                                    sc.find_best_crops_batched,
+                                ),
+                                "smartcrop", timings, deadline,
+                            )
                         except FutureTimeout:
                             if deadline is not None:
                                 deadline.check("smartcrop")

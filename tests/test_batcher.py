@@ -249,6 +249,82 @@ def test_aux_and_transform_groups_coexist(controller):
     assert f_transform.result(timeout=120).shape == (150, 200, 3)
 
 
+def test_aux_item_never_waits_a_long_deadline_behind_a_pending_transform():
+    """The aux flush rule (docs/architecture.md): on a controller whose
+    deadline is seconds long, with a transform group pending and far from
+    full, an aux item goes as soon as the executor is idle — the lone-flush
+    fast path holds for an aux group whatever else is pending — while the
+    transform group keeps waiting by its own rule."""
+    import time as _t
+
+    ctl = BatchController(max_batch=4, deadline_ms=2_000.0)
+    try:
+        img = make_test_image(600, 400, seed=3)
+        plan = _plan("w_200,h_150,c_1", 600, 400)
+        # two pending transforms: neither alone (total_pending != 1) nor full
+        pending = [ctl.submit(img, plan) for _ in range(2)]
+        t0 = _t.monotonic()
+        assert ctl.submit_aux(("inc",), 41, lambda ps: [p + 1 for p in ps]) \
+            .result(timeout=30) == 42
+        waited = _t.monotonic() - t0
+        assert waited < 1.0, f"aux item waited {waited:.2f}s (deadline 2s)"
+        assert not any(f.done() for f in pending)  # their rule is unchanged
+        assert all(f.result(timeout=120).shape == (150, 200, 3) for f in pending)
+        assert _t.monotonic() - t0 >= 1.5
+    finally:
+        ctl.close()
+
+
+def test_aux_items_arriving_during_an_aux_launch_form_the_next_one():
+    import threading
+
+    started, release = threading.Event(), threading.Event()
+    calls = []
+
+    def runner(payloads):
+        calls.append(list(payloads))
+        if len(calls) == 1:
+            started.set()
+            release.wait(timeout=30)
+        return list(payloads)
+
+    ctl = BatchController(max_batch=8, deadline_ms=10_000.0)
+    try:
+        first = ctl.submit_aux(("toy",), 0, runner)
+        assert started.wait(timeout=30)
+        rest = [ctl.submit_aux(("toy",), i, runner) for i in (1, 2, 3)]
+        release.set()
+        assert [f.result(timeout=30) for f in [first] + rest] == [0, 1, 2, 3]
+        assert calls == [[0], [1, 2, 3]]
+    finally:
+        ctl.close()
+
+
+@pytest.mark.parametrize("name,label", [("device", "device_aux"),
+                                        ("codec", "codec")])
+def test_aux_launches_are_observed_apart_from_transform_launches(name, label):
+    """``controller="device"`` series hold transform launches alone; a
+    controller that runs aux work only keeps its name."""
+    ctl = BatchController(max_batch=2, deadline_ms=10_000.0, name=name)
+    try:
+        futures = [ctl.submit_aux(("toy",), i, list) for i in range(2)]
+        assert [f.result(timeout=30) for f in futures] == [0, 1]
+        text = ctl.metrics.render_prometheus()
+        for family in ("flyimg_batch_bucket_size", "flyimg_batch_occupancy_ratio",
+                       "flyimg_batch_queue_wait_seconds"):
+            assert f'{family}_count{{controller="{label}"}} 1' in text
+        if label != name:
+            assert [line for line in text.splitlines()
+                    if f'controller="{name}"' in line
+                    and not line.startswith("flyimg_batcher_queue_depth")] == []
+        assert ctl.metrics.batch_efficiency(label).stats()["window_batches"] == 1
+        assert ctl.metrics.batch_efficiency(name).stats()["window_batches"] == \
+            (1 if label == name else 0)
+        assert ctl.metrics.summary().get("flyimg_aux_items_total") == 2.0
+    finally:
+        ctl.close()
+
+
 def test_mixed_size_rotate_shares_one_batch():
     """Two DIFFERENT-sized r_45 requests must land in one group (one
     compiled executable) and match the single-image path pixel-exactly."""

@@ -629,9 +629,20 @@ def test_swr_coalesces_n_stale_hits_into_one_refresh(tmp_path, source_png):
     from flyimg_tpu.service.handler import ImageHandler
 
     injector = faults.install(faults.FaultInjector())
-    # a pass-through plan: the harness counts firings only for points
-    # with a plan installed — this is the render counter
-    injector.plan("brownout.refresh", lambda **_: faults.PASS)
+    # the harness counts firings only for points with a plan installed —
+    # this is the render counter. The plan HOLDS the one refresh until
+    # all six hits have been served: a key coalesces while it is queued
+    # or refreshing, and on a loaded machine the re-render of this tiny
+    # image used to finish (entry rewritten, key released) before the
+    # last hit threads had started — a late hit then read a fresh entry,
+    # or queued a second refresh (the whole-run flake)
+    served = threading.Event()
+
+    def held_refresh(**_):
+        served.wait(timeout=60)
+        return faults.PASS
+
+    injector.plan("brownout.refresh", held_refresh)
     metrics = MetricsRegistry()
     params = _params(tmp_path)
     engine = BrownoutEngine(
@@ -665,6 +676,7 @@ def test_swr_coalesces_n_stale_hits_into_one_refresh(tmp_path, source_png):
         t.start()
     for t in threads:
         t.join(timeout=60)
+    served.set()
     assert not errors
     assert len(results) == 6
     # every hit served immediately from the stale entry

@@ -71,11 +71,11 @@ class _System:
             codec_batcher=self.codec, metrics=self.metrics,
         )
 
-    def transform(self, data, timings=None):
+    def transform(self, data, timings=None, text="w_120,h_90,c_1"):
         spec = OutputSpec(name="t.jpg", extension="jpg",
                           mime=EXT_TO_MIME["jpg"])
         return self.handler.transform_bytes(
-            data, _options(self.params), spec, timings
+            data, _options(self.params, text), spec, timings
         )
 
     def close(self):
@@ -247,6 +247,39 @@ def test_transform_bytes_under_a_trace_yields_the_span_tree(system):
     assert timings["decode_queue"] + timings["decode_run"] <= timings["decode"] + 1e-3
     assert timings["encode_queue"] + timings["encode_run"] <= timings["encode"] + 1e-3
     assert timings["device_queue"] <= timings["device"] + 1e-3
+
+
+def test_smart_crop_wait_is_split_like_the_codec_waits(system):
+    """The smc_1 post-pass: the host prescale (``smartcrop_prepare``), then
+    the scorer's aux launch on the device controller, split by the member's
+    own instants into ``smartcrop_queue`` / ``smartcrop_run``, all three
+    inside ``smartcrop``; the aux launch is observed apart from the
+    transform launch."""
+    trace = tracing.Trace(name="img0.jpg")
+    timings = {}
+    with tracing.activate(trace):
+        out = system.transform(_jpeg(240, 360), timings, text="w_120,h_120,smc_1")
+    trace.finish()
+    assert Image.open(io.BytesIO(out)).size[0] in (79, 80)
+    root = trace.as_dict()["spans"][0]
+    tree = _tree(root)
+    assert tree["img0.jpg"] == ["decode", "batch_wait", "smartcrop", "encode"]
+    assert [n for n in tree["smartcrop"] if n != "aux_execute"] == [
+        "smartcrop.prepare", "smartcrop.queue", "smartcrop.run"]
+    assert _find(root, "aux_execute") is not None
+    for stage, span_name in (("smartcrop", "smartcrop"),
+                             ("smartcrop_prepare", "smartcrop.prepare"),
+                             ("smartcrop_queue", "smartcrop.queue"),
+                             ("smartcrop_run", "smartcrop.run")):
+        assert _find(root, span_name)["duration_s"] == pytest.approx(
+            timings[stage], abs=2e-3), stage
+        assert _stage_count(system.metrics, stage) == 1, stage
+    assert timings["smartcrop_prepare"] + timings["smartcrop_queue"] \
+        + timings["smartcrop_run"] <= timings["smartcrop"] + 1e-3
+    text = system.metrics.render_prometheus()
+    assert 'flyimg_batch_bucket_size_count{controller="device"} 1' in text
+    assert 'flyimg_batch_bucket_size_count{controller="device_aux"} 1' in text
+    assert "flyimg_aux_items_total 1" in text  # the codec keeps its own registry
 
 
 def test_resolve_phase_reaches_the_attached_span_and_the_flight_row(system):
@@ -588,6 +621,13 @@ _TIMINGS = [
      "encode": 2.0, "encode_queue": 0.75, "encode_run": 1.0},
     {"decode": 1.0},  # a fallback decode: no codec launch carried it
 ]
+# the smc_1 post-pass (PR 31): the second image fell back to the
+# single-image scorer after a wedged wait, so no launch carried it
+_TIMINGS[0].update(smartcrop=0.4, smartcrop_prepare=0.01,
+                   smartcrop_queue=0.25, smartcrop_run=0.125)
+_TIMINGS[1].update(smartcrop=0.8, smartcrop_prepare=0.03)
+_BEFORE.update({"flyimg_aux_items_total": 10.0, "flyimg_aux_batches_total": 8.0})
+_AFTER.update({"flyimg_aux_items_total": 138.0, "flyimg_aux_batches_total": 72.0})
 
 
 @pytest.mark.parametrize("metric,expected", [
@@ -601,13 +641,19 @@ _TIMINGS = [
     ("d2h_ms_per_image", 5.0),
     ("device_run_ms_per_image", 4.0),
     ("readback_gap_ms", 150.0),
+    ("smartcrop_ms", 600.0),
+    ("smartcrop_prepare_ms", 20.0),
+    ("smartcrop_queue_ms", 250.0),
+    ("smartcrop_run_ms", 125.0),
+    ("aux_items_per_launch", 2.0),
 ])
 def test_new_metric_files_read_the_recorded_fixture(metric, expected):
     from perfbench.harness import manifest
 
     doc = manifest.load_manifest()
     entry = next(m for m in doc["per_layer"] if m["name"] == metric)
-    assert entry["moves"] == "latency_p95_ms"
+    assert entry["moves"] == (
+        "images_per_s" if metric == "aux_items_per_launch" else "latency_p95_ms")
     spec = manifest.load_metric(metric)
     read = manifest.load_reader(spec["reader"])
     planes = manifest.load_json(
@@ -620,6 +666,33 @@ def test_new_metric_files_read_the_recorded_fixture(metric, expected):
     empty = {"counters_before": {}, "counters_after": {},
              "timings": [{"decode": 1.0}], "images": 1, "trace_planes": []}
     assert read(empty, **spec["args"]) is None
+
+
+def test_scorer_roofline_counts_each_traced_aux_launch_as_one_item():
+    """``readers/trace_aux_share.py``: the scorer's two modules, three
+    launches of 0.5 ms + 0.25 ms; needed work 81.9 kB an item, 0.1 us at the
+    v5e's 819 GB/s; each launch counted as one item: 3 x 0.1 us of 2.25 ms."""
+    from perfbench.harness import manifest
+
+    spec = manifest.load_metric("scorer_roofline")
+    read = manifest.load_reader(spec["reader"])
+    events = []
+    for k in range(3):
+        events.append(["jit__batched_weighted(11)", 1e9 + k * 1e7, 5e5])
+        events.append(["jit__batched_scores(12)", 1e9 + k * 1e7 + 6e5, 2.5e5])
+    events.append(["jit_program(7)", 2e9, 4e8])   # the transform launch: not the scorer's
+    planes = [{"name": "/device:TPU:0",
+               "lines": [{"name": "XLA Modules", "events": events}]}]
+    ctx = {"trace_planes": planes, "device": {"kind": "TPU v5 lite"},
+           "work_per_image": {"smartcrop_score": {"flops": 2.0e6, "bytes": 81900.0}}}
+    assert read(ctx, **spec["args"]) == pytest.approx(100.0 * 3 * 1e-7 / 2.25e-3)
+    assert ctx["notes"]["smartcrop_score_traced_launches"] == 3
+    # nothing of the scorer in the trace (the parent's program in a cell
+    # without smc_1; a CPU run): nothing read, nothing raised
+    assert read(dict(ctx, trace_planes=[planes[0] | {"lines": [
+        {"name": "XLA Modules", "events": events[-1:]}]}]), **spec["args"]) is None
+    assert read(dict(ctx, trace_planes=[]), **spec["args"]) is None
+    assert read(dict(ctx, work_per_image={"resample": {}}), **spec["args"]) is None
 
 
 def test_trace_phase_gap_pairs_each_readback_with_the_module_before_it():
@@ -655,8 +728,23 @@ def test_manifest_with_the_new_metrics_keeps_the_rules():
                          "images_per_launch", "padded_slot_share",
                          "roundtrip_ms_per_image", "resample_roofline",
                          "device_idle_share"]
-    assert len(names) == 18 and len(set(names)) == 18
+    # the eighteen as accepted, in their order; what later PRs add comes
+    # after them and keeps the manifest's own rules: a list of the cells
+    # that report it, each a cell that reports the metric it moves, a
+    # metric file, and a reader that loads
+    assert names[8:18] == ["decode_queue_ms", "decode_run_ms",
+                           "encode_queue_ms", "encode_run_ms",
+                           "assemble_ms_per_image", "resolve_ms_per_image",
+                           "h2d_ms_per_image", "d2h_ms_per_image",
+                           "device_run_ms_per_image", "readback_gap_ms"]
+    assert len(set(names)) == len(names)
+    cells = {c["name"] for c in doc["workloads"]}
     for metric in doc["per_layer"][8:]:
-        assert metric["workloads"] == ["dslr-backfill-saturated"]
+        assert metric["workloads"] and set(metric["workloads"]) <= cells
+        for cell in metric["workloads"]:
+            assert metric["moves"] in {
+                m["name"] for m in manifest.metrics_for(doc, cell, "end_to_end")}
         assert callable(manifest.load_reader(
             manifest.load_metric(metric["name"])["reader"]))
+    for metric in doc["per_layer"][8:18]:
+        assert metric["workloads"] == ["dslr-backfill-saturated"]
