@@ -14,7 +14,11 @@ throughput/latency dial for dynamic batching; a lone request, and an aux
 group whatever else is pending, flushes at once when the executor is idle
 (``_group_ready``). Batch sizes are bucketed to
 powers of two (padding repeats the last image) so XLA compiles a handful of
-batch shapes per program, not one per occupancy.
+batch shapes per program, not one per occupancy. A transform group owns its
+launch's padded host block from the moment it is made, and ``submit`` copies
+each member's frame into its slot on the caller's thread while the launch is
+still filling (``_Group``, ``_copy_in``): a launch that is full is ready to
+stage.
 
 A single executor thread owns device DISPATCH: groups launch serially (the
 chip executes serially anyway), submissions return futures usable from
@@ -262,6 +266,10 @@ class _Pending:       # ndarray fields ("truth value is ambiguous" in any
     # the same instant as enqueued_at on the clock spans and launch phases
     # use (time.perf_counter()): the start of this member's queue wait
     enqueued_pc: float = field(default_factory=time.perf_counter)
+    # the slot of its group's block that ``submit`` reserved for this
+    # member's pixels and copied them into (``_Group.block``); None where
+    # the group had no block or no slot left: ``_assemble`` copies then
+    slot: Optional[int] = None
 
 
 class _Launch:
@@ -434,8 +442,40 @@ _PHASE_NAMES = {
 }
 
 
+def _fill_slot(frames: np.ndarray, k: int, image: np.ndarray,
+               edge: bool) -> None:
+    """One member's pixels into slot ``k`` of a launch's padded host block
+    ``u8[batch, bh, bw, 3]`` (zeros): THE copy of a frame on the host,
+    made by ``submit`` on the caller's thread where the group owns its
+    block, else by ``_assemble``. ``edge``: a pixel-op-only bucket, whose
+    padding replicates the frame's edge so that convolutions stay correct
+    at the valid region's boundary; every other bucket keeps zeros there
+    (the resample never samples them)."""
+    h, w = image.shape[:2]
+    bh, bw = frames.shape[1:3]
+    if edge and (h, w) != (bh, bw):
+        frames[k] = np.pad(
+            image, ((0, bh - h), (0, bw - w), (0, 0)), mode="edge"
+        )
+    else:
+        frames[k, :h, :w] = image
+
+
 @dataclass
 class _Group:
+    """The members queued for one program identity, and (transform groups)
+    the padded host block of the launch they will make: ``block`` is
+    ``np.zeros`` of ``u8[_padded_batch(max_batch), bh, bw, 3]`` from the
+    moment the group is made, member ``k`` of the group owns slot ``k`` of
+    it, and ``submit`` copies the member's pixels there on the caller's
+    thread while the group is still filling (``copying`` counts the copies
+    in flight: a group with one is never popped). A pop hands the block to
+    the popped launch and leaves what stays queued without one, so does a
+    copy that raised: members without a block (or beyond its capacity) are
+    copied by ``_assemble`` at their own launch. An untouched page of a
+    large ``np.zeros`` costs nothing, so a lone launch pays for one
+    frame."""
+
     key: Tuple
     in_shape: Tuple[int, int]
     resample_out: Optional[Tuple[int, int]]
@@ -461,6 +501,10 @@ class _Group:
     # this launch was held to by the HBM budget / family ceiling, None
     # when admission didn't constrain the pop
     mem_cap: Optional[int] = None
+    # the launch's padded host block and the submit-time copies into it
+    # still in flight (class docstring); aux groups own none
+    block: Optional[np.ndarray] = None
+    copying: int = 0
 
 
 class BatchController:
@@ -678,6 +722,17 @@ class BatchController:
     ) -> Future:
         """Queue one image+plan; resolves to the uint8 output array.
 
+        The member's pixels are copied into its launch's padded host block
+        HERE, on the caller's thread, before the future is returned: under
+        the lock the member joins its group and is given the next slot of
+        the group's block, outside it the frame is copied there
+        (``_copy_in``), and the landing is recorded under the lock again.
+        The callers of a filling launch are parked until it has run, so the
+        copies of a launch run beside one another and beside the decodes
+        that fill it, and ``_assemble`` finds the block whole. ``image``
+        stays the caller's array and the member keeps it: every recovery
+        path assembles from it.
+
         ``src_window`` (docs/host-pipeline.md "ROI window math"): the
         image is only the window of the plan's source at this (x, y)
         offset — the ROI-decode contract. Spans are per-member traced
@@ -749,7 +804,7 @@ class BatchController:
                 )
         elif plan.rotate is None or rotate_dynamic:
             # pixel-op-only and rotate plans ride input buckets too
-            # (edge-replicate fill in _execute keeps convolutional ops
+            # (edge-replicate fill in _fill_slot keeps convolutional ops
             # correct; dynamic rotate never samples padding). The valid
             # region is sliced per member. Same policy as ops/compose.py.
             in_shape = (_bucket_dim(h), _bucket_dim(w))
@@ -817,7 +872,7 @@ class BatchController:
                     ("__quarantine__", next(self._quarantine_seq)),
                 )
         group_key = key
-        self._admit_and_enqueue(
+        reserved = self._admit_and_enqueue(
             group_key,
             pending,
             lambda: _Group(
@@ -830,9 +885,61 @@ class BatchController:
                 rotate_dynamic=rotate_dynamic,
                 band_taps=band_taps,
                 base_key=base_key,
+                # the launch's block, at the policy in force now (made
+                # under the lock: untouched pages of a large np.zeros
+                # cost nothing until a member is copied into them)
+                block=np.zeros(
+                    (self._padded_batch(self.max_batch), *in_shape, 3),
+                    dtype=np.uint8,
+                ),
             ),
         )
+        if reserved is not None:
+            self._copy_in(*reserved, pending)
         return future
+
+    def _copy_in(self, group: _Group, block: np.ndarray,
+                 pending: _Pending) -> None:
+        """The submit-time copy of one member into the slot it was given,
+        outside the lock, on the submitting thread; the landing is recorded
+        under the lock, and that of the group's last copy in flight with a
+        ``notify``, which is what wakes the executor for a group that was
+        held back for them (``_group_ready``). A copy
+        that raises fails this member alone: it leaves the group, which
+        lets go of its block (a slot is empty now), so that the members
+        left are copied by ``_assemble``. Timed as
+        ``flyimg_batch_member_copy_seconds`` and, on the future, as
+        ``copy_times`` (``time.perf_counter()`` start and end), from which
+        the handler makes the request's ``device_copy_in`` stage."""
+        error = None
+        t0 = time.perf_counter()
+        try:
+            _fill_slot(
+                block, pending.slot, pending.image,
+                group.resample_out is None,
+            )
+        except BaseException as exc:
+            # settled below whatever was raised (and re-raised there unless
+            # an Exception): a copy left in flight would hold the group
+            error = exc
+        t1 = time.perf_counter()
+        with self._lock:
+            group.copying -= 1
+            if error is not None:
+                group.block = None
+                group.members.remove(pending)
+                if not group.members and self._groups.get(group.key) is group:
+                    del self._groups[group.key]
+            if not group.copying:
+                # the group's last copy in flight: only now can it be ready
+                self._lock.notify()
+        if error is not None:
+            pending.future.set_exception(error)
+            if not isinstance(error, Exception):
+                raise error
+            return
+        pending.future.copy_times = (t0, t1)
+        self.metrics.record_member_copy(t1 - t0)
 
     def submit_aux(self, key: Tuple, payload, runner) -> Future:
         """Queue one item for a batched AUXILIARY program (smart-crop
@@ -880,12 +987,16 @@ class BatchController:
         enqueue — over the bound this raises a typed 503 (load shed) in
         the submitter's thread; the slot frees when the future resolves,
         however it resolves — then group get-or-create + append under the
-        lock, releasing the admission slot if enqueue itself fails."""
+        lock, releasing the admission slot if enqueue itself fails. Where
+        the group owns a block with a slot left, the member is given the
+        next one and ``(group, block)`` is returned: the caller copies the
+        member's pixels there (``_copy_in``), and that landing, not this
+        enqueue, wakes the executor."""
         self.admission.acquire()
         pending.future.add_done_callback(
             lambda _f: self.admission.release()
         )
-        replacement = None
+        replacement = reserved = None
         try:
             with self._lock:
                 if self._stop:
@@ -895,8 +1006,16 @@ class BatchController:
                 if group is None:
                     group = make_group()
                     self._groups[key] = group
+                block = group.block
+                if block is not None and len(group.members) < len(block):
+                    # no member of a group that holds its block has been
+                    # popped yet, so the next slot is the member's index
+                    pending.slot = len(group.members)
+                    group.copying += 1
+                    reserved = (group, block)
+                else:
+                    self._lock.notify()
                 group.members.append(pending)
-                self._lock.notify()
         except BaseException:
             if not pending.future.done():
                 self.admission.release()
@@ -916,6 +1035,7 @@ class BatchController:
                     with self._lock:
                         self._executor_pending = False
                     raise
+        return reserved
 
     def _maybe_heal_executor_locked(self) -> Optional[threading.Thread]:
         """Executor self-healing, checked at every submission (caller
@@ -1284,10 +1404,15 @@ class BatchController:
         launch, up the power-of-two ladder, and the deadline (which a bulk
         deployment sets to seconds so that a transform launch fills) would
         only hold a post-pass back while the executor has nothing to run.
+        A group with a submit-time copy in flight is not ready whatever
+        else holds: no launch reads a slot whose copy has not landed (a copy
+        is short, and the landing of the group's last one notifies).
         ``policy`` is the caller's one-shot read of ``self._policy``: one
         decision pass must judge every group against ONE (size, timeout)
         pair even if apply_policy lands mid-pass."""
         max_batch, deadline_s = policy
+        if group.copying:
+            return False
         if len(group.members) >= max_batch:
             return True
         if now - group.members[0].enqueued_at >= deadline_s:
@@ -1307,12 +1432,17 @@ class BatchController:
         )
 
     def _next_deadline(self) -> Optional[float]:
+        """Seconds until the earliest deadline among the queued groups
+        that a deadline could flush. A group with a copy in flight is not
+        one: the landing's ``notify`` wakes the executor, and an expired
+        deadline of such a group would otherwise read 0, a busy wait for
+        as long as the copy lasts."""
         now = time.monotonic()
         deadline_s = self._policy[1]
         deadlines = [
             group.members[0].enqueued_at + deadline_s - now
             for group in self._groups.values()
-            if group.members
+            if group.members and not group.copying
         ]
         if not deadlines:
             return None
@@ -1369,6 +1499,10 @@ class BatchController:
         group.members = group.members[take_n:]
         if not group.members:
             self._groups.pop(best, None)
+        # the block goes with the launch; what stays queued (a pre-split's
+        # or a lowered max_batch's remainder, members beyond the block) is
+        # copied by _assemble at its own pop
+        block, group.block = group.block, None
         ready = _Group(
             key=group.key,
             in_shape=group.in_shape,
@@ -1382,6 +1516,7 @@ class BatchController:
             runner=group.runner,
             base_key=group.base_key,
             mem_cap=mem_cap,
+            block=block,
         )
         return ready
 
@@ -1523,6 +1658,11 @@ class BatchController:
         ``_run`` deregisters the batch."""
         members = group.members
         n = len(members)
+        # the block is this launch's alone: taken off the group here, so
+        # that every recovery sub-launch (which gets the group) assembles
+        # from the members' own arrays, and let go with this frame, so that
+        # the drain thread does not hold 4 GiB through the read-back
+        block, group.block = group.block, None
         # capture the id under the lock: drain-thread recovery launches
         # share the counter, and the span attribute + profiler
         # annotation below must name THIS launch, not whichever
@@ -1555,7 +1695,9 @@ class BatchController:
         profiler_poked = False
         try:
             with launch.phase("assemble", cpu=True):
-                launch.capacity, arrays = self._assemble(group, members)
+                launch.capacity, arrays = self._assemble(
+                    group, members, block
+                )
             batch = launch.capacity
             fn, launch.compile_hit = self._program(group, batch)
             # fault hook: a plan raising an XLA-style RESOURCE_EXHAUSTED
@@ -1707,20 +1849,39 @@ class BatchController:
         nd = self._n_devices
         return -(-batch // nd) * nd
 
-    def _assemble(self, group: _Group, members: List[_Pending]):
+    def _assemble(self, group: _Group, members: List[_Pending],
+                  block: Optional[np.ndarray] = None):
         """Padded host arrays for ONE launch of ``members`` (shared by
         the pipelined primary path and the synchronous recovery path).
-        Fires the ``batcher.member`` fault point per member — an injected
-        raising plan models a poison member taking down the whole launch
-        (the real failure mode: the device cannot say WHICH input killed
-        a fused batch program)."""
+        ``block`` is the popped group's block: where ``members`` are
+        exactly its slots ``0..n-1`` in order (a full launch, a deadline
+        pop, a lone flush), their pixels are there already, copied by
+        ``submit``, and only the four small per-member arrays and the pad
+        slots are built here; the images handed on are ``block[:batch]``.
+        Anything else (no block: every recovery sub-launch; a remainder
+        left by a pre-split or a lowered ``max_batch``; members beyond the
+        block) is zero-filled and copied from the members' own arrays,
+        here, and gives the same bytes.
+        ``flyimg_batch_member_copies_total{at=}`` counts the members of
+        either kind. Fires the ``batcher.member`` fault point per member —
+        an injected raising plan models a poison member taking down the
+        whole launch (the real failure mode: the device cannot say WHICH
+        input killed a fused batch program)."""
         n = len(members)
         batch = self._padded_batch(n)
         bh, bw = group.in_shape
         # dynamic-rotate groups widen in_true with the host-computed
         # rotated output extent (ops/compose.py make_program_fn)
         true_w = 4 if group.rotate_dynamic else 2
-        images = np.zeros((batch, bh, bw, 3), dtype=np.uint8)
+        early = (
+            block is not None
+            and batch <= len(block)
+            and all(m.slot == i for i, m in enumerate(members))
+        )
+        if early:
+            images = block[:batch]
+        else:
+            images = np.zeros((batch, bh, bw, 3), dtype=np.uint8)
         in_true = np.zeros((batch, true_w), dtype=np.float32)
         span_y = np.zeros((batch, 2), dtype=np.float32)
         span_x = np.zeros((batch, 2), dtype=np.float32)
@@ -1733,16 +1894,10 @@ class BatchController:
                 image=member.image,
             )
             h, w = member.image.shape[:2]
-            if group.resample_out is None and (h, w) != (bh, bw):
-                # pixel-op-only bucket: edge-replicate so convs stay
-                # correct at the valid-region boundary
-                images[i] = np.pad(
-                    member.image,
-                    ((0, bh - h), (0, bw - w), (0, 0)),
-                    mode="edge",
+            if not early:
+                _fill_slot(
+                    images, i, member.image, group.resample_out is None
                 )
-            else:
-                images[i, :h, :w] = member.image
             layout = plan_layout(member.plan)
             in_true[i, :2] = (h, w)
             if group.rotate_dynamic:
@@ -1762,6 +1917,7 @@ class BatchController:
             span_y[i] = span_y[n - 1]
             span_x[i] = span_x[n - 1]
             out_true[i] = out_true[n - 1]
+        self.metrics.record_member_copies("submit" if early else "assemble", n)
         return batch, (images, in_true, span_y, span_x, out_true)
 
     def _program(self, group: _Group, batch: int):
@@ -1792,8 +1948,9 @@ class BatchController:
         """Start the launch's ``h2d`` phase: hand the assembled arrays to
         the program's handle, which stages them in the form the program
         takes (``ProgramHandle.stage``: the images flat and in pieces,
-        views of ``_assemble``'s array). The call returns before the
-        copies have happened; ``_await_launch`` closes the phase."""
+        views of ``_assemble``'s array, which is the group's block where
+        ``submit`` copied the members into it). The call returns before
+        the transfers have happened; ``_await_launch`` closes the phase."""
         launch.open("h2d")
         with launch.annotate("h2d"):
             launch.dev_args = fn.stage(arrays)

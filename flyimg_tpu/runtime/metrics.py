@@ -537,6 +537,24 @@ class MetricsRegistry:
             "output out of the batch and resolving its future",
         ).observe(max(float(seconds), 0.0))
 
+    def record_member_copy(self, seconds: float) -> None:
+        self.histogram(
+            "flyimg_batch_member_copy_seconds",
+            "Per member of a transform launch: the copy of its frame into "
+            "its slot of the launch's padded host block, at submit, on the "
+            "caller's thread, while the launch is still filling",
+        ).observe(max(float(seconds), 0.0))
+
+    def record_member_copies(self, at: str, members: int) -> None:
+        self.counter(
+            f'flyimg_batch_member_copies_total{{at="{at}"}}',
+            "Members of transform launches by where their pixels reached "
+            "the launch's block: at submit (early, on the callers' "
+            "threads) or at assemble (the copying path, on the launching "
+            "thread: recovery sub-launches, a pre-split's remainder, "
+            "members beyond the block)",
+        ).inc(members)
+
     def record_compile_event(self, cache_hit: bool) -> None:
         """Batched-program compile cache outcome per device batch."""
         result = "hit" if cache_hit else "miss"
@@ -846,8 +864,10 @@ _TRANSFER_HELP = (
 )
 _LAUNCH_PHASE_HISTOGRAMS = (
     ("assemble", "flyimg_batch_assemble_seconds",
-     "Per transform launch: building the padded host batch (_assemble, "
-     "executor thread)"),
+     "Per transform launch: the phase around _assemble on the launching "
+     "thread: the per-member scalars and the pad slots, and the zero-fill "
+     "and copy of every frame only where submit had not already copied "
+     "them into the launch's block (flyimg_batch_member_copy_seconds)"),
     ("slot_wait", "flyimg_batch_slot_wait_seconds",
      "Per transform launch: waiting for a pipeline slot "
      "(batch_pipeline_depth launches between dispatch and read-back)"),

@@ -1842,8 +1842,20 @@ class ImageHandler:
                 if isinstance(s, Future) else s
                 for s, frame, frame_plan, window in staged
             ]
-            # the fill wait as this request saw it: its own enqueue ->
-            # its launch popped (of an animation's frames, the longest)
+            # the copy of the frame into its launch's block, made by
+            # submit on this thread (runtime/batcher.py _copy_in), and the
+            # fill wait as this request saw it: its own enqueue -> its
+            # launch popped (of an animation's frames, the longest of each)
+            copies = [
+                s.copy_times for s, _, _, _ in staged
+                if isinstance(s, Future) and hasattr(s, "copy_times")
+            ]
+            if copies:
+                start, end = max(copies, key=lambda c: c[1] - c[0])
+                tracing.stage_interval(
+                    "device_copy_in", start, end, timings, self.metrics,
+                    span_name="batch.copy_in",
+                )
             waits = [
                 s.launch_times for s, _, _, _ in staged
                 if isinstance(s, Future) and hasattr(s, "launch_times")

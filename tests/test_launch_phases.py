@@ -209,8 +209,8 @@ def test_span_carries_its_monotonic_start():
 # ---------------------------------------------------------------------------
 # 2. transform_bytes: the same stages with and without a trace
 
-NEW_KEYS = ("decode_queue", "decode_run", "device_queue", "encode_queue",
-            "encode_run")
+NEW_KEYS = ("decode_queue", "decode_run", "device_copy_in", "device_queue",
+            "encode_queue", "encode_run")
 
 
 def test_transform_bytes_under_a_trace_yields_the_span_tree(system):
@@ -226,7 +226,15 @@ def test_transform_bytes_under_a_trace_yields_the_span_tree(system):
     # the codec controller's shared aux span rides under the stage too
     assert [n for n in tree["decode"] if n != "aux_execute"] == [
         "decode.queue", "decode.run"]
-    assert sorted(tree["batch_wait"]) == ["device.queue", "device_execute"]
+    # the copy into the launch's block was made at submit, on this thread,
+    # inside batch_wait and before the fill wait's end
+    assert sorted(tree["batch_wait"]) == [
+        "batch.copy_in", "device.queue", "device_execute"]
+    copy_in = _find(root, "batch.copy_in")
+    wait = _find(root, "batch_wait")
+    assert wait["start_mono_ns"] <= copy_in["start_mono_ns"]
+    assert copy_in["start_mono_ns"] + copy_in["duration_s"] * 1e9 <= \
+        _find(root, "device_execute")["start_mono_ns"]
     assert [n for n in tree["encode"] if n != "aux_execute"] == [
         "encode.queue", "encode.run"]
     # the launch's phases ride the shared span
@@ -240,6 +248,7 @@ def test_transform_bytes_under_a_trace_yields_the_span_tree(system):
     for stage, span_name in (("decode", "decode"), ("device", "batch_wait"),
                              ("encode", "encode"),
                              ("decode_queue", "decode.queue"),
+                             ("device_copy_in", "batch.copy_in"),
                              ("encode_run", "encode.run")):
         assert _find(root, span_name)["duration_s"] == pytest.approx(
             timings[stage], abs=2e-3), stage
@@ -247,6 +256,12 @@ def test_transform_bytes_under_a_trace_yields_the_span_tree(system):
     assert timings["decode_queue"] + timings["decode_run"] <= timings["decode"] + 1e-3
     assert timings["encode_queue"] + timings["encode_run"] <= timings["encode"] + 1e-3
     assert timings["device_queue"] <= timings["device"] + 1e-3
+    assert timings["device_copy_in"] <= timings["device_queue"] + 1e-3
+    # the histogram of the moved work and the counter of where it was done
+    text = system.metrics.render_prometheus()
+    assert "flyimg_batch_member_copy_seconds_count 1" in text
+    assert 'flyimg_batch_member_copies_total{at="submit"} 1' in text
+    assert 'at="assemble"' not in text
 
 
 def test_smart_crop_wait_is_split_like_the_codec_waits(system):
@@ -490,6 +505,12 @@ def test_phase_record_adds_up_on_every_path(fake_launches, path):
         + launch.seconds("d2h"), rel=0.01)
     assert row["assemble_s"] >= 0.0 and row["assemble_cpu_s"] >= 0.0
     assert (row["slot_wait_s"] is None) == (path == "recovery")
+    # the primary launch found its member in the block; the recovery launch
+    # after it assembled from the member's own array
+    text = metrics.render_prometheus()
+    assert 'flyimg_batch_member_copies_total{at="submit"} 1' in text
+    assert ('flyimg_batch_member_copies_total{at="assemble"} 1' in text) == (
+        path == "recovery")
     queued, popped, ready = future.launch_times
     assert queued <= popped <= ready
 
@@ -511,6 +532,8 @@ def test_h2d_times_the_completed_transfer_not_the_call(fake_launches):
                    'flyimg_device_transfer_seconds_count{direction="d2h"} 1',
                    "flyimg_device_run_seconds_count 1",
                    "flyimg_batch_assemble_seconds_count 1",
+                   "flyimg_batch_member_copy_seconds_count 1",
+                   'flyimg_batch_member_copies_total{at="submit"} 1',
                    "flyimg_batch_slot_wait_seconds_count 1",
                    "flyimg_device_seconds_count 1"):
         assert series in text, series
@@ -601,6 +624,7 @@ def test_bulk_without_trace_out_creates_no_trace(tmp_path, span_count):
 _BEFORE = {
     "flyimg_images_processed_total": 64.0,
     "flyimg_batch_assemble_seconds_sum": 3.0,
+    "flyimg_batch_member_copy_seconds_sum": 4.0,
     "flyimg_batch_resolve_seconds_sum": 0.5,
     'flyimg_device_transfer_seconds_sum{direction="h2d"}': 24.0,
     'flyimg_device_transfer_seconds_sum{direction="d2h"}': 0.25,
@@ -609,6 +633,7 @@ _BEFORE = {
 _AFTER = {
     "flyimg_images_processed_total": 192.0,
     "flyimg_batch_assemble_seconds_sum": 9.4,
+    "flyimg_batch_member_copy_seconds_sum": 14.24,
     "flyimg_batch_resolve_seconds_sum": 1.14,
     'flyimg_device_transfer_seconds_sum{direction="h2d"}': 72.0,
     'flyimg_device_transfer_seconds_sum{direction="d2h"}': 0.89,
@@ -636,6 +661,7 @@ _AFTER.update({"flyimg_aux_items_total": 138.0, "flyimg_aux_batches_total": 72.0
     ("encode_queue_ms", 500.0),
     ("encode_run_ms", 750.0),
     ("assemble_ms_per_image", 50.0),
+    ("member_copy_ms_per_image", 80.0),
     ("resolve_ms_per_image", 5.0),
     ("h2d_ms_per_image", 375.0),
     ("d2h_ms_per_image", 5.0),
