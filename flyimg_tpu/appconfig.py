@@ -514,46 +514,12 @@ SERVER_DEFAULTS: Dict[str, Any] = {
     # Default OFF: no recorder installed, no manifests read/written,
     # byte-identical serving ---
     # record the program identities this replica compiles, publish them
-    # (and the autotuner's known-good policy table) as digest-stamped
-    # manifests on the shared tier, and AOT-precompile a peer manifest
-    # at boot so a scale-out replica serves at speed
+    # as a digest-stamped manifest on the shared tier, and AOT-precompile
+    # a peer manifest at boot so a scale-out replica serves at speed
     "warmstart_enable": False,
     # ceiling on manifest size (entries recorded per replica AND seeded
     # per boot) — oldest entries trim first on publish
     "warmstart_max_entries": 64,
-    # --- online policy autotuner (runtime/autotuner.py;
-    # docs/autotuning.md). Default OFF: with autotune_enable false the
-    # serving path is byte-for-byte today's behavior — no knob writes,
-    # no metrics, no endpoint content (pinned by tests/test_autotuner.py)
-    # ---
-    # master switch for the observatory->knobs feedback loop: bounded
-    # in-envelope adjustments to batch size/timeout per controller,
-    # resample-auto thresholds, reuse min-scale, and host-pipeline pool
-    # sizing, guard-railed by the SLO burn rates
-    "autotune_enable": False,
-    # adjustment period: at most one knob moves per interval (evaluation
-    # rides the request path, rate-limited like the brownout engine)
-    "autotune_interval_s": 30.0,
-    # revert-on-regression margin: an adjustment whose next window's
-    # objective (occupancy - queue-wait share - burn penalty) drops by
-    # more than this is reverted and the knob cools down
-    "autotune_regression_margin": 0.05,
-    # periods a reverted knob sits out before the engine may touch it
-    "autotune_cooldown_periods": 2,
-    # guard rail: tuning freezes (and reverts to last-known-good) when
-    # the worst normalized SLO burn rate reaches this (1.0 = the
-    # brownout thresholds), or the brownout engine reaches BROWNOUT
-    "autotune_freeze_at": 1.0,
-    # unfreeze only when burn pressure < freeze_at * hysteresis ...
-    "autotune_unfreeze_hysteresis": 0.75,
-    # ... and has stayed clear for this long
-    "autotune_freeze_dwell_s": 60.0,
-    # bounded decision-history ring served by /debug/autotune
-    "autotune_history": 64,
-    # per-knob envelope overrides: {knob: {lo, hi, step}} merged over the
-    # pinned ENVELOPES table (runtime/autotuner.py) — can narrow or
-    # shift a family's bounds; malformed entries fall back to the pins
-    "autotune_envelopes": {},
     # --- negative origin cache (runtime/brownout.py NegativeCache) ---
     # seconds a failing origin (retry-exhausted transient errors, open
     # breaker) short-circuits repeat fetches of the same host+path to an
@@ -571,9 +537,6 @@ SERVER_DEFAULTS: Dict[str, Any] = {
     # injectable monotonic clock for the brownout hysteresis engine
     # (runtime/brownout.py from_params) so dwell tests never sleep
     "brownout_clock": None,
-    # injectable monotonic clock for the autotuner's interval/dwell
-    # bookkeeping (runtime/autotuner.py from_params) — same hook style
-    "autotune_clock": None,
     # injectable monotonic clock for the device supervisor's storm
     # window / probe bookkeeping (runtime/devicesupervisor.py
     # from_params) — same hook style

@@ -96,32 +96,6 @@ def set_kernel_mode(mode: str) -> str:
     return _kernel_mode
 
 
-#: ``mode='auto'`` worth-it threshold: band only when each axis's K is
-#: strictly narrower than ``frac * axis``. 1.0 is the shipped policy
-#: (band whenever the band is narrower at all); the online autotuner
-#: (runtime/autotuner.py) may lower it within its envelope so marginal
-#: geometries stay dense — fewer distinct K-bucket programs, fewer
-#: compiles. The fraction steers SELECTION only; it is never part of
-#: program identity (the selected band_taps is what every cache/group/
-#: ledger key carries), so tuning it can't alias two different programs
-#: or retrace an existing one (pinned by tests/test_autotuner.py).
-_auto_band_frac = 1.0
-AUTO_BAND_FRAC_MIN = 0.1
-
-
-def auto_band_frac() -> float:
-    """The current ``auto``-mode band-width threshold fraction."""
-    return _auto_band_frac
-
-
-def set_auto_band_frac(frac: float) -> float:
-    """Set the ``auto``-mode worth-it fraction, clamped to
-    [AUTO_BAND_FRAC_MIN, 1.0]. Process-wide like ``set_kernel_mode``."""
-    global _auto_band_frac
-    _auto_band_frac = min(max(float(frac), AUTO_BAND_FRAC_MIN), 1.0)
-    return _auto_band_frac
-
-
 def band_taps(method: str, scale: float) -> int:
     """Exact taps one output sample needs at ``scale`` (= span/out; > 1
     is a downscale). Downscale antialiasing stretches the kernel by the
@@ -161,8 +135,7 @@ def select_band_taps(
     ``mode='banded'`` always bands (K clamped to the bucket axis — a
     band as wide as the axis is just a permuted dense contract);
     ``mode='auto'`` bands only when BOTH axes' bands are strictly
-    narrower than ``auto_band_frac()`` of the dense matrices they
-    replace (the shipped fraction 1.0 = "narrower at all")."""
+    narrower than the dense matrices they replace."""
     if mode == "dense":
         return None
     if mode not in KERNEL_MODES:
@@ -174,8 +147,7 @@ def select_band_taps(
     out_w = max(float(out_true_hw[1]), 1.0)
     ky = bucket_taps(band_taps(method, float(span_y[1]) / out_h))
     kx = bucket_taps(band_taps(method, float(span_x[1]) / out_w))
-    frac = _auto_band_frac
-    if mode == "auto" and not (ky < in_h * frac and kx < in_w * frac):
+    if mode == "auto" and not (ky < in_h and kx < in_w):
         return None
     return (min(ky, max(in_h, 1)), min(kx, max(in_w, 1)))
 

@@ -547,15 +547,9 @@ class BatchController:
         self.aux_name = (
             f"{name}_aux" if name == TRANSFORM_CONTROLLER else name
         )
-        # the LIVE flush policy as ONE atomic (max_batch, deadline_s)
-        # tuple: every flush decision reads the pair through a single
-        # reference load, so an online policy update (apply_policy — the
-        # autotuner's write path, docs/autotuning.md) can never be
-        # observed half-applied (a new batch size with the old timeout).
-        # The max_batch/deadline_s properties keep the original read API.
-        self._policy: Tuple[int, float] = (
-            int(max_batch), deadline_ms / 1000.0,
-        )
+        # the flush policy: fixed here, never written again
+        self.max_batch = int(max_batch)
+        self.deadline_s = deadline_ms / 1000.0
         # flush a lone request immediately when the device is idle (cuts
         # sparse-traffic p99 by deadline_ms; disable for deterministic
         # batch-forming in tests)
@@ -655,47 +649,6 @@ class BatchController:
         # while submissions keep queueing normally
         self._paused = False
         self._spawn_executor().start()
-
-    # -- live flush policy (runtime/autotuner.py writes here) ----------
-
-    @property
-    def max_batch(self) -> int:
-        return self._policy[0]
-
-    @property
-    def deadline_s(self) -> float:
-        return self._policy[1]
-
-    def policy(self) -> Tuple[int, float]:
-        """The current ``(max_batch, deadline_s)`` pair, read atomically
-        (one reference load — the same guarantee every flush decision
-        gets)."""
-        return self._policy
-
-    def apply_policy(
-        self,
-        max_batch: Optional[int] = None,
-        deadline_ms: Optional[float] = None,
-    ) -> Tuple[int, float]:
-        """Install a new flush policy online. Both fields swap as ONE
-        tuple under the controller lock, and the executor is notified so
-        a shortened deadline re-arms its wait immediately instead of
-        sleeping out the old one. Values are clamped to sane floors;
-        the ENVELOPE (how far and how fast policy may move) is the
-        autotuner's contract, not this method's."""
-        with self._lock:
-            cur_batch, cur_deadline = self._policy
-            new_batch = (
-                max(1, min(int(max_batch), MAX_BATCH_BUCKET))
-                if max_batch is not None else cur_batch
-            )
-            new_deadline = (
-                max(float(deadline_ms), 0.0) / 1000.0
-                if deadline_ms is not None else cur_deadline
-            )
-            self._policy = (new_batch, new_deadline)
-            self._lock.notify_all()
-            return self._policy
 
     def _spawn_executor(self) -> threading.Thread:
         """Install (or, from self-healing, replace) THE executor thread
@@ -885,9 +838,9 @@ class BatchController:
                 rotate_dynamic=rotate_dynamic,
                 band_taps=band_taps,
                 base_key=base_key,
-                # the launch's block, at the policy in force now (made
-                # under the lock: untouched pages of a large np.zeros
-                # cost nothing until a member is copied into them)
+                # the launch's block (made under the lock: untouched
+                # pages of a large np.zeros cost nothing until a member
+                # is copied into them)
                 block=np.zeros(
                     (self._padded_batch(self.max_batch), *in_shape, 3),
                     dtype=np.uint8,
@@ -1389,8 +1342,8 @@ class BatchController:
             if not member.future.done():
                 member.future.set_exception(exc)
 
-    def _group_ready(self, group: _Group, now: float, total_pending: int,
-                     policy: Tuple[int, float]) -> bool:
+    def _group_ready(self, group: _Group, now: float,
+                     total_pending: int) -> bool:
         """The ONE flush-readiness predicate (used by both the wait loop and
         the pop — drift between two copies would make _run busy-spin):
         batch full, deadline expired, or the idle-executor fast path. The
@@ -1406,16 +1359,12 @@ class BatchController:
         only hold a post-pass back while the executor has nothing to run.
         A group with a submit-time copy in flight is not ready whatever
         else holds: no launch reads a slot whose copy has not landed (a copy
-        is short, and the landing of the group's last one notifies).
-        ``policy`` is the caller's one-shot read of ``self._policy``: one
-        decision pass must judge every group against ONE (size, timeout)
-        pair even if apply_policy lands mid-pass."""
-        max_batch, deadline_s = policy
+        is short, and the landing of the group's last one notifies)."""
         if group.copying:
             return False
-        if len(group.members) >= max_batch:
+        if len(group.members) >= self.max_batch:
             return True
-        if now - group.members[0].enqueued_at >= deadline_s:
+        if now - group.members[0].enqueued_at >= self.deadline_s:
             return True
         return self.lone_flush and (
             total_pending == 1 or group.runner is not None
@@ -1423,10 +1372,9 @@ class BatchController:
 
     def _ready_group(self) -> bool:
         now = time.monotonic()
-        policy = self._policy
         total_pending = sum(len(g.members) for g in self._groups.values())
         return any(
-            self._group_ready(group, now, total_pending, policy)
+            self._group_ready(group, now, total_pending)
             for group in self._groups.values()
             if group.members
         )
@@ -1438,9 +1386,8 @@ class BatchController:
         deadline of such a group would otherwise read 0, a busy wait for
         as long as the copy lasts."""
         now = time.monotonic()
-        deadline_s = self._policy[1]
         deadlines = [
-            group.members[0].enqueued_at + deadline_s - now
+            group.members[0].enqueued_at + self.deadline_s - now
             for group in self._groups.values()
             if group.members and not group.copying
         ]
@@ -1450,8 +1397,6 @@ class BatchController:
 
     def _pop_ready_group(self) -> Optional[_Group]:
         now = time.monotonic()
-        policy = self._policy
-        max_batch, deadline_s = policy
         total_pending = sum(len(g.members) for g in self._groups.values())
         best = None
         best_score = None
@@ -1461,7 +1406,7 @@ class BatchController:
             if not group.members:
                 self._groups.pop(key, None)
                 continue
-            if not self._group_ready(group, now, total_pending, policy):
+            if not self._group_ready(group, now, total_pending):
                 continue
             age = now - group.members[0].enqueued_at
             # starvation guard: full groups normally win (throughput), but
@@ -1470,9 +1415,9 @@ class BatchController:
             # service time routinely exceeds a few deadlines, so a bare
             # 4x-deadline trigger would fire on nearly every pop under
             # load and collapse the fullest-group policy into oldest-first
-            if age >= max(4.0 * deadline_s, 0.25) and age > starving_age:
+            if age >= max(4.0 * self.deadline_s, 0.25) and age > starving_age:
                 starving, starving_age = key, age
-            full = len(group.members) >= max_batch
+            full = len(group.members) >= self.max_batch
             score = (1 if full else 0, len(group.members))
             if best_score is None or score > best_score:
                 best, best_score = key, score
@@ -1481,7 +1426,7 @@ class BatchController:
         if best is None:
             return None
         group = self._groups[best]
-        take_n = min(max_batch, len(group.members))
+        take_n = min(self.max_batch, len(group.members))
         mem_cap = None
         if group.runner is None and self.governor is not None:
             # memory-governor admission (runtime/memgovernor.py): cap
@@ -1500,8 +1445,8 @@ class BatchController:
         if not group.members:
             self._groups.pop(best, None)
         # the block goes with the launch; what stays queued (a pre-split's
-        # or a lowered max_batch's remainder, members beyond the block) is
-        # copied by _assemble at its own pop
+        # remainder, members beyond the block) is copied by _assemble at
+        # its own pop
         block, group.block = group.block, None
         ready = _Group(
             key=group.key,
@@ -1859,9 +1804,9 @@ class BatchController:
         ``submit``, and only the four small per-member arrays and the pad
         slots are built here; the images handed on are ``block[:batch]``.
         Anything else (no block: every recovery sub-launch; a remainder
-        left by a pre-split or a lowered ``max_batch``; members beyond the
-        block) is zero-filled and copied from the members' own arrays,
-        here, and gives the same bytes.
+        left by a pre-split; members beyond the block) is zero-filled and
+        copied from the members' own arrays, here, and gives the same
+        bytes.
         ``flyimg_batch_member_copies_total{at=}`` counts the members of
         either kind. Fires the ``batcher.member`` fault point per member —
         an injected raising plan models a poison member taking down the

@@ -233,8 +233,7 @@ class BrownoutEngine:
         self._rss_fn = rss_fn
         # the backend supervisor (runtime/devicesupervisor.py): a
         # replica failed over to CPU rendering carries a fixed pressure
-        # so degradation (and the autotuner's BROWNOUT+ freeze guard
-        # rail) react coherently with the much slower render path
+        # so degradation reacts to the much slower render path
         self._device_supervisor = device_supervisor
 
     def register_metrics(self, registry) -> None:
@@ -305,8 +304,8 @@ class BrownoutEngine:
                 # (runtime/devicesupervisor.py): a fixed pressure at
                 # exactly the BROWNOUT entry threshold — misses on the
                 # slow CPU path degrade (cheaper plans, stale serving)
-                # but never shed, and the autotuner's guard rail
-                # freezes (docs/degradation.md "Device-loss pressure")
+                # but never shed (docs/degradation.md "Device-loss
+                # pressure")
                 out["device_health"] = (
                     self.brownout_at
                     if self._device_supervisor.cpu_forced() else 0.0

@@ -56,8 +56,7 @@ request, like brownout transitions), ``/readyz``'s ``device`` field and
 the debug-gated ``/debug/device`` snapshot; ``FleetRouter`` skips
 owners whose health endpoint reports device-down (runtime/fleet.py),
 and the brownout engine gains a ``device_health`` pressure component so
-degradation and the autotuner's freeze guard rail react coherently
-(docs/degradation.md).
+degradation reacts to the slower render path (docs/degradation.md).
 
 Default OFF (``device_supervisor_enable: false``): disabled, the
 batcher carries no supervisor reference, no metrics register, no
@@ -567,7 +566,7 @@ class DeviceSupervisor:
     # -- observability -----------------------------------------------------
 
     def evaluate(self) -> None:
-        """Rides the request middleware next to brownout/autotuner
+        """Rides the request middleware next to brownout
         evaluation: drains span events queued by the worker/prober
         threads onto THIS request's trace. One list check when idle;
         nothing at all when disabled."""

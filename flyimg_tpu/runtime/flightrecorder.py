@@ -337,10 +337,10 @@ class FlightRecorder:
 
     def recent_summary(self, limit: int = 64) -> Dict[str, object]:
         """Aggregate view of the newest ``limit`` launch records — the
-        online autotuner's flight-recorder signal (occupancy, queue
-        wait vs device time, compile misses over the most recent
-        launches; runtime/autotuner.py). One lock hold + one pass; no
-        file IO."""
+        signal window's flight-recorder context (occupancy, queue wait
+        vs device time, compile misses over the most recent launches;
+        runtime/observatory.py SignalWindow). One lock hold + one pass;
+        no file IO."""
         with self._lock:
             records = list(self._ring)[-max(1, int(limit)):]
         if not records:

@@ -138,31 +138,23 @@ class StagePool:
         while True:
             task = self._queue.get()
             superseded = False
-            stopping = False
             with self._lock:
-                stopping = self._stop
                 if me not in self._busy:
                     superseded = True
-                elif self._stop and task is None:
+                elif task is None:  # close()'s stop sentinel
                     self._busy.pop(me, None)
                     return
-                elif task is not None:
+                else:
                     self._busy[me] = (time.monotonic(), task)
             if superseded:
-                # superseded by self-healing or resize(): hand the task
-                # to a live worker (outside the lock; the queue is
-                # unbounded but the lock-held-blocking-call discipline
-                # still applies) and exit. A ``None`` during shutdown is
-                # one of close()'s per-LIVE-worker stop sentinels, not a
-                # retirement sentinel — re-put it or the live worker it
+                # superseded by self-healing: hand the task to a live
+                # worker (outside the lock; the queue is unbounded but
+                # the lock-held-blocking-call discipline still applies)
+                # and exit. A ``None`` is one of close()'s per-LIVE-worker
+                # stop sentinels — re-put it too, or the live worker it
                 # was meant for parks for the whole drain budget.
-                if task is not None:
-                    self._queue.put(task)
-                elif stopping:
-                    self._queue.put(None)
+                self._queue.put(task)
                 return
-            if task is None:
-                continue
             wait_s = time.monotonic() - task.enqueued_at
             self._record_wait(task, wait_s)
             try:
@@ -296,49 +288,6 @@ class StagePool:
                 )
         for _ in range(respawn):
             self._spawn_worker()
-
-    # -- live pool sizing (runtime/autotuner.py writes here) ---------------
-
-    def resize(self, workers: int) -> int:
-        """Change the worker count online. Growth spawns immediately;
-        shrink retires workers (idle ones first) by dropping them from
-        the roster — a dropped worker exits at its next queue pickup via
-        the existing superseded path, and a retirement sentinel wakes
-        blocked ones so idle retirees don't park forever. The admission
-        bound follows the new size, so backpressure and the brownout
-        pressure signal stay truthful. Returns the applied count."""
-        target = max(1, int(workers))
-        retire: List[threading.Thread] = []
-        spawn = 0
-        with self._lock:
-            if self._stop:
-                return self.workers
-            current = len(self._busy)
-            if target > current:
-                spawn = target - current
-            elif target < current:
-                # idle workers first; a retired busy worker finishes its
-                # task normally (resolution is done()-guarded) then exits
-                ranked = sorted(
-                    self._busy, key=lambda t: self._busy[t] is not None
-                )
-                for thread in ranked[: current - target]:
-                    self._busy.pop(thread, None)
-                    retire.append(thread)
-            self.workers = target
-            self.admission.max_pending = target + self.queue_depth
-        for _ in range(spawn):
-            self._spawn_worker()
-        for _ in retire:
-            # one wake-up sentinel per retiree: a live worker that eats
-            # one instead just ignores it; the parked retiree then exits
-            # on whatever it picks up next (requeued, never dropped)
-            self._queue.put(None)
-        if retire or spawn:
-            tracing.add_event(
-                "host_pool.resize", pool=self.name, workers=target,
-            )
-        return target
 
     # -- introspection -----------------------------------------------------
 
@@ -486,21 +435,6 @@ class HostPipeline:
             "host_pipeline.staged", stage=stage, pending=pool.pending,
         )
         return future.result(timeout=timeout)
-
-    def apply_policy(self, stage_workers: Dict[str, int]) -> Dict[str, int]:
-        """Resize one or more stage pools online (the autotuner's write
-        path, docs/autotuning.md). Unknown stages are ignored; returns
-        the applied per-stage worker counts."""
-        applied: Dict[str, int] = {}
-        for stage, workers in stage_workers.items():
-            pool = self._pools.get(stage)
-            if pool is not None:
-                applied[stage] = pool.resize(workers)
-        return applied
-
-    def policy(self) -> Dict[str, int]:
-        """Current per-stage worker counts (the autotuner's read path)."""
-        return {name: pool.workers for name, pool in self._pools.items()}
 
     def close(self, drain_timeout_s: float = 10.0) -> None:
         for pool in self._pools.values():

@@ -593,9 +593,8 @@ class FleetMembership:
     ) -> "FleetMembership":
         # clock injectable through the (non-YAML)
         # `fleet_membership_clock` hook — the same object-passing style
-        # as brownout_clock/autotune_clock, so TTL/skew tests never
-        # sleep. Wall clock default: markers are compared across
-        # processes.
+        # as brownout_clock, so TTL/skew tests never sleep. Wall clock
+        # default: markers are compared across processes.
         clock = params.by_key("fleet_membership_clock") or time.time
         return cls(
             storage,

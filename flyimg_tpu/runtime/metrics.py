@@ -229,7 +229,7 @@ class BatchEfficiency:
         self._entries: deque = deque(maxlen=max(1, int(window)))
         # monotone launches-ever-recorded counter: the rolling window
         # itself never expires by time, so consumers that need RECENCY
-        # (the autotuner's since-last-evaluation launch delta) diff this
+        # (the signal window's since-last-assembly launch delta) diff this
         self._recorded_total = 0
 
     def record(self, *, images: int, capacity: int, queue_wait_s: float,

@@ -7,9 +7,9 @@ the cost ledger — answers for ONE replica, while PR 16 made the fleet
 elastic with no signal telling an external scaler *when* to act. This
 module closes that gap with three pieces:
 
-- **SignalWindow** — the one signal-assembly surface, extracted from
-  ``PolicyAutotuner._signals`` so the autotuner and the observatory
-  read the SAME vocabulary (controllers' efficiency windows with the
+- **SignalWindow** — the one signal-assembly surface, so the
+  observatory and the telemetry warehouse (runtime/telemetry.py) read
+  the SAME vocabulary (controllers' efficiency windows with the
   launches_delta recency diff, normalized SLO burn, brownout level,
   host-pool saturation, reuse, flight-recorder context). Each
   consumer owns its OWN instance: ``assemble()`` diffs
@@ -59,6 +59,7 @@ from flyimg_tpu.testing import faults
 
 __all__ = [
     "SignalWindow",
+    "reuse_signal_fn",
     "AutoscaleRecommender",
     "FleetObservatory",
     "DIGEST_VERSION",
@@ -73,9 +74,8 @@ DIGEST_VERSION = 1
 
 
 class SignalWindow:
-    """The observatory's signal-assembly surface, extracted verbatim
-    from ``PolicyAutotuner`` (runtime/autotuner.py) so the tuner and
-    the fleet observatory speak one vocabulary. ``attach()`` wires the
+    """The signal-assembly surface the fleet observatory and the
+    telemetry warehouse share. ``attach()`` wires the
     read surfaces (all optional — a missing source contributes neutral
     signals); ``assemble()`` returns one signal-window dict.
 
@@ -168,8 +168,8 @@ class SignalWindow:
                 pass
         if self._flight_recorder is not None:
             try:
-                # audit context (also surfaced via /debug/autotune): the
-                # most recent launches behind the efficiency windows
+                # audit context: the most recent launches behind the
+                # efficiency windows
                 out["flightrecorder"] = (
                     self._flight_recorder.recent_summary()
                 )
@@ -177,6 +177,37 @@ class SignalWindow:
                 pass
         out["kernel_mode"] = kernel_mode()
         return out
+
+
+def reuse_signal_fn(metrics) -> Callable[[], Dict]:
+    """The reuse hit-ratio signal source (service/app.py wiring): reads
+    the same ``flyimg_reuse_hits_total{outcome=}`` counters the handler
+    increments, WINDOWED per call — each read reports the delta since
+    the previous one, so the ratio describes the current window, not
+    the lifetime average. Counter handles are get-or-create on the
+    shared registry, so the families it touches are exactly the ones
+    the reuse path already registers."""
+    prev = {"hit": 0.0, "miss": 0.0, "unsafe": 0.0}
+
+    def read() -> Dict:
+        current = {
+            outcome: metrics.counter(
+                f'flyimg_reuse_hits_total{{outcome="{outcome}"}}',
+                "Derivative-reuse ancestor lookups by outcome",
+            ).value
+            for outcome in ("hit", "miss", "unsafe")
+        }
+        delta = {k: current[k] - prev[k] for k in current}
+        prev.update(current)
+        attempts = sum(delta.values())
+        return {
+            "attempts": attempts,
+            "hit_ratio": (
+                delta["hit"] / attempts if attempts > 0 else None
+            ),
+        }
+
+    return read
 
 
 class AutoscaleRecommender:

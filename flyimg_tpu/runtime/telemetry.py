@@ -37,10 +37,9 @@ fields are dropped + counted, never written) and statically by
 flylint's telemetry-schema-parity rule against the documented record
 table (docs/observability.md).
 
-Consumers: the debug-gated ``/debug/telemetry`` endpoint,
+Consumers: the debug-gated ``/debug/telemetry`` endpoint and
 ``tools/telemetry_query.py`` (windows / mix-report / burn-timeline /
-export), and ``tools/autotune_replay.py --telemetry`` — the planner
-input format of ROADMAP item 4, produced by every running replica.
+export).
 """
 
 from __future__ import annotations
@@ -74,8 +73,7 @@ RECORD_SCHEMAS: Dict[str, Tuple[str, ...]] = {
         "torn_recovered", "segments", "archive_bytes",
     ),
     # one per beat: the SignalWindow digest + SLO/brownout/ledger deltas
-    # + the traffic-mix stamp (controllers/host are embedded verbatim so
-    # autotune_replay can feed them straight to the DecisionEngine)
+    # + the traffic-mix stamp (controllers/host are embedded verbatim)
     "window": (
         "schema", "kind", "at_s", "replica", "window_s",
         "controllers", "host", "kernel_mode",
@@ -591,8 +589,8 @@ class TelemetryArchive:
 
 def read_archive(directory: str,
                  kinds: Optional[Tuple[str, ...]] = None) -> Dict[str, object]:
-    """Tolerant archive reader shared by tools/telemetry_query.py,
-    autotune_replay, and the tests: records in SEGMENT + LINE order
+    """Tolerant archive reader shared by tools/telemetry_query.py and
+    the tests: records in SEGMENT + LINE order
     (never timestamp order — a writer whose wall clock jumped must not
     reorder the timeline for readers; reader-clock skew is pinned by
     tests/test_telemetry.py), torn/corrupt lines skipped and counted.
@@ -725,8 +723,8 @@ class TelemetryPipeline:
                ) -> None:
         """Wire the read surfaces. The pipeline owns its OWN SignalWindow
         instance — launches_delta diffs recorded_total per window, so
-        sharing the observatory's or the autotuner's would corrupt
-        both consumers' deltas (the observatory docstring pins this)."""
+        sharing the observatory's would corrupt both consumers' deltas
+        (the observatory docstring pins this)."""
         if not self.enabled:
             return
         from flyimg_tpu.runtime.observatory import SignalWindow

@@ -784,7 +784,7 @@ class TierSupervisor:
     # -- observability -----------------------------------------------------
 
     def evaluate(self) -> None:
-        """Rides the request middleware next to brownout/autotuner/
+        """Rides the request middleware next to brownout/
         device-supervisor evaluation: drains span events queued by the
         prober/scrub threads onto THIS request's trace. One list check
         when idle; nothing at all when disabled."""

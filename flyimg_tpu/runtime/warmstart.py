@@ -1,8 +1,7 @@
-"""Fleet-wide warm start: program-cache and policy-table seeding over
-the shared L2 tier (docs/fleet.md "Membership and elasticity";
-ROADMAP item 3; arXiv 2403.12981 on why cold-start compile/warm-up —
-not steady-state compute — dominates perceived capacity during scale
-events).
+"""Fleet-wide warm start: program-cache seeding over the shared L2
+tier (docs/fleet.md "Membership and elasticity"; ROADMAP item 3;
+arXiv 2403.12981 on why cold-start compile/warm-up — not steady-state
+compute — dominates perceived capacity during scale events).
 
 A scale-out replica boots into a compile storm: every plan family in
 the live mix is a fresh XLA compile before it serves at speed. The
@@ -35,13 +34,6 @@ execute). Unknown fields/kinds are skipped the same way (forward
 compatibility), and a per-entry compile failure never fails the
 boot.
 
-The **policy table** rides the same mechanism: the autotuner's
-known-good knob values are published as a digest-stamped document,
-and a fresh replica adopts them through
-``PolicyAutotuner.seed_known_good`` — every value clamped to THIS
-replica's envelopes, so a foreign table can never push a knob out of
-its pinned bounds.
-
 Inert by default: with ``warmstart_enable`` off (the default) the
 recorder is never installed — the hooks in compose/batcher are one
 module-level ``None`` check (the ``faults.fire`` pattern), no
@@ -63,7 +55,6 @@ from flyimg_tpu.testing import faults
 __all__ = [
     "WarmStartCache",
     "PROGRAMS_MANIFEST",
-    "POLICY_MANIFEST",
     "record_single",
     "record_batched",
     "install",
@@ -72,9 +63,8 @@ __all__ = [
 
 LOGGER = "flyimg.fleet"
 
-#: shared-tier object names (flat — LocalStorage basenames every name)
+#: shared-tier object name (flat — LocalStorage basenames every name)
 PROGRAMS_MANIFEST = "warmstart-programs.manifest"
-POLICY_MANIFEST = "warmstart-policy.manifest"
 
 #: TransformPlan fields whose JSON lists must round back to tuples so
 #: the reconstructed plan is hash/eq-identical to the recorded one
@@ -170,13 +160,10 @@ class WarmStartCache:
         self.max_entries = max(int(max_entries), 1)
         self.metrics = metrics
         self.recorder = _Recorder(self.max_entries)
-        self._autotuner = None
-        self._published_policy: Optional[Dict[str, float]] = None
         self._lock = threading.Lock()
         # seed-time accounting for /debug/fleet and the elastic smoke
         self.stats: Dict[str, int] = {
             "seeded": 0, "mismatch": 0, "skipped": 0, "failed": 0,
-            "policy_applied": 0,
         }
 
     def _count(self, outcome: str, n: int = 1) -> None:
@@ -199,9 +186,6 @@ class WarmStartCache:
         if self.enabled:
             install(self)
         return self
-
-    def attach_autotuner(self, autotuner) -> None:
-        self._autotuner = autotuner
 
     def note_single(self, in_shape, resample_out, pad_canvas, pad_offset,
                     plan, band_taps) -> None:
@@ -266,8 +250,7 @@ class WarmStartCache:
     def publish(self) -> None:
         """Merge this replica's recorded program identities into the
         shared manifest (union by digest, newest appended, oldest
-        trimmed to ``warmstart_max_entries``) and refresh the policy
-        document when the known-good table moved. Last-write-wins
+        trimmed to ``warmstart_max_entries``). Last-write-wins
         storage makes concurrent publishers benign: each merges the
         other's last published set, so entries converge within a few
         beats."""
@@ -290,28 +273,11 @@ class WarmStartCache:
             self._write_manifest(
                 PROGRAMS_MANIFEST, {"version": 1, "entries": entries}
             )
-        if self._autotuner is not None and getattr(
-            self._autotuner, "enabled", False
-        ):
-            table = self._autotuner.known_good()
-            if table and table != self._published_policy:
-                doc = {"version": 1, "policy": table}
-                doc["digest"] = _entry_digest(doc)
-                if self._write_manifest(POLICY_MANIFEST, doc):
-                    self._published_policy = table
 
     def maybe_publish(self) -> None:
-        """The membership-beat hook: publish only when something moved
-        (new recorded programs, or a changed known-good table)."""
-        if not self.enabled:
-            return
-        policy_moved = (
-            self._autotuner is not None
-            and getattr(self._autotuner, "enabled", False)
-            and self._autotuner.known_good() != self._published_policy
-            and bool(self._autotuner.known_good())
-        )
-        if self.recorder.dirty or policy_moved:
+        """The membership-beat hook: publish only when new programs
+        were recorded."""
+        if self.enabled and self.recorder.dirty:
             self.publish()
 
     # -- seeding -----------------------------------------------------------
@@ -405,35 +371,6 @@ class WarmStartCache:
                 continue
             self._count("seeded")
         return dict(self.stats)
-
-    def seed_policy(self, autotuner) -> Dict[str, float]:
-        """Boot-time policy seeding: adopt the fleet's known-good knob
-        table through the autotuner's envelope clamps. A failed digest
-        check discards the whole document — a torn policy write must
-        not half-apply."""
-        self.attach_autotuner(autotuner)
-        if not self.enabled or not getattr(autotuner, "enabled", False):
-            return {}
-        doc = self._read_manifest(POLICY_MANIFEST)
-        if doc is None:
-            return {}
-        if doc.get("digest") != _entry_digest(doc):
-            self._count("mismatch")
-            logging.getLogger(LOGGER).warning(
-                "warm-start policy table failed digest validation; "
-                "booting with local defaults",
-            )
-            return {}
-        table = doc.get("policy")
-        if not isinstance(table, dict):
-            return {}
-        applied = autotuner.seed_known_good(table)
-        if applied:
-            self.stats["policy_applied"] = len(applied)
-            # seeding IS publication parity: what we adopted is what
-            # the fleet already has, so don't re-publish it unchanged
-            self._published_policy = autotuner.known_good()
-        return applied
 
     def snapshot(self) -> Dict[str, object]:
         return {

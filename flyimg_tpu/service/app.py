@@ -17,11 +17,10 @@ shutdown), and — debug-gated — /debug/trace (jax.profiler capture),
 per-launch ring + dump inventory), /debug/profile (arm/list/download
 batch-scoped device-profile captures), /debug/brownout (degradation
 level + pressure components), /debug/device (backend supervisor state:
-breaker, probes, failovers), /debug/autotune (online policy, envelopes,
-decision history), /debug/tier (shared-tier outage supervisor: island
-state, journal, scrubber), /debug/memory (memory governor: capacity
-ceilings, host byte budget, RSS watchdog), POST /debug/fleet/replicas
-(dynamic replica-set reload).
+breaker, probes, failovers), /debug/tier (shared-tier outage supervisor:
+island state, journal, scrubber), /debug/memory (memory governor:
+capacity ceilings, host byte budget, RSS watchdog), POST
+/debug/fleet/replicas (dynamic replica-set reload).
 
 plus the ``encrypt`` CLI subcommand (reference app.php:93-96):
 
@@ -79,10 +78,8 @@ HANDLER_KEY: web.AppKey[ImageHandler] = web.AppKey("handler", ImageHandler)
 METRICS_KEY: web.AppKey = web.AppKey("metrics", object)
 TRACER_KEY: web.AppKey = web.AppKey("tracer", object)
 # the fleet router (dynamic replica-set reload: POST /debug/fleet/replicas
-# and the serve-mode SIGHUP re-read both reach it through this key) and
-# the online policy autotuner (tools/smoke_autotune.py drives it)
+# and the serve-mode SIGHUP re-read both reach it through this key)
 FLEET_KEY: web.AppKey = web.AppKey("fleet", object)
-AUTOTUNER_KEY: web.AppKey = web.AppKey("autotuner", object)
 # the backend supervisor (runtime/devicesupervisor.py): tests and the
 # failover smoke reach the live state machine through this key
 SUPERVISOR_KEY: web.AppKey = web.AppKey("device_supervisor", object)
@@ -211,18 +208,14 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
     )
     debug_enabled = bool(params.by_key("debug"))
     log_access = bool(params.by_key("log_access", True))
-    # serving resample kernel (dense | banded | auto): process-wide like
-    # the program caches the choice keys into (ops/resample.py;
-    # docs/kernels.md). Applied BEFORE any program is built so the first
-    # compile already runs the configured variant.
-    from flyimg_tpu.ops.resample import set_auto_band_frac, set_kernel_mode
+    # serving resample kernel (dense | banded | auto): a process global
+    # because the program caches the choice keys into are process-wide
+    # too (ops/resample.py; ROADMAP.md D11), so two apps in one process
+    # share it and the last one built wins. Applied BEFORE any program is
+    # built so the first compile already runs the configured variant.
+    from flyimg_tpu.ops.resample import set_kernel_mode
 
     set_kernel_mode(str(params.by_key("resample_kernel", "dense")))
-    # the auto-mode worth-it threshold is process-wide like the kernel
-    # mode; reset it to the default here so a value TUNED by a previous
-    # app in this process (runtime/autotuner.py) never leaks into a
-    # freshly constructed one
-    set_auto_band_frac(1.0)
     storage = make_storage(params, metrics=metrics)
     import jax
 
@@ -523,47 +516,19 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
         ),
         # a replica failed over to CPU rendering carries a fixed
         # device_health pressure (docs/degradation.md "Device-loss
-        # pressure") so degradation and the autotuner guard rail react
+        # pressure") so degradation reacts
         device_supervisor=supervisor if supervisor.enabled else None,
         # process RSS vs the host memory limit (runtime/memgovernor.py
         # RssWatchdog): approaching the limit walks the same
         # stale-serve → degrade → shed ladder as every other signal
         rss_fn=rss_watchdog.pressure if rss_watchdog.enabled else None,
     )
-    # online policy autotuner (runtime/autotuner.py; docs/autotuning.md):
-    # closes the loop from the observatory (efficiency windows, SLO burn
-    # rates, brownout level, pool snapshots, flight recorder) back to
-    # the serving knobs, within pinned envelopes and behind the SLO-burn
-    # guard rail. Inert (no knob bindings, no metrics, one bool check
-    # per request) with autotune_enable off.
-    from flyimg_tpu.runtime.autotuner import PolicyAutotuner, reuse_signal_fn
-
-    autotuner = PolicyAutotuner.from_params(params, metrics=metrics)
-    if autotuner.enabled:
-        autotuner.register_knobs(
-            batcher=batcher,
-            codec_batcher=codec_batcher,
-            host_pipeline=host_pipeline,
-            handler=handler,
-        )
-        autotuner.attach_signals(
-            metrics=metrics,
-            slo=slo,
-            brownout=brownout,
-            host_pipeline=host_pipeline,
-            flight_recorder=flight_recorder,
-            reuse_fn=(
-                reuse_signal_fn(metrics)
-                if handler.reuse_enable else None
-            ),
-        )
-        autotuner.register_metrics(metrics)
     # fleet-wide warm start (runtime/warmstart.py; docs/fleet.md
     # "Membership and elasticity"): seed this replica's program cache
-    # and policy table from peer-published manifests on the SHARED tier
-    # BEFORE the first request, then record/publish what this replica
-    # compiles. Seeding is synchronous here by design — a replica that
-    # announces itself ready has already absorbed its compile storm.
+    # from the peer-published manifest on the SHARED tier BEFORE the
+    # first request, then record/publish what this replica compiles.
+    # Seeding is synchronous here by design — a replica that announces
+    # itself ready has already absorbed its compile storm.
     # Inert (no recorder, no manifest IO, no metrics) with
     # warmstart_enable off.
     from flyimg_tpu.runtime import warmstart as warmstart_mod
@@ -573,7 +538,6 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
     )
     if warmstart.enabled:
         warmstart.install()
-        warmstart.seed_policy(autotuner)
         warmstart.seed_programs(mesh=mesh)
     # elastic fleet membership (runtime/membership.py; docs/fleet.md):
     # announce/heartbeat/watch over TTL'd markers on the shared tier,
@@ -606,7 +570,10 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
     # graceful-drain path when fleet_autoscale_drain is on. Inert (no
     # markers, no metrics, no digest IO) with fleet_observatory_enable
     # off or membership off.
-    from flyimg_tpu.runtime.observatory import FleetObservatory
+    from flyimg_tpu.runtime.observatory import (
+        FleetObservatory,
+        reuse_signal_fn,
+    )
 
     observatory = FleetObservatory.from_params(
         params,
@@ -686,12 +653,8 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
             # transition's brownout.transition span event lands on the
             # request that triggered it (add_event is a no-op with no
             # ambient trace).
-            # The autotuner's guarded tuning step rides the same hook
-            # (rate-limited inside it; one bool check when disabled) so
-            # its autotune.* span events land on the triggering request.
             with tracing.activate(trace):
                 brownout.evaluate()
-                autotuner.evaluate()
                 # the supervisor's failover/re-promotion span events
                 # (queued by its worker threads, which have no ambient
                 # trace) land on this request — one list check when idle
@@ -793,7 +756,6 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
     app[METRICS_KEY] = metrics
     app[TRACER_KEY] = tracer
     app[FLEET_KEY] = fleet
-    app[AUTOTUNER_KEY] = autotuner
     app[SUPERVISOR_KEY] = supervisor
     app[MEMBERSHIP_KEY] = membership
     app[OBSERVATORY_KEY] = observatory
@@ -1429,20 +1391,6 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
             content_type="application/json",
         )
 
-    async def debug_autotune(_request: web.Request) -> web.Response:
-        """Online autotuner state: live policy vs last-known-good, the
-        envelope table, guard-rail state, and the bounded decision
-        history (runtime/autotuner.py snapshot; docs/autotuning.md)."""
-        import json as _json
-
-        denied = _debug_gate_404()
-        if denied is not None:
-            return denied
-        return web.Response(
-            text=_json.dumps(autotuner.snapshot()),
-            content_type="application/json",
-        )
-
     async def debug_fleet(_request: web.Request) -> web.Response:
         """Elastic membership state (runtime/membership.py snapshot +
         warm-start stats; docs/fleet.md "Membership and elasticity"):
@@ -1612,7 +1560,6 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
     )
     app.router.add_get("/debug/brownout", debug_brownout)
     app.router.add_get("/debug/device", debug_device)
-    app.router.add_get("/debug/autotune", debug_autotune)
     app.router.add_get("/debug/tier", debug_tier)
     app.router.add_get("/debug/memory", debug_memory)
     app.router.add_get("/debug/fleet", debug_fleet)

@@ -48,13 +48,6 @@ through:
                         ancestor) or raise (simulated pruned/corrupt
                         ancestor — the handler must fall back to the
                         full from-source pipeline, docs/caching.md)
-    ``autotune.signal`` one autotuner evaluation (runtime/autotuner.py
-                        PolicyAutotuner.evaluate): a plan returning a
-                        dict OVERRIDES the assembled signal window (and
-                        bypasses the evaluation rate limit), so tests
-                        and the CI smoke script exact adjustment /
-                        freeze sequences — the same contract as
-                        ``brownout.signal``
     ``device.backend``  one device-backend probe/init attempt
                         (parallel/mesh.py probe_device_backend — the ONE
                         helper shared by boot and the supervisor's
@@ -159,7 +152,6 @@ KNOWN_POINTS = frozenset({
     "brownout.signal",
     "brownout.refresh",
     "reuse.ancestor",
-    "autotune.signal",
     "device.backend",
     "fleet.proxy",
     "l2.lease",

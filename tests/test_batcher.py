@@ -24,19 +24,66 @@ def _plan(opts, w, h):
     return build_plan(OptionsBag(opts), w, h)
 
 
-def test_batch_matches_single_path(controller):
-    futures = []
-    sources = []
-    for i, (w, h) in enumerate([(600, 400), (620, 410), (580, 390), (600, 400)]):
-        img = make_test_image(w, h, seed=i)
-        plan = _plan("w_200,h_150,c_1", w, h)
-        sources.append((img, plan))
-        futures.append(controller.submit(img, plan))
-    outs = [f.result(timeout=120) for f in futures]
-    for out, (img, plan) in zip(outs, sources):
-        assert out.shape == (150, 200, 3)
-        single = run_plan(img, plan)
-        # batch path must be pixel-identical to the single path
+# plan family -> options. Every family the transform path groups and pads
+# differently (runtime/batcher.py ``submit``): a static extent (crop-fill,
+# extract + box, extent pad), a fit whose output is on a 64-px bucket edge
+# and one whose output is bucketed and sliced, the three rotates (a
+# multiple of 90, shape-bucketed dynamic alone and behind a resample),
+# pixel ops that ride the input bucket, and the conv post-ops, whose pad
+# rows must replicate the edge.
+_FAMILIES = {
+    "crop_fill": "w_100,h_75,c_1",
+    "fit": "w_128",
+    "fit_bucketed": "w_100",
+    "extract": "e_1,p1x_20,p1y_10,p2x_150,p2y_120,w_64",
+    "extent_pad_bg": "w_100,h_100,ett_120x120,bg_red",
+    "r_90": "r_90",
+    "r_30_dynamic": "r_30",
+    "resize_rotate": "r_-45,w_96,h_96",
+    "gray": "clsp_Gray",
+    "blur": "blr_2x1",
+    "sharpen": "sh_2x1",
+    "unsharp": "unsh_2x1+1+0.05",
+}
+# dynamic rotate against run_plan's static one: see _assert_rotate_parity
+_WITHIN_ONE_LEVEL = {"r_30_dynamic", "resize_rotate"}
+# all three in the 256 x 256 input bucket; the square fills it to the edge
+_SOURCES = {
+    "landscape": (250, 180),
+    "portrait": (180, 250),
+    "square_on_bucket_edge": (256, 256),
+}
+# launch -> members; the checked member is the last one
+_LAUNCHES = {"one_member": 1, "three_padded_to_four": 3}
+
+
+@pytest.mark.parametrize("launch", _LAUNCHES)
+@pytest.mark.parametrize("source", _SOURCES)
+@pytest.mark.parametrize("family", _FAMILIES)
+def test_batch_matches_single_path(family, source, launch):
+    """What ``submit`` answers is what ``run_plan`` answers for the same
+    image and plan, whatever the slot and however many pad slots ride
+    along: byte for byte, but for the dynamic rotate."""
+    n = _LAUNCHES[launch]
+    w, h = _SOURCES[source]
+    plan = _plan(_FAMILIES[family], w, h)
+    images = [make_test_image(w, h, seed=seed) for seed in range(n)]
+    # full at n, and a deadline no test waits out: exactly one launch
+    ctl = BatchController(max_batch=n, deadline_ms=60_000.0, lone_flush=False)
+    try:
+        futures = [ctl.submit(image, plan) for image in images]
+        out = futures[-1].result(timeout=120)
+        summary = ctl.metrics.summary()
+    finally:
+        ctl.close()
+    assert summary["flyimg_batches_total"] == 1
+    assert summary["flyimg_images_processed_total"] == n
+    assert summary["flyimg_batch_slots_total"] == {1: 1, 3: 4}[n]
+    single = run_plan(images[-1], plan)
+    assert out.shape == single.shape
+    if family in _WITHIN_ONE_LEVEL:
+        _assert_rotate_parity(out, single)
+    else:
         np.testing.assert_array_equal(out, single)
 
 
@@ -502,5 +549,39 @@ def test_equal_length_inflight_batches_drain_cleanly():
                     break
         with ctl._lock:
             assert not ctl._inflight_batches
+    finally:
+        ctl.close()
+
+
+def test_flush_policy_is_fixed_at_construction():
+    """``max_batch`` and ``deadline_s`` are what the constructor was given,
+    for as long as the controller lives: nothing on it changes them, and a
+    group made after 1,000 submissions owns a block of as many slots as
+    one made before them."""
+    ctl = BatchController(max_batch=4, deadline_ms=60_000.0, lone_flush=False)
+    for name in ("apply_policy", "policy", "_policy"):
+        assert not hasattr(ctl, name), name
+    image = make_test_image(40, 30)
+    plan = _plan("clsp_Gray", 40, 30)
+
+    def slots_of_the_waiting_group():
+        with ctl._lock:
+            (group,) = ctl._groups.values()
+            return group.block.shape[0]
+
+    try:
+        first = ctl.submit(image, plan)  # not full: its group waits
+        before = slots_of_the_waiting_group()
+        futures = [first] + [ctl.submit(image, plan) for _ in range(1_003)]
+        for future in futures:  # 251 full launches
+            future.result(timeout=120)
+        last = ctl.submit(image, plan)
+        after = slots_of_the_waiting_group()
+        rest = [ctl.submit(image, plan) for _ in range(3)]
+        for future in [last] + rest:
+            future.result(timeout=120)
+        assert before == after == 4
+        assert (ctl.max_batch, ctl.deadline_s) == (4, 60.0)
+        assert ctl.stats()["batches"] == 252
     finally:
         ctl.close()

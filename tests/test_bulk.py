@@ -5,6 +5,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 from PIL import Image
 
 from flyimg_tpu.bulk import bulk_process, main
@@ -132,3 +133,68 @@ def test_bulk_skips_retry_pass_when_every_job_times_out(tmp_path, monkeypatch):
     )
     assert summary["failed"] == 3 and summary["images"] == 0
     assert all(n == 1 for n in calls.values())  # no retry pass ran
+
+
+# (options, container): a static extent, a fit whose output is bucketed and
+# sliced, a quarter turn behind a resample, an extent pad with a conv post-op
+_MIXED_JOBS = [
+    ("w_100,h_80,c_1", "jpg"),
+    ("w_90", "png"),
+    ("r_90,w_80", "jpg"),
+    ("w_64,h_64,ett_80x80,bg_red,sh_2x1", "png"),
+]
+
+
+@pytest.mark.parametrize("opts,fmt", _MIXED_JOBS)
+def test_bulk_mixed_directory_matches_transform_bytes_one_by_one(
+        tmp_path, opts, fmt):
+    """A directory of mixed sizes and two source formats through launches
+    of at most four, part-filled ones among them, gives for every file the
+    bytes ``transform_bytes`` gives for it sent alone, a launch of one:
+    what a file's launch holds beside it does not show. (Against
+    ``run_plan`` a fit whose output is bucketed may differ by one level in
+    a pixel of some ten thousand: another program shape, another
+    contraction order. tests/test_batcher.py holds the two together.)"""
+    from flyimg_tpu.appconfig import AppParameters
+    from flyimg_tpu.runtime.batcher import BatchController
+    from flyimg_tpu.service.handler import ImageHandler
+    from flyimg_tpu.service.output_image import EXT_TO_MIME, OutputSpec
+    from flyimg_tpu.spec.options import OptionsBag
+
+    src = tmp_path / "src"
+    src.mkdir()
+    rng = np.random.default_rng(7)
+    # five files share the 256 x 384 input bucket: more than one launch of four
+    sizes = [(300, 200), (280, 210), (310, 190), (290, 205), (300, 200),
+             (200, 300), (256, 256)]
+    for i, (w, h) in enumerate(sizes):
+        pixels = rng.integers(0, 255, (h, w, 3), dtype=np.uint8)
+        if i % 2:
+            Image.fromarray(pixels).save(src / f"f{i}.jpg", quality=92)
+        else:
+            Image.fromarray(pixels).save(src / f"f{i}.png")
+    out = tmp_path / "out"
+    # full at four, or whatever has gathered 50 ms after the oldest came
+    ctl = BatchController(max_batch=4, deadline_ms=50.0)
+    try:
+        summary = bulk_process(str(src), str(out), opts, out_format=fmt,
+                               workers=len(sizes), batcher=ctl)
+    finally:
+        ctl.close()
+    assert summary["images"] == len(sizes) and summary["failed"] == 0
+    assert 2 <= summary["batches"] <= len(sizes)
+
+    # one caller, an idle executor: every file is a lone launch
+    lone = BatchController(max_batch=4, deadline_ms=50.0)
+    alone = ImageHandler(storage=None, params=AppParameters(), batcher=lone)
+    try:
+        for name in sorted(os.listdir(src)):
+            stem = os.path.splitext(name)[0]
+            spec = OutputSpec(name=f"{stem}.{fmt}", extension=fmt,
+                              mime=EXT_TO_MIME[fmt])
+            expected = alone.transform_bytes(
+                (src / name).read_bytes(), OptionsBag(opts), spec)
+            assert (out / f"{stem}.{fmt}").read_bytes() == expected, name
+        assert lone.stats()["batches"] == len(sizes)
+    finally:
+        lone.close()

@@ -651,7 +651,13 @@ def test_forced_breach_dumps_flightrecorder(tmp_path, source_png):
         dump = json.load(fh)
     assert dump["reason"] == "slo_breach"
     assert dump["summary"]["records"] >= 1
-    assert dump["records"][0]["controller"] in ("device", "codec")
+    # host_stage rows aside (a fetch or decode that waited 5 ms for its
+    # pool worker, under load, lands in the ring before the first launch)
+    launches = [
+        record for record in dump["records"]
+        if record["kind"] != "host_stage"
+    ]
+    assert launches[0]["controller"] in ("device", "codec")
     assert dump["context"].get("event") == "slo.breach"
     assert files[0].split(os.sep)[-1] in doc["dumps"]["files"]
 

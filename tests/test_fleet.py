@@ -960,6 +960,15 @@ def test_update_replicas_toggles_enabled_and_self_id():
     assert router.self_id == "http://a"
 
 
+def test_owner_of_emptied_replica_set_is_self_not_valueerror():
+    router = FleetRouter(["http://a", "http://b"], "http://a")
+    key = "abc123"
+    assert router.owner(key) in ("http://a", "http://b")
+    router.update_replicas([])  # SIGHUP reload to an empty set
+    assert router.owner(key) == "http://a"  # local render, no raise
+    assert not router.enabled
+
+
 def test_debug_fleet_replicas_endpoint_applies_and_validates(tmp_path):
     from flyimg_tpu.service.app import FLEET_KEY, make_app
 

@@ -3,9 +3,8 @@
 docs/fleet.md "Membership and elasticity"): marker TTL under skewed
 clocks, wedged-replica staleness, crash detection with minimal
 re-homing, graceful drain, degraded-not-dead, warm-start digest
-validation (recompile-not-execute), policy-table seeding through the
-envelope clamps, the split-brain guard on the manual escape hatches,
-and the all-knobs-off byte-identity pin."""
+validation (recompile-not-execute), the split-brain guard on the
+manual escape hatches, and the all-knobs-off byte-identity pin."""
 
 from __future__ import annotations
 
@@ -502,53 +501,6 @@ def test_publish_merges_by_digest_across_replicas(tmp_path):
     b.publish()
     manifest = json.loads(store.read(PROGRAMS_MANIFEST))
     assert len(manifest["entries"]) == 2
-
-
-def test_policy_seeding_clamps_to_local_envelopes(tmp_path):
-    from flyimg_tpu.runtime.autotuner import PolicyAutotuner
-    from flyimg_tpu.runtime.warmstart import POLICY_MANIFEST, _entry_digest
-
-    store = _store(tmp_path)
-    tuner = PolicyAutotuner(enabled=True)
-    current = {"value": 8.0}
-    tuner.bind(
-        "device.max_batch",
-        lambda: current["value"],
-        lambda v: current.update(value=v),
-    )
-    env = tuner.envelopes["device.max_batch"]
-    doc = {"version": 1, "policy": {
-        "device.max_batch": env.hi * 100.0,   # far out of envelope
-        "codec.max_batch": 4.0,               # unbound here: ignored
-    }}
-    doc["digest"] = _entry_digest(doc)
-    store.write(POLICY_MANIFEST, json.dumps(doc, sort_keys=True).encode())
-    ws = WarmStartCache(store, enabled=True)
-    applied = ws.seed_policy(tuner)
-    assert applied == {"device.max_batch": env.hi}
-    assert current["value"] == env.hi
-    assert tuner.known_good()["device.max_batch"] == env.hi
-
-
-def test_policy_digest_mismatch_discards_whole_table(tmp_path):
-    from flyimg_tpu.runtime.autotuner import PolicyAutotuner
-    from flyimg_tpu.runtime.warmstart import POLICY_MANIFEST
-
-    store = _store(tmp_path)
-    tuner = PolicyAutotuner(enabled=True)
-    current = {"value": 8.0}
-    tuner.bind(
-        "device.max_batch",
-        lambda: current["value"],
-        lambda v: current.update(value=v),
-    )
-    store.write(POLICY_MANIFEST, json.dumps({
-        "version": 1, "policy": {"device.max_batch": 16.0},
-        "digest": "torn-write",
-    }).encode())
-    ws = WarmStartCache(store, enabled=True)
-    assert ws.seed_policy(tuner) == {}
-    assert current["value"] == 8.0 and tuner.known_good() == {}
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ from flyimg_tpu.runtime.observatory import (
     AutoscaleRecommender,
     FleetObservatory,
     SignalWindow,
+    reuse_signal_fn,
 )
 from flyimg_tpu.storage.local import LocalStorage
 from flyimg_tpu.storage.tiered import DIGEST_SUFFIX, digest_name
@@ -473,8 +474,9 @@ def test_drain_disabled_surfaces_recommendation_only(tmp_path):
 
 
 def test_signal_window_is_not_shared_between_consumers():
-    """assemble() diffs recorded_total per instance — the autotuner and
-    the observatory each own a window, or every launches_delta halves."""
+    """assemble() diffs recorded_total per instance — the observatory
+    and the telemetry warehouse each own a window, or every
+    launches_delta halves."""
 
     class Stats:
         def __init__(self):
@@ -500,6 +502,30 @@ def test_signal_window_is_not_shared_between_consumers():
     assert w1.assemble()["controllers"]["device"]["launches_delta"] == 10.0
     # the second consumer sees the SAME delta, not the leftovers
     assert w2.assemble()["controllers"]["device"]["launches_delta"] == 10.0
+
+
+def test_reuse_signal_fn_windows_per_read():
+    metrics = MetricsRegistry()
+
+    def bump(outcome, n):
+        metrics.counter(
+            f'flyimg_reuse_hits_total{{outcome="{outcome}"}}',
+            "Derivative-reuse ancestor lookups by outcome",
+        ).inc(n)
+
+    read = reuse_signal_fn(metrics)
+    # cold-start miss streak
+    bump("miss", 40)
+    first = read()
+    assert first["attempts"] == 40 and first["hit_ratio"] == 0.0
+    # the NEXT period is all hits: the windowed ratio must say so (a
+    # lifetime ratio would still read 40/80 = 0.5)
+    bump("hit", 40)
+    second = read()
+    assert second["attempts"] == 40 and second["hit_ratio"] == 1.0
+    # quiet period: no attempts, no evidence
+    third = read()
+    assert third["attempts"] == 0 and third["hit_ratio"] is None
 
 
 # ---------------------------------------------------------------------------

@@ -192,6 +192,41 @@ def test_close_strands_get_timeout_error():
     gate.set()  # release the abandoned worker
 
 
+def test_abandoned_worker_never_swallows_a_stop_sentinel():
+    """A worker that self-healing abandoned as wedged, and that finished
+    late, parks on ``queue.get()`` off the roster. ``close()`` puts one
+    stop sentinel per LIVE worker; the abandoned one, parked first, is
+    woken first and takes it: it must re-put it, or the live worker it
+    was meant for parks for the whole drain budget."""
+    pool = StagePool(
+        "retiree", workers=1, queue_depth=8, wedge_timeout_s=0.05,
+    )
+    first, second = threading.Event(), threading.Event()
+    try:
+        pool.submit(first.wait)          # wedges the only worker
+        time.sleep(0.15)                 # exceed the wedge timeout
+        held = pool.submit(second.wait)  # heal: abandon it, spawn another
+        first.set()                      # the abandoned worker finishes
+        time.sleep(0.1)                  # ... and parks on the queue
+        second.set()                     # the live worker parks behind it
+        assert held.result(timeout=10) is True
+        time.sleep(0.1)
+        t0 = time.monotonic()
+        pool.close(drain_timeout_s=10.0)
+        assert time.monotonic() - t0 < 5.0
+        leftover = [
+            thread for thread in threading.enumerate()
+            if thread.name == "flyimg-host-retiree"
+        ]
+        for thread in leftover:
+            thread.join(timeout=5.0)
+        assert not any(thread.is_alive() for thread in leftover)
+    finally:
+        first.set()
+        second.set()
+        pool.close()
+
+
 # ---------------------------------------------------------------------------
 # HostPipeline wiring
 

@@ -206,7 +206,8 @@ def test_a_group_with_a_copy_in_flight_is_not_popped_and_run_does_not_spin(monke
         submitter.start()
         assert entered.wait(timeout=30)
         before = len(wakes)
-        ctl.apply_policy(deadline_ms=1.0)  # wakes the executor: it looks, and parks again
+        with ctl._lock:
+            ctl._lock.notify_all()  # wakes the executor: it looks, and parks again
         time.sleep(0.3)  # many deadlines long
         # parked on the condition, not polling it
         assert len(wakes) - before <= 2, wakes
@@ -246,6 +247,15 @@ def _waiting_ctl(**over):
     return ctl
 
 
+def _expire_deadline(ctl):
+    """The test's clock: what is queued has waited out the deadline."""
+    with ctl._lock:
+        for group in ctl._groups.values():
+            for member in group.members:
+                member.enqueued_at -= 2 * ctl.deadline_s
+        ctl._lock.notify_all()
+
+
 def _submit_all(ctl, n=4):
     members = _members("w_120,h_90,c_1", [(320, 240), (300, 200), (310, 250), (290, 230)][:n])
     return members, [ctl.submit(image, plan) for image, plan, _ in members]
@@ -266,24 +276,9 @@ def test_governor_presplit_keeps_the_block_for_the_prefix_and_copies_the_rest():
         _check_answers(members[:2], futures[:2])
         assert metrics.summary()["flyimg_mem_presplits_total"] == 1
         assert _counts(metrics) == (4, 2, 0)
-        ctl.apply_policy(deadline_ms=0.0)    # the remainder's own pop
+        _expire_deadline(ctl)                # the remainder's own pop
         _check_answers(members[2:], futures[2:])
         assert _counts(metrics) == (4, 2, 2)
-    finally:
-        ctl.close()
-
-
-def test_lowered_max_batch_keeps_the_block_for_the_prefix_and_copies_the_rest():
-    ctl = _waiting_ctl()
-    try:
-        members, futures = _submit_all(ctl, 3)  # not full: waits
-        ctl.apply_policy(max_batch=2)           # now two of them are a launch
-        _check_answers(members[:2], futures[:2])
-        assert not futures[2].done()
-        assert _counts(ctl.metrics) == (3, 2, 0)
-        ctl.apply_policy(deadline_ms=0.0)       # the remainder's own pop
-        _check_answers(members[2:], futures[2:])
-        assert _counts(ctl.metrics) == (3, 2, 1)
     finally:
         ctl.close()
 

@@ -4,7 +4,7 @@ edges (torn-tail recovery, rotation under an injectable clock,
 oldest-first retention eviction, reader-clock skew), emit-time schema
 validation, the traffic-mix classifier's centroids and hysteresis, the
 assembled pipeline end to end through the real app, the offline round
-trip (telemetry_query + autotune_replay from segments alone), the
+trip (telemetry_query from segments alone), the
 unified dump-retention override, and the default-off byte identity."""
 
 from __future__ import annotations
@@ -418,9 +418,9 @@ def test_pipeline_end_to_end_mix_flip_and_round_trip(tmp_path):
     """The full loop: thumbnail burst then cropzoom burst through the
     real app under an injected clock -> the adopted label flips with
     hysteresis, window + launch records land in segments, the gauge and
-    transition counter move, and the offline half (telemetry_query,
-    autotune_replay --telemetry) reproduces everything from disk alone
-    after the process state is gone."""
+    transition counter move, and the offline half (telemetry_query)
+    reproduces everything from disk alone after the process state is
+    gone."""
     from flyimg_tpu.service.app import TELEMETRY_KEY, make_app
 
     src = _write_src(tmp_path)
@@ -527,25 +527,6 @@ def test_pipeline_end_to_end_mix_flip_and_round_trip(tmp_path):
     exported = [json.loads(line) for line in
                 open(out, encoding="utf-8") if line.strip()]
     assert len(exported) == len(windows)
-
-    # autotune_replay accepts both the directory and the exported file
-    from tools import autotune_replay
-
-    for path in (tel_dir, out):
-        replay_windows = autotune_replay._telemetry_windows(path)
-        assert len(replay_windows) == len(windows)
-        assert all(
-            w["_row"]["metric"].startswith("telemetry_window:")
-            for w in replay_windows
-        )
-    out_dir = str(tmp_path / "replay")
-    assert autotune_replay.main(
-        ["--telemetry", tel_dir, "--out-dir", out_dir]
-    ) == 0
-    proposal = json.loads(
-        open(os.path.join(out_dir, "proposal.json"), encoding="utf-8").read()
-    )
-    assert proposal["windows"] == len(windows)
 
 
 def test_mix_report_flags_tampered_labels(tmp_path):

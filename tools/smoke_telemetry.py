@@ -10,9 +10,8 @@ warehouse & traffic-mix classifier"):
 - archive segments rotate under the injected clock and the window +
   launch records land on disk;
 - ``tools/telemetry_query.py mix-report`` reproduces every stored label
-  from the segment files alone (the live process gone), and
-  ``tools/autotune_replay.py --telemetry`` accepts the exported archive
-  and emits a proposal;
+  from the segment files alone (the live process gone), and ``export``
+  writes every archived window into one JSONL stream;
 - a default-off app is byte-clean: no flyimg_telemetry_* /
   flyimg_traffic_mix metrics, no archive directory, a disabled
   /debug/telemetry document.
@@ -191,7 +190,7 @@ async def main() -> int:
 
     # 4) the offline half: labels reproduce from segment files ALONE
     from flyimg_tpu.runtime.telemetry import read_archive
-    from tools import autotune_replay, telemetry_query
+    from tools import telemetry_query
 
     offline = read_archive(tel_dir)
     windows = [r for r in offline["records"] if r["kind"] == "window"]
@@ -211,18 +210,11 @@ async def main() -> int:
         ) == 0,
         "telemetry_query export",
     )
-    out_dir = os.path.join(tmp, "replay")
+    with open(export, encoding="utf-8") as fh:
+        exported = [json.loads(line) for line in fh if line.strip()]
     _require(
-        autotune_replay.main(["--telemetry", export, "--out-dir", out_dir])
-        == 0,
-        "autotune_replay accepts the exported archive",
-    )
-    proposal_path = os.path.join(out_dir, "proposal.json")
-    with open(proposal_path, encoding="utf-8") as fh:
-        proposal = json.load(fh)
-    _require(
-        proposal["windows"] == len(windows),
-        f"replay consumed every archived window (got {proposal['windows']}"
+        len(exported) == len(windows),
+        f"export holds every archived window (got {len(exported)}"
         f" of {len(windows)})",
     )
 
@@ -259,7 +251,7 @@ async def main() -> int:
     print(
         "telemetry smoke OK: thumbnail -> cropzoom flip with hysteresis, "
         f"{len(windows)} windows across {len(offline['segments'])} rotated "
-        "segments, mix-report + autotune_replay reproduce from disk, "
+        "segments, mix-report + export reproduce from disk, "
         "default-off clean"
     )
     return 0

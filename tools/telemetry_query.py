@@ -20,8 +20,7 @@ Subcommands:
                       burn + brownout level per window) for incident
                       reconstruction
 - ``export``        — concatenate segments into one JSONL stream
-                      (optionally filtered by --kind), the input format
-                      ``tools/autotune_replay.py --telemetry`` accepts
+                      (optionally filtered by --kind)
 
 Usage:
     python tools/telemetry_query.py windows var/tmp/telemetry
@@ -206,8 +205,7 @@ def main(argv=None) -> int:
     p_burn.set_defaults(fn=cmd_burn_timeline)
     p_export = sub.add_parser(
         "export",
-        help="concatenate segments to one JSONL stream "
-             "(autotune_replay --telemetry input)",
+        help="concatenate segments to one JSONL stream",
     )
     p_export.add_argument(
         "--kind", action="append",
