@@ -20,7 +20,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from . import compare, corpus as corpus_mod, manifest as mf, trace as trace_mod
 from .traffic import ClosedLoop
 
-HOST_KEEP = re.compile(r"^flyimg:batch:")
+HOST_KEEP = re.compile(rf"^(flyimg:batch:|{re.escape(trace_mod.SLICE_MARK)}$)")
 
 
 def log(*parts: Any) -> None:
@@ -55,47 +55,63 @@ class RssWatch(threading.Thread):
         self._halt.set()
 
 
+# a cycle with a lone launch in it is longer than the others: the full launch
+# waits for the lone launch's caller to be answered and its next frame to be
+# decoded, which is up to half a cycle more
+SLICE_CAP_CYCLES = 1.5
+
+
 def trace_one_launch(loop: ClosedLoop, t_burst: float, cycle: float, t_close: float,
-                     mix: Dict[str, Any]) -> Tuple[str, float]:
-    """Put the profiler around one cycle's device work: every launch between
-    two bursts of answers, whatever programs it runs. Placed by the harness's
-    own clock alone: the callers move in step with the launches, so the
-    window's first burst comes one cycle after the pre-roll's (``t_burst``),
-    and the slice opens ``trace_at_cycle_share`` of the pre-roll's cycle after
-    that burst, while the launch is still filling or being assembled on the
-    host. It ends on the first answer after that (the cycle's launches have
-    run and been read back), or after half a cycle. The share is set early,
-    with the cap, so that the slice holds the device work where the pre-roll's
-    cycle is anything from a tenth shorter to twice as long as the window's
-    (a checkout's first run builds the host codec in its pre-roll); the
-    program has no signal to go by, since it feeds its launch timers only
-    once a launch has been read back (PERF.md section 6, PR 29). ``start_trace`` called
-    while a launch is being staged returns only when the staging has ended
-    (0.37 s since PR 28). Returns the trace's directory and the slice's
-    seconds."""
+                     burst_gap: float) -> Tuple[str, float, Dict[str, float]]:
+    """Put the profiler around one cycle's device work: every launch from the
+    window's opening to the first burst of answers, whatever programs it
+    runs. Placed by the opening and the answers alone, so that no gain on the
+    host can move the launch out of the slice.
+
+    It opens with the window, at the last answer of the pre-roll's burst
+    (``t_burst``): all the callers have just been answered and are out again,
+    so no launch of the new cycle can have been staged yet, however short the
+    fill becomes. It ends a fixed 0.5 s (the read-back's own tail) after the
+    SECOND answer of the new cycle: the first may be a lone launch's (the
+    first frame decoded can reach the idle executor alone, and its one answer
+    is followed by seconds of nothing), while the callers of a full launch
+    wake 20 ms apart, so the second answer says that the full launch has been
+    read back and is being resolved. An answer counts for the new cycle where
+    its call was sent since the pre-roll's burst began (``burst_gap`` tells
+    bursts apart, as for the closing rule): after a lone launch in the
+    pre-roll, its caller's second call rides the pre-roll's full launch and
+    can answer a moment after ``t_burst``. Where the two answers do not come
+    the slice ends ``SLICE_CAP_CYCLES`` cycles of the pre-roll's after it
+    opened, and never later than 1 s before the time is up.
+
+    On the slice's own clock the harness opens the annotation
+    ``trace.SLICE_MARK`` for as long as the slice lasts, from which
+    ``trace.slice_margins`` reads how far inside it the launch sits. Returns
+    the trace's directory, the slice's seconds, and what the profiler's two
+    calls took."""
     import jax
 
-    at = min(t_burst + float(mix["trace_at_cycle_share"]) * cycle, t_close - 2.0)
-    cap = 0.5 * cycle
-    time.sleep(max(at - time.perf_counter(), 0.0))
     trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 1
+    began = loop.burst_began(t_burst, burst_gap)
     t_call = time.perf_counter()
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     t_on = time.perf_counter()
-    until = min(t_on + cap, max(t_close - 1.0, t_on))
-    answered = loop.wait_answer_after(t_on, until - t_on)
-    if answered is not None:
-        time.sleep(min(0.5, max(until - time.perf_counter(), 0.0)))  # the read-back's own tail
-    slice_s = time.perf_counter() - t_on
+    last = max(t_close - 1.0, t_on)
+    with jax.profiler.TraceAnnotation(trace_mod.SLICE_MARK):
+        answered = loop.wait_answers(2, t_on, began, min(t_on + SLICE_CAP_CYCLES * cycle, last) - t_on)
+        if answered is not None:
+            time.sleep(min(0.5, max(last - time.perf_counter(), 0.0)))
+        t_off = time.perf_counter()
     jax.profiler.stop_trace()
-    log(f"# trace: cycle of the pre-roll {cycle:.2f} s; start_trace called {t_call - t_burst:.1f} s after its burst, "
-        f"returned after {t_on - t_call:.1f} s; {slice_s:.2f} s traced until "
-        f"{'the first answer after it' if answered is not None else 'the cap (no answer came)'}; "
-        f"stopped in {time.perf_counter() - t_on - slice_s:.1f} s")
-    return trace_dir, slice_s
+    took = {"start_trace_s": t_on - t_call, "stop_trace_s": time.perf_counter() - t_off}
+    log(f"# trace: cycle of the pre-roll {cycle:.2f} s; start_trace called {t_call - t_burst:.2f} s after its burst, "
+        f"returned after {took['start_trace_s']:.2f} s; {t_off - t_on:.2f} s traced until "
+        f"{'0.5 s after the second answer of the new cycle' if answered is not None else 'the cap (no two answers came)'}; "
+        f"stopped in {took['stop_trace_s']:.2f} s")
+    return trace_dir, t_off - t_on, took
 
 
 def trim_heap() -> None:
@@ -169,8 +185,7 @@ def device_report(planes: List[Dict[str, Any]], slice_s: float, window_s: float,
     keeps of it."""
     dev_planes = trace_mod.device_planes(planes)
     busy = sum(trace_mod.busy_seconds(p) for p in dev_planes) / len(dev_planes)
-    host_marks = [e for p in planes if p not in dev_planes
-                  for line in p["lines"] for e in line["events"]]
+    host_marks = [e for e in trace_mod.host_events(planes) if e[0] != trace_mod.SLICE_MARK]
     log("# trace: gaps between the slice's device ops, seconds:", json.dumps(trace_mod.attribute_gaps(
         trace_mod.idle_gaps(dev_planes[0]), host_marks,
         "host inside a flyimg:batch dispatch", "host between dispatches")[:4]))
@@ -287,7 +302,7 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     loop.close_at(t_up, burst_gap, float(mix["burst_cap_cycle_share"]) * cycle)
     trace_dir = slice_s = None
     if traced:
-        trace_dir, slice_s = trace_one_launch(loop, t_burst, cycle, t_up, mix)
+        trace_dir, slice_s, notes = trace_one_launch(loop, t_burst, cycle, t_up, burst_gap)
     time.sleep(max(t_up - time.perf_counter(), 0.0))
     unanswered = loop.drain(float(mix["drain_seconds"]))
     t_close = time.perf_counter()
@@ -362,7 +377,19 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
             "trace_planes": planes, "trace_slice_s": slice_s, "launch_sizes": sizes,
             "device": device,
             "work_per_image": bound.reference.work(config),
+            "notes": notes,
         }
+        margins = trace_mod.slice_margins(planes)
+        if margins:
+            # the full launch's place in the slice; a lone launch beside it is
+            # in the log alone
+            notes.update({k: margins[0][k] for k in ("staged_after_open_s", "readback_before_end_s")})
+            log("# trace: launches in the slice [seq, staging began s after the opening, read-back ended s "
+                "before the end, held s], the one held longest first:", json.dumps(
+                    [[m["seq"], m["staged_after_open_s"], m["readback_before_end_s"], m["hold_s"]]
+                     for m in margins]))
+        else:
+            log("# trace: no launch of the program is whole in the slice (dispatch to read-back)")
         for metric in mf.metrics_for(manifest, name, "per_layer"):
             spec = mf.load_metric(metric["name"])
             if metric["source"] == "device_trace" and not on_chip:
@@ -374,8 +401,7 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
             device_out["busy_s"], device_out["window_s"] = busy, window_s
             log(f"# trace: device busy {busy:.4f} s of the {slice_s:.2f} s slice; "
                 f"window {window_s:.2f} s")
-        if ctx.get("notes"):
-            log("# notes:", json.dumps(ctx["notes"]))
+        log("# notes:", json.dumps(notes))
 
     units = {m["name"]: m["unit"] for kind in ("end_to_end", "per_layer") for m in manifest[kind]}
     metrics = {k: {"value": v, "unit": units[k]} for k, v in values.items() if v is not None}
@@ -404,5 +430,7 @@ def run_cell(manifest: Dict[str, Any], name: str, seed: int, seconds: float,
     result["seed"] = seed
     result["launch_sizes"] = sizes
     result["host_peak_rss_bytes"] = rss
+    if traced:
+        result["notes"] = notes
     result["compared"] = numbers
     return result

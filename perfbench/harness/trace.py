@@ -1,6 +1,7 @@
 """Reduction of a profiler trace to numbers: device-busy union, device time
-of named XLA modules, the heaviest device operations, and the longest idle
-gaps by what the host was doing in them.
+of named XLA modules, the heaviest device operations, the longest idle gaps
+by what the host was doing in them, and the launches the program annotates
+on the host, each with its hold of the device and its place in the slice.
 
 The functions work on a plain structure, so that they can be checked on a
 small recorded trace kept as JSON (``fixtures/``):
@@ -25,6 +26,11 @@ DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 LAYOUT = re.compile(r"\{[^{}]*\}")
+# the program opens every phase of a transform launch as a host annotation of
+# this name (``runtime/batcher.py`` ``_Launch.annotate``); the harness opens
+# one of its own over the traced slice (``cell.trace_one_launch``)
+LAUNCH_PHASE = re.compile(r"^flyimg:batch:(\d+):([a-z0-9_]+)$")
+SLICE_MARK = "perfbench:slice"
 
 
 def find_xplane(log_dir: str) -> Optional[str]:
@@ -89,12 +95,73 @@ def busy_seconds(plane: Plane) -> float:
     return sum(b - a for a, b in spans) / 1e9
 
 
+def modules(plane: Plane, pattern: str) -> List[List[Any]]:
+    """The XLA modules that ran on this device and whose name matches
+    ``pattern``, one event a run."""
+    rx = re.compile(pattern)
+    return [e for e in _line(plane, MODULES_LINE) if rx.search(e[0])]
+
+
 def module_seconds(plane: Plane, pattern: str) -> Tuple[float, int]:
     """Total device seconds, and the count, of the XLA modules whose name
     matches ``pattern``."""
-    rx = re.compile(pattern)
-    hits = [e for e in _line(plane, MODULES_LINE) if rx.search(e[0])]
+    hits = modules(plane, pattern)
     return sum(e[2] for e in hits) / 1e9, len(hits)
+
+
+def longest_module(plane: Plane, pattern: str) -> Optional[List[Any]]:
+    """The XLA module matching ``pattern`` that ran longest on this device:
+    of the launches a slice holds, the full one's."""
+    return max(modules(plane, pattern), key=lambda e: e[2], default=None)
+
+
+def host_events(planes: Sequence[Plane]) -> List[List[Any]]:
+    """Every event kept of the planes that are no device's."""
+    return [e for p in planes if not DEVICE_PLANE.match(p["name"])
+            for line in p["lines"] for e in line["events"]]
+
+
+def launch_phases(planes: Sequence[Plane]) -> Dict[int, Dict[str, Interval]]:
+    """The phases the program annotated on the host, by launch:
+    ``{seq: {phase: (start_ns, end_ns)}}``. An annotation that began before
+    the profiler was on is not in the trace."""
+    out: Dict[int, Dict[str, Interval]] = {}
+    for name, start, duration in host_events(planes):
+        m = LAUNCH_PHASE.match(name)
+        if m:
+            out.setdefault(int(m.group(1)), {})[m.group(2)] = (start, start + duration)
+    return out
+
+
+def launch_holds(planes: Sequence[Plane]) -> Dict[int, Interval]:
+    """For each launch the trace holds whole, the interval in which the
+    program held the device for it: the start of its ``dispatch`` annotation
+    to the end of its ``d2h``, which is what the program's own
+    ``flyimg_device_seconds`` spans (``batcher._Launch.device_s``)."""
+    return {seq: (phases["dispatch"][0], phases["d2h"][1])
+            for seq, phases in launch_phases(planes).items()
+            if "dispatch" in phases and "d2h" in phases}
+
+
+def slice_margins(planes: Sequence[Plane]) -> List[Dict[str, Any]]:
+    """Where each launch sits in the traced slice, on the trace's own clock:
+    seconds from the slice's opening (the start of the harness's
+    ``SLICE_MARK`` annotation) to the start of the launch's staging call
+    (``h2d``), seconds from the end of its read-back (``d2h``) to the slice's
+    end, and its hold. The launch held longest, the full one, comes first.
+    A launch whose staging began before the profiler was on has no ``h2d``
+    in the trace and reads None there. No mark in the trace: nothing."""
+    mark = next(((s, s + d) for name, s, d in host_events(planes) if name == SLICE_MARK), None)
+    if mark is None:
+        return []
+    phases = launch_phases(planes)
+    rows = []
+    for seq, (a, b) in launch_holds(planes).items():
+        staged = phases[seq].get("h2d")
+        rows.append({"seq": seq, "hold_s": (b - a) / 1e9,
+                     "staged_after_open_s": None if staged is None else (staged[0] - mark[0]) / 1e9,
+                     "readback_before_end_s": (mark[1] - b) / 1e9})
+    return sorted(rows, key=lambda r: -r["hold_s"])
 
 
 def top_ops(planes: Sequence[Plane], n: int = 10) -> List[List[Any]]:

@@ -18,14 +18,22 @@ parameters (``traffic/<name>.json``):
                     through whole (its callers send again, and the last launch
                     is a full one, not a part of one that waits out the
                     program's deadline): a caller answered after the time-up
-                    sends again only while no gap between answers since before
-                    it has reached the first share of a cycle, and not later
-                    than the second share of a cycle after it
+                    sends again only where its answer belongs to a burst that
+                    began before it, and not later than the second share of a
+                    cycle after it. A burst begins with an answer before which
+                    nothing answered for the first share of a cycle; but where
+                    the burst so far has answered more than one caller and
+                    fewer than ``in_flight``, and began less than the second
+                    share of a cycle ago, the pause was the machine standing
+                    still in the middle of it and the burst goes on (PR 34: a
+                    stall of 5.9 s inside the straddling burst left 15 calls
+                    to wait out the deadline as a launch of their own)
 ``drain_seconds``   how long to wait, after the time-up, for answers still due
 ``warm_launch_sizes``  padded launch sizes the loop can produce, to be warmed
-``trace_at_cycle_share``  a traced run calls the profiler this share of a
-                    cycle after the pre-roll's last answer, while the window's
-                    launch is being staged (see ``cell.trace_one_launch``)
+
+A traced run takes nothing from the mix but the gap that tells two bursts
+apart: its slice of the profiler is placed by the window's opening and by the
+answers alone (``cell.trace_one_launch``).
 
 The generator knows nothing of images: it calls ``call(item)`` and records
 when each call was sent, when it answered, and what ``record`` makes of the
@@ -72,6 +80,9 @@ class ClosedLoop:
         self._stop = False
         self._close: Optional[tuple] = None  # (time-up, gap, cap), seconds
         self._last_done = float("-inf")
+        # the burst of answers in progress, once ``close_at`` has said what a
+        # pause is: when it began and how many it has answered
+        self._burst_began, self._burst_count = float("-inf"), 0
         self._threads: List[threading.Thread] = []
 
     def _next_item(self) -> int:
@@ -96,9 +107,14 @@ class ClosedLoop:
                              error=f"{type(exc).__name__}: {exc}")
             with self._lock:
                 self._records.append(rec)
-                if self._close is not None and rec.done >= self._close[0]:
+                if self._close is not None:
                     up, gap, cap = self._close
-                    if rec.done - self._last_done >= gap or rec.done >= up + cap:
+                    stalled_inside = (1 < self._burst_count < self.in_flight
+                                      and rec.done - self._burst_began < cap)
+                    if rec.done - self._last_done >= gap and not stalled_inside:
+                        self._burst_began, self._burst_count = rec.done, 0
+                    self._burst_count += 1
+                    if rec.done >= up and (self._burst_began >= up or rec.done >= up + cap):
                         self._stop = True
                 self._last_done = max(self._last_done, rec.done)
                 self._lock.notify_all()
@@ -125,15 +141,31 @@ class ClosedLoop:
                         f"pre-roll: {len(first)} of the first {count} calls answered in {timeout:.0f} s")
                 self._lock.wait(timeout=left)
 
-    def wait_answer_after(self, start: float, timeout: float) -> Optional[float]:
-        """Block until a call answers later than ``start``; returns the clock
-        at that answer, or None where none comes within ``timeout``."""
+    def burst_began(self, end: float, gap: float) -> float:
+        """The clock at the first answer of the burst of answers that ``end``
+        closes: going back from ``end``, the last answer before which nothing
+        answered for ``gap`` seconds or longer."""
+        with self._lock:
+            times = sorted((r.done for r in self._records if r.done <= end), reverse=True)
+        began = end
+        for done in times:
+            if began - done >= gap:
+                break
+            began = done
+        return began
+
+    def wait_answers(self, count: int, done_after: float, sent_after: float,
+                     timeout: float) -> Optional[float]:
+        """Block until ``count`` calls sent at ``sent_after`` or later have
+        answered later than ``done_after``; returns the clock at the last of
+        those answers, or None where they do not come within ``timeout``."""
         deadline = time.perf_counter() + timeout
         with self._lock:
             while True:
-                for rec in reversed(self._records):
-                    if rec.done > start:
-                        return rec.done
+                hits = sorted(r.done for r in self._records
+                              if r.done > done_after and r.sent >= sent_after)
+                if len(hits) >= count:
+                    return hits[count - 1]
                 left = deadline - time.perf_counter()
                 if left <= 0:
                     return None
@@ -143,6 +175,8 @@ class ClosedLoop:
         """The time is up at ``up``: see ``burst_gap_cycle_share`` above."""
         with self._lock:
             self._close = (up, gap, cap)
+            # called where a burst has just ended: the next pause begins a new one
+            self._burst_began, self._burst_count = self._last_done, self.in_flight
 
     def stop(self) -> None:
         with self._lock:
