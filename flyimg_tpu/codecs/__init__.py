@@ -222,7 +222,7 @@ def jpeg_batch_scale_num(data_info: MediaInfo, target_hint) -> int:
 
 
 @_pool_tracked("decode")
-def batch_jpeg_decode(items: list) -> list:
+def batch_jpeg_decode(items: list, split=None) -> list:
     """Aux-group runner: decode many JPEGs in ONE native pool call — C
     worker threads run in parallel regardless of Python thread counts.
     ``items`` are ``(bytes, scale_num, roi)`` with a uniform scale (the
@@ -232,7 +232,8 @@ def batch_jpeg_decode(items: list) -> list:
     skip the EXIF transpose. Full entries return oriented RGB arrays;
     ROI entries return ``(rgb, (out_x, out_y), (full_w, full_h))`` with
     the iMCU-actualized window geometry. None = fall back to the
-    single-image path."""
+    single-image path. ``split`` (a ``native_codec.LaunchSplit``) is
+    filled with the pool launch's two parts and its buffers."""
     pool = native_codec.get_pool()
     if pool is None:
         return [None] * len(items)
@@ -244,7 +245,7 @@ def batch_jpeg_decode(items: list) -> list:
             x0, y0, x1, y1 = (int(v) for v in roi)
             rois.append((x0, y0, x1 - x0, y1 - y0))
     outs = pool.decode_batch(
-        [d for d, _, _ in items], items[0][1], rois=rois
+        [d for d, _, _ in items], items[0][1], rois=rois, split=split
     )
     results = []
     for (data, _, roi), decoded in zip(items, outs):
@@ -303,7 +304,7 @@ def parse_sampling_factor(value) -> Tuple[int, int]:
 
 
 @_pool_tracked("encode")
-def batch_jpeg_encode(items: list) -> list:
+def batch_jpeg_encode(items: list, split=None) -> list:
     """Aux-group runner: encode many RGB frames to JPEG in ONE native pool
     call — C worker threads run the (expensive) trellis DP in parallel.
     ``items`` are (rgb, quality, sampling, mozjpeg) tuples with uniform
@@ -311,7 +312,9 @@ def batch_jpeg_encode(items: list) -> list:
     item (None = fall back to the single-image encode()). moz_0 means a
     BASELINE encode — no trellis, no Huffman optimization, no progressive
     scans — exactly matching the single-image encode(mozjpeg=False) path
-    so the pooled and fallback bytes are identical for one cache key."""
+    so the pooled and fallback bytes are identical for one cache key.
+    ``split`` (a ``native_codec.LaunchSplit``) is filled with the pool
+    launch's buffers."""
     pool = native_codec.get_pool()
     if pool is None:
         return [None] * len(items)
@@ -323,6 +326,7 @@ def batch_jpeg_encode(items: list) -> list:
         optimize=mozjpeg,
         progressive=mozjpeg,
         sampling=sampling,
+        split=split,
     )
 
 

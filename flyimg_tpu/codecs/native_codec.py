@@ -13,10 +13,12 @@ WARNING.
 from __future__ import annotations
 
 import ctypes
+import dataclasses
 import logging
 import os
 import subprocess
 import threading
+import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -233,11 +235,63 @@ def available() -> bool:
     return bool(_load())
 
 
-def _take_buffer(lib, ptr: int, nbytes: int) -> np.ndarray:
-    buf = ctypes.cast(ptr, ctypes.POINTER(ctypes.c_uint8 * nbytes)).contents
-    arr = np.frombuffer(buf, dtype=np.uint8).copy()
-    lib.fc_free(ptr)
-    return arr
+@dataclasses.dataclass
+class LaunchSplit:
+    """What one pool launch says of itself, for whoever records it (the
+    handler, into the registry it was built with: ``DecodePool`` keeps no
+    totals). ``native_s`` is the pool call (C workers, GIL released),
+    ``handover_s`` the walk over its results on the calling thread (a
+    decode launch's; an encode launch leaves both 0); ``buffers`` /
+    ``buffer_bytes`` count the native buffers handed over."""
+
+    native_s: float = 0.0
+    handover_s: float = 0.0
+    buffers: int = 0
+    buffer_bytes: int = 0
+
+
+class _NativePixels:
+    """Owner of one malloc'd buffer of decoded pixels. numpy keeps it alive
+    as the end of the ``base`` chain of every view (a ``reshape``'s ``base``
+    is the array ``_adopt_pixels`` makes, whose ``base`` is this object), so
+    the buffer is freed when the last view goes, once, on whichever thread
+    drops it. ``__array_interface__`` names the memory by address: no ctypes
+    array type is made per byte length. The free function is held here, so
+    ``__del__`` looks nothing up in a module that interpreter exit may
+    already have cleared, and the handle it calls through cannot go first."""
+
+    __slots__ = ("_free", "_ptr", "__array_interface__")
+
+    def __init__(self, lib, ptr: int, nbytes: int) -> None:
+        self._free = lib.fc_free
+        self._ptr = ptr
+        self.__array_interface__ = {
+            "version": 3,
+            "shape": (nbytes,),
+            "typestr": "|u1",
+            "data": (ptr, False),
+        }
+
+    def __del__(self) -> None:
+        ptr, self._ptr = self._ptr, 0
+        if ptr:
+            self._free(ptr)
+
+
+def _adopt_pixels(lib, ptr: int, nbytes: int) -> np.ndarray:
+    """Decoded pixels: the native buffer itself as a writable flat ``uint8``
+    array that owns it (``_NativePixels``). Nothing is copied; a small view
+    kept for long pins the whole buffer, as it pinned numpy's copy before."""
+    return np.asarray(_NativePixels(lib, ptr, nbytes))
+
+
+def _copy_bytes(lib, ptr: int, nbytes: int) -> bytes:
+    """Encoded output: the one copy the ``bytes`` contract needs, then the
+    native buffer is freed."""
+    try:
+        return ctypes.string_at(ptr, nbytes)
+    finally:
+        lib.fc_free(ptr)
 
 
 def jpeg_decode(
@@ -252,7 +306,7 @@ def jpeg_decode(
     ptr = lib.fc_jpeg_decode(data, len(data), scale_num, ctypes.byref(w), ctypes.byref(h))
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, w.value * h.value * 3)
+    arr = _adopt_pixels(lib, ptr, w.value * h.value * 3)
     return arr.reshape(h.value, w.value, 3)
 
 
@@ -288,7 +342,7 @@ def jpeg_decode_roi(
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, w.value * h.value * 3)
+    arr = _adopt_pixels(lib, ptr, w.value * h.value * 3)
     return (
         arr.reshape(h.value, w.value, 3),
         (ox.value, oy.value),
@@ -318,8 +372,7 @@ def jpeg_encode(
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, out_len.value)
-    return arr.tobytes()
+    return _copy_bytes(lib, ptr, out_len.value)
 
 
 def jpeg_encode_trellis(
@@ -346,8 +399,7 @@ def jpeg_encode_trellis(
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, out_len.value)
-    return arr.tobytes()
+    return _copy_bytes(lib, ptr, out_len.value)
 
 
 # fc_probe format codes (keep in sync with enum fc_format in fastcodec.cpp)
@@ -400,7 +452,7 @@ def png_decode(
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, w.value * h.value * ch.value)
+    arr = _adopt_pixels(lib, ptr, w.value * h.value * ch.value)
     return arr.reshape(h.value, w.value, ch.value), ch.value
 
 
@@ -420,8 +472,7 @@ def png_encode(pixels: np.ndarray) -> Optional[bytes]:
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, out_len.value)
-    return arr.tobytes()
+    return _copy_bytes(lib, ptr, out_len.value)
 
 
 def webp_decode_auto(data: bytes) -> Optional[Tuple[np.ndarray, int]]:
@@ -437,7 +488,7 @@ def webp_decode_auto(data: bytes) -> Optional[Tuple[np.ndarray, int]]:
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, w.value * h.value * ch.value)
+    arr = _adopt_pixels(lib, ptr, w.value * h.value * ch.value)
     return arr.reshape(h.value, w.value, ch.value), ch.value
 
 
@@ -459,8 +510,7 @@ def webp_encode(
     )
     if not ptr:
         return None
-    arr = _take_buffer(lib, ptr, out_len.value)
-    return arr.tobytes()
+    return _copy_bytes(lib, ptr, out_len.value)
 
 
 class DecodePool:
@@ -478,13 +528,15 @@ class DecodePool:
         blobs: List[bytes],
         scale_num: int = 8,
         rois: Optional[List[Optional[Tuple[int, int, int, int]]]] = None,
+        split: Optional[LaunchSplit] = None,
     ) -> list:
         """Decode many JPEGs in ONE pool call. Plain entries return an
         RGB array (or None on per-image failure). ``rois`` (parallel to
         ``blobs``; entries may be None) requests sub-window decodes in
         OUTPUT coordinates — those entries return ``(rgb, (out_x, out_y),
         (full_w, full_h))`` like :func:`jpeg_decode_roi`, with the same
-        iMCU-actualized geometry contract."""
+        iMCU-actualized geometry contract. ``split``, when given, is
+        filled with this launch's two parts and its buffers."""
         n = len(blobs)
         if n == 0:
             return []
@@ -513,16 +565,21 @@ class DecodePool:
                 else:
                     items[i].roi_w = 0
                     items[i].roi_h = 0
+        called = time.perf_counter()
         self._lib.fc_pool_decode_jpeg_batch(
             self._pool, ctypes.cast(items, ctypes.POINTER(_BatchItem)), n
         )
+        decoded = time.perf_counter()
         out: list = []
+        buffers = nbytes = 0
         for i in range(n):
             if not items[i].out:
                 out.append(None)
                 continue
             w, h = items[i].width, items[i].height
-            arr = _take_buffer(self._lib, items[i].out, w * h * 3)
+            buffers += 1
+            nbytes += w * h * 3
+            arr = _adopt_pixels(self._lib, items[i].out, w * h * 3)
             rgb = arr.reshape(h, w, 3)
             if roi_build and items[i].roi_w > 0:
                 out.append((
@@ -532,6 +589,11 @@ class DecodePool:
                 ))
             else:
                 out.append(rgb)
+        if split is not None:
+            split.native_s = decoded - called
+            split.handover_s = time.perf_counter() - decoded
+            split.buffers = buffers
+            split.buffer_bytes = nbytes
         return out
 
     def encode_batch(
@@ -543,12 +605,14 @@ class DecodePool:
         optimize: bool = True,
         progressive: bool = True,
         sampling: Tuple[int, int] = (1, 1),
+        split: Optional[LaunchSplit] = None,
     ) -> List[Optional[bytes]]:
         """Encode many RGB frames to JPEG in ONE native pool call — the
         encode-side twin of decode_batch. The trellis DP is the expensive
         half of a miss (several ms/image), so bursts must pay it in
         parallel on C worker threads, not serially under one Python
-        caller."""
+        caller. ``split``, when given, is filled with this launch's
+        buffers (nobody reads an encode launch's two parts: not timed)."""
         n = len(frames)
         if n == 0:
             return []
@@ -576,8 +640,11 @@ class DecodePool:
                 out.append(None)
                 continue
             out.append(
-                _take_buffer(self._lib, items[i].out, items[i].out_len).tobytes()
+                _copy_bytes(self._lib, items[i].out, items[i].out_len)
             )
+        if split is not None:
+            split.buffers = n - out.count(None)
+            split.buffer_bytes = sum(len(blob) for blob in out if blob)
         return out
 
     def close(self) -> None:

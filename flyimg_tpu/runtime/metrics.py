@@ -555,6 +555,42 @@ class MetricsRegistry:
             "members beyond the block)",
         ).inc(members)
 
+    def record_codec_decode_launch(self, split) -> None:
+        """One decode launch of the host codec's pool
+        (``codecs.native_codec.LaunchSplit``, carried back by the
+        handler's runner): its two parts, one observation a launch, and
+        the frames it handed over."""
+        self.histogram(
+            "flyimg_codec_native_seconds",
+            "Per decode launch of the native codec pool: the pool call "
+            "(C workers decode, GIL released)",
+        ).observe(max(float(split.native_s), 0.0))
+        self.histogram(
+            "flyimg_codec_handover_seconds",
+            "Per decode launch of the native codec pool: the walk over "
+            "its results on the calling thread, each native buffer of "
+            "pixels handed to the array that is returned",
+        ).observe(max(float(split.handover_s), 0.0))
+        self.record_codec_buffers(
+            "adopted", split.buffers, split.buffer_bytes
+        )
+
+    def record_codec_buffers(self, handover: str, buffers: int,
+                             nbytes: int) -> None:
+        """Native buffers the codec pool's launches handed over, by how:
+        ``adopted`` (decoded pixels: the returned array owns the buffer,
+        nothing copied) or ``bytes`` (encoded output: copied once into
+        ``bytes``, then freed)."""
+        self.counter(
+            f'flyimg_codec_buffers_total{{handover="{handover}"}}',
+            "Native buffers handed over by the codec pool's launches",
+        ).inc(buffers)
+        self.counter(
+            f'flyimg_codec_buffer_bytes_total{{handover="{handover}"}}',
+            "Bytes of the native buffers handed over by the codec "
+            "pool's launches",
+        ).inc(nbytes)
+
     def record_compile_event(self, cache_hit: bool) -> None:
         """Batched-program compile cache outcome per device batch."""
         result = "hit" if cache_hit else "miss"

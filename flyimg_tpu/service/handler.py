@@ -1148,6 +1148,34 @@ class ImageHandler:
                 "single-image path instead",
             ).inc()
 
+    def _decode_launch(self, items: list) -> list:
+        """The codec controller's runner for a decode group:
+        ``codecs.batch_jpeg_decode``, then what the launch says of itself
+        (``native_codec.LaunchSplit``) into the registry THIS handler was
+        built with: the codec controller keeps one of its own, which no
+        exporter reads. A bound method of one handler is one stable
+        runner (the runner is part of the group's key)."""
+        from flyimg_tpu.codecs import batch_jpeg_decode, native_codec
+
+        split = native_codec.LaunchSplit()
+        results = batch_jpeg_decode(items, split)
+        if self.metrics is not None:
+            self.metrics.record_codec_decode_launch(split)
+        return results
+
+    def _encode_launch(self, items: list) -> list:
+        """The encode-side twin of :meth:`_decode_launch`: the launch's
+        buffers alone are counted (each copied once into ``bytes``)."""
+        from flyimg_tpu.codecs import batch_jpeg_encode, native_codec
+
+        split = native_codec.LaunchSplit()
+        results = batch_jpeg_encode(items, split)
+        if self.metrics is not None:
+            self.metrics.record_codec_buffers(
+                "bytes", split.buffers, split.buffer_bytes
+            )
+        return results
+
     def _aux_result(self, future: Future, stage: str,
                     timings: Dict[str, float],
                     deadline: Optional[Deadline]):
@@ -1157,8 +1185,7 @@ class ImageHandler:
         ``<stage>_queue`` is enqueue -> its launch popped, ``<stage>_run``
         the runner call that carried it. What is left of the enclosing
         stage is the handler's own work around the launch (probe, ROI
-        window, the copy out of the pool's buffer, the prescale and the
-        cut, the wake-up)."""
+        window, the prescale and the cut, the wake-up)."""
         result = future.result(timeout=self._device_wait_s(deadline))
         times = getattr(future, "launch_times", None)
         if times is not None:
@@ -1365,11 +1392,7 @@ class ImageHandler:
         tag, the never-cache decision keyed on it, and the bytes can
         never drift apart (PNG/GIF ignore quality; lossless WebP bytes
         must stay byte-identical to the normal render)."""
-        from flyimg_tpu.codecs import (
-            batch_jpeg_encode,
-            native_codec,
-            parse_sampling_factor,
-        )
+        from flyimg_tpu.codecs import native_codec, parse_sampling_factor
 
         quality = options.int_option("quality", 90) or 90
         lossy = spec.extension == "jpg" or (
@@ -1411,7 +1434,7 @@ class ImageHandler:
                         ("jpegenc", quality, sampling, mozjpeg),
                         (np.ascontiguousarray(frame), quality, sampling,
                          mozjpeg),
-                        batch_jpeg_encode,
+                        self._encode_launch,
                     ),
                     "encode", timings, deadline,
                 )
@@ -1501,10 +1524,9 @@ class ImageHandler:
             return None
         from flyimg_tpu.codecs import (
             DecodedImage,
-            batch_jpeg_decode,
             jpeg_batch_scale_num,
+            native_codec,
         )
-        from flyimg_tpu.codecs import native_codec
         from flyimg_tpu.codecs.exif import jpeg_orientation
 
         if info.mime != "image/jpeg" or native_codec.get_pool() is None:
@@ -1517,7 +1539,8 @@ class ImageHandler:
         try:
             result = self._aux_result(
                 self.codec_batcher.submit_aux(
-                    ("jpegdec", scale), (data, scale, roi), batch_jpeg_decode
+                    ("jpegdec", scale), (data, scale, roi),
+                    self._decode_launch,
                 ),
                 "decode", timings, deadline,
             )

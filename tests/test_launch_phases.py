@@ -297,6 +297,46 @@ def test_smart_crop_wait_is_split_like_the_codec_waits(system):
     assert "flyimg_aux_items_total 1" in text  # the codec keeps its own registry
 
 
+def _samples(metrics, prefix):
+    out = {}
+    for line in metrics.render_prometheus().splitlines():
+        if line.startswith(prefix) and "_bucket" not in line:
+            name, value = line.rsplit(" ", 1)
+            out[name] = float(value)
+    return out
+
+
+def test_codec_launch_split_reaches_the_registry_the_handler_was_given(system):
+    """PR 35: the codec controller keeps a registry of its own, so what a
+    codec launch says of itself (``native_codec.LaunchSplit``) is recorded
+    by the handler's runner into the handler's registry: the two timers,
+    one observation a decode launch, and the buffers by how they were
+    handed over."""
+    from flyimg_tpu.codecs import native_codec
+
+    if native_codec.get_pool() is None:
+        pytest.skip("native codec not built")
+    frames = 3
+    for seed in range(frames):
+        timings = {}
+        system.transform(_jpeg(seed=seed), timings)
+        assert "decode_run" in timings and "encode_run" in timings
+    got = _samples(system.metrics, "flyimg_codec_")
+    assert got['flyimg_codec_buffers_total{handover="adopted"}'] == frames
+    assert got['flyimg_codec_buffer_bytes_total{handover="adopted"}'] == frames * 320 * 240 * 3
+    assert got['flyimg_codec_buffers_total{handover="bytes"}'] == frames
+    assert got['flyimg_codec_buffer_bytes_total{handover="bytes"}'] > 0
+    for timer in ("flyimg_codec_native_seconds", "flyimg_codec_handover_seconds"):
+        assert got[timer + "_count"] == frames  # one caller: launches of one
+        assert got[timer + "_sum"] > 0
+    # the hand-over is inside the member's run, the pool call too
+    stage = _samples(system.metrics, 'flyimg_stage_seconds_sum{stage="decode_run"}')
+    assert (got["flyimg_codec_native_seconds_sum"]
+            + got["flyimg_codec_handover_seconds_sum"]) <= sum(stage.values())
+    # and none of it on the codec controller's own registry
+    assert _samples(system.codec.metrics, "flyimg_codec_") == {}
+
+
 def test_resolve_phase_reaches_the_attached_span_and_the_flight_row(system):
     trace = tracing.Trace()
     with tracing.activate(trace):
@@ -653,6 +693,11 @@ _TIMINGS[0].update(smartcrop=0.4, smartcrop_prepare=0.01,
 _TIMINGS[1].update(smartcrop=0.8, smartcrop_prepare=0.03)
 _BEFORE.update({"flyimg_aux_items_total": 10.0, "flyimg_aux_batches_total": 8.0})
 _AFTER.update({"flyimg_aux_items_total": 138.0, "flyimg_aux_batches_total": 72.0})
+# the codec launches' hand-over (PR 35): four decode launches of 32 in the window
+_BEFORE.update({"flyimg_codec_handover_seconds_sum": 2.0,
+                "flyimg_codec_handover_seconds_count": 2.0})
+_AFTER.update({"flyimg_codec_handover_seconds_sum": 2.064,
+               "flyimg_codec_handover_seconds_count": 6.0})
 
 
 @pytest.mark.parametrize("metric,expected", [
@@ -672,6 +717,7 @@ _AFTER.update({"flyimg_aux_items_total": 138.0, "flyimg_aux_batches_total": 72.0
     ("smartcrop_queue_ms", 250.0),
     ("smartcrop_run_ms", 125.0),
     ("aux_items_per_launch", 2.0),
+    ("decode_handover_ms_per_image", 0.5),
 ])
 def test_new_metric_files_read_the_recorded_fixture(metric, expected):
     from perfbench.harness import manifest
@@ -764,6 +810,7 @@ def test_manifest_with_the_new_metrics_keeps_the_rules():
                            "h2d_ms_per_image", "d2h_ms_per_image",
                            "device_run_ms_per_image", "readback_gap_ms"]
     assert len(set(names)) == len(names)
+    assert "decode_handover_ms_per_image" in names[18:]
     cells = {c["name"] for c in doc["workloads"]}
     for metric in doc["per_layer"][8:]:
         assert metric["workloads"] and set(metric["workloads"]) <= cells
