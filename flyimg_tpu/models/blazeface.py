@@ -17,7 +17,7 @@ which is what __graft_entry__.dryrun_multichip exercises.
 
 from __future__ import annotations
 
-from functools import partial
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import flax.linen as nn
@@ -141,8 +141,12 @@ def decode_boxes(raw: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([cx, cy, w, h], axis=-1)
 
 
-@partial(jax.jit, static_argnames=("score_threshold",))
-def _forward(params, images, score_threshold: float = 0.5):
+@jax.jit
+def _forward(params, images):
+    """[B, 128, 128, 3] float32 in [-1, 1] -> probabilities [B, 896] and
+    decoded (cx, cy, w, h) boxes [B, 896, 4] of the view, before any
+    threshold. On the TPU the float32 convolutions run at
+    ``jax.lax.Precision.DEFAULT``: bfloat16 operands, float32 sums."""
     with jax.named_scope("flyimg.blazeface"):
         scores, raw = BlazeFace().apply(params, images)
         probs = jax.nn.sigmoid(scores)
@@ -247,70 +251,91 @@ def _view_input(rgb: np.ndarray, x: int, y: int, vw: int, vh: int) -> np.ndarray
     return canvas.astype(np.float32) / 127.5 - 1.0
 
 
-def detect_faces(
-    params,
-    rgb: np.ndarray,
-    *,
-    score_threshold: float = 0.5,
-    max_faces: int = 16,
-) -> List[Tuple[int, int, int, int]]:
-    """[h, w, 3] uint8 -> list of (x, y, w, h) pixel boxes. Same contract as
-    facefind.detect_faces so the handler can swap backends."""
-    return detect_faces_batch(
-        params, [rgb], score_threshold=score_threshold, max_faces=max_faces
-    )[0]
+#: boxes a detection keeps (the serving default; facefind keeps 32)
+MAX_FACES = 16
 
 
-def detect_faces_batch(
-    params,
-    rgbs: List[np.ndarray],
-    *,
-    score_threshold: float = 0.5,
-    max_faces: int = 16,
-) -> List[List[Tuple[int, int, int, int]]]:
-    """Many images -> boxes in ONE batched forward: every view of every
-    image shares the fixed 128x128 network input, so the whole multiscale
-    pyramid across all images is a single compiled program launch (batch
-    axis rides the power-of-two ladder). Per image, view detections merge
-    in one global NMS (anchors from a corner tile compete with full-frame
-    anchors on score)."""
-    n = len(rgbs)
-    if n == 0:
-        return []
-    views_per = [_views(rgb) for rgb in rgbs]
-    flat: List[np.ndarray] = []
-    for rgb, views in zip(rgbs, views_per):
-        for x, y, vw, vh in views:
-            flat.append(_view_input(rgb, x, y, vw, vh))
-    # chunk to the runtime's batch-bucket ceiling (runtime/batcher.py
-    # MAX_BATCH_BUCKET): a 64-image aux flush can carry up to 6 views
-    # each, and one 512-wide forward would mean fresh XLA compiles for
-    # never-before-seen buckets at serve time, under burst load
+@dataclass(frozen=True)
+class FaceViews:
+    """One image's network inputs, made where the request runs."""
+
+    inputs: np.ndarray                           # [V, 128, 128, 3] float32
+    views: Tuple[Tuple[int, int, int, int], ...]  # (x, y, w, h) of each, in the image
+    width: int
+    height: int
+    # fixed network input -> every request shares one aux bucket/key
+    bucket: Tuple[int, int] = (INPUT_SIZE, INPUT_SIZE)
+
+
+def prepare_views(rgb: np.ndarray) -> FaceViews:
+    """[h, w, 3] uint8 -> the image's views as network inputs: the host's
+    share of a detection (six Pillow resizes for a large frame), on the
+    caller's thread."""
+    h, w = rgb.shape[:2]
+    views = _views(rgb)
+    inputs = np.stack([_view_input(rgb, *view) for view in views])
+    return FaceViews(inputs=inputs, views=tuple(views), width=w, height=h)
+
+
+def forward_views(params, works: List[FaceViews],
+                  stats: Optional[Dict[str, int]] = None):
+    """Every view of every work through ``_forward``, in chunks of the
+    runtime's batch-bucket ceiling (runtime/batcher.py MAX_BATCH_BUCKET):
+    a 64-image aux flush carries up to 6 views each, and one 512-wide
+    forward would mean fresh XLA compiles for never-before-seen buckets
+    at serve time, under burst load. Returns probabilities and boxes,
+    one row a view, in order. ``stats``, where given, gains ``views``
+    (real inputs), ``slots`` (padded inputs run) and ``forwards``."""
     from flyimg_tpu.runtime.batcher import MAX_BATCH_BUCKET, _round_batch
 
+    flat = [view for work in works for view in work.inputs]
     probs_parts, boxes_parts = [], []
+    slots = 0
     for start in range(0, len(flat), MAX_BATCH_BUCKET):
         chunk = flat[start : start + MAX_BATCH_BUCKET]
         nb = _round_batch(len(chunk))
         inputs = np.zeros((nb, INPUT_SIZE, INPUT_SIZE, 3), np.float32)
-        inputs[: len(chunk)] = np.stack(chunk)
+        np.stack(chunk, out=inputs[: len(chunk)])
         p, b = _forward(params, jnp.asarray(inputs))
         probs_parts.append(np.asarray(p)[: len(chunk)])
         boxes_parts.append(np.asarray(b)[: len(chunk)])
-    probs = np.concatenate(probs_parts)
-    boxes = np.concatenate(boxes_parts)
+        slots += nb
+    if stats is not None:
+        stats["views"] = stats.get("views", 0) + len(flat)
+        stats["slots"] = stats.get("slots", 0) + slots
+        stats["forwards"] = stats.get("forwards", 0) + len(probs_parts)
+    return np.concatenate(probs_parts), np.concatenate(boxes_parts)
 
+
+def detect_prepared(
+    params,
+    works: List[FaceViews],
+    *,
+    score_threshold: float = 0.5,
+    max_faces: int = MAX_FACES,
+    stats: Optional[Dict[str, int]] = None,
+) -> List[List[Tuple[int, int, int, int]]]:
+    """Prepared images -> boxes: every view of every image shares the
+    fixed 128x128 network input, so the whole multiscale pyramid across
+    all images is one compiled program a chunk (batch axis on the
+    power-of-two ladder). Per image, view detections merge in one global
+    NMS (anchors from a corner tile compete with full-frame anchors on
+    score). THE detection path: ``detect_faces`` and the batched runner
+    (models/faces.py) both end here."""
+    if not works:
+        return []
+    probs, boxes = forward_views(params, works, stats)
     out: List[List[Tuple[int, int, int, int]]] = []
     vi = 0
-    for rgb, views in zip(rgbs, views_per):
-        h, w = rgb.shape[:2]
+    for work in works:
+        w, h = work.width, work.height
         ps, bs = [], []
-        for x, y, vw, vh in views:
-            p = probs[vi]
+        for x, y, vw, vh in work.views:
             b = boxes[vi]
+            ps.append(probs[vi])
             vi += 1
             # view-normalized (cx, cy, w, h) -> full-frame normalized
-            gb = np.stack(
+            bs.append(np.stack(
                 [
                     (x + b[:, 0] * vw) / w,
                     (y + b[:, 1] * vh) / h,
@@ -318,9 +343,7 @@ def detect_faces_batch(
                     b[:, 3] * vh / h,
                 ],
                 axis=-1,
-            )
-            ps.append(p)
-            bs.append(gb)
+            ))
         out.append(
             _boxes_from_scores(
                 np.concatenate(ps), np.concatenate(bs), w, h,
@@ -328,6 +351,21 @@ def detect_faces_batch(
             )
         )
     return out
+
+
+def detect_faces(
+    params,
+    rgb: np.ndarray,
+    *,
+    score_threshold: float = 0.5,
+    max_faces: int = MAX_FACES,
+) -> List[Tuple[int, int, int, int]]:
+    """[h, w, 3] uint8 -> list of (x, y, w, h) pixel boxes. Same contract as
+    facefind.detect_faces so the handler can swap backends."""
+    return detect_prepared(
+        params, [prepare_views(rgb)],
+        score_threshold=score_threshold, max_faces=max_faces,
+    )[0]
 
 
 def _iou(a, b) -> float:
@@ -421,13 +459,21 @@ def save_checkpoint(params, path: str) -> None:
 
 
 def load_checkpoint(path: str):
-    """Restore params saved by save_checkpoint."""
+    """Restore params saved by save_checkpoint, against the tree of
+    ``init_params`` (its shapes alone: nothing is initialised or
+    compiled), so a checkpoint of another architecture fails here and not
+    at the first forward."""
     import os
 
     import orbax.checkpoint as ocp
 
+    device = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    target = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=device),
+        jax.eval_shape(init_params, jax.random.PRNGKey(0)),
+    )
     with ocp.StandardCheckpointer() as ckptr:
-        return ckptr.restore(os.path.abspath(path))
+        return ckptr.restore(os.path.abspath(path), target)
 
 
 def train_synthetic(

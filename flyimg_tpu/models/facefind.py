@@ -15,8 +15,9 @@ same list-of-boxes contract with two interchangeable backends:
   same detect_faces() signature.
 
 Face blur reproduces the reference's pixelation (down/up-scale 10% region
-round trip, FaceDetectProcessor.php:51-76) via ops/pixelate.py in one fused
-program; face crop slices the Nth detected box (``fcp``,
+round trip, FaceDetectProcessor.php:51-76) via ops/pixelate.py in one
+jitted, batched program (blocks aligned to the image, not to each
+region); face crop slices the Nth detected box (``fcp``,
 FaceDetectProcessor.php:22-42).
 """
 
@@ -29,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flyimg_tpu.ops.pixelate import pixelate_regions
+from flyimg_tpu.ops.pixelate import pixelate_image
 
 Box = Tuple[int, int, int, int]  # x, y, w, h
 
@@ -175,10 +176,13 @@ def _batched_face_masks(
         return jax.vmap(one)(images, in_true, thresholds)
 
 
-def detect_faces_batched(items: List[FaceWork]) -> List[List[Box]]:
+def detect_faces_batched(items: List[FaceWork], stats=None) -> List[List[Box]]:
     """Face boxes for many images: one jitted mask program per shape
     bucket, host component extraction per member. Equivalent to per-image
-    detect_faces (pinned by tests/test_handler.py)."""
+    detect_faces (pinned by tests/test_handler.py). ``stats`` is the
+    runner's contract (models/faces.py); this detector has nothing to say
+    there."""
+    del stats
     from collections import defaultdict
 
     from flyimg_tpu.ops.compose import bucket_batch
@@ -217,16 +221,11 @@ def detect_faces_batched(items: List[FaceWork]) -> List[List[Box]]:
 
 def blur_faces(rgb: np.ndarray, boxes: List[Box]) -> np.ndarray:
     """Pixelate every face region (reference blurFaces,
-    FaceDetectProcessor.php:51-76) in one device program."""
-    if not boxes:
-        return rgb
-    padded = np.zeros((MAX_FACES, 4), np.float32)
-    for i, box in enumerate(boxes[:MAX_FACES]):
-        padded[i] = box
-    out = pixelate_regions(
-        jnp.asarray(rgb, jnp.float32), jnp.asarray(padded)
-    )
-    return np.asarray(jnp.clip(jnp.round(out), 0, 255).astype(jnp.uint8))
+    FaceDetectProcessor.php:51-76): one image through the batched
+    ``uint8`` program of ops/pixelate.py. The handler sends its images
+    there through the device controller instead (``submit_aux``); this is
+    the path without one, and the wedged-executor fallback."""
+    return pixelate_image(rgb, boxes)
 
 
 def crop_face(rgb: np.ndarray, boxes: List[Box], position: int = 0) -> np.ndarray:

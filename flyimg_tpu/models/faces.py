@@ -14,8 +14,10 @@ by the ``face_backend`` / ``face_checkpoint`` app parameters:
 - ``facefind`` — the dependency-free classical skin-blob proposer
   (models/facefind.py); the fallback when neither is available.
 
-Blur (pixelation) and crop are shared device-side ops regardless of the
-detector (facefind.blur_faces / crop_face wrap ops/pixelate.py).
+Blur (pixelation) and crop are shared regardless of the detector:
+``blur_faces`` is one image through the batched ``uint8`` program of
+ops/pixelate.py (the handler sends its images there through the device
+controller), ``crop_face`` a host slice.
 """
 
 from __future__ import annotations
@@ -95,22 +97,21 @@ class BlazeFaceBackend:
             self.params, rgb, score_threshold=self.score_threshold
         )
 
-    # batched serving path (handler submits via the aux batcher): payloads
-    # are full images; the runner resizes + runs ONE batched forward
+    # batched serving path (handler submits via the aux batcher): the
+    # payload is the request's NETWORK INPUTS, made by the request's own
+    # thread (six Pillow resizes a large frame); the runner, on the device
+    # controller's one executor thread, stacks them, runs the forward in
+    # chunks, and maps the boxes back
     def prepare_face_work(self, rgb: np.ndarray, threshold: float = 0.0):
         del threshold
-        return facefind.FaceWork(
-            image=np.ascontiguousarray(rgb),
-            threshold=self.score_threshold,
-            # fixed network input -> every request shares one bucket/key
-            bucket=(self._bf.INPUT_SIZE, self._bf.INPUT_SIZE),
-        )
+        return self._bf.prepare_views(rgb)
 
-    def detect_faces_batched(self, items) -> List[List[Box]]:
-        return self._bf.detect_faces_batch(
-            self.params,
-            [item.image for item in items],
-            score_threshold=self.score_threshold,
+    def detect_faces_batched(self, items, stats=None) -> List[List[Box]]:
+        """The aux runner. ``stats`` (a dict) gains the launch's ``views``,
+        ``slots`` and ``forwards`` (blazeface.forward_views)."""
+        return self._bf.detect_prepared(
+            self.params, items,
+            score_threshold=self.score_threshold, stats=stats,
         )
 
     blur_faces = staticmethod(facefind.blur_faces)

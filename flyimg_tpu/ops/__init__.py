@@ -11,5 +11,5 @@ from flyimg_tpu.ops.filters import gaussian_blur, sharpen, unsharp_mask  # noqa:
 from flyimg_tpu.ops.color import to_grayscale, monochrome_dither  # noqa: F401
 from flyimg_tpu.ops.rotate import rotate_image  # noqa: F401
 from flyimg_tpu.ops.pad import extent_pad  # noqa: F401
-from flyimg_tpu.ops.pixelate import pixelate_regions  # noqa: F401
+from flyimg_tpu.ops.pixelate import pixelate_image, pixelate_images  # noqa: F401
 from flyimg_tpu.ops.compose import build_program, run_plan  # noqa: F401

@@ -591,6 +591,44 @@ class MetricsRegistry:
             "pool's launches",
         ).inc(nbytes)
 
+    def record_face_detect_launch(self, stats: dict, boxes: int) -> None:
+        """One face-detection aux launch (handler ``_face_detect_launch``):
+        the network inputs the convnet detector ran, real (``views``) and
+        padded up its batch ladder (``slots``), its forward launches, and
+        the boxes kept after NMS. A detector without a forward pass
+        (facefind) counts boxes alone."""
+        for key, name, text in (
+            ("views", "flyimg_face_views_total",
+             "Network inputs (views of images) the face detector ran"),
+            ("slots", "flyimg_face_view_slots_total",
+             "Padded network inputs the face detector's forwards ran"),
+            ("forwards", "flyimg_face_forwards_total",
+             "Forward launches of the face detector"),
+        ):
+            if stats.get(key):
+                self.counter(name, text).inc(stats[key])
+        self.counter(
+            "flyimg_face_boxes_total",
+            "Face boxes kept after NMS",
+        ).inc(boxes)
+
+    def record_face_pixelate_launch(self, stats: dict) -> None:
+        """One ``fb_1`` pixelation aux launch: its images, the padded
+        batches they ran in, and the program calls that carried them (a
+        chunk a call: ops/pixelate.py)."""
+        self.counter(
+            "flyimg_face_pixelate_images_total",
+            "Images through the batched face-pixelation program",
+        ).inc(stats.get("images", 0))
+        self.counter(
+            "flyimg_face_pixelate_slots_total",
+            "Padded image slots the face-pixelation program's calls ran",
+        ).inc(stats.get("slots", 0))
+        self.counter(
+            "flyimg_face_pixelate_launches_total",
+            "Calls of the batched face-pixelation program",
+        ).inc(stats.get("launches", 0))
+
     def record_compile_event(self, cache_hit: bool) -> None:
         """Batched-program compile cache outcome per device batch."""
         result = "hit" if cache_hit else "miss"

@@ -171,6 +171,12 @@ class ImageHandler:
         self.sp_mesh = sp_mesh
         self._face_backend = face_backend
         self._smartcrop_backend = smartcrop_backend
+        # a deployment that NAMES the convnet detector loads its
+        # checkpoint now, not inside its first face request
+        if face_backend is None and str(
+            params.by_key("face_backend", "auto")
+        ).lower() == "blazeface":
+            self._faces()
         self._singleflight = _SingleFlight()
         # resilience wiring (runtime/resilience.py): fetch retry/breaker
         # policy, per-request deadline default, wedged-executor behavior
@@ -1176,6 +1182,58 @@ class ImageHandler:
             )
         return results
 
+    def _face_detect_launch(self, items: list) -> list:
+        """The device controller's runner for a face-detection group: the
+        backend's batched detector, then what the launch says of itself
+        into this handler's registry (blazeface: the network inputs it
+        ran, real and padded, and its forward launches; any detector: the
+        boxes it kept)."""
+        stats: Dict[str, int] = {}
+        results = self._faces().detect_faces_batched(items, stats)
+        if self.metrics is not None:
+            self.metrics.record_face_detect_launch(
+                stats, sum(len(boxes) for boxes in results)
+            )
+        return results
+
+    def _face_pixelate_launch(self, items: list) -> list:
+        """The runner of ``fb_1``'s second trip: the batched ``uint8``
+        pixelation program (ops/pixelate.py), counted here."""
+        from flyimg_tpu.ops import pixelate
+
+        stats: Dict[str, int] = {}
+        results = pixelate.pixelate_images(items, stats)
+        if self.metrics is not None:
+            self.metrics.record_face_pixelate_launch(stats)
+        return results
+
+    def _blur_faces(self, ff, out: np.ndarray, faces: list,
+                    timings: Dict[str, float],
+                    deadline: Optional[Deadline]) -> np.ndarray:
+        """``fb_1`` on one rendition with at least one box: a second trip
+        through the device controller, under a key of its own, so that
+        the request's thread dispatches nothing to the device itself."""
+        if self.batcher is None:
+            return ff.blur_faces(out, faces)
+        from flyimg_tpu.ops import pixelate
+
+        item = pixelate.prepare_work(out, faces)
+        with tracing.stage("faces_pixelate", timings, self.metrics,
+                           span_name="faces.pixelate"):
+            try:
+                return self._aux_result(
+                    self.batcher.submit_aux(
+                        ("face_pixelate", item.bucket), item,
+                        self._face_pixelate_launch,
+                    ),
+                    "faces_pixelate", timings, deadline,
+                )
+            except FutureTimeout:
+                if deadline is not None:
+                    deadline.check("faces")
+                self._record_wedge()
+                return ff.blur_faces(out, faces)
+
     def _aux_result(self, future: Future, stage: str,
                     timings: Dict[str, float],
                     deadline: Optional[Deadline]):
@@ -1949,14 +2007,21 @@ class ImageHandler:
                     if self.batcher is not None and hasattr(
                         ff, "prepare_face_work"
                     ):
-                        # batched detection: one mask program per shape
-                        # bucket
-                        item = ff.prepare_face_work(out)
+                        # batched detection on the device controller, as
+                        # smc_1's scoring: the host's share first, here
+                        # (blazeface: the six views as network inputs)
+                        with tracing.stage("faces_prepare", timings,
+                                           self.metrics,
+                                           span_name="faces.prepare"):
+                            item = ff.prepare_face_work(out)
                         try:
-                            faces = self.batcher.submit_aux(
-                                ("face", item.bucket), item,
-                                ff.detect_faces_batched,
-                            ).result(timeout=self._device_wait_s(deadline))
+                            faces = self._aux_result(
+                                self.batcher.submit_aux(
+                                    ("face", item.bucket), item,
+                                    self._face_detect_launch,
+                                ),
+                                "faces", timings, deadline,
+                            )
                         except FutureTimeout:
                             if deadline is not None:
                                 deadline.check("faces")
@@ -1964,8 +2029,10 @@ class ImageHandler:
                             faces = ff.detect_faces(out)
                     else:
                         faces = ff.detect_faces(out)
-                    if plan.face_blur:
-                        out = ff.blur_faces(out, faces)
+                    if plan.face_blur and faces:
+                        out = self._blur_faces(
+                            ff, out, faces, timings, deadline
+                        )
                     if plan.face_crop:
                         out = ff.crop_face(out, faces, plan.face_crop_position)
             out_frames = [out]

@@ -165,17 +165,22 @@ def test_extent_pad_with_background():
 
 
 def test_pixelate_regions():
-    from flyimg_tpu.ops.pixelate import pixelate_regions
-    import jax.numpy as jnp
+    from flyimg_tpu.ops.pixelate import pixelate_image
 
-    img = make_test_image(100, 100, seed=9).astype(np.float32)
-    boxes = jnp.array([[10, 10, 40, 40], [0, 0, 0, 0]], dtype=jnp.float32)
-    out = np.asarray(pixelate_regions(jnp.asarray(img), boxes))
+    img = make_test_image(100, 100, seed=9)
+    out = pixelate_image(img, [(10, 10, 40, 40), (0, 0, 0, 0)])
+    assert out.dtype == np.uint8 and out.shape == img.shape
     # outside box unchanged
     np.testing.assert_array_equal(out[60:, 60:], img[60:, 60:])
-    # inside box is blockwise-constant (10x10 blocks)
+    # inside box is blockwise-constant (10x10 image-aligned blocks): the
+    # block's mean, rounded half to even
     block = out[10:20, 10:20]
-    assert np.allclose(block, block[0, 0], atol=1e-3)
+    assert (block == block[0, 0]).all()
+    np.testing.assert_array_equal(
+        block[0, 0], np.rint(img[10:20, 10:20].astype(np.float64).mean(axis=(0, 1)))
+    )
+    # no box: the image itself, no device trip
+    assert pixelate_image(img, []) is img
 
 
 def test_program_cache_reuse_across_sizes():
