@@ -18,7 +18,9 @@ batch shapes per program, not one per occupancy. A transform group owns its
 launch's padded host block from the moment it is made, and ``submit`` copies
 each member's frame into its slot on the caller's thread while the launch is
 still filling (``_Group``, ``_copy_in``): a launch that is full is ready to
-stage.
+stage. A block whose launch has run becomes the controller's spare, and the
+next group of that shape takes it in place of a fresh ``np.zeros``
+(``_take_block``, ``_keep_block``): its pages are there already.
 
 A single executor thread owns device DISPATCH: groups launch serially (the
 chip executes serially anyway), submissions return futures usable from
@@ -308,7 +310,7 @@ class _Launch:
     __slots__ = (
         "seq", "kind", "aux", "images", "capacity", "popped",
         "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
-        "transfer_bytes", "_cursor", "_opened",
+        "block", "transfer_bytes", "_cursor", "_opened",
     )
 
     def __init__(self, seq: int, members: List[_Pending], *,
@@ -332,6 +334,10 @@ class _Launch:
         # drain thread must not keep a launch's inputs alive through the
         # read-back (a Thread keeps its args until run() returns)
         self.dev_args = None
+        # the host block the inputs were staged from, held until the
+        # program has run and then handed to the controller as its spare
+        # (``BatchController._keep_block``); a launch that fails lets it go
+        self.block: Optional[np.ndarray] = None
         self._cursor = self.popped
         self._opened = None
 
@@ -443,14 +449,19 @@ _PHASE_NAMES = {
 
 
 def _fill_slot(frames: np.ndarray, k: int, image: np.ndarray,
-               edge: bool) -> None:
+               edge: bool, stale: bool = False) -> None:
     """One member's pixels into slot ``k`` of a launch's padded host block
-    ``u8[batch, bh, bw, 3]`` (zeros): THE copy of a frame on the host,
+    ``u8[batch, bh, bw, 3]``: THE copy of a frame on the host,
     made by ``submit`` on the caller's thread where the group owns its
     block, else by ``_assemble``. ``edge``: a pixel-op-only bucket, whose
     padding replicates the frame's edge so that convolutions stay correct
     at the valid region's boundary; every other bucket keeps zeros there
-    (the resample never samples them)."""
+    (the resample never samples them). ``stale``: the block has carried a
+    launch before (``_Group.block_kept``), so the slot holds that launch's
+    pixels and not zeros: the margin outside the frame is cleared after
+    the frame is written (under 128 rows and 128 columns, the bucket's
+    step), and the slot holds byte for byte what a fresh block's would.
+    An edge bucket's ``np.pad`` writes the whole slot as it is."""
     h, w = image.shape[:2]
     bh, bw = frames.shape[1:3]
     if edge and (h, w) != (bh, bw):
@@ -459,21 +470,35 @@ def _fill_slot(frames: np.ndarray, k: int, image: np.ndarray,
         )
     else:
         frames[k, :h, :w] = image
+        if stale:
+            frames[k, h:] = 0
+            frames[k, :h, w:] = 0
 
 
 @dataclass
 class _Group:
     """The members queued for one program identity, and (transform groups)
     the padded host block of the launch they will make: ``block`` is
-    ``np.zeros`` of ``u8[_padded_batch(max_batch), bh, bw, 3]`` from the
-    moment the group is made, member ``k`` of the group owns slot ``k`` of
-    it, and ``submit`` copies the member's pixels there on the caller's
-    thread while the group is still filling (``copying`` counts the copies
-    in flight: a group with one is never popped). A pop hands the block to
-    the popped launch and leaves what stays queued without one, so does a
-    copy that raised: members without a block (or beyond its capacity) are
-    copied by ``_assemble`` at their own launch. An untouched page of a
-    large ``np.zeros`` costs nothing, so a lone launch pays for one
+    ``u8[_padded_batch(max_batch), bh, bw, 3]`` from the moment the group
+    is made (``BatchController._take_block``), member ``k`` of the group
+    owns slot ``k`` of it, and ``submit`` copies the member's pixels there
+    on the caller's thread while the group is still filling (``copying``
+    counts the copies in flight: a group with one is never popped). A pop
+    hands the block to the popped launch and leaves what stays queued
+    without one, so does a copy that raised: members without a block (or
+    beyond its capacity) are copied by ``_assemble`` at their own launch.
+
+    Where the block comes from decides what a copy costs. A fresh
+    ``np.zeros`` costs nothing until it is written, and then every page is
+    touched for the first time: 0.4-0.6 thread-seconds a 72 MB frame with
+    64 callers at it, and the faults are taken in the address space the
+    decode workers fault their own buffers in. The controller's spare (a
+    block whose launch has run; ``block_kept``) has its pages already:
+    some 30 ms a frame. Its slots hold the last launch's pixels, so
+    ``_fill_slot`` clears the margin of each slot it writes; slots no
+    member of this launch owns keep what is there, and no launch reads
+    them (``_assemble`` fills the pad slots up to the padded batch and
+    stages no slot beyond it). Either way a lone launch pays for one
     frame."""
 
     key: Tuple
@@ -505,6 +530,9 @@ class _Group:
     # still in flight (class docstring); aux groups own none
     block: Optional[np.ndarray] = None
     copying: int = 0
+    # the block has carried a launch before (the controller's spare, not a
+    # fresh np.zeros): its slots hold that launch's pixels
+    block_kept: bool = False
 
 
 class BatchController:
@@ -626,6 +654,12 @@ class BatchController:
         self._quarantine_seq = itertools.count()
         self._batch_seq = 0  # batch-id counter (executor thread only)
         self._groups: Dict[Tuple, _Group] = {}
+        # host blocks of launches that have run, newest first, for the next
+        # groups of their shapes (``_take_block`` / ``_keep_block``). Two
+        # at most, whatever their shapes: one filling while one is in
+        # flight is how launches overlap, and a lone launch beside a full
+        # one makes a second block too; a third is let go
+        self._spare_blocks: List[np.ndarray] = []
         self._lock = threading.Condition()
         self._stop = False
         # double buffering (see module docstring): dispatch up to
@@ -825,10 +859,10 @@ class BatchController:
                     ("__quarantine__", next(self._quarantine_seq)),
                 )
         group_key = key
-        reserved = self._admit_and_enqueue(
-            group_key,
-            pending,
-            lambda: _Group(
+
+        def make_group() -> _Group:
+            block, kept = self._take_block(in_shape)
+            return _Group(
                 key=group_key,
                 in_shape=in_shape,
                 resample_out=resample_out,
@@ -838,18 +872,44 @@ class BatchController:
                 rotate_dynamic=rotate_dynamic,
                 band_taps=band_taps,
                 base_key=base_key,
-                # the launch's block (made under the lock: untouched
-                # pages of a large np.zeros cost nothing until a member
-                # is copied into them)
-                block=np.zeros(
-                    (self._padded_batch(self.max_batch), *in_shape, 3),
-                    dtype=np.uint8,
-                ),
-            ),
-        )
+                block=block,
+                block_kept=kept,
+            )
+
+        reserved = self._admit_and_enqueue(group_key, pending, make_group)
         if reserved is not None:
             self._copy_in(*reserved, pending)
         return future
+
+    def _take_block(self, in_shape: Tuple[int, int]):
+        """The host block of a new transform group (caller holds the lock)
+        and whether it has carried a launch before: the spare of exactly
+        this shape where there is one, else a fresh ``np.zeros`` (whose
+        untouched pages cost nothing until a member is copied into them).
+        A spare of another shape is left where it is. Nothing to set: what
+        happens depends only on what launches of this shape have left
+        behind. ``flyimg_batch_blocks_total{from=}`` counts either kind."""
+        shape = (self._padded_batch(self.max_batch), *in_shape, 3)
+        for i, spare in enumerate(self._spare_blocks):
+            if spare.shape == shape:
+                del self._spare_blocks[i]
+                self.metrics.record_block("kept")
+                return spare, True
+        self.metrics.record_block("fresh")
+        return np.zeros(shape, dtype=np.uint8), False
+
+    def _keep_block(self, launch: _Launch) -> None:
+        """A launch's host block becomes the controller's spare: called
+        when the program has RUN (``_await_launch``), the one point at
+        which nothing reads the block on any backend (the CPU backend's
+        ``device_put`` may alias the host array, so there the staged inputs
+        ARE the block until the output is ready). The two newest are kept."""
+        block, launch.block = launch.block, None
+        if block is None:
+            return
+        with self._lock:
+            if not self._stop:
+                self._spare_blocks = [block, *self._spare_blocks[:1]]
 
     def _copy_in(self, group: _Group, block: np.ndarray,
                  pending: _Pending) -> None:
@@ -869,7 +929,7 @@ class BatchController:
         try:
             _fill_slot(
                 block, pending.slot, pending.image,
-                group.resample_out is None,
+                group.resample_out is None, group.block_kept,
             )
         except BaseException as exc:
             # settled below whatever was raised (and re-raised there unless
@@ -1098,6 +1158,7 @@ class BatchController:
     def close(self, drain_timeout_s: float = 30.0) -> None:
         with self._lock:
             self._stop = True
+            self._spare_blocks = []
             self._lock.notify_all()
         # a wedged executor cannot be joined; don't let the join spend
         # more than the caller's whole drain budget waiting for it
@@ -1605,8 +1666,9 @@ class BatchController:
         n = len(members)
         # the block is this launch's alone: taken off the group here, so
         # that every recovery sub-launch (which gets the group) assembles
-        # from the members' own arrays, and let go with this frame, so that
-        # the drain thread does not hold 4 GiB through the read-back
+        # from the members' own arrays. The launch's record holds it until
+        # the program has run, then it is the controller's spare
+        # (``_keep_block``); a launch that fails lets it go
         block, group.block = group.block, None
         # capture the id under the lock: drain-thread recovery launches
         # share the counter, and the span attribute + profiler
@@ -1635,6 +1697,7 @@ class BatchController:
             )
             return
         launch = _Launch(seq, members)
+        launch.block = block
         span_obj = None
         fn = None
         profiler_poked = False
@@ -1712,6 +1775,7 @@ class BatchController:
                 inflight.release()
                 raise
         except Exception as exc:
+            launch.block = None
             if profiler_poked:
                 # a failed dispatch never reaches _drain's finally — the
                 # armed capture's batch budget must still decrement or
@@ -1931,9 +1995,10 @@ class BatchController:
         laps that share their end points: the staged inputs are on the
         device (``h2d`` ends; the inputs are let go at once, so that a
         launch's 4 GiB of them are not held through its read-back), the
-        output is ready (``run``), the output is on the host (``d2h``).
-        The inputs are not donated, so waiting on them after the dispatch
-        is legal. Returns the output as a host array."""
+        output is ready (``run``; the launch's host block goes to the
+        controller here, ``_keep_block``), the output is on the host
+        (``d2h``). The inputs are not donated, so waiting on them after the
+        dispatch is legal. Returns the output as a host array."""
         with launch.annotate("h2d_wait"):
             jax.block_until_ready(launch.dev_args)
         launch.dev_args = None
@@ -1941,6 +2006,7 @@ class BatchController:
         with launch.annotate("run"):
             jax.block_until_ready(dev_out)
         launch.lap("run")
+        self._keep_block(launch)
         with launch.annotate("d2h"):
             out = np.asarray(dev_out)
         launch.lap("d2h")
@@ -2012,7 +2078,7 @@ class BatchController:
                 self._resolve_members(group, members, out, launch)
             self._publish_resolve(launch, row, copies)
         except Exception as exc:
-            launch.dev_args = None
+            launch.dev_args = launch.block = None
             if span_obj is not None and span_obj.duration_s is None:
                 # not yet ended -> the failure happened before the attach
                 # above; record and attach the errored span instead

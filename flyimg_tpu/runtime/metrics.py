@@ -555,6 +555,15 @@ class MetricsRegistry:
             "members beyond the block)",
         ).inc(members)
 
+    def record_block(self, origin: str) -> None:
+        self.counter(
+            f'flyimg_batch_blocks_total{{from="{origin}"}}',
+            "Host blocks transform groups were made with, by where the "
+            "block came from: kept (the controller's spare, a block whose "
+            "launch has run: its pages are there) or fresh (np.zeros: "
+            "every page a copy writes is touched for the first time)",
+        ).inc()
+
     def record_codec_decode_launch(self, split) -> None:
         """One decode launch of the host codec's pool
         (``codecs.native_codec.LaunchSplit``, carried back by the

@@ -6,11 +6,18 @@ bytes on the fast path and on the copying path; a copy in flight holds its
 group back without a busy wait; every case that is not "the block's slots
 0..n-1 in order" falls back to the copying path and answers correctly; a
 copy that raises fails its member alone; the counters and the histogram
-read what happened. Small shapes, CPU, no upper bound on any time."""
+read what happened. Since PR 38 a block whose launch has run is the
+controller's spare and the next group of its shape takes it (``_take_block``,
+``_keep_block``): section 5 pins that a launch on a kept block stages the
+bytes a fresh one would, that what it leaves in slots no member owns
+reaches no answer, when the block changes hands, how many are held, and
+what ``flyimg_batch_blocks_total`` counts. Small shapes, CPU, no upper
+bound on any time."""
 
 import sys
 import threading
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -177,11 +184,11 @@ def test_a_group_with_a_copy_in_flight_is_not_popped_and_run_does_not_spin(monke
     gate, entered = threading.Event(), threading.Event()
     real = batcher_mod._fill_slot
 
-    def blocked_at_submit(frames, k, image, edge):
+    def blocked_at_submit(frames, k, image, edge, *stale):
         if threading.current_thread().name == "submitter":
             entered.set()
             assert gate.wait(timeout=30)
-        real(frames, k, image, edge)
+        real(frames, k, image, edge, *stale)
 
     monkeypatch.setattr(batcher_mod, "_fill_slot", blocked_at_submit)
     # a deadline that is over at once: a predicate that let the deadline
@@ -297,17 +304,24 @@ def test_bisect_after_an_execute_fault_assembles_from_the_members_own_arrays():
         ctl.close()
 
 
-def test_a_copy_that_raises_fails_that_member_alone(monkeypatch):
+def _refuse_the_marked_frame_once(monkeypatch):
+    """``_fill_slot`` raises for the first frame whose first sample is 251;
+    returns the list that gets the slot it refused."""
     real = batcher_mod._fill_slot
     marked = []
 
-    def refuses_the_marked_frame_once(frames, k, image, edge):
+    def refuses_the_marked_frame_once(frames, k, image, edge, *stale):
         if image[0, 0, 0] == 251 and not marked:
             marked.append(k)
             raise MemoryError("no page for the slot")
-        real(frames, k, image, edge)
+        real(frames, k, image, edge, *stale)
 
     monkeypatch.setattr(batcher_mod, "_fill_slot", refuses_the_marked_frame_once)
+    return marked
+
+
+def test_a_copy_that_raises_fails_that_member_alone(monkeypatch):
+    marked = _refuse_the_marked_frame_once(monkeypatch)
     ctl = _waiting_ctl(max_batch=3, max_queue_depth=8)
     try:
         members = _members("w_120,h_90,c_1", [(320, 240)] * 4)
@@ -395,3 +409,320 @@ def test_many_callers_at_once_every_answer_is_its_own():
     assert early + late == len(jobs)
     assert observed >= early
     assert ctl.metrics.summary()["flyimg_images_processed_total"] == len(jobs)
+
+
+# ---------------------------------------------------------------------------
+# 5. a block kept from launch to launch (PR 38)
+
+
+def _blocks(metrics):
+    """(groups made with a kept block, with a fresh one)."""
+    summary = metrics.summary()
+    return (int(summary.get('flyimg_batch_blocks_total{from="kept"}', 0)),
+            int(summary.get('flyimg_batch_blocks_total{from="fresh"}', 0)))
+
+
+def _parked_launch(ctl, members):
+    """Submit, pop and assemble ``members`` on a parked controller as a
+    launch would: (the popped group, the arrays handed to ``stage``)."""
+    for image, plan, window in members:
+        ctl.submit(image, plan, src_window=window)
+    with ctl._lock:
+        ready = ctl._pop_ready_group()
+    assert [m.slot for m in ready.members] == list(range(len(members)))
+    _, arrays = ctl._assemble(ready, ready.members, ready.block)
+    assert np.shares_memory(arrays[0], ready.block)
+    return ready, arrays
+
+
+def _has_run(ctl, block):
+    """What ``_await_launch`` does with a launch's block once the output is
+    ready (``_keep_block`` touches nothing of the launch but ``block``)."""
+    launch = SimpleNamespace(block=block)
+    ctl._keep_block(launch)
+    assert launch.block is None
+
+
+# name -> (options, first launch's sizes, second launch's sizes, max_batch):
+# every frame of the second launch is smaller than the one its slot held
+_SECOND_LAUNCHES = {
+    # bucket 256 x 384; a pad slot (3 members of 4) repeats the last member
+    "resample_bucket": ("w_120,h_90,c_1", [(380, 250)] * 4,
+                        [(300, 200), (290, 230), (310, 140)], 4),
+    "resample_bucket_lone_launch": ("w_120,h_90,c_1", [(380, 250)] * 4,
+                                    [(300, 200)], 4),
+    # bucket 256 x 256, padding replicated from the frame's edge; the last
+    # frame is the bucket's own size (no padding: the slot is the frame)
+    "edge_replicated_bucket": ("blr_2x1", [(250, 190), (240, 250)],
+                               [(200, 150), (256, 256)], 2),
+    "edge_replicated_bucket_lone_launch": ("blr_2x1", [(250, 190), (240, 250)],
+                                           [(130, 140)], 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SECOND_LAUNCHES))
+def test_a_second_launch_on_a_kept_block_stages_the_same_bytes_as_on_a_fresh_one(name):
+    options, first_sizes, second_sizes, max_batch = _SECOND_LAUNCHES[name]
+    ctl = _Parked(max_batch=max_batch, deadline_ms=0.0, lone_flush=False)
+    try:
+        first, _ = _parked_launch(ctl, _members(options, first_sizes))
+        assert _blocks(ctl.metrics) == (0, 1)
+        block = first.block
+        _has_run(ctl, block)
+        assert ctl._spare_blocks == [block]
+        members = [(255 - image, plan, window)  # no zeros to hide behind
+                   for image, plan, window in _members(options, second_sizes)]
+        second, kept = _parked_launch(ctl, members)
+        assert second.block is block
+        assert ctl._spare_blocks == [] and _blocks(ctl.metrics) == (1, 1)
+        batch, fresh = ctl._assemble(second, second.members)
+        assert not np.shares_memory(fresh[0], block)
+        assert kept[0].shape == fresh[0].shape == (batch, *block.shape[1:])
+        for early, late in zip(kept, fresh):
+            assert early.tobytes() == late.tobytes()
+        # the slots beyond the padded batch hold the first launch's pixels,
+        # and no array of this launch reaches them
+        if batch < len(block):
+            h, w = first.members[batch].image.shape[:2]
+            assert np.array_equal(block[batch, :h, :w], first.members[batch].image)
+            assert kept[0].shape[0] == batch
+    finally:
+        _close(ctl)
+
+
+def test_a_fresh_blocks_slots_are_not_cleared(monkeypatch):
+    """A fresh ``np.zeros`` pays for the frame alone: ``_fill_slot`` is told
+    that the block is stale only where it is."""
+    seen = []
+    real = batcher_mod._fill_slot
+
+    def noting(frames, k, image, edge, stale=False):
+        seen.append(stale)
+        real(frames, k, image, edge, stale)
+
+    monkeypatch.setattr(batcher_mod, "_fill_slot", noting)
+    ctl = _Parked(max_batch=2, deadline_ms=0.0, lone_flush=False)
+    try:
+        first, _ = _parked_launch(ctl, _members("w_120,h_90,c_1", [(320, 240)] * 2))
+        _has_run(ctl, first.block)
+        _parked_launch(ctl, _members("w_120,h_90,c_1", [(300, 200)]))
+        assert seen == [False, False, True]
+    finally:
+        _close(ctl)
+
+
+def _launch_and_check(ctl, members, expire=False):
+    futures = [ctl.submit(image, plan) for image, plan, _ in members]
+    if expire:
+        _expire_deadline(ctl)
+    _check_answers(members, futures)
+
+
+def _bright(members):
+    """Other pixels in the same shapes: a later launch's own frames."""
+    return [(255 - image, plan, window) for image, plan, window in members]
+
+
+def test_stale_pad_slots_change_no_real_members_answer():
+    """A full launch, then three members and then one on the block it left:
+    the slots they do not own hold the full launch's pixels, and every
+    answer is the single-image path's for its own frame."""
+    ctl = _waiting_ctl()
+    try:
+        full, _ = _submit_all(ctl)
+        _check_answers(full, _)
+        (block,) = ctl._spare_blocks
+        assert np.array_equal(block[3, :230, :290], full[3][0])
+        three = _bright(_members("w_120,h_90,c_1", [(280, 210), (270, 140), (300, 220)]))
+        _launch_and_check(ctl, three, expire=True)
+        assert ctl._spare_blocks[0] is block
+        lone = _members("w_120,h_90,c_1", [(260, 130)])
+        _launch_and_check(ctl, lone, expire=True)
+        assert ctl._spare_blocks[0] is block
+        # slot 1 still holds the launch of three's frame
+        assert np.array_equal(block[1, :140, :270], three[1][0])
+        assert _blocks(ctl.metrics) == (2, 1)
+        assert _counts(ctl.metrics) == (8, 8, 0)
+    finally:
+        ctl.close()
+
+
+def test_a_group_of_another_shape_gets_a_fresh_block_and_at_most_two_are_held():
+    ctl = _waiting_ctl(max_batch=2)
+    try:
+        shapes = {"a": (320, 240), "b": (640, 480), "c": (900, 200)}
+        blocks = {}
+
+        def launch(name, seed):
+            w, h = shapes[name]
+            members = [(make_test_image(w, h, seed=seed + k),
+                        _plan("w_120,h_90,c_1", w, h), None) for k in range(2)]
+            futures = [ctl.submit(image, plan) for image, plan, _ in members]
+            with ctl._lock:
+                held = list(ctl._spare_blocks)
+            _check_answers(members, futures)
+            return held
+
+        launch("a", 0)
+        (blocks["a"],) = ctl._spare_blocks
+        # another shape: a fresh block, and the spare stays where it is
+        assert [b is blocks["a"] for b in launch("b", 10)] == [True]
+        assert _blocks(ctl.metrics) == (0, 2)
+        assert ctl._spare_blocks[1] is blocks["a"]
+        blocks["b"] = ctl._spare_blocks[0]
+        assert blocks["b"].shape == (2, 512, 640, 3)
+        # a third shape: fresh again; when it comes back the oldest is let go
+        assert len(launch("c", 20)) == 2
+        assert len(ctl._spare_blocks) == 2
+        assert ctl._spare_blocks[1] is blocks["b"]
+        assert ctl._spare_blocks[0].shape == (2, 256, 1024, 3)
+        # the shape that was let go starts fresh, one that is held does not
+        launch("a", 30)
+        assert _blocks(ctl.metrics) == (0, 4)
+        assert launch("c", 40) == [ctl._spare_blocks[1]]
+        assert _blocks(ctl.metrics) == (1, 4)
+    finally:
+        ctl.close()
+    assert ctl._spare_blocks == []
+    # a launch that ends after the close hands nothing on
+    _has_run(ctl, np.zeros((2, 8, 8, 3), np.uint8))
+    assert ctl._spare_blocks == []
+
+
+def test_the_block_is_not_handed_on_before_the_launchs_output_is_ready(monkeypatch):
+    """On the CPU backend the staged inputs may BE the block until the
+    program has run: while the output is held back the controller has no
+    spare, and a group made meanwhile gets a fresh block."""
+    import jax
+
+    gate, entered = threading.Event(), threading.Event()
+    real = jax.block_until_ready
+
+    def held_output(x):
+        # the staged inputs are a list; the output is one array
+        if not isinstance(x, (list, tuple)) and not entered.is_set():
+            entered.set()
+            assert gate.wait(timeout=60)
+        return real(x)
+
+    monkeypatch.setattr(batcher_mod.jax, "block_until_ready", held_output)
+    ctl = _waiting_ctl(max_batch=2)
+    try:
+        first = _members("w_120,h_90,c_1", [(320, 240)] * 2)
+        futures = [ctl.submit(image, plan) for image, plan, _ in first]
+        assert entered.wait(timeout=120)
+        with ctl._lock:
+            assert ctl._spare_blocks == []
+        assert not any(f.done() for f in futures)
+        second = _bright(first)
+        later = [ctl.submit(image, plan) for image, plan, _ in second]
+        assert _blocks(ctl.metrics) == (0, 2)
+        gate.set()
+        _check_answers(first, futures)
+        _check_answers(second, later)
+        assert len(ctl._spare_blocks) == 2
+        assert ctl._spare_blocks[0] is not ctl._spare_blocks[1]
+    finally:
+        gate.set()
+        ctl.close()
+
+
+def _copy_raises(monkeypatch):
+    _refuse_the_marked_frame_once(monkeypatch)
+    return _waiting_ctl(), 1
+
+
+def _execute_fault(monkeypatch):
+    injector = faults.install(faults.FaultInjector())
+    injector.plan("batcher.execute", faults.fail_n_then_succeed(
+        1, lambda: ValueError("the launch was refused")))
+    return _waiting_ctl(), None
+
+
+def _drain_fault(monkeypatch):
+    injector = faults.install(faults.FaultInjector())
+    injector.plan("batcher.drain", faults.fail_n_then_succeed(
+        1, lambda: ValueError("the read-back was refused")))
+    return _waiting_ctl(), None
+
+
+def _presplit(monkeypatch):
+    metrics = MetricsRegistry()
+    return _waiting_ctl(
+        metrics=metrics, governor=_CapAtTwo(enabled=True, metrics=metrics)), None
+
+
+# name -> (what happens to the first launch, blocks (kept, fresh) after the
+# two launches that follow it)
+_EVENTS = {
+    # the group let go of its block; nothing came back
+    "a_copy_that_raises": (_copy_raises, (1, 2)),
+    # the failed launch let its block go; the halves assembled their own
+    "a_bisect_after_an_execute_fault": (_execute_fault, (1, 2)),
+    "a_bisect_after_a_drain_fault": (_drain_fault, (1, 2)),
+    # the prefix launched from the block and handed it on
+    "a_governor_presplit": (_presplit, (2, 1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_EVENTS))
+def test_after_a_launch_that_went_wrong_the_next_launchs_answers_are_its_own(name, monkeypatch):
+    make, blocks = _EVENTS[name]
+    ctl, poisoned = make(monkeypatch)
+    try:
+        members = _members("w_120,h_90,c_1", [(320, 240), (300, 200), (310, 250), (290, 230)])
+        if poisoned is not None:
+            members[poisoned][0][0, 0, 0] = 251
+        futures = [ctl.submit(image, plan) for image, plan, _ in members]
+        if poisoned is not None:
+            # that member's copy raised; the three left launch without a block
+            with pytest.raises(MemoryError):
+                futures.pop(poisoned).result(timeout=30)
+            members.pop(poisoned)
+        _expire_deadline(ctl)
+        _check_answers(members, futures)
+        for later in (_bright(members), members[::-1]):
+            futures = [ctl.submit(image, plan) for image, plan, _ in later]
+            _expire_deadline(ctl)
+            _check_answers(later, futures)
+        assert _blocks(ctl.metrics) == blocks
+        assert ctl.admission.pending == 0
+    finally:
+        ctl.close()
+
+
+def test_kept_block_share_reads_the_counter_the_controller_keeps():
+    """``perfbench/metrics/kept_block_share.json`` through the benchmark's
+    own reader, on a controller's registry as the harness scrapes it."""
+    from perfbench.harness import manifest
+    from perfbench.harness.system import parse_prometheus
+
+    doc = manifest.load_manifest()
+    entry = next(m for m in doc["per_layer"] if m["name"] == "kept_block_share")
+    assert entry == doc["per_layer"][-1]
+    assert entry["layer"] == "batcher" and entry["moves"] == "images_per_s"
+    assert entry["workloads"] == [c["name"] for c in doc["workloads"]]
+    spec = manifest.load_metric("kept_block_share")
+    read = manifest.load_reader(spec["reader"])
+    ctl = _waiting_ctl()
+    try:
+        before = parse_prometheus(ctl.metrics.render_prometheus())
+        members, futures = _submit_all(ctl)
+        _check_answers(members, futures)
+        first = parse_prometheus(ctl.metrics.render_prometheus())
+        # one launch, on a fresh block: no block kept yet, nothing read
+        assert first['flyimg_batch_blocks_total{from="fresh"}'] == 1.0
+        assert read({"counters_before": before, "counters_after": first},
+                    **spec["args"]) is None
+        for _ in range(3):
+            _launch_and_check(ctl, _bright(members))
+        after = parse_prometheus(ctl.metrics.render_prometheus())
+        assert read({"counters_before": before, "counters_after": after},
+                    **spec["args"]) == pytest.approx(75.0)
+        assert read({"counters_before": first, "counters_after": after},
+                    **spec["args"]) == pytest.approx(100.0)
+    finally:
+        ctl.close()
+    # the parent's program has no such counter: nothing read, nothing raised
+    assert read({"counters_before": {}, "counters_after": {
+        "flyimg_batches_total": 4.0}}, **spec["args"]) is None
