@@ -20,8 +20,9 @@ def every(kind):
 
 
 def toy_config(which, name):
-    """Configuration ``name`` at its toy size, with what it names loaded."""
-    doc = MANIFESTS[which]
+    """Configuration ``name`` at its toy size, with what it names loaded;
+    ``which`` is a key of ``MANIFESTS`` or a manifest of its own."""
+    doc = MANIFESTS[which] if isinstance(which, str) else which
     config = manifest.load_json(manifest.config_file(doc, name))
     manifest.apply_toy(config)
     return config, manifest.bind(doc, name, config)

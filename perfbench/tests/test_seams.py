@@ -17,39 +17,37 @@ from conftest import HERE, MANIFESTS, ROOT, every, toy_config
 from perfbench.control import answers_for
 from perfbench.harness import compare, corpus, manifest, plain
 
-# sha256 of each configuration's toy corpus at the seed 2**31 + 3, and its
-# reference's needed work at full size: the JPEG configurations' as the
-# parent commit of the 16-bit seams (PR 39) made them, so that teaching the
-# harness a second depth moved no byte of the 8-bit originals and no digit
-# of a roofline's work
+# Each configuration's pins, ``pins/<config>.json`` beside its own ``configs/``
+# (the first of ``manifest.plug_roots``): the sha256 of its toy corpus at the
+# seed ``SAME_SEED`` and its reference's needed work at full size, compared
+# with ``==``. A pin is what these tests expect and nothing a run reads, so it
+# is a file of its own and not a key of the configuration. The JPEG
+# configurations' are as the harness made them before it was taught a second
+# sample depth, so that teaching it moved no byte of the 8-bit originals and
+# no digit of a roofline's work.
 SAME_SEED = 2**31 + 3
-PINNED_CORPUS = {
-    "dslr-backfill-24mp": "e93c7dcfa220c02e08dfc39cf67905e8ebbb8d02de038078c5c62e4a9eb0df90",
-    "portrait-smartcrop-24mp": "93d59707435f6d9d2444dbd6c41a9dd9528cd22c9c3eabe94b1bebc9e1e1a07b",
-    "group-faceblur-24mp": "de2c5c77be9aec11ad134301e6160c7c9ad1cac7db5d8462eca9b45df89a1628",
-    "resize-fit-416": "e93c7dcfa220c02e08dfc39cf67905e8ebbb8d02de038078c5c62e4a9eb0df90",
-    "png16-fit-416": "ded8c4103491e0700016515676a9bc5b9a3a0b54d009c52604a8887741d12dbf",
-}
-PINNED_WORK = {
-    "dslr-backfill-24mp": {"resample": {"flops": 1093374320.524836, "bytes": 77116800.0}},
-    "portrait-smartcrop-24mp": {"resample": {"flops": 1036800000.0, "bytes": 74880000.0},
-                                "smartcrop_score": {"flops": 1812171.0, "bytes": 144662.0}},
-    "group-faceblur-24mp": {"resample": {"flops": 1093374320.524836, "bytes": 77116800.0},
-                            "blazeface_forward": {"flops": 72925184.0, "bytes": 642288.0},
-                            "face_pixelate": {"flops": 10233600.0, "bytes": 10233600.0}},
-    "resize-fit-416": {"resample": {"flops": 71940096.00000001, "bytes": 5064288.0}},
-    # 1536x1024 -> 416x277 at two bytes a sample: the bytes twice resize-fit-416's
-    "png16-fit-416": {"resample": {"flops": 71940096.00000001, "bytes": 10128576.0}},
-}
 
 
-def _same_bytes_and_work(which, name):
+def load_pin(doc, name):
+    """``{"toy_corpus_sha256", "work"}`` of configuration ``name``; a test
+    that finds no pin file fails naming the file and how to make it."""
+    path = os.path.join(manifest.plug_roots(doc, name)[0], "pins", name + ".json")
+    if not os.path.exists(path):
+        pytest.fail(
+            f"configuration {name} has no pin file {path}: write it as "
+            '{"toy_corpus_sha256": corpus.digest(<its toy corpus at the seed 2**31 + 3>), '
+            '"work": <its reference.work(config) at full size>}', pytrace=False)
+    return manifest.load_json(path)
+
+
+def _same_bytes_and_work(doc, name):
     """The toy corpus at ``SAME_SEED``: its digest as pinned, every original
     read back by ``plain.decode`` at the frame's depth (a PNG's sample for
     sample as made); the reference's needed work as pinned."""
-    config, bound = toy_config(which, name)
+    pin = load_pin(doc, name)
+    config, bound = toy_config(doc, name)
     made = corpus.make_corpus(bound.make_image, SAME_SEED, config["frame"], config["corpus"]["images"])
-    assert corpus.digest(made) == hashlib.sha256(b"".join(made)).hexdigest() == PINNED_CORPUS[name]
+    assert corpus.digest(made) == hashlib.sha256(b"".join(made)).hexdigest() == pin["toy_corpus_sha256"]
     for index, data in enumerate(made[:2]):
         back = plain.decode(data)
         assert back.dtype == plain.DTYPES[bound.depth] and back.shape == (
@@ -57,22 +55,25 @@ def _same_bytes_and_work(which, name):
         if config["frame"]["format"] == "png":
             np.testing.assert_array_equal(
                 back, bound.make_image(SAME_SEED, index, config["frame"]["width"], config["frame"]["height"]))
-    full = manifest.load_config(MANIFESTS[which], name)
-    assert manifest.bind(MANIFESTS[which], name, full).reference.work(full) == PINNED_WORK[name]
+    full = manifest.load_config(doc, name)
+    assert manifest.bind(doc, name, full).reference.work(full) == pin["work"]
 
 
-@pytest.mark.parametrize("which,name", every("configs"))
-def test_what_a_configuration_names_loads_and_exposes_the_interface(which, name):
-    doc = MANIFESTS[which]
+def _loads_and_exposes(doc, name):
     assert manifest.validate(doc) == []
     config = manifest.load_config(doc, name)
-    for sized, bound in ((config, manifest.bind(doc, name, config)), toy_config(which, name)):
+    for sized, bound in ((config, manifest.bind(doc, name, config)), toy_config(doc, name)):
         assert all(callable(getattr(bound.reference, f)) for f in ("parse", "render", "judge_original", "work"))
         assert set(bound.reference.NUMBERS) == set(sized["limits"])
         assert callable(bound.make_image) and [w for w, _ in bound.warmers] == sized["warm"]
         kernels = bound.reference.work(sized)
         assert kernels and all(set(w) == {"flops", "bytes"} and min(w.values()) > 0 for w in kernels.values())
-    _same_bytes_and_work(which, name)
+    _same_bytes_and_work(doc, name)
+
+
+@pytest.mark.parametrize("which,name", every("configs"))
+def test_what_a_configuration_names_loads_and_exposes_the_interface(which, name):
+    _loads_and_exposes(MANIFESTS[which], name)
 
 
 def _size(c):
@@ -101,8 +102,7 @@ FRAME_BREAKS = [
 ]
 
 
-@pytest.mark.parametrize("which,name", every("configs"))
-@pytest.mark.parametrize("break_it,says", [
+BREAKS = [
     (lambda c: c["options"].update(url=c["options"]["url"] + ",smc_1"), "smc_1"),
     (lambda c: c["limits"].pop(sorted(c["limits"])[0]), "every number has a limit"),
     (lambda c: c["limits"].update(sharpness=1.0), "every limit a number"),
@@ -110,13 +110,62 @@ FRAME_BREAKS = [
     (lambda c: c.update(reference="no_such_reference"), "no references/no_such_reference.py"),
     (lambda c: c["corpus"].pop("kind"), "bad name None under corpora/"),
     (lambda c: c.update(warm=["../transform"]), "bad name"),
-] + FRAME_BREAKS)
-def test_a_configuration_that_breaks_a_rule_fails_at_load(which, name, break_it, says):
-    doc = MANIFESTS[which]
+] + FRAME_BREAKS
+
+
+def _breaks_at_load(doc, name, break_it, says):
     config = manifest.load_json(manifest.config_file(doc, name))
     break_it(config)
     with pytest.raises(manifest.ManifestError, match=says):
         manifest.bind(doc, name, config)
+
+
+@pytest.mark.parametrize("which,name", every("configs"))
+@pytest.mark.parametrize("break_it,says", BREAKS)
+def test_a_configuration_that_breaks_a_rule_fails_at_load(which, name, break_it, says):
+    _breaks_at_load(MANIFESTS[which], name, break_it, says)
+
+
+def _added_as_files(tmp_path, pinned):
+    """A copy of the fixture's 16-bit configuration under a new name, added
+    as files alone in a tree of its own: its configuration file, its pin
+    file where ``pinned``, the plugs of its deployment linked beside them,
+    and a manifest that names it and one cell of it."""
+    fixture, source, name = MANIFESTS["fixture"], "png16-fit-416", "png16-fit-416-added"
+    config = dict(manifest.load_config(fixture, source), name=name)
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "configs" / (name + ".json")).write_text(json.dumps(config))
+    for kind in ("references", "corpora", "warmers"):
+        if os.path.isdir(os.path.join(HERE, "fixtures", kind)):
+            os.symlink(os.path.join(HERE, "fixtures", kind), tmp_path / kind)
+    if pinned:
+        (tmp_path / "pins").mkdir()
+        (tmp_path / "pins" / (name + ".json")).write_text(json.dumps(load_pin(fixture, source)))
+    doc = json.loads(json.dumps(fixture))
+    entry = next(c for c in doc["configs"] if c["name"] == source)
+    cell = next(w for w in doc["workloads"] if w["config"] == source)
+    doc["paths"] = [str(tmp_path)]
+    doc["configs"] = [dict(entry, name=name, file=str(tmp_path / "configs" / (name + ".json")))]
+    doc["workloads"] = [dict(cell, name=name + "-saturated", config=name)]
+    return doc, name
+
+
+def test_a_configuration_added_as_files_alone_keeps_every_rule(tmp_path):
+    """Files and a manifest entry are all a configuration needs: the
+    interface, its pins and every break case, with no edit to this file."""
+    doc, name = _added_as_files(tmp_path, pinned=True)
+    _loads_and_exposes(doc, name)
+    for break_it, says in BREAKS:
+        _breaks_at_load(doc, name, break_it, says)
+
+
+def test_a_configuration_without_its_pin_file_fails_naming_the_file(tmp_path):
+    doc, name = _added_as_files(tmp_path, pinned=False)
+    with pytest.raises(pytest.fail.Exception) as failed:
+        _loads_and_exposes(doc, name)
+    says = str(failed.value)
+    assert str(tmp_path / "pins" / (name + ".json")) in says, says
+    assert "corpus.digest" in says and "2**31 + 3" in says and "reference.work(config)" in says, says
 
 
 @pytest.mark.parametrize("break_it,says", [
