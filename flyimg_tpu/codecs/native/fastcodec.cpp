@@ -8,7 +8,8 @@
 //
 // Design:
 //  - Plain C ABI (ctypes-friendly), all buffers malloc'd here and released
-//    via fc_free; no global state, safe to call from many threads at once.
+//    via fc_free (a frame the pool keeps, via fc_pool_release); no global
+//    state, safe to call from many threads at once.
 //  - JPEG via libjpeg(-turbo): decode with optional DCT scaling
 //    (scale 1/1..1/8 — the decode-time prescale that feeds 4k sources to
 //    thumbnail pipelines cheaply); two encoders — a plain optimized one
@@ -17,7 +18,9 @@
 //    measured ~5-10% smaller at ~equal PSNR on photographic content).
 //  - WebP via libwebp: lossy (quality) and lossless encode, decode to RGB.
 //  - A worker pool (fc_pool_*) so a multi-core host can saturate decode
-//    while the GIL is released on the Python side.
+//    while the GIL is released on the Python side. The pool keeps the
+//    output buffers of large full frames (fc_frame_pool) and hands them to
+//    later frames, which are given back through fc_pool_release.
 
 #include <cmath>
 #include <csetjmp>
@@ -43,6 +46,7 @@
 #endif
 
 #include <atomic>
+#include <chrono>
 #include <condition_variable>
 #include <functional>
 #include <mutex>
@@ -74,29 +78,183 @@ static void fc_jpeg_error_exit(j_common_ptr cinfo) {
   longjmp(err->setjmp_buffer, 1);
 }
 
-// Decode a JPEG buffer to RGB. scale_num/8 is the libjpeg DCT scale
-// (pass 8 for full size, 4 for 1/2, 2 for 1/4, 1 for 1/8).
-// CMYK and YCCK (Adobe print-origin) sources decode natively: libjpeg
-// hands back CMYK samples (it converts YCCK->CMYK itself but cannot emit
-// RGB from a CMYK family), and the multiplicative CMYK->RGB fold happens
-// here — the reference feeds such JPEGs through ImageMagick transparently
-// (src/Core/Processor/ImageProcessor.php:68), so the native path must not
-// silently punt them to the slow PIL fallback.
-// Returns malloc'd RGB8 buffer or nullptr; fills width/height.
-uint8_t* fc_jpeg_decode(const uint8_t* data, size_t len, int scale_num,
-                        int* width, int* height) {
+// ---------------------------------------------------------------------------
+// Frame buffers kept for reuse. A full-frame decode at least
+// kFramePoolMinBytes long writes into a buffer an earlier frame already
+// touched, and the buffer goes back to the pool, not to free(), when its
+// last view goes. glibc serves an allocation of DEFAULT_MMAP_THRESHOLD_MAX
+// (32 MiB on 64-bit) or more from a fresh mmap whatever its dynamic
+// threshold has risen to: the kernel faults and zeroes every page while the
+// decoder writes the rows, and free() unmaps the frame, taking the address
+// space's lock and shooting down TLBs on every core the process runs on.
+// Smaller frames (ROI windows, prescaled thumbnails, frames under some
+// 11 MP) come from the heap, whose pages are reused already; they keep
+// malloc and fc_free.
+// ---------------------------------------------------------------------------
+
+static const size_t kFramePoolMinBytes = size_t{32} << 20;
+
+// An idle buffer no frame took for this long is freed: a process that
+// falls quiet after a burst gives the burst's frames back. Three cycles of
+// the slowest measured launch (6-7 s), so a steady stream keeps its own.
+static const int64_t kFrameIdleMs = 20000;
+
+// How often a worker with nothing to do frees what has aged out.
+static const std::chrono::milliseconds kFrameTrimEvery{1000};
+
+using FrameClock = std::chrono::steady_clock;
+
+struct fc_frame_pool {
+  struct Idle {
+    uint8_t* ptr;
+    size_t cap;
+    FrameClock::time_point since;  // when it was given back
+  };
+  std::mutex mu;
+  std::vector<Idle> idle;  // in the order given back: the oldest first
+  size_t idle_bytes = 0;
+  size_t live = 0;  // pooled buffers handed out and not yet given back
+  size_t live_bytes = 0;
+  // the most bytes live at once: idle_bytes + live_bytes never exceeds it,
+  // so the pool holds no more than the process held at its own peak
+  size_t peak_bytes = 0;
+  std::atomic<size_t> min_bytes{kFramePoolMinBytes};
+  std::atomic<int64_t> idle_ms{kFrameIdleMs};
+  bool closed = false;  // its fc_pool is gone: buffers given back are freed
+};
+
+// Under fp->mu: the idle buffers given back more than idle_ms ago leave
+// the list into *stale, for the caller to free outside the lock.
+static void frame_age_locked(fc_frame_pool* fp, std::vector<uint8_t*>* stale) {
+  const auto oldest =
+      FrameClock::now() - std::chrono::milliseconds(fp->idle_ms.load());
+  size_t n = 0;
+  while (n < fp->idle.size() && fp->idle[n].since < oldest) {
+    stale->push_back(fp->idle[n].ptr);
+    fp->idle_bytes -= fp->idle[n].cap;
+    ++n;
+  }
+  fp->idle.erase(fp->idle.begin(), fp->idle.begin() + n);
+}
+
+// Free the idle buffers that have aged out.
+static void frame_trim(fc_frame_pool* fp) {
+  std::vector<uint8_t*> stale;
+  {
+    std::lock_guard<std::mutex> lock(fp->mu);
+    frame_age_locked(fp, &stale);
+  }
+  for (uint8_t* ptr : stale) std::free(ptr);
+}
+
+// A buffer for a full frame of nbytes. Below the pool's size (or with no
+// pool) plain malloc and *cap 0; else the smallest idle buffer that holds
+// the frame and is at most twice its size (*reused 1), or a new one
+// (*reused 0), with *cap its capacity.
+static uint8_t* frame_take(fc_frame_pool* fp, size_t nbytes, size_t* cap,
+                           int* reused) {
+  *cap = 0;
+  *reused = 0;
+  if (fp == nullptr || nbytes < fp->min_bytes) {
+    return static_cast<uint8_t*>(std::malloc(nbytes));
+  }
+  std::vector<uint8_t*> spill;
+  {
+    std::lock_guard<std::mutex> lock(fp->mu);
+    // of the smallest that fit, the last in the list (the one given back
+    // last: its pages are the likeliest cached), so a surplus the cycle
+    // does not need stays at the front and ages out
+    size_t best = fp->idle.size();
+    for (size_t i = 0; i < fp->idle.size(); ++i) {
+      const size_t c = fp->idle[i].cap;
+      if (c >= nbytes && c - nbytes <= nbytes &&
+          (best == fp->idle.size() || c <= fp->idle[best].cap)) {
+        best = i;
+      }
+    }
+    ++fp->live;
+    if (best < fp->idle.size()) {
+      uint8_t* ptr = fp->idle[best].ptr;
+      *cap = fp->idle[best].cap;
+      *reused = 1;
+      fp->idle_bytes -= *cap;
+      fp->live_bytes += *cap;
+      fp->idle.erase(fp->idle.begin() + best);
+      return ptr;
+    }
+    fp->live_bytes += nbytes;
+    if (fp->live_bytes > fp->peak_bytes) fp->peak_bytes = fp->live_bytes;
+    // the new buffer makes room for itself: the oldest idle ones go until
+    // idle + live is back within the most that was ever live
+    size_t n = 0;
+    while (n < fp->idle.size() &&
+           fp->idle_bytes + fp->live_bytes > fp->peak_bytes) {
+      spill.push_back(fp->idle[n].ptr);
+      fp->idle_bytes -= fp->idle[n].cap;
+      ++n;
+    }
+    fp->idle.erase(fp->idle.begin(), fp->idle.begin() + n);
+  }
+  for (uint8_t* ptr : spill) std::free(ptr);
+  uint8_t* out = static_cast<uint8_t*>(std::malloc(nbytes));
+  if (out == nullptr) {
+    std::lock_guard<std::mutex> lock(fp->mu);
+    --fp->live;
+    fp->live_bytes -= nbytes;
+    return nullptr;
+  }
+  *cap = nbytes;
+  return out;
+}
+
+// Give back a frame's buffer: cap 0 is free(); a pooled one goes idle (and
+// what has aged out goes), or is freed once the pool is closed (the last
+// one deletes the pool).
+static void frame_give(fc_frame_pool* fp, uint8_t* ptr, size_t cap) {
+  if (cap == 0) {
+    std::free(ptr);
+    return;
+  }
+  std::vector<uint8_t*> stale;
+  bool last = false;
+  {
+    std::lock_guard<std::mutex> lock(fp->mu);
+    --fp->live;
+    fp->live_bytes -= cap;
+    if (!fp->closed) {
+      fp->idle.push_back({ptr, cap, FrameClock::now()});
+      fp->idle_bytes += cap;
+      frame_age_locked(fp, &stale);
+      ptr = nullptr;
+    } else {
+      last = fp->live == 0;
+    }
+  }
+  for (uint8_t* old : stale) std::free(old);
+  std::free(ptr);
+  if (last) delete fp;
+}
+
+// The full-frame decode behind fc_jpeg_decode and the pool's batches: the
+// output buffer comes from frame_take (``frames`` null: malloc) and its
+// capacity back in *cap (0: fc_free releases it). Every row is written or
+// the decode fails, so a reused buffer never shows an earlier frame.
+static uint8_t* decode_full(const uint8_t* data, size_t len, int scale_num,
+                            int* width, int* height, fc_frame_pool* frames,
+                            size_t* cap, int* reused) {
   jpeg_decompress_struct cinfo;
   fc_jpeg_error_mgr jerr;
   cinfo.err = jpeg_std_error(&jerr.pub);
   jerr.pub.error_exit = fc_jpeg_error_exit;
-  // volatile: both are modified between setjmp and a potential longjmp;
+  // volatile: these are modified between setjmp and a potential longjmp;
   // without it the error path would free indeterminate (register-cached)
   // values — double-free or leak (C11 7.13.2.1p2)
   uint8_t* volatile out = nullptr;
+  volatile size_t out_cap = 0;
   uint8_t* volatile row4 = nullptr;  // CMYK scanline scratch
   if (setjmp(jerr.setjmp_buffer)) {
     jpeg_destroy_decompress(&cinfo);
-    std::free(out);
+    if (out) frame_give(frames, out, out_cap);
     std::free(row4);
     return nullptr;
   }
@@ -124,7 +282,10 @@ uint8_t* fc_jpeg_decode(const uint8_t* data, size_t len, int scale_num,
   const int w = cinfo.output_width;
   const int h = cinfo.output_height;
   const int stride = w * 3;
-  out = static_cast<uint8_t*>(std::malloc(static_cast<size_t>(stride) * h));
+  size_t taken_cap = 0;
+  out = frame_take(frames, static_cast<size_t>(stride) * h, &taken_cap,
+                   reused);
+  out_cap = taken_cap;
   if (!out) {
     jpeg_abort_decompress(&cinfo);
     jpeg_destroy_decompress(&cinfo);
@@ -135,18 +296,23 @@ uint8_t* fc_jpeg_decode(const uint8_t* data, size_t len, int scale_num,
     if (!row4) {
       jpeg_abort_decompress(&cinfo);
       jpeg_destroy_decompress(&cinfo);
-      std::free(out);
+      frame_give(frames, out, out_cap);
       return nullptr;
     }
   }
   while (cinfo.output_scanline < cinfo.output_height) {
     uint8_t* row = out + static_cast<size_t>(cinfo.output_scanline) * stride;
-    if (!cmyk) {
-      jpeg_read_scanlines(&cinfo, &row, 1);
-      continue;
+    JSAMPROW rows[1] = {cmyk ? row4 : row};
+    if (jpeg_read_scanlines(&cinfo, rows, 1) != 1) {
+      // no row came back: fail rather than leave the rest of the buffer
+      // as it was (a reused buffer holds an earlier frame)
+      std::free(row4);
+      jpeg_abort_decompress(&cinfo);
+      jpeg_destroy_decompress(&cinfo);
+      frame_give(frames, out, out_cap);
+      return nullptr;
     }
-    JSAMPROW rows[1] = {row4};
-    jpeg_read_scanlines(&cinfo, rows, 1);
+    if (!cmyk) continue;
     // multiplicative fold: R = (255-C)*(255-K)/255 over real ink values;
     // with Adobe's inverted storage the (255 - s) terms cancel to s*k/255
     for (int x = 0; x < w; ++x) {
@@ -169,7 +335,25 @@ uint8_t* fc_jpeg_decode(const uint8_t* data, size_t len, int scale_num,
   jpeg_destroy_decompress(&cinfo);
   *width = w;
   *height = h;
+  *cap = out_cap;
   return out;
+}
+
+// Decode a JPEG buffer to RGB. scale_num/8 is the libjpeg DCT scale
+// (pass 8 for full size, 4 for 1/2, 2 for 1/4, 1 for 1/8).
+// CMYK and YCCK (Adobe print-origin) sources decode natively: libjpeg
+// hands back CMYK samples (it converts YCCK->CMYK itself but cannot emit
+// RGB from a CMYK family), and the multiplicative CMYK->RGB fold happens
+// here — the reference feeds such JPEGs through ImageMagick transparently
+// (src/Core/Processor/ImageProcessor.php:68), so the native path must not
+// silently punt them to the slow PIL fallback.
+// Returns malloc'd RGB8 buffer or nullptr; fills width/height.
+uint8_t* fc_jpeg_decode(const uint8_t* data, size_t len, int scale_num,
+                        int* width, int* height) {
+  size_t cap;
+  int reused;
+  return decode_full(data, len, scale_num, width, height, nullptr, &cap,
+                     &reused);
 }
 
 // ---------------------------------------------------------------------------
@@ -1107,6 +1291,8 @@ struct fc_pool {
   std::mutex mu;
   std::condition_variable cv;
   std::atomic<bool> stop{false};
+  // outlives the pool while a buffer it handed out is live
+  fc_frame_pool* frames = new fc_frame_pool();
 };
 
 fc_pool* fc_pool_create(int n_threads) {
@@ -1118,24 +1304,79 @@ fc_pool* fc_pool_create(int n_threads) {
         std::function<void()> task;
         {
           std::unique_lock<std::mutex> lock(pool->mu);
-          pool->cv.wait(lock,
-                        [pool] { return pool->stop || !pool->tasks.empty(); });
-          if (pool->stop && pool->tasks.empty()) return;
-          task = std::move(pool->tasks.front());
-          pool->tasks.pop();
+          const bool woken = pool->cv.wait_for(
+              lock, kFrameTrimEvery,
+              [pool] { return pool->stop || !pool->tasks.empty(); });
+          if (woken) {
+            if (pool->stop && pool->tasks.empty()) return;
+            task = std::move(pool->tasks.front());
+            pool->tasks.pop();
+          }
         }
-        task();
+        if (task) {
+          task();
+        } else {
+          // nothing to do for a while: a quiet process gives back
+          // the frames it no longer uses
+          frame_trim(pool->frames);
+        }
       }
     });
   }
   return pool;
 }
 
+// Stops the workers and frees every idle frame buffer; the frame pool
+// itself goes with the last buffer still live (fc_pool_release).
 void fc_pool_destroy(fc_pool* pool) {
   pool->stop = true;
   pool->cv.notify_all();
   for (auto& worker : pool->workers) worker.join();
+  fc_frame_pool* fp = pool->frames;
+  std::vector<fc_frame_pool::Idle> idle;
+  bool last;
+  {
+    std::lock_guard<std::mutex> lock(fp->mu);
+    fp->closed = true;
+    idle.swap(fp->idle);
+    fp->idle_bytes = 0;
+    last = fp->live == 0;
+  }
+  for (const auto& buffer : idle) std::free(buffer.ptr);
+  if (last) delete fp;
   delete pool;
+}
+
+// The pool's frame buffers, as fc_pool_release takes them: the handle
+// stays valid after fc_pool_destroy while a buffer it handed out is live.
+fc_frame_pool* fc_pool_frames(fc_pool* pool) { return pool->frames; }
+
+// Give back a decoded frame whose batch item reported frame_cap > 0 (any
+// other buffer is fc_free'd). Safe from any thread, before or after
+// fc_pool_destroy.
+void fc_pool_release(fc_frame_pool* frames, void* ptr, size_t cap) {
+  frame_give(frames, static_cast<uint8_t*>(ptr), cap);
+}
+
+// Pool full frames of min_bytes and up instead of kFramePoolMinBytes, and
+// free idle buffers after idle_ms instead of kFrameIdleMs; 0 and a negative
+// idle_ms keep the limit (tests decode small frames through the pool, and
+// age them, with it).
+void fc_pool_set_frame_limits(fc_pool* pool, size_t min_bytes,
+                              int64_t idle_ms) {
+  if (min_bytes > 0) pool->frames->min_bytes = min_bytes;
+  if (idle_ms >= 0) pool->frames->idle_ms = idle_ms;
+}
+
+// The frame pool's state into out[5]: idle buffers, live ones, their
+// bytes (idle, live), and the most bytes live at once.
+void fc_frame_pool_stats(fc_frame_pool* frames, size_t* out) {
+  std::lock_guard<std::mutex> lock(frames->mu);
+  out[0] = frames->idle.size();
+  out[1] = frames->live;
+  out[2] = frames->idle_bytes;
+  out[3] = frames->live_bytes;
+  out[4] = frames->peak_bytes;
 }
 
 struct fc_batch_item {
@@ -1156,6 +1397,11 @@ struct fc_batch_item {
   int out_y;
   int full_w;
   int full_h;
+  // a full frame the pool keeps (see kFramePoolMinBytes): its buffer's
+  // capacity, which fc_pool_release takes; 0 for a buffer fc_free takes.
+  // frame_reused is 1 where an earlier frame had touched those pages.
+  size_t frame_cap;
+  int frame_reused;
 };
 
 // Decode a batch of JPEGs in parallel on the pool; blocks until done.
@@ -1163,15 +1409,21 @@ struct fc_batch_item {
 // failure (malformed/truncated bytes) nulls that item's `out` and the
 // worker thread survives — the error path in both decoders is a
 // setjmp-contained cleanup, never an abort of the process or the pool.
+// A full frame of the frame pool's size is decoded into one of its kept
+// buffers (frame_cap > 0: give it back through fc_pool_release).
 void fc_pool_decode_jpeg_batch(fc_pool* pool, fc_batch_item* items, int n) {
+  frame_trim(pool->frames);
   std::atomic<int> remaining{n};
   std::mutex done_mu;
   std::condition_variable done_cv;
+  fc_frame_pool* frames = pool->frames;
   for (int i = 0; i < n; ++i) {
     fc_batch_item* item = &items[i];
     {
       std::lock_guard<std::mutex> lock(pool->mu);
-      pool->tasks.emplace([item, &remaining, &done_mu, &done_cv] {
+      pool->tasks.emplace([item, frames, &remaining, &done_mu, &done_cv] {
+        item->frame_cap = 0;
+        item->frame_reused = 0;
         if (item->roi_w > 0 && item->roi_h > 0) {
           item->out = fc_jpeg_decode_roi(
               item->data, item->len, item->scale_num, item->roi_x,
@@ -1179,8 +1431,9 @@ void fc_pool_decode_jpeg_batch(fc_pool* pool, fc_batch_item* items, int n) {
               &item->height, &item->out_x, &item->out_y, &item->full_w,
               &item->full_h);
         } else {
-          item->out = fc_jpeg_decode(item->data, item->len, item->scale_num,
-                                     &item->width, &item->height);
+          item->out = decode_full(item->data, item->len, item->scale_num,
+                                  &item->width, &item->height, frames,
+                                  &item->frame_cap, &item->frame_reused);
         }
         if (remaining.fetch_sub(1) == 1) {
           std::lock_guard<std::mutex> dl(done_mu);

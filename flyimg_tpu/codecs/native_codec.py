@@ -12,6 +12,7 @@ WARNING.
 
 from __future__ import annotations
 
+import _ctypes
 import ctypes
 import dataclasses
 import logging
@@ -19,7 +20,7 @@ import os
 import subprocess
 import threading
 import time
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -27,22 +28,20 @@ _DIR = os.path.join(os.path.dirname(__file__), "native")
 _LIB_PATH = os.path.join(_DIR, "libfastcodec.so")
 _lib = None
 _lib_lock = threading.Lock()
-# Two distinct facts about the loaded library (set during _load):
-# _roi_symbol — the ROI entry points EXIST, which also means the library
-# was built with the widened fc_batch_item struct (the fields are
-# unconditional in fastcodec.cpp; only the ROI body is #if-gated), so it
-# decides the ctypes batch-item LAYOUT. _roi_supported — the build can
-# actually honor a window (fc_roi_supported(): libjpeg-turbo underneath),
-# so it decides whether ROI requests are forwarded. A fresh plain-libjpeg
-# build has the symbol (widened layout) but no support — conflating the
-# two would feed the narrow struct to code striding by the wide one.
-_roi_symbol = False
+# The build can honor a ROI window (fc_roi_supported(): libjpeg-turbo
+# underneath); a plain-libjpeg build has the same entry points and batch
+# layout but decodes full frames only, so ROI requests are not forwarded.
 _roi_supported = False
+# The newest entry point: a binary without it was built from older sources
+# (another batch-item layout), and is rebuilt like one that will not load.
+_REQUIRED_SYMBOL = "fc_pool_release"
 
 
 class _BatchItem(ctypes.Structure):
     # mirrors fc_batch_item in fastcodec.cpp: roi_w <= 0 = full decode;
-    # the actualized window geometry comes back in out_x/out_y/full_w/full_h
+    # the actualized window geometry comes back in out_x/out_y/full_w/full_h;
+    # frame_cap > 0 marks a buffer that goes back through fc_pool_release
+    # (its capacity), and frame_reused one an earlier frame had touched
     _fields_ = [
         ("data", ctypes.c_char_p),
         ("len", ctypes.c_size_t),
@@ -58,21 +57,8 @@ class _BatchItem(ctypes.Structure):
         ("out_y", ctypes.c_int),
         ("full_w", ctypes.c_int),
         ("full_h", ctypes.c_int),
-    ]
-
-
-class _BatchItemV1(ctypes.Structure):
-    # pre-ROI fc_batch_item layout: a stale prebuilt .so (no
-    # fc_jpeg_decode_roi symbol -> _roi_supported False) still expects
-    # this shape, and feeding it the widened struct would corrupt the
-    # call — layout chosen per-call in DecodePool.decode_batch
-    _fields_ = [
-        ("data", ctypes.c_char_p),
-        ("len", ctypes.c_size_t),
-        ("scale_num", ctypes.c_int),
-        ("out", ctypes.c_void_p),
-        ("width", ctypes.c_int),
-        ("height", ctypes.c_int),
+        ("frame_cap", ctypes.c_size_t),
+        ("frame_reused", ctypes.c_int),
     ]
 
 
@@ -117,24 +103,32 @@ def _stale() -> bool:
     )
 
 
+def _open_current():
+    """``ctypes.CDLL`` of the library if it loads and was built from these
+    sources, else None (a binary of older sources is let go, so that a
+    rebuild at the same path is loaded afresh)."""
+    try:
+        lib = ctypes.CDLL(_LIB_PATH)
+    except OSError:
+        return None
+    if hasattr(lib, _REQUIRED_SYMBOL):
+        return lib
+    _ctypes.dlclose(lib._handle)
+    return None
+
+
 def _open_library():
     """``ctypes.CDLL`` of the library built from the tracked sources, or
     None. The binary is not in git: it is (re)built when missing or older
     than its sources, and again when what is there will not load on this
     machine (a copied-in binary linked against another installation's
-    sonames)."""
+    sonames) or lacks an entry point of these sources."""
     if _stale():
         _build()
-    try:
-        return ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        pass
-    if _build():
-        try:
-            return ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            pass
-    return None
+    lib = _open_current()
+    if lib is None and _build():
+        lib = _open_current()
+    return lib
 
 
 def _load():
@@ -155,28 +149,34 @@ def _load():
             ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
             ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
         ]
-        # ROI decode entry points are feature-gated: a stale prebuilt .so
-        # (no symbol -> old narrow batch struct) or a plain-libjpeg build
-        # (symbol present, fc_roi_supported() == 0 -> widened struct but
-        # no window decode) simply has callers fall back to full-frame
-        # decode + host crop
-        global _roi_symbol, _roi_supported
-        try:
-            lib.fc_jpeg_decode_roi.restype = ctypes.c_void_p
-            lib.fc_jpeg_decode_roi.argtypes = [
-                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
-                ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-                ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-                ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-            ]
-            lib.fc_roi_supported.restype = ctypes.c_int
-            lib.fc_roi_supported.argtypes = []
-            _roi_symbol = True
-            _roi_supported = bool(lib.fc_roi_supported())
-        except AttributeError:
-            _roi_symbol = False
-            _roi_supported = False
+        # a plain-libjpeg build (fc_roi_supported() == 0) has callers fall
+        # back to full-frame decode + host crop
+        global _roi_supported
+        lib.fc_jpeg_decode_roi.restype = ctypes.c_void_p
+        lib.fc_jpeg_decode_roi.argtypes = [
+            ctypes.c_char_p, ctypes.c_size_t, ctypes.c_int,
+            ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+        ]
+        lib.fc_roi_supported.restype = ctypes.c_int
+        lib.fc_roi_supported.argtypes = []
+        _roi_supported = bool(lib.fc_roi_supported())
+        lib.fc_pool_frames.restype = ctypes.c_void_p
+        lib.fc_pool_frames.argtypes = [ctypes.c_void_p]
+        lib.fc_pool_release.restype = None
+        lib.fc_pool_release.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+        ]
+        lib.fc_pool_set_frame_limits.restype = None
+        lib.fc_pool_set_frame_limits.argtypes = [
+            ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int64,
+        ]
+        lib.fc_frame_pool_stats.restype = None
+        lib.fc_frame_pool_stats.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_size_t),
+        ]
         lib.fc_jpeg_encode.restype = ctypes.c_void_p
         lib.fc_jpeg_encode.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
@@ -242,28 +242,42 @@ class LaunchSplit:
     totals). ``native_s`` is the pool call (C workers, GIL released),
     ``handover_s`` the walk over its results on the calling thread (a
     decode launch's; an encode launch leaves both 0); ``buffers`` /
-    ``buffer_bytes`` count the native buffers handed over."""
+    ``buffer_bytes`` count the native buffers handed over. Of a decode
+    launch's frames, ``frames_pooled`` were decoded into a buffer of the
+    pool's that an earlier frame had touched and ``frames_fresh`` into one
+    it allocated for them (full frames of the pool's size alone; any
+    other frame counts in neither)."""
 
     native_s: float = 0.0
     handover_s: float = 0.0
     buffers: int = 0
     buffer_bytes: int = 0
+    frames_pooled: int = 0
+    frames_fresh: int = 0
 
 
 class _NativePixels:
     """Owner of one malloc'd buffer of decoded pixels. numpy keeps it alive
     as the end of the ``base`` chain of every view (a ``reshape``'s ``base``
     is the array ``_adopt_pixels`` makes, whose ``base`` is this object), so
-    the buffer is freed when the last view goes, once, on whichever thread
-    drops it. ``__array_interface__`` names the memory by address: no ctypes
-    array type is made per byte length. The free function is held here, so
+    the buffer is released when the last view goes, once, on whichever
+    thread drops it: through ``fc_free``, or, for a frame the decode pool
+    keeps (``cap`` > 0), back to that pool through ``fc_pool_release``.
+    ``__array_interface__`` names the memory by address: no ctypes array
+    type is made per byte length. Both functions are held here, so
     ``__del__`` looks nothing up in a module that interpreter exit may
-    already have cleared, and the handle it calls through cannot go first."""
+    already have cleared, and the handle it calls through cannot go first
+    (the pool's frame handle outlives the pool while a buffer is live)."""
 
-    __slots__ = ("_free", "_ptr", "__array_interface__")
+    __slots__ = ("_free", "_release", "_frames", "_cap", "_ptr",
+                 "__array_interface__")
 
-    def __init__(self, lib, ptr: int, nbytes: int) -> None:
+    def __init__(self, lib, ptr: int, nbytes: int, frames=None,
+                 cap: int = 0) -> None:
         self._free = lib.fc_free
+        self._release = lib.fc_pool_release if cap else None
+        self._frames = frames
+        self._cap = cap
         self._ptr = ptr
         self.__array_interface__ = {
             "version": 3,
@@ -274,15 +288,21 @@ class _NativePixels:
 
     def __del__(self) -> None:
         ptr, self._ptr = self._ptr, 0
-        if ptr:
+        if not ptr:
+            return
+        if self._cap:
+            self._release(self._frames, ptr, self._cap)
+        else:
             self._free(ptr)
 
 
-def _adopt_pixels(lib, ptr: int, nbytes: int) -> np.ndarray:
+def _adopt_pixels(lib, ptr: int, nbytes: int, frames=None,
+                  cap: int = 0) -> np.ndarray:
     """Decoded pixels: the native buffer itself as a writable flat ``uint8``
-    array that owns it (``_NativePixels``). Nothing is copied; a small view
-    kept for long pins the whole buffer, as it pinned numpy's copy before."""
-    return np.asarray(_NativePixels(lib, ptr, nbytes))
+    array that owns it (``_NativePixels``; ``frames`` / ``cap`` for a
+    buffer the decode pool keeps). Nothing is copied; a small view kept
+    for long pins the whole buffer, as it pinned numpy's copy before."""
+    return np.asarray(_NativePixels(lib, ptr, nbytes, frames, cap))
 
 
 def _copy_bytes(lib, ptr: int, nbytes: int) -> bytes:
@@ -513,15 +533,58 @@ def webp_encode(
     return _copy_bytes(lib, ptr, out_len.value)
 
 
-class DecodePool:
-    """Parallel JPEG decode over the native worker pool."""
+class FrameBuffers(NamedTuple):
+    """The decode pool's kept frame buffers: how many are idle and live,
+    their bytes, and the most bytes that were live at once, which
+    ``idle_bytes + live_bytes`` never exceeds."""
 
-    def __init__(self, n_threads: Optional[int] = None) -> None:
+    idle: int
+    live: int
+    idle_bytes: int
+    live_bytes: int
+    peak_bytes: int
+
+
+def _frame_buffers_of(lib, frames) -> FrameBuffers:
+    """``FrameBuffers`` of a frame pool handle (``fc_pool_frames``), valid
+    while its decode pool is open or a buffer it handed out is live."""
+    out = (ctypes.c_size_t * 5)()
+    lib.fc_frame_pool_stats(frames, out)
+    return FrameBuffers(*out)
+
+
+class DecodePool:
+    """Parallel JPEG decode over the native worker pool. A full frame of
+    32 MiB or more (``kFramePoolMinBytes`` in ``fastcodec.cpp``: glibc's
+    largest mmap threshold) is decoded into a buffer the pool keeps, which
+    comes back to it when the frame's last view goes. The pool never holds
+    more bytes of such buffers, idle and live, than were live at once,
+    hands a frame no buffer over twice its size, and frees a buffer no
+    frame took for 20 s. ``_frame_min_bytes`` and ``_frame_idle_s`` lower
+    the size and the time (tests decode small frames through the pool,
+    and age them, with them)."""
+
+    def __init__(self, n_threads: Optional[int] = None, *,
+                 _frame_min_bytes: Optional[int] = None,
+                 _frame_idle_s: Optional[float] = None) -> None:
         lib = _load()
         if not lib:
             raise RuntimeError("fastcodec unavailable")
         self._lib = lib
         self._pool = lib.fc_pool_create(n_threads or os.cpu_count() or 1)
+        self._frames = lib.fc_pool_frames(self._pool)
+        if _frame_min_bytes is not None or _frame_idle_s is not None:
+            # 0 and -1: the library's own limit
+            lib.fc_pool_set_frame_limits(
+                self._pool, _frame_min_bytes or 0,
+                -1 if _frame_idle_s is None else round(_frame_idle_s * 1000),
+            )
+
+    def frame_buffers(self) -> FrameBuffers:
+        """The pool's kept frame buffers (all zero once it is closed)."""
+        if self._frames is None:
+            return FrameBuffers(0, 0, 0, 0, 0)
+        return _frame_buffers_of(self._lib, self._frames)
 
     def decode_batch(
         self,
@@ -540,12 +603,7 @@ class DecodePool:
         n = len(blobs)
         if n == 0:
             return []
-        # layout follows the SYMBOL (struct width); honoring windows
-        # follows the CAPABILITY — a plain-libjpeg rebuild has the
-        # widened struct with fc_roi_supported() == 0
-        roi_build = _roi_symbol
-        item_cls = _BatchItem if roi_build else _BatchItemV1
-        items = (item_cls * n)()
+        items = (_BatchItem * n)()
         keepalive = []
         for i, blob in enumerate(blobs):
             buf = ctypes.create_string_buffer(blob, len(blob))
@@ -553,25 +611,18 @@ class DecodePool:
             items[i].data = ctypes.cast(buf, ctypes.c_char_p)
             items[i].len = len(blob)
             items[i].scale_num = scale_num
-            if roi_build:
-                roi = (
-                    rois[i] if rois is not None and _roi_supported else None
-                )
-                if roi is not None:
-                    items[i].roi_x = int(roi[0])
-                    items[i].roi_y = int(roi[1])
-                    items[i].roi_w = int(roi[2])
-                    items[i].roi_h = int(roi[3])
-                else:
-                    items[i].roi_w = 0
-                    items[i].roi_h = 0
+            # a plain-libjpeg build decodes the full frame (roi_w 0)
+            roi = rois[i] if rois is not None and _roi_supported else None
+            if roi is not None:
+                items[i].roi_x = int(roi[0])
+                items[i].roi_y = int(roi[1])
+                items[i].roi_w = int(roi[2])
+                items[i].roi_h = int(roi[3])
         called = time.perf_counter()
-        self._lib.fc_pool_decode_jpeg_batch(
-            self._pool, ctypes.cast(items, ctypes.POINTER(_BatchItem)), n
-        )
+        self._lib.fc_pool_decode_jpeg_batch(self._pool, items, n)
         decoded = time.perf_counter()
         out: list = []
-        buffers = nbytes = 0
+        buffers = nbytes = pooled = fresh = 0
         for i in range(n):
             if not items[i].out:
                 out.append(None)
@@ -579,9 +630,15 @@ class DecodePool:
             w, h = items[i].width, items[i].height
             buffers += 1
             nbytes += w * h * 3
-            arr = _adopt_pixels(self._lib, items[i].out, w * h * 3)
+            cap = items[i].frame_cap
+            if cap and items[i].frame_reused:
+                pooled += 1
+            elif cap:
+                fresh += 1
+            arr = _adopt_pixels(
+                self._lib, items[i].out, w * h * 3, self._frames, cap)
             rgb = arr.reshape(h, w, 3)
-            if roi_build and items[i].roi_w > 0:
+            if items[i].roi_w > 0:
                 out.append((
                     rgb,
                     (items[i].out_x, items[i].out_y),
@@ -594,6 +651,8 @@ class DecodePool:
             split.handover_s = time.perf_counter() - decoded
             split.buffers = buffers
             split.buffer_bytes = nbytes
+            split.frames_pooled = pooled
+            split.frames_fresh = fresh
         return out
 
     def encode_batch(
@@ -651,6 +710,8 @@ class DecodePool:
         if self._pool:
             self._lib.fc_pool_destroy(self._pool)
             self._pool = None
+            # the handle lives on in the frames still out, not here
+            self._frames = None
 
     def __del__(self) -> None:  # pragma: no cover
         try:

@@ -248,7 +248,8 @@ def build_batched_program(
 @dataclass(eq=False)  # identity equality: generated __eq__ would compare
 class _Pending:       # ndarray fields ("truth value is ambiguous" in any
     # list membership test over in-flight batches)
-    image: np.ndarray               # [h, w, 3] uint8 (or aux payload)
+    # [h, w, 3] uint8 (or aux payload); None once the member is answered
+    image: Optional[np.ndarray]
     plan: Optional[TransformPlan]
     future: Future
     enqueued_at: float              # time.monotonic(): the flush policy's clock
@@ -717,8 +718,8 @@ class BatchController:
         The callers of a filling launch are parked until it has run, so the
         copies of a launch run beside one another and beside the decodes
         that fill it, and ``_assemble`` finds the block whole. ``image``
-        stays the caller's array and the member keeps it: every recovery
-        path assembles from it.
+        stays the caller's array and the member keeps it until it is
+        answered: every recovery path assembles from it.
 
         ``src_window`` (docs/host-pipeline.md "ROI window math"): the
         image is only the window of the plan's source at this (x, y)
@@ -1975,7 +1976,10 @@ class BatchController:
         remaining member of the batch. Each future first gets the
         member's own three instants (``launch_times``: queued, its launch
         popped, its result ready; ``time.perf_counter()``), from which
-        the handler fills the request's ``*_queue`` / ``*_run`` stages."""
+        the handler fills the request's ``*_queue`` / ``*_run`` stages.
+        An answered member lets its ``image`` go at once: nothing recovers
+        it after, and the launch's other members may take a second to be
+        answered, while its caller goes on to decode its next frame."""
         ready = time.perf_counter()
         for i, member in enumerate(members):
             result = outputs[i]
@@ -1989,6 +1993,7 @@ class BatchController:
                     member.enqueued_pc, launch.popped, ready
                 )
                 member.future.set_result(result)
+                member.image = None
 
     def _await_launch(self, launch: _Launch, dev_out):
         """The device side of one launch after its dispatch, in three

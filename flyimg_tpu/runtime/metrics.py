@@ -567,8 +567,9 @@ class MetricsRegistry:
     def record_codec_decode_launch(self, split) -> None:
         """One decode launch of the host codec's pool
         (``codecs.native_codec.LaunchSplit``, carried back by the
-        handler's runner): its two parts, one observation a launch, and
-        the frames it handed over."""
+        handler's runner): its two parts, one observation a launch, the
+        frames it handed over, and of its full frames of the pool's size
+        how many reused a buffer the pool kept."""
         self.histogram(
             "flyimg_codec_native_seconds",
             "Per decode launch of the native codec pool: the pool call "
@@ -583,6 +584,16 @@ class MetricsRegistry:
         self.record_codec_buffers(
             "adopted", split.buffers, split.buffer_bytes
         )
+        for origin, frames in (("pooled", split.frames_pooled),
+                               ("fresh", split.frames_fresh)):
+            self.counter(
+                f'flyimg_codec_frame_buffers_total{{from="{origin}"}}',
+                "Full frames of 32 MiB and up that the codec pool's decode "
+                "launches decoded, by where the buffer came from: pooled "
+                "(one an earlier frame had touched, kept by the pool) or "
+                "fresh (allocated for this frame: every page faulted in "
+                "as the decoder writes it)",
+            ).inc(frames)
 
     def record_codec_buffers(self, handover: str, buffers: int,
                              nbytes: int) -> None:

@@ -741,17 +741,19 @@ def _private_native_dir(tmp_path, monkeypatch):
     return dst
 
 
-@pytest.mark.parametrize("found", ["missing", "unloadable", "stale"])
+@pytest.mark.parametrize("found", ["missing", "unloadable", "stale", "older_sources"])
 def test_loader_builds_library_from_tracked_sources(
     tmp_path, monkeypatch, found
 ):
     """Whatever is found where the library should be — nothing, another
-    installation's binary that will not load here, or a build older than
-    its sources — the loader ends up on a library built from the tracked
-    sources. (One directory per case: dlopen resolves a path it has
+    installation's binary that will not load here, a build older than its
+    sources, or a newer file built from older sources (it loads, but lacks
+    an entry point) — the loader ends up on a library built from the
+    tracked sources. (One directory per case: dlopen resolves a path it has
     already loaded to the old handle.)"""
     import os
     import shutil
+    import subprocess
 
     if not (shutil.which("make") and shutil.which("g++")):
         pytest.skip("no toolchain")
@@ -765,7 +767,13 @@ def test_loader_builds_library_from_tracked_sources(
     elif found == "stale":
         shutil.copy2(built_here, lib)
         os.utime(lib, (1, 1))
-    if found != "unloadable":
+    elif found == "older_sources":
+        old = tmp_path / "old.cpp"
+        old.write_text('extern "C" const char* fc_version() { return "0"; }\n')
+        subprocess.run(["g++", "-shared", "-fPIC", "-o", str(lib), str(old)],
+                       check=True)
+        assert not native_codec._stale()  # newer than the sources
+    if found not in ("unloadable", "older_sources"):
         assert native_codec._stale()
     assert native_codec._open_library() is not None
     assert not native_codec._stale()

@@ -12,6 +12,7 @@ import gc
 import io
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -44,11 +45,13 @@ class _StubLib:
 
 
 class _CountingLib:
-    """The real library with ``fc_free`` counted before it frees."""
+    """The real library with ``fc_free`` and ``fc_pool_release`` counted
+    before they free or keep."""
 
     def __init__(self, real):
         self._real = real
         self.freed = []
+        self.released = []
 
     def __getattr__(self, name):
         return getattr(self._real, name)
@@ -56,6 +59,10 @@ class _CountingLib:
     def fc_free(self, ptr):
         self.freed.append(ptr)
         self._real.fc_free(ptr)
+
+    def fc_pool_release(self, frames, ptr, cap):
+        self.released.append(ptr)
+        self._real.fc_pool_release(frames, ptr, cap)
 
 
 @pytest.fixture()
@@ -386,3 +393,382 @@ def test_encoders_return_the_bytes_of_the_double_copy_and_free_once(pool, lib):
     assert blobs[0] == trellis and type(blobs[1]) is bytes
     assert len(lib.freed) == freed + 2
     assert split.buffers == 2 and split.buffer_bytes == len(blobs[0]) + len(blobs[1])
+
+
+# ---------------------------------------------------------------------------
+# the decode pool's frame buffers: a full frame of the pool's size is decoded
+# into a buffer an earlier frame touched, and goes back to the pool. The
+# pools below keep frames of 1 KiB and up, so small frames take that path.
+
+SMALL = 1024
+
+
+def _rgb(w, h):
+    return w * h * 3
+
+
+@pytest.fixture()
+def frame_pool(lib):
+    made = native_codec.DecodePool(4, _frame_min_bytes=SMALL)
+    yield made
+    made.close()
+
+
+def _cmyk_jpeg(w, h, seed):
+    buf = io.BytesIO()
+    Image.fromarray(_photo(w, h, seed=seed)).convert("CMYK").save(
+        buf, format="JPEG", quality=90)
+    return buf.getvalue()
+
+
+def _flat_jpeg(w, h, level):
+    return _encoded(np.full((h, w, 3), level, np.uint8), "JPEG", quality=95)
+
+
+def _held(made):
+    """(idle buffers, live ones, the most bytes live at once), with the
+    bytes held, idle and live, checked against that most."""
+    held = made.frame_buffers()
+    assert held.idle_bytes + held.live_bytes <= held.peak_bytes, held
+    return held.idle, held.live, held.peak_bytes
+
+
+@needs_lib
+def test_a_frame_in_a_reused_buffer_is_the_fresh_decode_byte_for_byte(frame_pool, lib):
+    first = [_encoded(_photo(96, 64, seed=k), "JPEG", quality=90) for k in range(4)]
+    # others of each size and kind: a CMYK source, 4:2:0, a smaller frame
+    # (into a larger buffer), a grey one
+    second = [
+        _cmyk_jpeg(96, 64, seed=7),
+        _encoded(_photo(96, 64, seed=8), "JPEG", quality=80, subsampling=2),
+        _encoded(_photo(80, 56, seed=9), "JPEG", quality=90),
+        _encoded(_photo(96, 64, seed=10)[..., 0], "JPEG", quality=90),
+    ]
+    split = native_codec.LaunchSplit()
+    outs = frame_pool.decode_batch(first, 8, split=split)
+    assert (split.frames_fresh, split.frames_pooled) == (4, 0)
+    assert _held(frame_pool) == (0, 4, 4 * _rgb(96, 64))
+    del outs
+    gc.collect()
+    assert _held(frame_pool) == (4, 0, 4 * _rgb(96, 64))
+    assert len(lib.released) == 4 and lib.freed == []
+    split = native_codec.LaunchSplit()
+    outs = frame_pool.decode_batch(second, 8, split=split)
+    assert (split.frames_fresh, split.frames_pooled) == (0, 4)
+    assert split.buffers == 4
+    for blob, got in zip(second, outs):
+        _assert_adopted(got)
+        ref = native_codec.jpeg_decode(blob)
+        assert got.shape == ref.shape and np.array_equal(got, ref)
+    del outs, ref, got
+    gc.collect()
+    assert len(lib.released) == 8
+    # the references went through fc_free, the pool's frames did not
+    assert len(lib.freed) == 4
+
+
+@needs_lib
+def test_a_pooled_buffer_goes_back_once_after_its_last_view(frame_pool, lib):
+    blob = _encoded(_photo(64, 48, seed=1), "JPEG")
+    (frame,) = frame_pool.decode_batch([blob])
+    views = [frame[4:9, 1:], apply_orientation(frame, 6),
+             np.ascontiguousarray(frame)]
+    del frame
+    for _ in range(len(views)):
+        gc.collect()
+        assert lib.released == [] and _held(frame_pool)[1] == 1
+        views.pop()
+    gc.collect()
+    assert len(lib.released) == 1 and lib.freed == []
+    assert _held(frame_pool) == (1, 0, _rgb(64, 48))
+
+
+@needs_lib
+def test_threads_dropping_pooled_frames_at_once_give_each_back_once(frame_pool, lib):
+    blobs = [_encoded(_photo(40 + 8 * (k % 3), 32, seed=k), "JPEG") for k in range(6)]
+    # the byte bound is read under the pool's lock all through the churn
+    sampled, stop = [], threading.Event()
+
+    def sample():
+        while not stop.is_set():
+            sampled.append(_held(frame_pool))
+
+    sampler = threading.Thread(target=sample)
+    sampler.start()
+    try:
+        n = _churn(lambda k: frame_pool.decode_batch([blobs[k % 6]])[0], rounds=10)
+    finally:
+        stop.set()
+        sampler.join(timeout=30)
+    assert sampled and len(lib.released) == n and lib.freed == []
+    idle, live, peak = _held(frame_pool)
+    assert live == 0 and 1 <= idle and peak <= 36 * _rgb(56, 32)
+
+
+@needs_lib
+def test_idle_and_live_never_exceed_the_high_water(frame_pool, lib):
+    s, l = _rgb(48, 32), _rgb(96, 80)
+    small = [_encoded(_photo(48, 32, seed=k), "JPEG") for k in range(5)]
+    large = [_encoded(_photo(96, 80, seed=k), "JPEG") for k in range(3)]
+    held = frame_pool.decode_batch(small[:3])
+    assert _held(frame_pool) == (0, 3, 3 * s)
+    del held
+    gc.collect()
+    held = frame_pool.decode_batch(small)
+    # three reused, two new: the high-water is five frames' bytes
+    assert _held(frame_pool) == (0, 5, 5 * s)
+    del held[3:]
+    gc.collect()
+    assert _held(frame_pool) == (2, 3, 5 * s)
+    # no idle buffer holds a larger frame: the first new one raises the
+    # high-water past idle + live only by giving the idle ones up
+    split = native_codec.LaunchSplit()
+    big = frame_pool.decode_batch(large, split=split)
+    assert (split.frames_fresh, split.frames_pooled) == (3, 0)
+    assert _held(frame_pool) == (0, 6, 3 * s + 3 * l)
+    del held
+    gc.collect()
+    assert _held(frame_pool) == (3, 3, 3 * s + 3 * l)
+    del big
+    gc.collect()
+    assert _held(frame_pool) == (6, 0, 3 * s + 3 * l)
+    # the smallest buffer that holds a frame is the one taken
+    split = native_codec.LaunchSplit()
+    again = frame_pool.decode_batch(small[:3] + large[:1], split=split)
+    assert split.frames_pooled == 4
+    assert _held(frame_pool) == (2, 4, 3 * s + 3 * l)
+    assert [a.shape for a in again] == [(32, 48, 3)] * 3 + [(80, 96, 3)]
+    del again
+    gc.collect()
+    assert len(lib.released) == 3 + 5 + 3 + 4 and lib.freed == []
+
+
+@needs_lib
+def test_a_frame_gets_no_buffer_over_twice_its_size_and_bytes_stay_bounded(frame_pool, lib):
+    s, m, l = _rgb(48, 32), _rgb(64, 48), _rgb(96, 80)
+    big = frame_pool.decode_batch(
+        [_encoded(_photo(96, 80, seed=k), "JPEG") for k in range(3)])
+    del big
+    gc.collect()
+    assert _held(frame_pool) == (3, 0, 3 * l)
+    # a frame a fifth of the idle buffers' size takes none of them: a new
+    # buffer, and the oldest idle one goes to keep the bytes within 3 l
+    split = native_codec.LaunchSplit()
+    small = frame_pool.decode_batch(
+        [_encoded(_photo(48, 32, seed=k), "JPEG") for k in range(2)], split=split)
+    assert (split.frames_fresh, split.frames_pooled) == (2, 0)
+    held = frame_pool.frame_buffers()
+    assert (held.idle, held.live, held.idle_bytes, held.live_bytes) == (2, 2, 2 * l, 2 * s)
+    del small
+    gc.collect()
+    assert _held(frame_pool) == (4, 0, 3 * l)
+    # under half of a large buffer, over a small one: new again, and it fits
+    # within the bytes the pool may hold
+    split = native_codec.LaunchSplit()
+    (medium,) = frame_pool.decode_batch(
+        [_encoded(_photo(64, 48, seed=9), "JPEG")], split=split)
+    assert (split.frames_fresh, split.frames_pooled) == (1, 0) and m * 2 < l
+    assert frame_pool.frame_buffers().idle_bytes == 2 * l + 2 * s
+    # a large frame takes a large buffer, never a smaller one
+    split = native_codec.LaunchSplit()
+    (large,) = frame_pool.decode_batch(
+        [_encoded(_photo(90, 80, seed=5), "JPEG")], split=split)
+    assert split.frames_pooled == 1
+    assert frame_pool.frame_buffers().live_bytes == m + l
+    assert np.array_equal(large, native_codec.jpeg_decode(
+        _encoded(_photo(90, 80, seed=5), "JPEG")))
+    del medium, large
+    gc.collect()
+    assert _held(frame_pool)[:2] == (5, 0)
+
+
+@needs_lib
+def test_roi_and_frames_under_the_size_keep_fc_free(lib):
+    made = native_codec.DecodePool(2, _frame_min_bytes=64 * 48 * 3)
+    try:
+        under = _encoded(_photo(40, 30, seed=1), "JPEG")
+        full = _encoded(_photo(64, 48, seed=2), "JPEG")
+        roi = (8, 8, 32, 24) if native_codec.roi_supported() else None
+        split = native_codec.LaunchSplit()
+        outs = made.decode_batch([under, full, full], 8, rois=[None, None, roi],
+                                 split=split)
+        expect_freed = 2 if roi is not None else 1
+        assert (split.frames_fresh, split.frames_pooled) == (3 - expect_freed, 0)
+        del outs
+        gc.collect()
+        assert len(lib.freed) == expect_freed
+        assert len(lib.released) == 3 - expect_freed
+        # at the default size every frame here is far under it
+        default = native_codec.DecodePool(2)
+        try:
+            split = native_codec.LaunchSplit()
+            outs = default.decode_batch([under, full], 8, split=split)
+            assert (split.frames_fresh, split.frames_pooled) == (0, 0)
+            del outs
+            gc.collect()
+            assert len(lib.freed) == expect_freed + 2
+            assert default.frame_buffers() == (0, 0, 0, 0, 0)
+        finally:
+            default.close()
+    finally:
+        made.close()
+
+
+@needs_lib
+def test_destroying_the_pool_frees_its_idle_buffers_and_a_live_one_later(lib):
+    made = native_codec.DecodePool(2, _frame_min_bytes=SMALL)
+    blobs = [_encoded(_photo(64, 48, seed=k), "JPEG") for k in range(3)]
+    outs = made.decode_batch(blobs)
+    kept = outs[1]
+    del outs
+    gc.collect()
+    handle = made._frames
+    assert _held(made) == (2, 1, 3 * _rgb(64, 48))
+    made.close()
+    held = native_codec._frame_buffers_of(lib._real, handle)
+    assert (held.idle, held.live, held.idle_bytes) == (0, 1, 0)
+    assert np.array_equal(kept, native_codec.jpeg_decode(blobs[1]))
+    released = len(lib.released)
+    del kept
+    gc.collect()
+    # given back to a closed pool: freed, and the pool's last buffer takes
+    # the pool with it
+    assert len(lib.released) == released + 1
+
+
+@needs_lib
+@pytest.mark.parametrize("damage", ["truncated", "corrupt", "truncated_progressive",
+                                    "corrupt_progressive"])
+def test_a_damaged_frame_in_a_reused_buffer_shows_nothing_of_the_last(frame_pool, lib, damage):
+    w, h = 96, 64
+    source = _encoded(_photo(w, h, seed=4), "JPEG", quality=90,
+                      progressive=damage.endswith("progressive"))
+    if damage == "corrupt":
+        blob = bytearray(source)
+        start = len(blob) // 3
+        blob[start:start + 64] = bytes(range(64))
+        blob = bytes(blob)
+    else:
+        blob = source[: len(source) // 2]
+    got = []
+    for level in (250, 5):
+        # a frame of one level, then the damaged one in the buffer it left
+        (previous,) = frame_pool.decode_batch([_flat_jpeg(w, h, level)])
+        del previous
+        gc.collect()
+        split = native_codec.LaunchSplit()
+        (frame,) = frame_pool.decode_batch([blob], split=split)
+        if frame is None:
+            got.append(None)
+            assert _held(frame_pool)[1] == 0
+            continue
+        assert split.frames_pooled == 1
+        got.append(frame.copy())
+        del frame
+        gc.collect()
+    ref = native_codec.jpeg_decode(blob)
+    if ref is None:
+        assert got == [None, None]
+    else:
+        # every row written: the same pixels whatever the buffer held
+        assert np.array_equal(got[0], got[1]) and np.array_equal(got[0], ref)
+    assert _held(frame_pool)[1] == 0
+
+
+@needs_lib
+def test_a_failed_item_gives_its_buffer_back(frame_pool, lib):
+    good = _encoded(_photo(64, 48, seed=3), "JPEG")
+    outs = frame_pool.decode_batch([good, b"\xff\xd8\xff not a jpeg", good[:200]])
+    assert outs[1] is None
+    del outs
+    gc.collect()
+    idle, live, peak = _held(frame_pool)
+    assert live == 0
+
+
+@needs_lib
+def test_pooled_frame_share_reads_the_counter_the_handler_keeps(lib, monkeypatch):
+    """``perfbench/metrics/pooled_frame_share.json`` through the benchmark's
+    own reader, on the handler's registry as the harness scrapes it, with
+    the process's decode pool keeping the test's small frames."""
+    from perfbench.harness import manifest
+    from perfbench.harness.system import parse_prometheus
+    from test_launch_phases import _jpeg, _System
+
+    doc = manifest.load_manifest()
+    entry = next(m for m in doc["per_layer"] if m["name"] == "pooled_frame_share")
+    assert entry["layer"] == "host_decode" and entry["moves"] == "images_per_s"
+    assert entry["workloads"] == [c["name"] for c in doc["workloads"]]
+    spec = manifest.load_metric("pooled_frame_share")
+    read = manifest.load_reader(spec["reader"])
+    made = native_codec.DecodePool(2, _frame_min_bytes=SMALL)
+    monkeypatch.setattr(native_codec, "_POOL", made)
+    system = _System()
+    try:
+        before = parse_prometheus(system.metrics.render_prometheus())
+        system.transform(_jpeg(seed=0))
+        first = parse_prometheus(system.metrics.render_prometheus())
+        # one frame, into a buffer of its own: nothing reused
+        assert first['flyimg_codec_frame_buffers_total{from="fresh"}'] == 1.0
+        assert read({"counters_before": before, "counters_after": first},
+                    **spec["args"]) == 0.0
+        for seed in (1, 2, 3):
+            system.transform(_jpeg(seed=seed))
+        after = parse_prometheus(system.metrics.render_prometheus())
+        assert read({"counters_before": before, "counters_after": after},
+                    **spec["args"]) == pytest.approx(75.0)
+        assert read({"counters_before": first, "counters_after": after},
+                    **spec["args"]) == pytest.approx(100.0)
+        # none of it on the codec controller's own registry
+        assert "flyimg_codec_frame_buffers_total" not in (
+            system.codec.metrics.render_prometheus())
+    finally:
+        system.close()
+        made.close()
+    # the parent's program has no such counter: nothing read, nothing raised
+    assert read({"counters_before": {}, "counters_after": {
+        'flyimg_codec_buffers_total{handover="adopted"}': 4.0}},
+        **spec["args"]) is None
+
+
+@needs_lib
+def test_buffers_no_frame_took_for_the_idle_time_go_at_a_release_or_a_launch(lib):
+    made = native_codec.DecodePool(2, _frame_min_bytes=_rgb(64, 48), _frame_idle_s=1.0)
+    try:
+        blobs = [_encoded(_photo(64, 48, seed=k), "JPEG") for k in range(3)]
+        first = made.decode_batch(blobs[:2])
+        second = made.decode_batch(blobs[2:])
+        del first
+        gc.collect()
+        assert _held(made) == (2, 1, 3 * _rgb(64, 48))
+        time.sleep(1.3)
+        # the two aged out; the one given back now stays
+        del second
+        gc.collect()
+        assert _held(made)[:2] == (1, 0)
+        time.sleep(1.3)
+        # a launch of frames under the size begins by freeing what aged out
+        small = [_encoded(_photo(16, 16, seed=1), "JPEG")]
+        assert made.decode_batch(small)[0].shape == (16, 16, 3)
+        assert _held(made) == (0, 0, 3 * _rgb(64, 48))
+        assert len(lib.released) == 3 and len(lib.freed) == 1
+    finally:
+        made.close()
+
+
+@needs_lib
+def test_a_quiet_pool_gives_its_idle_buffers_back(lib):
+    made = native_codec.DecodePool(2, _frame_min_bytes=SMALL, _frame_idle_s=0.2)
+    try:
+        frames = made.decode_batch(
+            [_encoded(_photo(64, 48, seed=k), "JPEG") for k in range(2)])
+        del frames
+        gc.collect()
+        assert _held(made)[:2] == (2, 0)
+        # no decode, no release: the idle workers free them
+        deadline = time.monotonic() + 10
+        while made.frame_buffers().idle and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert _held(made) == (0, 0, 2 * _rgb(64, 48))
+    finally:
+        made.close()

@@ -155,6 +155,31 @@ def test_stage_gets_the_same_bytes_on_the_fast_and_the_copying_path(name):
         _close(ctl)
 
 
+def test_an_answered_member_lets_its_frame_go_before_the_next_is_answered():
+    """Nothing recovers an answered member, so its frame is not held while
+    the launch's other members are answered; a member nobody answers
+    keeps it."""
+    ctl = _Parked(max_batch=3, deadline_ms=0.0, lone_flush=False)
+    try:
+        members = _members("w_120,h_90,c_1", [(320, 240)] * 3)
+        futures = [ctl.submit(image, plan) for image, plan, _ in members]
+        with ctl._lock:
+            group = ctl._pop_ready_group()
+        seen = []
+        futures[1].add_done_callback(
+            lambda _f: seen.append([m.image is None for m in group.members]))
+        futures[2].cancel()  # its caller gave up
+        outputs = [run_plan(image, plan) for image, plan, _ in members]
+        launch = batcher_mod._Launch(0, group.members)
+        ctl._resolve_members(group, group.members, outputs, launch)
+        assert seen == [[True, False, False]]
+        assert [m.image is None for m in group.members] == [True, True, False]
+        for i in (0, 1):
+            np.testing.assert_array_equal(futures[i].result(timeout=0), outputs[i])
+    finally:
+        _close(ctl)
+
+
 def test_members_beyond_the_block_are_copied_at_their_own_pop():
     ctl = _Parked(max_batch=2, deadline_ms=0.0, lone_flush=False)
     try:
@@ -699,7 +724,6 @@ def test_kept_block_share_reads_the_counter_the_controller_keeps():
 
     doc = manifest.load_manifest()
     entry = next(m for m in doc["per_layer"] if m["name"] == "kept_block_share")
-    assert entry == doc["per_layer"][-1]
     assert entry["layer"] == "batcher" and entry["moves"] == "images_per_s"
     assert entry["workloads"] == [c["name"] for c in doc["workloads"]]
     spec = manifest.load_metric("kept_block_share")
