@@ -107,6 +107,7 @@ def bulk_process(
     codec_batcher = BatchController(
         max_batch=int(params.by_key("decode_batch_max", 32)),
         deadline_ms=float(params.by_key("decode_deadline_ms", 1.0)),
+        name="codec",
         **containment,
     )
     handler = ImageHandler(
@@ -218,6 +219,7 @@ def bulk_process(
                     doc["name"] = summary["name"]
                     fh.write(json.dumps(doc) + "\n")
     finally:
+        handler.close()
         codec_batcher.close()
         if own_batcher:
             batcher.close()

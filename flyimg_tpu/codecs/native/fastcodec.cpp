@@ -1285,6 +1285,15 @@ uint8_t* fc_webp_encode(const uint8_t* pixels, int width, int height,
 // released (the ctypes call site releases it automatically).
 // ---------------------------------------------------------------------------
 
+// A worker's instants on CLOCK_MONOTONIC (libstdc++'s steady_clock), in
+// nanoseconds: the clock Python's time.perf_counter_ns() reads on Linux, so
+// a caller can place them inside its own timing of the pool call.
+static int64_t monotonic_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
 struct fc_pool {
   std::vector<std::thread> workers;
   std::queue<std::function<void()>> tasks;
@@ -1347,6 +1356,11 @@ void fc_pool_destroy(fc_pool* pool) {
   delete pool;
 }
 
+// The pool's worker threads.
+int fc_pool_workers(fc_pool* pool) {
+  return static_cast<int>(pool->workers.size());
+}
+
 // The pool's frame buffers, as fc_pool_release takes them: the handle
 // stays valid after fc_pool_destroy while a buffer it handed out is live.
 fc_frame_pool* fc_pool_frames(fc_pool* pool) { return pool->frames; }
@@ -1402,6 +1416,10 @@ struct fc_batch_item {
   // frame_reused is 1 where an earlier frame had touched those pages.
   size_t frame_cap;
   int frame_reused;
+  // when a worker took the item and when it was done with it
+  // (monotonic_ns)
+  int64_t t_start_ns;
+  int64_t t_end_ns;
 };
 
 // Decode a batch of JPEGs in parallel on the pool; blocks until done.
@@ -1422,6 +1440,7 @@ void fc_pool_decode_jpeg_batch(fc_pool* pool, fc_batch_item* items, int n) {
     {
       std::lock_guard<std::mutex> lock(pool->mu);
       pool->tasks.emplace([item, frames, &remaining, &done_mu, &done_cv] {
+        item->t_start_ns = monotonic_ns();
         item->frame_cap = 0;
         item->frame_reused = 0;
         if (item->roi_w > 0 && item->roi_h > 0) {
@@ -1435,6 +1454,7 @@ void fc_pool_decode_jpeg_batch(fc_pool* pool, fc_batch_item* items, int n) {
                                   &item->width, &item->height, frames,
                                   &item->frame_cap, &item->frame_reused);
         }
+        item->t_end_ns = monotonic_ns();
         if (remaining.fetch_sub(1) == 1) {
           std::lock_guard<std::mutex> dl(done_mu);
           done_cv.notify_all();
@@ -1459,6 +1479,8 @@ struct fc_encode_item {
   int samp_v;
   uint8_t* out;     // fc_free() when done; null on per-image failure
   size_t out_len;
+  int64_t t_start_ns;  // as fc_batch_item's
+  int64_t t_end_ns;
 };
 
 // Encode a batch of RGB frames to JPEG in parallel on the pool; blocks
@@ -1476,6 +1498,7 @@ void fc_pool_encode_jpeg_batch(fc_pool* pool, fc_encode_item* items, int n) {
     {
       std::lock_guard<std::mutex> lock(pool->mu);
       pool->tasks.emplace([item, &remaining, &done_mu, &done_cv] {
+        item->t_start_ns = monotonic_ns();
         item->out_len = 0;
         if (item->trellis) {
           item->out = fc_jpeg_encode_trellis(
@@ -1487,6 +1510,7 @@ void fc_pool_encode_jpeg_batch(fc_pool* pool, fc_encode_item* items, int n) {
               item->optimize, item->progressive, item->samp_h, item->samp_v,
               &item->out_len);
         }
+        item->t_end_ns = monotonic_ns();
         if (remaining.fetch_sub(1) == 1) {
           std::lock_guard<std::mutex> dl(done_mu);
           done_cv.notify_all();

@@ -34,14 +34,16 @@ _lib_lock = threading.Lock()
 _roi_supported = False
 # The newest entry point: a binary without it was built from older sources
 # (another batch-item layout), and is rebuilt like one that will not load.
-_REQUIRED_SYMBOL = "fc_pool_release"
+_REQUIRED_SYMBOL = "fc_pool_workers"
 
 
 class _BatchItem(ctypes.Structure):
     # mirrors fc_batch_item in fastcodec.cpp: roi_w <= 0 = full decode;
     # the actualized window geometry comes back in out_x/out_y/full_w/full_h;
     # frame_cap > 0 marks a buffer that goes back through fc_pool_release
-    # (its capacity), and frame_reused one an earlier frame had touched
+    # (its capacity), and frame_reused one an earlier frame had touched;
+    # t_start_ns / t_end_ns are the worker's instants on CLOCK_MONOTONIC
+    # (time.perf_counter_ns()'s clock)
     _fields_ = [
         ("data", ctypes.c_char_p),
         ("len", ctypes.c_size_t),
@@ -59,6 +61,8 @@ class _BatchItem(ctypes.Structure):
         ("full_h", ctypes.c_int),
         ("frame_cap", ctypes.c_size_t),
         ("frame_reused", ctypes.c_int),
+        ("t_start_ns", ctypes.c_int64),
+        ("t_end_ns", ctypes.c_int64),
     ]
 
 
@@ -75,6 +79,8 @@ class _EncodeItem(ctypes.Structure):
         ("samp_v", ctypes.c_int),
         ("out", ctypes.c_void_p),
         ("out_len", ctypes.c_size_t),
+        ("t_start_ns", ctypes.c_int64),
+        ("t_end_ns", ctypes.c_int64),
     ]
 
 
@@ -163,6 +169,8 @@ def _load():
         lib.fc_roi_supported.restype = ctypes.c_int
         lib.fc_roi_supported.argtypes = []
         _roi_supported = bool(lib.fc_roi_supported())
+        lib.fc_pool_workers.restype = ctypes.c_int
+        lib.fc_pool_workers.argtypes = [ctypes.c_void_p]
         lib.fc_pool_frames.restype = ctypes.c_void_p
         lib.fc_pool_frames.argtypes = [ctypes.c_void_p]
         lib.fc_pool_release.restype = None
@@ -241,12 +249,15 @@ class LaunchSplit:
     handler, into the registry it was built with: ``DecodePool`` keeps no
     totals). ``native_s`` is the pool call (C workers, GIL released),
     ``handover_s`` the walk over its results on the calling thread (a
-    decode launch's; an encode launch leaves both 0); ``buffers`` /
+    decode launch's; an encode launch leaves it 0); ``buffers`` /
     ``buffer_bytes`` count the native buffers handed over. Of a decode
     launch's frames, ``frames_pooled`` were decoded into a buffer of the
     pool's that an earlier frame had touched and ``frames_fresh`` into one
     it allocated for them (full frames of the pool's size alone; any
-    other frame counts in neither)."""
+    other frame counts in neither). Inside the call, by the workers' own
+    instants: ``worker_s`` the seconds they spent on the launch's items,
+    ``wait_s`` the seconds its items waited from the call to a worker's
+    start, and ``workers`` the pool's size."""
 
     native_s: float = 0.0
     handover_s: float = 0.0
@@ -254,6 +265,9 @@ class LaunchSplit:
     buffer_bytes: int = 0
     frames_pooled: int = 0
     frames_fresh: int = 0
+    worker_s: float = 0.0
+    wait_s: float = 0.0
+    workers: int = 0
 
 
 class _NativePixels:
@@ -572,6 +586,7 @@ class DecodePool:
             raise RuntimeError("fastcodec unavailable")
         self._lib = lib
         self._pool = lib.fc_pool_create(n_threads or os.cpu_count() or 1)
+        self._workers = lib.fc_pool_workers(self._pool)
         self._frames = lib.fc_pool_frames(self._pool)
         if _frame_min_bytes is not None or _frame_idle_s is not None:
             # 0 and -1: the library's own limit
@@ -618,8 +633,7 @@ class DecodePool:
                 items[i].roi_y = int(roi[1])
                 items[i].roi_w = int(roi[2])
                 items[i].roi_h = int(roi[3])
-        called = time.perf_counter()
-        self._lib.fc_pool_decode_jpeg_batch(self._pool, items, n)
+        self._pool_call(self._lib.fc_pool_decode_jpeg_batch, items, n, split)
         decoded = time.perf_counter()
         out: list = []
         buffers = nbytes = pooled = fresh = 0
@@ -647,7 +661,6 @@ class DecodePool:
             else:
                 out.append(rgb)
         if split is not None:
-            split.native_s = decoded - called
             split.handover_s = time.perf_counter() - decoded
             split.buffers = buffers
             split.buffer_bytes = nbytes
@@ -671,7 +684,7 @@ class DecodePool:
         half of a miss (several ms/image), so bursts must pay it in
         parallel on C worker threads, not serially under one Python
         caller. ``split``, when given, is filled with this launch's
-        buffers (nobody reads an encode launch's two parts: not timed)."""
+        pool call, its workers and its buffers."""
         n = len(frames)
         if n == 0:
             return []
@@ -692,7 +705,7 @@ class DecodePool:
             items[i].progressive = int(progressive)
             items[i].samp_h = int(sampling[0])
             items[i].samp_v = int(sampling[1])
-        self._lib.fc_pool_encode_jpeg_batch(self._pool, items, n)
+        self._pool_call(self._lib.fc_pool_encode_jpeg_batch, items, n, split)
         out: List[Optional[bytes]] = []
         for i in range(n):
             if not items[i].out:
@@ -705,6 +718,28 @@ class DecodePool:
             split.buffers = n - out.count(None)
             split.buffer_bytes = sum(len(blob) for blob in out if blob)
         return out
+
+    def _pool_call(self, call, items, n: int,
+                   split: Optional[LaunchSplit]) -> None:
+        """One pool call over ``items``, annotated as the launch's ``pool``
+        where a batch controller runs this thread's launch
+        (``tracing.launch_annotation``); with ``split``, its seconds and
+        what its workers did (the items' own instants)."""
+        from flyimg_tpu.runtime import tracing
+
+        with tracing.launch_annotation("pool"):
+            called_ns = time.perf_counter_ns()
+            call(self._pool, items, n)
+            returned_ns = time.perf_counter_ns()
+        if split is not None:
+            split.native_s = (returned_ns - called_ns) * 1e-9
+            split.workers = self._workers
+            split.worker_s = sum(
+                items[i].t_end_ns - items[i].t_start_ns for i in range(n)
+            ) * 1e-9
+            split.wait_s = sum(
+                items[i].t_start_ns - called_ns for i in range(n)
+            ) * 1e-9
 
     def close(self) -> None:
         if self._pool:

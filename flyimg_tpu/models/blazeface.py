@@ -17,6 +17,7 @@ which is what __graft_entry__.dryrun_multichip exercises.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -278,32 +279,48 @@ def prepare_views(rgb: np.ndarray) -> FaceViews:
 
 
 def forward_views(params, works: List[FaceViews],
-                  stats: Optional[Dict[str, int]] = None):
+                  stats: Optional[Dict[str, float]] = None):
     """Every view of every work through ``_forward``, in chunks of the
     runtime's batch-bucket ceiling (runtime/batcher.py MAX_BATCH_BUCKET):
     a 64-image aux flush carries up to 6 views each, and one 512-wide
     forward would mean fresh XLA compiles for never-before-seen buckets
     at serve time, under burst load. Returns probabilities and boxes,
     one row a view, in order. ``stats``, where given, gains ``views``
-    (real inputs), ``slots`` (padded inputs run) and ``forwards``."""
+    (real inputs), ``slots`` (padded inputs run) and ``forwards``, and
+    the seconds of two parts, each chunk's summed: ``stack_s`` (the
+    views into one padded array) and ``forward_s`` (the array to the
+    device, the forward, both read-backs). Each part is also the
+    annotation of its name inside the launch that runs this
+    (``tracing.launch_annotation``)."""
+    from flyimg_tpu.runtime import tracing
     from flyimg_tpu.runtime.batcher import MAX_BATCH_BUCKET, _round_batch
 
     flat = [view for work in works for view in work.inputs]
     probs_parts, boxes_parts = [], []
     slots = 0
+    stack_s = forward_s = 0.0
     for start in range(0, len(flat), MAX_BATCH_BUCKET):
         chunk = flat[start : start + MAX_BATCH_BUCKET]
         nb = _round_batch(len(chunk))
-        inputs = np.zeros((nb, INPUT_SIZE, INPUT_SIZE, 3), np.float32)
-        np.stack(chunk, out=inputs[: len(chunk)])
-        p, b = _forward(params, jnp.asarray(inputs))
-        probs_parts.append(np.asarray(p)[: len(chunk)])
-        boxes_parts.append(np.asarray(b)[: len(chunk)])
+        t0 = time.perf_counter()
+        with tracing.launch_annotation("stack"):
+            inputs = np.zeros((nb, INPUT_SIZE, INPUT_SIZE, 3), np.float32)
+            np.stack(chunk, out=inputs[: len(chunk)])
+        t1 = time.perf_counter()
+        with tracing.launch_annotation("forward"):
+            p, b = _forward(params, jnp.asarray(inputs))
+            probs_parts.append(np.asarray(p)[: len(chunk)])
+            boxes_parts.append(np.asarray(b)[: len(chunk)])
+        t2 = time.perf_counter()
+        stack_s += t1 - t0
+        forward_s += t2 - t1
         slots += nb
     if stats is not None:
         stats["views"] = stats.get("views", 0) + len(flat)
         stats["slots"] = stats.get("slots", 0) + slots
         stats["forwards"] = stats.get("forwards", 0) + len(probs_parts)
+        stats["stack_s"] = stats.get("stack_s", 0.0) + stack_s
+        stats["forward_s"] = stats.get("forward_s", 0.0) + forward_s
     return np.concatenate(probs_parts), np.concatenate(boxes_parts)
 
 
@@ -313,7 +330,7 @@ def detect_prepared(
     *,
     score_threshold: float = 0.5,
     max_faces: int = MAX_FACES,
-    stats: Optional[Dict[str, int]] = None,
+    stats: Optional[Dict[str, float]] = None,
 ) -> List[List[Tuple[int, int, int, int]]]:
     """Prepared images -> boxes: every view of every image shares the
     fixed 128x128 network input, so the whole multiscale pyramid across
@@ -321,10 +338,27 @@ def detect_prepared(
     power-of-two ladder). Per image, view detections merge in one global
     NMS (anchors from a corner tile compete with full-frame anchors on
     score). THE detection path: ``detect_faces`` and the batched runner
-    (models/faces.py) both end here."""
+    (models/faces.py) both end here. ``stats`` as ``forward_views``',
+    and ``boxes_s``: the view boxes mapped back and the NMS of every
+    image (annotated ``boxes``)."""
+    from flyimg_tpu.runtime import tracing
+
     if not works:
         return []
     probs, boxes = forward_views(params, works, stats)
+    t0 = time.perf_counter()
+    with tracing.launch_annotation("boxes"):
+        out = _boxes_of(works, probs, boxes, score_threshold, max_faces)
+    if stats is not None:
+        stats["boxes_s"] = stats.get("boxes_s", 0.0) + (
+            time.perf_counter() - t0
+        )
+    return out
+
+
+def _boxes_of(works: List[FaceViews], probs: np.ndarray, boxes: np.ndarray,
+              score_threshold: float, max_faces: int):
+    """Each image's view boxes mapped back to the frame, then its NMS."""
     out: List[List[Tuple[int, int, int, int]]] = []
     vi = 0
     for work in works:

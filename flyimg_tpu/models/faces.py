@@ -108,7 +108,8 @@ class BlazeFaceBackend:
 
     def detect_faces_batched(self, items, stats=None) -> List[List[Box]]:
         """The aux runner. ``stats`` (a dict) gains the launch's ``views``,
-        ``slots`` and ``forwards`` (blazeface.forward_views)."""
+        ``slots`` and ``forwards``, and the seconds of its parts
+        (blazeface.detect_prepared)."""
         return self._bf.detect_prepared(
             self.params, items,
             score_threshold=self.score_threshold, stats=stats,

@@ -32,8 +32,10 @@ overlapping the D2H copy with compute (not yet measured on the chip:
 ROADMAP S3/D5). Every launch keeps ONE account of its phases (``_Launch``:
 fill, assemble, slot wait, h2d, dispatch, run, d2h, resolve) and hands it
 whole to the span, the histograms, the efficiency window and the flight
-recorder; every member's future carries its own queued / popped / ready
-instants (``launch_times``).
+recorder; every member's future carries its own queued / popped / ready /
+answered instants (``launch_times``). The transform controller also puts
+every second in which none of its launches ran down to what it was doing
+then (``devicegaps.GapAccount``).
 
 Failure containment (docs/resilience.md): sharing a batch must not mean
 sharing its failures. A failed launch is classified
@@ -80,6 +82,7 @@ from flyimg_tpu.ops.compose import (
 )
 from flyimg_tpu.ops.resample import kernel_mode, select_band_taps
 from flyimg_tpu.runtime import costledger, tracing
+from flyimg_tpu.runtime.devicegaps import GapAccount
 from flyimg_tpu.runtime.resilience import (
     OVERSIZE,
     POISON,
@@ -298,24 +301,33 @@ class _Launch:
     after the dispatch, plus run, plus d2h, exactly (the three laps share
     their end points). An aux launch has ``fill``, ``run`` (the runner
     call) and ``resolve``. ``marks`` holds ``time.perf_counter()`` pairs;
-    ``cpu_s`` the process CPU seconds (``time.process_time()``, all
-    threads) spent during ``assemble`` and ``h2d``: where the phases of a
-    cycle are serial, ``cpu_s / seconds`` says whether the host computed
-    through a phase or waited through it; ``transfer_bytes`` the bytes
-    staged (``h2d``) and read back (``d2h``). Every phase is also opened as a
-    ``jax.profiler.TraceAnnotation`` named ``flyimg:batch:<seq>:<phase>``
-    on the thread that runs it (``h2d`` is two: ``h2d`` around the staging
-    call, ``h2d_wait`` around the wait), so a profiler trace carries the
-    same intervals on the device trace's clock."""
+    ``cpu_s`` the CPU seconds of the one thread that ran ``assemble`` and
+    ``resolve`` (``time.thread_time()``), and the executor's over the
+    staging call that opens ``h2d``: over the phase's seconds, the share
+    its thread computed rather than waited; ``transfer_bytes`` the bytes
+    staged (``h2d``) and read back (``d2h``). Every phase is also opened as
+    a ``jax.profiler.TraceAnnotation`` named ``flyimg:batch:<seq>:<phase>``
+    (an aux launch's: ``flyimg:aux:<controller>:<seq>:<phase>``) on the
+    thread that runs it (``h2d`` is two: ``h2d`` around the staging call,
+    ``h2d_wait`` around the wait; each member's answer is ``answer`` inside
+    ``resolve``), so a profiler trace carries the same intervals on the
+    device trace's clock. A transform launch of the transform controller
+    also tells the controller's ``GapAccount`` each phase it enters
+    (``gaps``): popped, ``staging`` at the staging call, ``run`` when its
+    inputs are on the device, ``d2h``, ``resolve`` from the read-back's end,
+    done when it is answered or has failed (``settle``)."""
 
     __slots__ = (
         "seq", "kind", "aux", "images", "capacity", "popped",
         "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
-        "block", "transfer_bytes", "_cursor", "_opened",
+        "block", "transfer_bytes", "_cursor", "_opened", "prefix",
+        "_gaps", "_at",
     )
 
     def __init__(self, seq: int, members: List[_Pending], *,
-                 kind: str = "primary", aux: bool = False) -> None:
+                 kind: str = "primary", aux: bool = False,
+                 controller: str = TRANSFORM_CONTROLLER,
+                 gaps: Optional[GapAccount] = None) -> None:
         self.seq = seq
         self.kind = kind
         self.aux = aux
@@ -341,43 +353,60 @@ class _Launch:
         self.block: Optional[np.ndarray] = None
         self._cursor = self.popped
         self._opened = None
+        # aux launches number their own controller's sequence: their names
+        # carry the controller, apart from the transform launches'
+        self.prefix = (
+            f"flyimg:aux:{controller}:{seq}" if aux else f"flyimg:batch:{seq}"
+        )
+        self._gaps = None if aux else gaps
+        self._at: Optional[str] = None
+        self._enter("launch", self.popped)
 
     def annotate(self, label: str):
-        if self.aux:
-            # aux launches number their own controller's sequence: keep
-            # them apart from the device launches' names
-            return jax.profiler.TraceAnnotation(
-                f"flyimg:aux:{self.seq}:{label}"
-            )
-        return jax.profiler.TraceAnnotation(
-            f"flyimg:batch:{self.seq}:{label}"
-        )
+        return jax.profiler.TraceAnnotation(f"{self.prefix}:{label}")
+
+    def _enter(self, category: Optional[str],
+               now: Optional[float] = None) -> None:
+        """Tell the controller's gap account the launch is in
+        ``category`` now (None: done)."""
+        if self._gaps is not None and category != self._at:
+            self._gaps.move(self._at, category, now)
+            self._at = category
+
+    def settle(self) -> None:
+        """The launch is answered or has failed: it holds nothing of the
+        controller's any more."""
+        self._enter(None)
 
     @contextmanager
     def phase(self, name: str, *, cpu: bool = False):
         """Time (and annotate) a phase that starts and ends on this
-        thread."""
+        thread; ``cpu``: with the thread's own CPU seconds over it."""
         with self.annotate(name):
-            cpu0 = time.process_time() if cpu else 0.0
+            cpu0 = time.thread_time() if cpu else 0.0
             t0 = time.perf_counter()
             try:
                 yield
             finally:
                 self.marks[name] = (t0, time.perf_counter())
                 if cpu:
-                    self.cpu_s[name] = time.process_time() - cpu0
+                    self.cpu_s[name] = time.thread_time() - cpu0
+                if name == "resolve":
+                    self.settle()
 
     def open(self, name: str) -> None:
         """Start a phase that another thread will ``close`` (h2d: staged
         on the executor, waited for on the drain thread)."""
-        self._opened = (name, time.perf_counter(), time.process_time())
+        self._opened = (name, time.perf_counter())
+        self._enter("staging", self._opened[1])
 
     def close(self) -> None:
-        """End the ``open`` phase now; ``lap`` continues from here."""
-        name, t0, cpu0 = self._opened
+        """End the ``open`` phase now; ``lap`` continues from here, and
+        the launch runs."""
+        name, t0 = self._opened
         self._cursor = time.perf_counter()
         self.marks[name] = (t0, self._cursor)
-        self.cpu_s[name] = time.process_time() - cpu0
+        self._enter("run", self._cursor)
 
     def lap(self, name: str) -> None:
         """End phase ``name`` now; it began where the last lap (or
@@ -385,6 +414,7 @@ class _Launch:
         now = time.perf_counter()
         self.marks[name] = (self._cursor, now)
         self._cursor = now
+        self._enter(_GAP_AFTER_LAP.get(name, self._at), now)
 
     def seconds(self, name: str) -> Optional[float]:
         mark = self.marks.get(name)
@@ -433,6 +463,12 @@ class _Launch:
         device_s = self.device_s
         if device_s is not None and not self.aux:
             span_obj.set_attribute("device.seconds", round(device_s, 6))
+
+
+# what the gap account (``GapAccount``) reads a transform launch as doing
+# from the end of each lap (from the pop it is ``launch``, from the staging
+# call ``staging``, from the end of ``h2d`` ``run``)
+_GAP_AFTER_LAP = {"run": "d2h", "d2h": "resolve"}
 
 
 # phase -> (shared span attribute, flight-recorder field): ``batch.*`` is
@@ -613,6 +649,12 @@ class BatchController:
         # disabled path is byte-identical.
         self.governor = governor
         self._ledger = costledger.get_ledger()
+        # what the transform controller was doing while none of its
+        # launches ran (runtime/devicegaps.py); a controller of aux work
+        # alone (the codec controller) keeps none
+        self._gaps = (
+            GapAccount(self.metrics) if name == TRANSFORM_CONTROLLER else None
+        )
         # admission control: "pending" = submitted and not yet resolved
         # (queued OR executing). When the bound is hit, submit sheds with
         # a 503 + Retry-After instead of queueing into collapse; 0 keeps
@@ -944,6 +986,7 @@ class BatchController:
                 group.members.remove(pending)
                 if not group.members and self._groups.get(group.key) is group:
                     del self._groups[group.key]
+                self._note_queue_locked()
             if not group.copying:
                 # the group's last copy in flight: only now can it be ready
                 self._lock.notify()
@@ -1030,6 +1073,7 @@ class BatchController:
                 else:
                     self._lock.notify()
                 group.members.append(pending)
+                self._note_queue_locked()
         except BaseException:
             if not pending.future.done():
                 self.admission.release()
@@ -1050,6 +1094,14 @@ class BatchController:
                         self._executor_pending = False
                     raise
         return reserved
+
+    def _note_queue_locked(self) -> None:
+        """Tell the gap account whether transform members are queued
+        (caller holds the lock)."""
+        if self._gaps is not None:
+            self._gaps.queued(any(
+                g.members and g.runner is None for g in self._groups.values()
+            ))
 
     def _maybe_heal_executor_locked(self) -> Optional[threading.Thread]:
         """Executor self-healing, checked at every submission (caller
@@ -1525,6 +1577,7 @@ class BatchController:
             mem_cap=mem_cap,
             block=block,
         )
+        self._note_queue_locked()
         return ready
 
     # ------------------------------------------------------------------
@@ -1558,7 +1611,6 @@ class BatchController:
         span_obj.set_attribute(
             "batch.id", launch.seq if launch is not None else self._batch_seq
         )
-        span_obj.set_attribute("batch.controller", self.name)
         span_obj.set_attribute("batch.occupancy", n)
         span_obj.set_attribute("batch.size", batch)
         span_obj.set_attribute("batch.padded_slots", batch - n)
@@ -1651,7 +1703,9 @@ class BatchController:
         if seconds is None:
             return
         if not launch.aux:
-            self.metrics.record_launch_resolve(seconds)
+            self.metrics.record_launch_resolve(
+                seconds, launch.cpu_s.get("resolve")
+            )
         if row is not None:
             row["resolve_s"] = round(seconds, 6)
         for copy in span_copies:
@@ -1694,10 +1748,12 @@ class BatchController:
         # (the other half is device_s, measured at readback)
         if group.runner is not None:
             self._execute_aux(
-                group, members, _Launch(seq, members, kind="aux", aux=True)
+                group, members,
+                _Launch(seq, members, kind="aux", aux=True,
+                        controller=self.name),
             )
             return
-        launch = _Launch(seq, members)
+        launch = _Launch(seq, members, gaps=self._gaps)
         launch.block = block
         span_obj = None
         fn = None
@@ -1722,7 +1778,6 @@ class BatchController:
                     "program.compile_cache",
                     "hit" if launch.compile_hit else "miss",
                 )
-                span_obj.set_attribute("program.in_shape", str(group.in_shape))
                 if group.mem_cap is not None:
                     # the pre-split happened on the executor thread with
                     # no ambient trace — the decision rides the shared
@@ -1777,6 +1832,7 @@ class BatchController:
                 raise
         except Exception as exc:
             launch.block = None
+            launch.settle()
             if profiler_poked:
                 # a failed dispatch never reaches _drain's finally — the
                 # armed capture's batch budget must still decrement or
@@ -1805,18 +1861,8 @@ class BatchController:
         hung-native-pool wedge worth re-homing the queue over)."""
         n = len(members)
         span_obj = self._start_batch_span("aux_execute", n, n, members, launch)
-        if span_obj is not None:
-            span_obj.set_attribute(
-                "batch.runner", getattr(group.runner, "__name__", "aux")
-            )
         try:
-            with launch.phase("run"):
-                outputs = group.runner([m.image for m in members])
-            if len(outputs) != n:
-                raise RuntimeError(
-                    f"aux runner returned {len(outputs)} results for "
-                    f"{n} payloads"
-                )
+            outputs = self._run_aux(group, members, launch)
             # aux items are requests already counted by their transform
             # batch — separate counters so images_processed/occupancy
             # keep meaning "images through the transform pipeline"
@@ -1837,7 +1883,7 @@ class BatchController:
             )
             row = self._record_flight(group, members, launch)
             copies = self._end_batch_span(span_obj, members, launch)
-            with launch.phase("resolve"):
+            with launch.phase("resolve", cpu=True):
                 self._resolve_members(group, members, outputs, launch)
             self._publish_resolve(launch, row, copies)
         except Exception as exc:
@@ -1847,6 +1893,26 @@ class BatchController:
                 group, members, launch, error=type(exc).__name__
             )
             self._recover(group, members, exc)
+
+    def _run_aux(self, group: _Group, members: List[_Pending],
+                 launch: _Launch) -> list:
+        """The ``run`` of an aux launch: ``runner(payloads)`` on this
+        thread, named as the launch for what the runner annotates, and
+        counted by the gap account as an aux runner while it runs."""
+        if self._gaps is not None:
+            self._gaps.aux(+1)
+        try:
+            with launch.phase("run"), tracing.launch_scope(launch.prefix):
+                outputs = group.runner([m.image for m in members])
+        finally:
+            if self._gaps is not None:
+                self._gaps.aux(-1)
+        if len(outputs) != len(members):
+            raise RuntimeError(
+                f"aux runner returned {len(outputs)} results for "
+                f"{len(members)} payloads"
+            )
+        return outputs
 
     def _padded_batch(self, n: int) -> int:
         """The padded device batch one launch of ``n`` members actually
@@ -1963,7 +2029,9 @@ class BatchController:
         the transfers have happened; ``_await_launch`` closes the phase."""
         launch.open("h2d")
         with launch.annotate("h2d"):
+            cpu0 = time.thread_time()
             launch.dev_args = fn.stage(arrays)
+            launch.cpu_s["h2d"] = time.thread_time() - cpu0
         launch.transfer_bytes["h2d"] = sum(a.nbytes for a in arrays)
 
     def _resolve_members(self, group: _Group, members: List[_Pending],
@@ -1974,26 +2042,31 @@ class BatchController:
         late) must skip, not raise InvalidStateError mid-loop — which
         previously diverted to the except path and wrongly failed every
         remaining member of the batch. Each future first gets the
-        member's own three instants (``launch_times``: queued, its launch
-        popped, its result ready; ``time.perf_counter()``), from which
-        the handler fills the request's ``*_queue`` / ``*_run`` stages.
-        An answered member lets its ``image`` go at once: nothing recovers
-        it after, and the launch's other members may take a second to be
-        answered, while its caller goes on to decode its next frame."""
+        member's own four instants (``launch_times``: queued, its launch
+        popped, its result ready, and its ``set_result``, which is
+        ``answered``; ``time.perf_counter()``), from which the handler
+        fills the request's ``*_queue`` / ``*_run`` stages and its place in
+        this loop. Each member's slice, copy and ``set_result`` are the
+        annotation ``answer`` of the launch. An answered member lets its
+        ``image`` go at once: nothing recovers it after, and the launch's
+        other members may take a second to be answered, while its caller
+        goes on to decode its next frame."""
         ready = time.perf_counter()
         for i, member in enumerate(members):
-            result = outputs[i]
-            if group.runner is None:
-                if member.needs_slice:
-                    th, tw = member.final_true
-                    result = result[: int(th), : int(tw)]
-                result = np.ascontiguousarray(result)
-            if not member.future.done():
-                member.future.launch_times = (
-                    member.enqueued_pc, launch.popped, ready
-                )
-                member.future.set_result(result)
-                member.image = None
+            with launch.annotate("answer"):
+                result = outputs[i]
+                if group.runner is None:
+                    if member.needs_slice:
+                        th, tw = member.final_true
+                        result = result[: int(th), : int(tw)]
+                    result = np.ascontiguousarray(result)
+                if not member.future.done():
+                    member.future.launch_times = (
+                        member.enqueued_pc, launch.popped, ready,
+                        time.perf_counter(),
+                    )
+                    member.future.set_result(result)
+                    member.image = None
 
     def _await_launch(self, launch: _Launch, dev_out):
         """The device side of one launch after its dispatch, in three
@@ -2079,11 +2152,12 @@ class BatchController:
             row, copies = self._launch_done(
                 group, members, launch, fn, span_obj
             )
-            with launch.phase("resolve"):
+            with launch.phase("resolve", cpu=True):
                 self._resolve_members(group, members, out, launch)
             self._publish_resolve(launch, row, copies)
         except Exception as exc:
             launch.dev_args = launch.block = None
+            launch.settle()
             if span_obj is not None and span_obj.duration_s is None:
                 # not yet ended -> the failure happened before the attach
                 # above; record and attach the errored span instead
@@ -2097,6 +2171,7 @@ class BatchController:
             )
             self._recover(group, members, exc)
         finally:
+            launch.settle()
             if self.profiler is not None:
                 self.profiler.on_batch_end()
             (inflight if inflight is not None else self._inflight).release()
@@ -2356,10 +2431,19 @@ class BatchController:
             self._batch_seq += 1
             seq = self._batch_seq
         self._touch_busy()  # each recovery launch is wedge-clock progress
-        n = len(members)
         launch = _Launch(
-            seq, members, kind="recovery", aux=group.runner is not None
+            seq, members, kind="recovery", aux=group.runner is not None,
+            controller=self.name, gaps=self._gaps,
         )
+        try:
+            self._run_recovery(group, members, launch)
+        finally:
+            launch.settle()
+
+    def _run_recovery(self, group: _Group, members: List[_Pending],
+                      launch: _Launch) -> None:
+        """``_run_members``' one launch, whatever its kind."""
+        n = len(members)
         if group.runner is not None:
             for i, member in enumerate(members):
                 faults.fire(
@@ -2368,13 +2452,7 @@ class BatchController:
                     index=i,
                     image=member.image,
                 )
-            with launch.phase("run"):
-                outputs = group.runner([m.image for m in members])
-            if len(outputs) != n:
-                raise RuntimeError(
-                    f"aux runner returned {len(outputs)} results for "
-                    f"{n} payloads"
-                )
+            outputs = self._run_aux(group, members, launch)
             faults.fire("batcher.drain", key=group.key, n=n, batch=n)
             self.metrics.record_launch(
                 self.aux_name, launch,
@@ -2396,7 +2474,7 @@ class BatchController:
                 self.profiler.on_batch_start()
             try:
                 self._stage(launch, fn, arrays)
-                with jax.profiler.TraceAnnotation(f"flyimg:batch:{seq}"):
+                with jax.profiler.TraceAnnotation(f"flyimg:batch:{launch.seq}"):
                     with launch.phase("dispatch"):
                         dev_out = fn(*launch.dev_args)
                 self._touch_busy()  # dispatch returned: progress
@@ -2407,6 +2485,6 @@ class BatchController:
                 if self.profiler is not None:
                     self.profiler.on_batch_end()
             row, copies = self._launch_done(group, members, launch, fn)
-        with launch.phase("resolve"):
+        with launch.phase("resolve", cpu=True):
             self._resolve_members(group, members, outputs, launch)
         self._publish_resolve(launch, row, copies)

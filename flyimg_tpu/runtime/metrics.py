@@ -468,6 +468,18 @@ class MetricsRegistry:
             "Per-stage pipeline latency",
         ).observe(seconds, trace_id=self._exemplar_trace_id())
 
+    def record_stage_thread(self, stage: str, seconds: float) -> None:
+        """The calling thread's own CPU seconds over one stage
+        (``tracing.stage``), beside its wall seconds in
+        ``flyimg_stage_seconds``: over the sum of those, the share of the
+        stage the thread computed rather than waited."""
+        self.counter(
+            "flyimg_stage_thread_seconds_total"
+            f'{{stage="{escape_label_value(stage)}"}}',
+            "Per-stage CPU seconds of the thread that ran the stage "
+            "(time.thread_time())",
+        ).inc(max(float(seconds), 0.0))
+
     def record_device_batch_seconds(
         self, seconds: float, trace_id: Optional[str] = None
     ) -> None:
@@ -530,11 +542,28 @@ class MetricsRegistry:
             aux=launch.aux,
         )
 
-    def record_launch_resolve(self, seconds: float) -> None:
+    def record_launch_resolve(self, seconds: float,
+                              thread_s: Optional[float] = None) -> None:
+        """A transform launch's ``resolve``: its seconds, and the drain
+        thread's own CPU seconds over them (``time.thread_time()``)."""
         self.histogram(
             "flyimg_batch_resolve_seconds",
             "Per transform launch: slicing and copying each member's "
             "output out of the batch and resolving its future",
+        ).observe(max(float(seconds), 0.0))
+        if thread_s is not None:
+            self.counter(
+                "flyimg_batch_resolve_thread_seconds_total",
+                "CPU seconds of the drain thread over transform launches' "
+                "resolve: over flyimg_batch_resolve_seconds_sum, the share "
+                "of the resolve it computed rather than waited",
+            ).inc(max(float(thread_s), 0.0))
+
+    def record_member_wake(self, seconds: float) -> None:
+        self.histogram(
+            "flyimg_batch_wake_seconds",
+            "Per member of a transform launch: its future's set_result on "
+            "the drain thread until its caller's result() returned",
         ).observe(max(float(seconds), 0.0))
 
     def record_member_copy(self, seconds: float) -> None:
@@ -595,6 +624,26 @@ class MetricsRegistry:
                 "as the decoder writes it)",
             ).inc(frames)
 
+    def record_codec_workers(self, op: str, split) -> None:
+        """What one pool launch's workers did (``LaunchSplit``): the seconds
+        they spent on its items, the seconds its items waited from the
+        call to a worker's start, and the workers the call held for its
+        length (the pool's size times the call's seconds)."""
+        self.counter(
+            f'flyimg_codec_worker_seconds_total{{op="{op}"}}',
+            "Seconds the native codec pool's workers spent on items",
+        ).inc(max(float(split.worker_s), 0.0))
+        self.counter(
+            f'flyimg_codec_worker_wait_seconds_total{{op="{op}"}}',
+            "Seconds the native codec pool's items waited from the pool "
+            "call to a worker's start",
+        ).inc(max(float(split.wait_s), 0.0))
+        self.counter(
+            f'flyimg_codec_worker_capacity_seconds_total{{op="{op}"}}',
+            "Worker-seconds the native codec pool's calls held: workers "
+            "times the call's seconds",
+        ).inc(max(float(split.workers * split.native_s), 0.0))
+
     def record_codec_buffers(self, handover: str, buffers: int,
                              nbytes: int) -> None:
         """Native buffers the codec pool's launches handed over, by how:
@@ -631,6 +680,16 @@ class MetricsRegistry:
             "flyimg_face_boxes_total",
             "Face boxes kept after NMS",
         ).inc(boxes)
+        for part in FACE_DETECT_PARTS:
+            seconds = stats.get(f"{part}_s")
+            if seconds is not None:
+                self.counter(
+                    f'flyimg_face_detect_seconds_total{{part="{part}"}}',
+                    "Seconds of face-detection launches by part: stack "
+                    "(the views into one padded array), forward (each "
+                    "chunk's transfer, forward and read-back) and boxes "
+                    "(view boxes mapped back, NMS an image)",
+                ).inc(max(float(seconds), 0.0))
 
     def record_face_pixelate_launch(self, stats: dict) -> None:
         """One ``fb_1`` pixelation aux launch: its images, the padded
@@ -648,6 +707,15 @@ class MetricsRegistry:
             "flyimg_face_pixelate_launches_total",
             "Calls of the batched face-pixelation program",
         ).inc(stats.get("launches", 0))
+
+    def record_device_gap(self, during: str, seconds: float) -> None:
+        self.counter(
+            f'flyimg_device_gap_seconds_total{{during="{during}"}}',
+            "Seconds since the device controller's first run in which no "
+            "transform launch ran, by what the controller was doing "
+            "(runtime/devicegaps.py); aux_overlap: of those, the seconds "
+            "an aux runner ran",
+        ).inc(max(float(seconds), 0.0))
 
     def record_compile_event(self, cache_hit: bool) -> None:
         """Batched-program compile cache outcome per device batch."""
@@ -946,6 +1014,106 @@ class MetricsRegistry:
             "stages": stages,
             "device": device_doc,
         }
+
+
+class GcWatch:
+    """The process's garbage collections into one registry, while open:
+    ``flyimg_gc_seconds_total{generation}`` and
+    ``flyimg_gc_collections_total{generation}``, and a log line naming the
+    thread each full (generation 2) collection ran on. One ``gc.callbacks``
+    entry serves every open watch and is removed with the last. The
+    collector calls it with the lock of whatever the thread was doing held,
+    a registry's among them, so the counters are made when the watch opens
+    and the callback takes no registry lock."""
+
+    def __init__(self, registry: "MetricsRegistry") -> None:
+        self._counters = {}
+        for generation in range(3):
+            self._counters[generation] = (
+                registry.counter(
+                    f'flyimg_gc_seconds_total{{generation="{generation}"}}',
+                    "Seconds the process spent in the garbage collector, "
+                    "by the generation collected",
+                ),
+                registry.counter(
+                    "flyimg_gc_collections_total"
+                    f'{{generation="{generation}"}}',
+                    "Garbage collections, by the generation collected",
+                ),
+            )
+        _gc_hook.add(self)
+
+    def record(self, generation: int, seconds: float) -> None:
+        counters = self._counters.get(generation)
+        if counters is not None:
+            counters[0].inc(seconds)
+            counters[1].inc()
+
+    def close(self) -> None:
+        _gc_hook.discard(self)
+
+
+class _GcHook:
+    """The one ``gc.callbacks`` entry behind every open ``GcWatch``. The
+    collector runs one collection at a time and calls ``start`` and
+    ``stop`` on the thread that triggered it."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._watches: Tuple = ()
+        self._started: Optional[float] = None
+
+    def add(self, watch: GcWatch) -> None:
+        import gc
+        import weakref
+
+        with self._lock:
+            if self not in gc.callbacks:
+                gc.callbacks.append(self)
+            self._watches = tuple(
+                ref for ref in self._watches if ref() is not None
+            ) + (weakref.ref(watch),)
+
+    def discard(self, watch: GcWatch) -> None:
+        import gc
+
+        with self._lock:
+            kept = tuple(
+                ref for ref in self._watches
+                if ref() is not None and ref() is not watch
+            )
+            if not kept and self in gc.callbacks:
+                gc.callbacks.remove(self)
+            self._watches = kept
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._started = time.perf_counter()
+            return
+        started, self._started = self._started, None
+        if started is None:
+            return
+        seconds = time.perf_counter() - started
+        generation = info.get("generation", 0)
+        for ref in self._watches:
+            watch = ref()
+            if watch is not None:
+                watch.record(generation, seconds)
+        if generation == 2:
+            import logging
+
+            logging.getLogger("flyimg.gc").info(
+                "gc.full %.6f s on thread %s", seconds,
+                threading.current_thread().name,
+            )
+
+
+_gc_hook = _GcHook()
+
+
+#: the parts of a face-detection launch (models/blazeface.py
+#: ``detect_prepared``: ``stats["<part>_s"]``)
+FACE_DETECT_PARTS = ("stack", "forward", "boxes")
 
 
 # one histogram per phase of a transform launch (runtime/batcher.py

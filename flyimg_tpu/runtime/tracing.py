@@ -36,7 +36,7 @@ import random
 import re
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
 
 __all__ = [
@@ -49,6 +49,9 @@ __all__ = [
     "span",
     "stage",
     "stage_interval",
+    "annotation",
+    "launch_scope",
+    "launch_annotation",
     "add_event",
     "parse_traceparent",
     "format_traceparent",
@@ -359,19 +362,56 @@ def span(name: str, **attrs):
         _close_child(child, None)
 
 
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: the interval it
+    is entered for lands on a device trace's timeline, on the thread that
+    enters it, while a profiler is on (a relaxed atomic read while none is).
+    jax is imported on first use: nothing here needs it before."""
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+@contextmanager
+def launch_scope(prefix: str):
+    """Name this thread's launch while a batch controller's runner runs it:
+    ``launch_annotation`` opens its children under ``prefix`` (the batcher
+    passes ``flyimg:aux:<controller>:<seq>``)."""
+    prev = getattr(_local, "launch", None)
+    _local.launch = prefix
+    try:
+        yield
+    finally:
+        _local.launch = prev
+
+
+def launch_annotation(label: str):
+    """The annotation ``<prefix>:<label>`` of the launch this thread runs
+    (``launch_scope``), or a no-op outside one: what a runner annotates
+    inside its launch, the codec pool's call or a face launch's parts."""
+    prefix = getattr(_local, "launch", None)
+    if prefix is None:
+        return nullcontext()
+    return annotation(f"{prefix}:{label}")
+
+
 class stage:
-    """One pipeline stage, timed once for all three of its readers: on a
+    """One pipeline stage, timed once for all four of its readers: on a
     clean exit the seconds land in ``timings[name]``, in
-    ``flyimg_stage_seconds{stage=name}`` when a registry is given, and in
-    a child span (``span_name``, default ``name``) when a trace is active
-    on this thread. The served path and ``transform_bytes`` both time
-    their stages through this one helper, so they record the same series.
-    With no trace active no ``Span`` is allocated and ``with`` yields None;
-    a stage that raises ends its span as an error and records nothing else
-    (the request failed; its partial stage is no latency sample)."""
+    ``flyimg_stage_seconds{stage=name}`` when a registry is given, beside
+    the calling thread's own CPU seconds over the stage
+    (``time.thread_time()``: ``flyimg_stage_thread_seconds_total``), and
+    in a child span (``span_name``, default ``name``) when a trace is
+    active on this thread; the stage is also the annotation
+    ``flyimg:stage:<name>`` on this thread. The served path and
+    ``transform_bytes`` both time their stages through this one helper, so
+    they record the same series. With no trace active no ``Span`` is
+    allocated and ``with`` yields None; a stage that raises ends its span
+    as an error and records nothing else (the request failed; its partial
+    stage is no latency sample)."""
 
     __slots__ = ("name", "timings", "metrics", "span_name", "attrs",
-                 "_span", "_t0")
+                 "_span", "_t0", "_cpu0", "_note")
 
     def __init__(self, name: str, timings: Dict[str, float], metrics=None,
                  *, span_name: Optional[str] = None, **attrs) -> None:
@@ -386,15 +426,22 @@ class stage:
         trace = current_trace()
         if trace is not None:
             self._span = _open_child(trace, self.span_name, self.attrs)
+        self._note = annotation(f"flyimg:stage:{self.name}")
+        self._note.__enter__()
+        self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         seconds = time.perf_counter() - self._t0
+        thread_s = time.thread_time() - self._cpu0
+        self._note.__exit__(exc_type, exc, tb)
         if self._span is not None:
             _close_child(self._span, exc)
         if exc is None:
             _record_stage(self.name, seconds, self.timings, self.metrics)
+            if self.metrics is not None:
+                self.metrics.record_stage_thread(self.name, thread_s)
         return False
 
 
@@ -409,11 +456,14 @@ def stage_interval(name: str, start: float, end: float,
                    timings: Dict[str, float], metrics=None, *,
                    span_name: Optional[str] = None) -> None:
     """``stage`` for an interval that was timed elsewhere: the batcher
-    stamps each member with when it was queued, when its launch was popped
-    and when its result was ready (``time.perf_counter()`` readings), and
-    the handler turns those into the ``*_queue`` / ``*_run`` stages of the
-    request that waited. Same three readers; the span (when a trace is
-    active) is a finished child of the current one over that interval."""
+    stamps each member with when it was queued, when its launch was popped,
+    when its result was ready and when it was answered
+    (``time.perf_counter()`` readings), and the handler turns those into
+    the ``*_queue`` / ``*_run`` stages of the request that waited. The
+    timings, the histogram (when a registry is given) and the span (when a
+    trace is active: a finished child of the current one over that
+    interval). The interval was spent on other threads, or waiting: it has
+    no thread CPU and no annotation of its own."""
     trace = current_trace()
     if trace is not None:
         parent = current_span()

@@ -788,6 +788,7 @@ def make_app(params: Optional[AppParameters] = None) -> web.Application:
         supervisor.close()
         batcher.close(drain_timeout_s)
         codec_batcher.close(drain_timeout_s)
+        handler.close()
         host_pipeline.close(drain_timeout_s)
         # phase 2: the drains finished — publish what this replica
         # compiled for the next scale-out, release the membership

@@ -33,6 +33,7 @@ from flyimg_tpu.exceptions import (
 )
 from flyimg_tpu.ops.compose import run_plan
 from flyimg_tpu.runtime import tracing
+from flyimg_tpu.runtime.metrics import GcWatch
 from flyimg_tpu.runtime.resilience import Deadline
 from flyimg_tpu.runtime.variantindex import VariantFacts, VariantIndex
 from flyimg_tpu.service.input_source import FetchPolicy, load_source
@@ -165,6 +166,9 @@ class ImageHandler:
         # serializing against device launches
         self.codec_batcher = codec_batcher
         self.metrics = metrics  # runtime.metrics.MetricsRegistry or None
+        # the process's garbage collections into that registry, until
+        # close()
+        self._gc_watch = GcWatch(metrics) if metrics is not None else None
         # multi-device mesh with an 'sp' axis: very large inputs shard
         # H-wise with ppermute halo exchange (parallel/tiling.py — the
         # image-domain analog of context parallelism, SURVEY.md section 5)
@@ -266,6 +270,14 @@ class ImageHandler:
         self.max_source_pixels = int(
             params.by_key("mem_max_source_pixels", 0) or 0
         )
+
+    def close(self) -> None:
+        """Stop recording the process's garbage collections into this
+        handler's registry. The controllers it was given are their
+        owner's to close."""
+        if self._gc_watch is not None:
+            self._gc_watch.close()
+            self._gc_watch = None
 
     def _stage(self, name: str, fn, deadline: Optional[Deadline],
                *, inline_fallback: bool = True):
@@ -1167,11 +1179,12 @@ class ImageHandler:
         results = batch_jpeg_decode(items, split)
         if self.metrics is not None:
             self.metrics.record_codec_decode_launch(split)
+            self.metrics.record_codec_workers("decode", split)
         return results
 
     def _encode_launch(self, items: list) -> list:
         """The encode-side twin of :meth:`_decode_launch`: the launch's
-        buffers alone are counted (each copied once into ``bytes``)."""
+        buffers (each copied once into ``bytes``) and its workers."""
         from flyimg_tpu.codecs import batch_jpeg_encode, native_codec
 
         split = native_codec.LaunchSplit()
@@ -1180,15 +1193,16 @@ class ImageHandler:
             self.metrics.record_codec_buffers(
                 "bytes", split.buffers, split.buffer_bytes
             )
+            self.metrics.record_codec_workers("encode", split)
         return results
 
     def _face_detect_launch(self, items: list) -> list:
         """The device controller's runner for a face-detection group: the
         backend's batched detector, then what the launch says of itself
         into this handler's registry (blazeface: the network inputs it
-        ran, real and padded, and its forward launches; any detector: the
-        boxes it kept)."""
-        stats: Dict[str, int] = {}
+        ran, real and padded, its forward launches and the seconds of each
+        part of the launch; any detector: the boxes it kept)."""
+        stats: Dict[str, float] = {}
         results = self._faces().detect_faces_batched(items, stats)
         if self.metrics is not None:
             self.metrics.record_face_detect_launch(
@@ -1247,7 +1261,7 @@ class ImageHandler:
         result = future.result(timeout=self._device_wait_s(deadline))
         times = getattr(future, "launch_times", None)
         if times is not None:
-            queued, popped, ready = times
+            queued, popped, ready, _ = times
             tracing.stage_interval(
                 f"{stage}_queue", queued, popped, timings, self.metrics,
                 span_name=f"{stage}.queue",
@@ -1270,9 +1284,12 @@ class ImageHandler:
         an exhausted budget is a 504 (fail fast, no further waiting); a
         wedged executor falls back to the direct single-image program in
         THIS thread (degraded but correct) or, with the fallback disabled,
-        sheds as a 503."""
+        sheds as a 503. A member answered by its launch records how long
+        this thread took to wake after its ``set_result``
+        (``launch_times``' last instant; ``flyimg_batch_wake_seconds``),
+        and keeps the instant it woke on the future (``woke``)."""
         try:
-            return future.result(timeout=self._device_wait_s(deadline))
+            result = future.result(timeout=self._device_wait_s(deadline))
         except FutureTimeout:
             if deadline is not None:
                 deadline.check("device")
@@ -1283,6 +1300,12 @@ class ImageHandler:
                 "device executor did not produce a result in time"
             )
             raise exc from None
+        times = getattr(future, "launch_times", None)
+        if times is not None:
+            future.woke = time.perf_counter()
+            if self.metrics is not None:
+                self.metrics.record_member_wake(future.woke - times[3])
+        return result
 
     def _tiled_or_none(self, frame: np.ndarray, plan: TransformPlan):
         """Run an H-sharded tiled program when one applies to a tall input:
@@ -1748,7 +1771,6 @@ class ImageHandler:
                 )
             decode_mode = self._decode_mode(decoded, data_info, hint)
             if decode_span is not None:
-                decode_span.set_attribute("decode.mime", data_info.mime)
                 decode_span.set_attribute("decode.batched", batched_decode)
                 decode_span.set_attribute("decode.mode", decode_mode)
         # the per-mode stage series feeds the perf-gate's decode-mode
@@ -1942,11 +1964,25 @@ class ImageHandler:
                 if isinstance(s, Future) and hasattr(s, "launch_times")
             ]
             if waits:
-                queued, popped, _ = max(waits, key=lambda w: w[1] - w[0])
+                queued, popped, _, _ = max(waits, key=lambda w: w[1] - w[0])
                 tracing.stage_interval(
                     "device_queue", queued, popped, timings, self.metrics,
                     span_name="device.queue",
                 )
+            # where the request sat in its launch's resolve loop (its
+            # result ready -> its set_result) and how long this thread
+            # took to wake after it (of an animation's frames, the
+            # latest to wake)
+            woken = [
+                (s.launch_times, s.woke) for s, _, _, _ in staged
+                if isinstance(s, Future) and hasattr(s, "woke")
+            ]
+            if woken:
+                (_, _, ready, answered), woke = max(
+                    woken, key=lambda w: w[1] - w[0][3]
+                )
+                timings["device_answer"] = answered - ready
+                timings["device_wake"] = woke - answered
 
         # post-passes on the transformed output, in reference order:
         # smart-crop, then face blur, then face crop — all skipped for GIF
@@ -2040,7 +2076,7 @@ class ImageHandler:
         if deadline is not None:
             deadline.check("encode")
         with tracing.stage("encode", timings, self.metrics,
-                           format=spec.extension) as encode_span:
+                           format=spec.extension):
             # attach-time decision mirrors keeps_alpha (the flatten
             # decision): attaching alpha to rgb that was already flattened
             # over bg would double-composite semi-transparent pixels
@@ -2094,8 +2130,6 @@ class ImageHandler:
                     meta.icc = None
                 if meta:
                     content = meta_mod.inject(content, spec.extension, meta)
-            if encode_span is not None:
-                encode_span.set_attribute("encode.bytes", len(content))
         if self.metrics is not None:
             self.metrics.counter(
                 "flyimg_encode_bytes_total",

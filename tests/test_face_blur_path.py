@@ -14,6 +14,7 @@ import io
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -138,8 +139,27 @@ def test_batched_detection_equals_per_image_box_for_box(bound, backend):
     batched = backend.detect_faces_batched(items, stats)
     assert batched == [backend.detect_faces(img) for img in images]
     assert sum(map(len, batched)) >= 8
-    # 22 views in one chunk, padded up the ladder to 32
+    # 22 views in one chunk, padded up the ladder to 32, and the seconds of
+    # the launch's three parts
+    assert stats.pop("stack_s") >= 0 and stats.pop("forward_s") > 0
+    assert stats.pop("boxes_s") >= 0
     assert stats == {"views": 22, "slots": 32, "forwards": 1}
+
+
+@pytest.mark.parametrize("copies", [1, 12])
+def test_the_parts_of_a_detection_launch_add_up_to_no_more_than_its_call(
+        bound, backend, copies):
+    """``stack_s`` + ``forward_s`` + ``boxes_s`` are disjoint parts of the
+    runner's call, on its one thread (in one chunk and in two)."""
+    item = backend.prepare_face_work(_group(bound, 46))
+    backend.detect_faces_batched([item] * copies)  # compiled before timing
+    stats = {}
+    t0 = time.perf_counter()
+    backend.detect_faces_batched([item] * copies, stats)
+    call_s = time.perf_counter() - t0
+    parts = stats["stack_s"] + stats["forward_s"] + stats["boxes_s"]
+    assert 0 < parts <= call_s
+    assert min(stats["stack_s"], stats["forward_s"], stats["boxes_s"]) > 0
 
 
 def test_a_launch_of_many_views_runs_in_chunks_of_the_bucket_ceiling(bound, backend):
@@ -147,7 +167,8 @@ def test_a_launch_of_many_views_runs_in_chunks_of_the_bucket_ceiling(bound, back
     stats = {}
     out = backend.detect_faces_batched([item] * 12, stats)
     assert all(boxes == out[0] for boxes in out)
-    assert stats == {"views": 72, "slots": 64 + 8, "forwards": 2}
+    assert {k: stats[k] for k in ("views", "slots", "forwards")} == {
+        "views": 72, "slots": 64 + 8, "forwards": 2}
 
 
 # ---------------------------------------------------------------------------

@@ -5,8 +5,10 @@ annotations, ``bulk_process(trace_out=)``, and the benchmark files that
 read them. Nothing here asserts an upper bound on a time: sleeps give lower
 bounds, identities hold by construction."""
 
+import gc
 import io
 import json
+import logging
 import os
 import sys
 import threading
@@ -18,8 +20,10 @@ from PIL import Image
 
 from flyimg_tpu.appconfig import AppParameters
 from flyimg_tpu.runtime import batcher as batcher_mod
+from flyimg_tpu.runtime import metrics as metrics_mod
 from flyimg_tpu.runtime import tracing
 from flyimg_tpu.runtime.batcher import BatchController
+from flyimg_tpu.runtime.devicegaps import LABELS, GapAccount
 from flyimg_tpu.runtime.flightrecorder import PHASE_FIELDS, FlightRecorder
 from flyimg_tpu.runtime.metrics import MetricsRegistry
 from flyimg_tpu.service.handler import ImageHandler
@@ -79,6 +83,7 @@ class _System:
         )
 
     def close(self):
+        self.handler.close()
         self.codec.close()
         self.batcher.close()
 
@@ -514,8 +519,9 @@ def test_phase_record_adds_up_on_every_path(fake_launches, path):
         row = _rows(recorder, "aux")[0]
         assert row["run_s"] >= 0.03 and row["device_s"] == row["run_s"]
         assert row["queue_wait_s"] >= 0.0 and row["h2d_s"] is None
-        queued, popped, ready = future.launch_times
+        queued, popped, ready, answered = future.launch_times
         assert queued <= popped and ready - popped >= 0.03
+        assert ready <= answered
         return
     if path == "recovery":
         from flyimg_tpu.testing import faults
@@ -551,8 +557,8 @@ def test_phase_record_adds_up_on_every_path(fake_launches, path):
     assert 'flyimg_batch_member_copies_total{at="submit"} 1' in text
     assert ('flyimg_batch_member_copies_total{at="assemble"} 1' in text) == (
         path == "recovery")
-    queued, popped, ready = future.launch_times
-    assert queued <= popped <= ready
+    queued, popped, ready, answered = future.launch_times
+    assert queued <= popped <= ready <= answered
 
 
 def test_h2d_times_the_completed_transfer_not_the_call(fake_launches):
@@ -821,3 +827,321 @@ def test_manifest_with_the_new_metrics_keeps_the_rules():
             manifest.load_metric(metric["name"])["reader"]))
     for metric in doc["per_layer"][8:18]:
         assert metric["workloads"] == ["dslr-backfill-saturated"]
+
+
+# ---------------------------------------------------------------------------
+# 6. where the host's time goes while the device waits: each member's
+#    answer, the callers' wake, the threads' own CPU, the gap split, the
+#    collector, and the annotations that name them
+
+
+class _Recording:
+    """``jax.profiler.TraceAnnotation`` stand-in: every name entered, with
+    the thread it was made on."""
+
+    names = None
+
+    def __init__(self, name, **_):
+        self.names.append((name, threading.current_thread().name))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    names = []
+    monkeypatch.setattr(_Recording, "names", names)
+    monkeypatch.setattr(batcher_mod.jax.profiler, "TraceAnnotation", _Recording)
+    return names
+
+
+def _grouped(ctl, n):
+    """``n`` transform members of one launch: held until all are queued."""
+    ctl.pause_launches()
+    try:
+        futures = [_submit_one(ctl) for _ in range(n)]
+    finally:
+        ctl.resume_launches()
+    for future in futures:
+        future.result(timeout=30)
+    return futures
+
+
+def _settled(ctl, launches):
+    """Wait until every launch has written its resolve."""
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline and not all(
+            "resolve" in launch.marks for launch in launches):
+        time.sleep(0.01)
+
+
+def test_answered_lies_within_the_resolve_in_member_order(fake_launches,
+                                                          annotations):
+    ctl, program, recorder, metrics = fake_launches
+    futures = _grouped(ctl, 3)
+    launch = program.launches[-1]
+    _settled(ctl, [launch])
+    assert launch.images == 3
+    start, end = launch.marks["resolve"]
+    times = [f.launch_times for f in futures]
+    assert len({t[1] for t in times}) == 1 and len({t[2] for t in times}) == 1
+    answered = [t[3] for t in times]
+    assert start <= times[0][2] <= answered[0] < answered[1] < answered[2] <= end
+    # each member's answer is a child annotation of the resolve, on the
+    # drain thread
+    answers = [t for n, t in annotations if n == f"flyimg:batch:{launch.seq}:answer"]
+    assert answers == ["flyimg-batcher-drain"] * 3
+    # the drain thread's own CPU over the resolve, beside its seconds
+    got = _samples(metrics, "flyimg_batch_resolve")
+    assert 0 <= got["flyimg_batch_resolve_thread_seconds_total"] <= \
+        got["flyimg_batch_resolve_seconds_sum"] + 1e-3
+
+
+def test_the_wake_is_recorded_once_per_transform_member(system):
+    frames = 3
+    for seed in range(frames):
+        timings = {}
+        system.transform(_jpeg(seed=seed), timings)
+        assert timings["device_answer"] >= 0 and timings["device_wake"] >= 0
+        assert timings["device_answer"] + timings["device_wake"] <= \
+            timings["device"] + 1e-3
+    # the codec launches' members are answered too, but are no transform's
+    got = _samples(system.metrics, "flyimg_batch_wake_seconds")
+    assert got["flyimg_batch_wake_seconds_count"] == frames
+    assert got["flyimg_batch_wake_seconds_sum"] >= 0
+    # a smart-crop member of the device controller's aux launch wakes
+    # nobody's transform: still one a transform member
+    system.transform(_jpeg(240, 360), {}, text="w_120,h_120,smc_1")
+    assert _samples(system.metrics, "flyimg_batch_wake_seconds_count") == {
+        "flyimg_batch_wake_seconds_count": frames + 1}
+
+
+def _thread_seconds(metrics):
+    out = {}
+    for name, value in _samples(metrics, "flyimg_stage_thread_seconds_total").items():
+        out[name.split('stage="')[1].split('"')[0]] = value
+    return out
+
+
+def test_thread_cpu_is_no_more_than_wall_time_for_every_stage(system,
+                                                              annotations):
+    system.transform(_jpeg(seed=4), {})
+    system.transform(_jpeg(240, 360), {}, text="w_120,h_120,smc_1")
+    threads = _thread_seconds(system.metrics)
+    walls = _samples(system.metrics, "flyimg_stage_seconds_sum")
+    assert {"decode", "device", "encode", "smartcrop_prepare"} <= set(threads)
+    for stage, thread_s in threads.items():
+        wall = walls[f'flyimg_stage_seconds_sum{{stage="{stage}"}}']
+        assert 0 <= thread_s <= wall + 1e-3, stage
+    # a stage the thread computes through reads near its wall time, one it
+    # sleeps through near nothing; each is an annotation on its own thread
+    metrics = MetricsRegistry()
+    with tracing.stage("busy", {}, metrics):
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.05:
+            pass
+    with tracing.stage("idle", {}, metrics):
+        time.sleep(0.05)
+    threads = _thread_seconds(metrics)
+    assert threads["busy"] >= 0.025 and threads["idle"] < 0.025
+    me = threading.current_thread().name
+    assert ("flyimg:stage:busy", me) in annotations
+    assert ("flyimg:stage:idle", me) in annotations
+    # the pipeline's stages ran on the caller's thread, annotated there
+    assert ("flyimg:stage:decode", me) in annotations
+    assert {t for n, t in annotations if n.startswith("flyimg:stage:")} == {me}
+
+
+def _gap_seconds(metrics):
+    """``{during: seconds}`` of ``flyimg_device_gap_seconds_total``."""
+    return {name.split('during="')[1].split('"')[0]: value for name, value
+            in _samples(metrics, "flyimg_device_gap_seconds_total").items()}
+
+
+def test_gap_account_takes_the_first_label_that_holds():
+    metrics = MetricsRegistry()
+    gaps = GapAccount(metrics)
+    gaps.queued(True, 0.0)            # before the first run: nothing counted
+    gaps.move(None, "launch", 1.0)
+    gaps.queued(False, 1.0)
+    gaps.move("launch", "staging", 2.0)
+    gaps.move("staging", "run", 3.0)  # the first run
+    gaps.queued(True, 3.5)            # the next members, during the run
+    gaps.move("run", "d2h", 4.0)      # d2h over fill
+    gaps.move(None, "launch", 4.5)    # launch over d2h
+    gaps.queued(False, 4.5)
+    gaps.move("d2h", "resolve", 5.0)  # launch over resolve
+    gaps.move("launch", "staging", 5.5)
+    gaps.move("staging", "run", 6.0)
+    gaps.move("resolve", None, 6.5)   # answered while the next runs
+    gaps.move("run", "d2h", 7.0)
+    gaps.move("d2h", "resolve", 7.2)
+    gaps.aux(+1, 7.3)                 # an aux runner from here
+    gaps.move("resolve", None, 7.4)
+    gaps.aux(-1, 7.6)
+    gaps.queued(True, 7.8)
+    gaps.move(None, "launch", 8.0)
+    expected = {"staging": 0.5, "launch": 1.0, "d2h": 0.7, "resolve": 0.2,
+                "fill": 0.2, "empty": 0.4, "aux_overlap": 0.3}
+    assert _gap_seconds(metrics) == pytest.approx(expected)
+    assert gaps.run_s == pytest.approx(2.0)
+    assert (gaps.first_run, gaps.latest) == (3.0, 8.0)
+    assert sum(expected[label] for label in LABELS) + gaps.run_s == \
+        pytest.approx(gaps.latest - gaps.first_run)
+    # a move read a moment before the last one took the lock adds nothing
+    gaps.move("launch", "staging", 7.9)
+    assert gaps.latest == 8.0
+    assert _gap_seconds(metrics) == pytest.approx(expected)
+
+
+def test_the_gap_split_adds_up_on_a_controller_driven_with_a_fake_runner(
+        fake_launches):
+    ctl, program, recorder, metrics = fake_launches
+    _submit_one(ctl).result(timeout=30)
+    _grouped(ctl, 2)
+    assert ctl.submit_aux(("k",), 21, _slow_aux).result(timeout=30) == 42
+    _submit_one(ctl).result(timeout=30)
+    _settled(ctl, program.launches)
+    gaps, seconds = ctl._gaps, _gap_seconds(metrics)
+    assert len(program.launches) == 3
+    assert set(seconds) == set(LABELS + ("aux_overlap",))
+    assert gaps.first_run == program.launches[0].marks["run"][0]
+    assert sum(seconds[label] for label in LABELS) + gaps.run_s == \
+        pytest.approx(gaps.latest - gaps.first_run, abs=1e-6)
+    # one launch at a time: the runs are the launches' own, and the device
+    # waited for each later launch's staging and every read-back
+    assert gaps.run_s == pytest.approx(
+        sum(launch.seconds("run") for launch in program.launches), rel=1e-3)
+    assert seconds["staging"] >= 2 * program.h2d_s * 0.9
+    assert seconds["d2h"] >= 3 * program.d2h_s * 0.9
+    # the aux runner ran in a gap, counted apart; the codec controller
+    # keeps no account
+    assert seconds["aux_overlap"] >= 0.03
+    codec = BatchController(deadline_ms=1.0, name="codec")
+    codec.close()
+    assert codec._gaps is None
+
+
+def test_gc_collect_bumps_generation_two_and_close_removes_the_hook(caplog):
+    metrics = MetricsRegistry()
+    handler = ImageHandler(storage=None, params=AppParameters(), metrics=metrics)
+    hook = metrics_mod._gc_hook
+    assert hook in gc.callbacks
+    caplog.set_level(logging.INFO, logger="flyimg.gc")
+    gc.collect()
+    got = _samples(metrics, "flyimg_gc_")
+    assert got['flyimg_gc_collections_total{generation="2"}'] >= 1
+    assert got['flyimg_gc_seconds_total{generation="2"}'] > 0
+    # a full collection names the thread it ran on
+    me = threading.current_thread().name
+    assert any(r.name == "flyimg.gc" and me in r.getMessage()
+               for r in caplog.records)
+    watch = handler._gc_watch
+    handler.close()
+    assert not any(ref() is watch for ref in hook._watches)
+    gc.collect()
+    assert _samples(metrics, "flyimg_gc_") == got
+    # the one callback stays only while another handler's watch is open
+    assert (hook in gc.callbacks) == any(
+        ref() is not None for ref in hook._watches)
+
+
+def _annotating_aux(payloads):
+    with tracing.launch_annotation("pool"):
+        return [p + 1 for p in payloads]
+
+
+def test_aux_annotation_names_carry_their_controller(fake_launches,
+                                                     annotations):
+    ctl, program, recorder, metrics = fake_launches
+    codec = BatchController(deadline_ms=1.0, name="codec")
+    try:
+        assert ctl.submit_aux(("k",), 1, _annotating_aux).result(timeout=30) == 2
+        assert codec.submit_aux(("k",), 1, _annotating_aux).result(timeout=30) == 2
+    finally:
+        codec.close()
+    names = [n for n, _ in annotations if n.startswith("flyimg:aux:")]
+    # both controllers count their own launches from 1: the names no longer
+    # collide
+    for controller in ("device", "codec"):
+        for phase in ("run", "pool", "resolve", "answer"):
+            assert f"flyimg:aux:{controller}:1:{phase}" in names, (controller, phase)
+    assert all(n.split(":")[2] in ("device", "codec") for n in names)
+    # outside a launch a runner's annotation is nothing
+    assert _annotating_aux([1]) == [2]
+    assert not [n for n, _ in annotations if n == "pool"]
+
+
+# two launches of 64 in the window, as the program renders its counters
+_HOST_BEFORE = {
+    "flyimg_batches_total": 10.0,
+    "flyimg_images_processed_total": 640.0,
+    "flyimg_batch_resolve_seconds_sum": 12.0,
+    "flyimg_batch_resolve_thread_seconds_total": 1.2,
+    "flyimg_batch_wake_seconds_sum": 6.4,
+    "flyimg_batch_wake_seconds_count": 640.0,
+    'flyimg_device_gap_seconds_total{during="resolve"}': 12.5,
+    'flyimg_device_gap_seconds_total{during="fill"}': 5.0,
+    'flyimg_codec_worker_seconds_total{op="decode"}': 50.0,
+    'flyimg_codec_worker_capacity_seconds_total{op="decode"}': 90.0,
+    'flyimg_codec_buffers_total{handover="adopted"}': 640.0,
+    'flyimg_stage_thread_seconds_total{stage="faces_prepare"}': 100.0,
+    'flyimg_stage_seconds_sum{stage="faces_prepare"}': 500.0,
+    'flyimg_face_detect_seconds_total{part="forward"}': 3.0,
+    'flyimg_face_detect_seconds_total{part="boxes"}': 1.0,
+    'flyimg_gc_seconds_total{generation="2"}': 0.5,
+}
+_HOST_AFTER = dict(_HOST_BEFORE, **{
+    "flyimg_batches_total": 12.0,
+    "flyimg_images_processed_total": 768.0,
+    "flyimg_batch_resolve_seconds_sum": 14.5,
+    "flyimg_batch_resolve_thread_seconds_total": 1.45,
+    "flyimg_batch_wake_seconds_sum": 8.96,
+    "flyimg_batch_wake_seconds_count": 768.0,
+    'flyimg_device_gap_seconds_total{during="resolve"}': 15.0,
+    'flyimg_device_gap_seconds_total{during="fill"}': 6.0,
+    'flyimg_codec_worker_seconds_total{op="decode"}': 51.28,
+    'flyimg_codec_worker_capacity_seconds_total{op="decode"}': 92.56,
+    'flyimg_codec_buffers_total{handover="adopted"}': 768.0,
+    'flyimg_stage_thread_seconds_total{stage="faces_prepare"}': 130.0,
+    'flyimg_stage_seconds_sum{stage="faces_prepare"}': 620.0,
+    'flyimg_face_detect_seconds_total{part="forward"}': 3.384,
+    'flyimg_face_detect_seconds_total{part="boxes"}': 1.128,
+    'flyimg_gc_seconds_total{generation="2"}': 0.5,
+})
+
+
+@pytest.mark.parametrize("metric,expected,moves", [
+    ("resolve_own_cpu_share", 10.0, "latency_p95_ms"),
+    ("answer_wake_ms", 20.0, "latency_p95_ms"),
+    ("gap_resolve_ms_per_launch", 1250.0, "images_per_s"),
+    ("gap_fill_ms_per_launch", 500.0, "images_per_s"),
+    ("codec_pool_busy_share", 50.0, "images_per_s"),
+    ("decode_frame_ms", 10.0, "images_per_s"),
+    ("faces_prepare_cpu_share", 25.0, "images_per_s"),
+    ("faces_forward_ms_per_image", 3.0, "images_per_s"),
+    ("faces_boxes_ms_per_image", 1.0, "images_per_s"),
+    ("gc_full_ms_per_launch", 0.0, "images_per_s"),
+])
+def test_host_attribution_metric_files_read_the_recorded_fixture(
+        metric, expected, moves):
+    from perfbench.harness import manifest
+
+    doc = manifest.load_manifest()
+    entry = next(m for m in doc["per_layer"] if m["name"] == metric)
+    assert entry["moves"] == moves and entry["source"] == "program_counter"
+    spec = manifest.load_metric(metric)
+    read = manifest.load_reader(spec["reader"])
+    ctx = {"counters_before": _HOST_BEFORE, "counters_after": _HOST_AFTER}
+    assert read(ctx, **spec["args"]) == pytest.approx(expected)
+    # the parent of this PR has none of these series: nothing read
+    assert read({"counters_before": {}, "counters_after": {}}, **spec["args"]) is None
+    # a metric that moves the tail lists only the cells that report it
+    cells = {"latency_p95_ms": ["dslr-backfill-saturated",
+                                "portrait-smartcrop-saturated"]}
+    if moves in cells:
+        assert entry["workloads"] == cells[moves]

@@ -395,6 +395,45 @@ def test_encoders_return_the_bytes_of_the_double_copy_and_free_once(pool, lib):
     assert split.buffers == 2 and split.buffer_bytes == len(blobs[0]) + len(blobs[1])
 
 
+@needs_lib
+@pytest.mark.parametrize("op", ["decode", "encode"])
+def test_each_workers_instants_fall_inside_its_pool_call(pool, lib, op):
+    """``fastcodec.cpp`` stamps each item's start and end on
+    CLOCK_MONOTONIC, the clock ``time.perf_counter_ns()`` reads: every
+    item's pair lies inside the caller's own timing of the pool call, and
+    the launch's split sums them."""
+    name = f"fc_pool_{op}_jpeg_batch"
+    real = getattr(lib._real, name)
+    seen = []
+
+    def timed(handle, items, n):
+        t0 = time.perf_counter_ns()
+        real(handle, items, n)
+        t1 = time.perf_counter_ns()
+        seen.append((t0, t1, [(items[i].t_start_ns, items[i].t_end_ns)
+                              for i in range(n)]))
+
+    setattr(lib, name, timed)
+    frames = [_photo(96 + 16 * k, 64 + 8 * k, seed=k) for k in range(6)]
+    split = native_codec.LaunchSplit()
+    if op == "decode":
+        out = pool.decode_batch(
+            [_encoded(f, "JPEG", quality=90) for f in frames], split=split)
+    else:
+        out = pool.encode_batch(frames, 85, split=split)
+    assert all(o is not None for o in out)
+    (t0, t1, instants), = seen
+    assert len(instants) == len(frames)
+    for start, end in instants:
+        assert t0 <= start <= end <= t1
+    assert split.workers == 4
+    assert split.worker_s == pytest.approx(
+        sum(end - start for start, end in instants) * 1e-9)
+    assert split.wait_s >= sum(start - t0 for start, _ in instants) * 1e-9 - 1e-9
+    assert 0 < split.worker_s <= split.workers * split.native_s
+    assert split.wait_s + split.worker_s <= len(frames) * split.native_s
+
+
 # ---------------------------------------------------------------------------
 # the decode pool's frame buffers: a full frame of the pool's size is decoded
 # into a buffer an earlier frame touched, and goes back to the pool. The
