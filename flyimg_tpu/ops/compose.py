@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import layout as jax_layout
 
 from flyimg_tpu.ops.color import monochrome_dither, to_grayscale
 from flyimg_tpu.ops.filters import gaussian_blur, sharpen as sharpen_op, unsharp_mask
@@ -320,6 +321,32 @@ def unflatten_images(flat, in_shape: Tuple[int, int]):
         return flat.reshape(flat.shape[0], *in_shape, 3)
 
 
+def flatten_images(images):
+    """``u8[frames, h, w, 3]`` -> ``u8[frames, h, w * 3]`` inside the device
+    program: the last operation on every piece's output, the mirror of
+    ``unflatten_images``. The device keeps the NHWC output planar, and the
+    host's read-back of a planar array is a strided view whose every
+    member the resolve re-interleaved pixel by pixel; flat, and laid out
+    row-major (``flat_output_format``), the read-back lands in the host's
+    order and ``ProgramHandle.unstage`` re-shapes it without a copy."""
+    with jax.named_scope("flyimg.flatten"):
+        return images.reshape(*images.shape[:2], -1)
+
+
+def flat_output_format(sharding) -> jax_layout.Format:
+    """The format a batched program returns its flat output in: on
+    ``sharding``, laid out row-major (batch, then rows, then the row's
+    bytes), which is the host's order. Pinned, because the device picks a
+    ``uint8`` array's layout by its shape to spare its tiles' padding: on a
+    TPU v5e ``u8[64, 1066, 4800]`` defaults to the batch axis between the
+    rows and the row's bytes (``major_to_minor=(1, 0, 2)``), whose
+    read-back is strided again, and ``u8[64, 1216, 2496]`` to row-major. A
+    function of nothing but the program's static shape."""
+    return jax_layout.Format(
+        jax_layout.Layout(major_to_minor=(0, 1, 2)), sharding
+    )
+
+
 class ProgramHandle:
     """One device program: callable like the jitted function it wraps,
     but compiled through the AOT API so its XLA cost analysis feeds the
@@ -340,10 +367,12 @@ class ProgramHandle:
     fail a render that compiled.
 
     A batched program (``pieces`` >= 1) takes its image argument in the
-    staged form (``flat_pieces``) and the handle owns that form: callers
-    assemble and describe images as ``[n, h, w, 3]``; ``stage`` and
-    ``precompile`` map them, so the executable a handle is warmed with is
-    the one its staged arrays run.
+    staged form (``flat_pieces``) and returns its output flat (``u8[n,
+    h, w * 3]``, ``flatten_images``), and the handle owns both forms:
+    callers assemble and describe images as ``[n, h, w, 3]``; ``stage``
+    and ``precompile`` map them, so the executable a handle is warmed with
+    is the one its staged arrays run, and ``unstage`` turns the read-back
+    into ``[n, h, w, 3]`` again.
     """
 
     __slots__ = (
@@ -396,6 +425,16 @@ class ProgramHandle:
         copies have happened (the caller waits with
         ``jax.block_until_ready``); ``__call__`` takes what this returns."""
         return jax.device_put(self._staged(arrays), self.in_sharding)
+
+    def unstage(self, out):
+        """The host read-back of this program's output as the batcher uses
+        it: a batched program's ``u8[n, h, w * 3]`` as ``[n, h, w, 3]``, a
+        view where the read-back is C-contiguous (a copy in the host's
+        order where it is not); a single-image program's as it is."""
+        if not self.pieces:
+            return out
+        n, h, row = out.shape
+        return out.reshape(n, h, row // 3, 3)
 
     def precompile(self, args) -> None:
         """Compile (and ledger-record) for ``args``'s shapes WITHOUT

@@ -74,6 +74,8 @@ from flyimg_tpu.ops.compose import (
     _bucket_dim,
     bucket_batch,
     final_extent,
+    flat_output_format,
+    flatten_images,
     make_program_fn,
     plan_descriptor,
     plan_layout,
@@ -155,15 +157,16 @@ def build_batched_program(
     batcher's exact compile-hit signal. One cache entry = one (batch,
     shape) program = one compiled executable. The program takes the
     images flat and in pieces (``stage_pieces`` of them, each ``u8[batch /
-    pieces, h, w * 3]``; one piece for every launch of small frames) and
+    pieces, h, w * 3]``; one piece for every launch of small frames),
     un-flattens and transforms them piece by piece, in a loop over one
-    traced body; callers keep assembling and describing ``[batch, h, w,
-    3]`` and go through the handle's ``stage`` / ``precompile``, which
-    own the mapping (ops/compose.py ``flat_pieces``). ``band_taps`` (the
-    banded
-    resample's static per-axis K; docs/kernels.md) is part of the cache
-    key AND the ledger key — dense and banded variants of one plan must
-    never collide in either."""
+    traced body, and returns the output flat too, ``u8[batch, H, W *
+    3]``, laid out row-major (``flat_output_format``); callers keep
+    assembling and describing ``[batch, h, w, 3]`` and go through the
+    handle's ``stage`` / ``precompile`` / ``unstage``, which own the
+    mapping (ops/compose.py ``flat_pieces``, ``flatten_images``).
+    ``band_taps`` (the banded resample's static per-axis K;
+    docs/kernels.md) is part of the cache key AND the ledger key — dense
+    and banded variants of one plan must never collide in either."""
     batched = jax.vmap(make_program_fn(
         resample_out, pad_canvas, pad_offset, plan,
         rotate_dynamic=rotate_dynamic, band_taps=band_taps,
@@ -177,11 +180,14 @@ def build_batched_program(
     def program(flat_pieces_u8, in_true, span_y, span_x, out_true):
         # the images arrive as ProgramHandle.stage leaves them (flat, in
         # pieces: the form the host copies straight through) and every
-        # piece is un-flattened and transformed here, inside the one
-        # jit_program module, so the device time of the re-layout counts
-        # with the program's
+        # piece is un-flattened, transformed and flattened again here,
+        # inside the one jit_program module, so the device time of both
+        # re-layouts counts with the program's, and the read-back lands
+        # in the host's row order
         def one(piece, *scalars):
-            return batched(unflatten_images(piece, in_shape), *scalars)
+            return flatten_images(
+                batched(unflatten_images(piece, in_shape), *scalars)
+            )
 
         scalars = (in_true, span_y, span_x, out_true)
         if pieces == 1:
@@ -206,9 +212,15 @@ def build_batched_program(
         ))
         return out.reshape(batch_size, *out.shape[2:])
 
+    # the output is laid out row-major wherever it lives (the device's
+    # default for a flat uint8 array depends on its shape)
     sharding = None
     if mesh is None:
-        jitted = jax.jit(program)
+        from jax.sharding import SingleDeviceSharding
+
+        jitted = jax.jit(program, out_shardings=flat_output_format(
+            SingleDeviceSharding(jax.local_devices()[0])
+        ))
     else:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -216,7 +228,7 @@ def build_batched_program(
         jitted = jax.jit(
             program,
             in_shardings=(sharding,) * 5,
-            out_shardings=sharding,
+            out_shardings=flat_output_format(sharding),
         )
     key = (
         "batched", batch_size, in_shape, resample_out, pad_canvas,
@@ -305,8 +317,10 @@ class _Launch:
     ``resolve`` (``time.thread_time()``), and the executor's over the
     staging call that opens ``h2d``: over the phase's seconds, the share
     its thread computed rather than waited; ``transfer_bytes`` the bytes
-    staged (``h2d``) and read back (``d2h``). Every phase is also opened as
-    a ``jax.profiler.TraceAnnotation`` named ``flyimg:batch:<seq>:<phase>``
+    staged (``h2d``) and read back (``d2h``); ``readback`` the form the
+    read-back arrived in (``row_major`` or ``strided``). Every phase is
+    also opened as a ``jax.profiler.TraceAnnotation`` named
+    ``flyimg:batch:<seq>:<phase>``
     (an aux launch's: ``flyimg:aux:<controller>:<seq>:<phase>``) on the
     thread that runs it (``h2d`` is two: ``h2d`` around the staging call,
     ``h2d_wait`` around the wait; each member's answer is ``answer`` inside
@@ -320,8 +334,8 @@ class _Launch:
     __slots__ = (
         "seq", "kind", "aux", "images", "capacity", "popped",
         "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
-        "block", "transfer_bytes", "_cursor", "_opened", "prefix",
-        "_gaps", "_at",
+        "block", "transfer_bytes", "readback", "_cursor", "_opened",
+        "prefix", "_gaps", "_at",
     )
 
     def __init__(self, seq: int, members: List[_Pending], *,
@@ -342,6 +356,11 @@ class _Launch:
         # "d2h": the output read back): with the two transfer phases'
         # seconds, the rate an operator reads
         self.transfer_bytes: Dict[str, int] = {}
+        # the form the output was read back in: "row_major" where the host
+        # array is C-contiguous (the host's order: a member is a view of
+        # it), "strided" where it is a view in another order (None: not
+        # read back)
+        self.readback: Optional[str] = None
         self.compile_hit: Optional[bool] = None
         # the staged inputs, held only until the h2d wait returns: the
         # drain thread must not keep a launch's inputs alive through the
@@ -2068,7 +2087,7 @@ class BatchController:
                     member.future.set_result(result)
                     member.image = None
 
-    def _await_launch(self, launch: _Launch, dev_out):
+    def _await_launch(self, launch: _Launch, dev_out, fn: ProgramHandle):
         """The device side of one launch after its dispatch, in three
         laps that share their end points: the staged inputs are on the
         device (``h2d`` ends; the inputs are let go at once, so that a
@@ -2076,7 +2095,9 @@ class BatchController:
         output is ready (``run``; the launch's host block goes to the
         controller here, ``_keep_block``), the output is on the host
         (``d2h``). The inputs are not donated, so waiting on them after the
-        dispatch is legal. Returns the output as a host array."""
+        dispatch is legal. Returns the output as a host array ``[n, h, w,
+        3]`` (``fn.unstage``: a view of the read-back where it arrived
+        row-major, which ``launch.readback`` records)."""
         with launch.annotate("h2d_wait"):
             jax.block_until_ready(launch.dev_args)
         launch.dev_args = None
@@ -2089,7 +2110,10 @@ class BatchController:
             out = np.asarray(dev_out)
         launch.lap("d2h")
         launch.transfer_bytes["d2h"] = out.nbytes
-        return out
+        launch.readback = (
+            "row_major" if out.flags.c_contiguous else "strided"
+        )
+        return fn.unstage(out)
 
     def _launch_done(self, group: _Group, members: List[_Pending],
                      launch: _Launch, fn, span_obj=None,
@@ -2148,7 +2172,7 @@ class BatchController:
         n, batch = launch.images, launch.capacity
         try:
             faults.fire("batcher.drain", key=group.key, n=n, batch=batch)
-            out = self._await_launch(launch, dev_out)
+            out = self._await_launch(launch, dev_out, fn)
             row, copies = self._launch_done(
                 group, members, launch, fn, span_obj
             )
@@ -2479,7 +2503,7 @@ class BatchController:
                         dev_out = fn(*launch.dev_args)
                 self._touch_busy()  # dispatch returned: progress
                 faults.fire("batcher.drain", key=group.key, n=n, batch=batch)
-                outputs = self._await_launch(launch, dev_out)
+                outputs = self._await_launch(launch, dev_out, fn)
             finally:
                 launch.dev_args = None
                 if self.profiler is not None:

@@ -501,10 +501,11 @@ class MetricsRegistry:
         (runtime/batcher.py ``_Launch``: the launch's one record, read
         here by ``seconds(phase)``, ``device_s``, ``queue_wait_s``,
         ``images``, ``capacity``, ``compile_hit``, ``aux``,
-        ``transfer_bytes``). A transform launch observes
+        ``transfer_bytes``, ``readback``). A transform launch observes
         ``flyimg_device_seconds`` (dispatch to completed read-back, as
-        ever), one histogram per phase and the bytes it moved each way
-        (``flyimg_device_transfer_bytes_total``); every launch,
+        ever), one histogram per phase, the bytes it moved each way
+        (``flyimg_device_transfer_bytes_total``) and the form its output
+        was read back in (``flyimg_batch_readbacks_total``); every launch,
         aux included, feeds the per-controller efficiency record
         (``record_batch_launch``) under the label its controller gives
         it: the transform controller's aux launches go under
@@ -535,6 +536,15 @@ class MetricsRegistry:
                     "(d2h: the output); over the sum of "
                     "flyimg_device_transfer_seconds, the link's rate",
                 ).inc(nbytes)
+            if launch.readback is not None:
+                self.counter(
+                    f'flyimg_batch_readbacks_total{{layout="{launch.readback}"}}',
+                    "Transform launches by the form their output was read "
+                    "back in: row_major (a C-contiguous host array, each "
+                    "member a view of it) or strided (a view in another "
+                    "order, such as the device's planar one: each member "
+                    "copied out of it on the drain thread)",
+                ).inc()
         self.record_batch_launch(
             controller, images=launch.images, capacity=launch.capacity,
             queue_wait_s=launch.queue_wait_s, device_s=launch.device_s,

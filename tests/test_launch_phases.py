@@ -19,6 +19,7 @@ import pytest
 from PIL import Image
 
 from flyimg_tpu.appconfig import AppParameters
+from flyimg_tpu.ops.compose import ProgramHandle
 from flyimg_tpu.runtime import batcher as batcher_mod
 from flyimg_tpu.runtime import metrics as metrics_mod
 from flyimg_tpu.runtime import tracing
@@ -429,12 +430,16 @@ class _Output:
         self.program.inputs_alive_at_readback.append(
             self.program.launch().dev_args is not None)
         time.sleep(self.program.d2h_s)
-        return np.zeros((self.batch, 24, 32, 3), np.uint8)
+        return np.zeros((self.batch, 24, 32 * 3), np.uint8)
 
 
 class _FakeProgram:
     ledger_key = "fake-program"
     is_compiled = True
+    # a batched program's output form: flat, re-shaped by the real handle's
+    # own inverse
+    pieces = 1
+    unstage = ProgramHandle.unstage
 
     def __init__(self, h2d_s=0.06, run_s=0.04, d2h_s=0.05):
         self.h2d_s, self.run_s, self.d2h_s = h2d_s, run_s, d2h_s

@@ -1,11 +1,15 @@
-"""The staged form of a launch's image argument (PR 28): the batcher
-assembles ``u8[batch, h, w, 3]``, ``ProgramHandle.stage`` hands the device
-the same bytes flat and in pieces (``u8[batch / pieces, h, w * 3]``, views;
-one piece for every launch of small frames), and the batched program
-un-flattens and transforms them piece by piece, in a loop over one body. Pinned here: the output
-bytes do not change, a handle warmed with image-shaped specs runs staged
-arrays without a second compile, staging copies nothing on the host, and
-the launch's record carries the bytes it moved."""
+"""The staged form of a launch's image argument and of its output: the
+batcher assembles ``u8[batch, h, w, 3]``, ``ProgramHandle.stage`` hands the
+device the same bytes flat and in pieces (``u8[batch / pieces, h, w * 3]``,
+views; one piece for every launch of small frames), the batched program
+un-flattens and transforms them piece by piece, in a loop over one body,
+and returns its output flat, ``u8[batch, H, W * 3]``, which
+``ProgramHandle.unstage`` turns into ``[batch, H, W, 3]`` without a copy.
+Pinned here: the output bytes do not change, a handle warmed with
+image-shaped specs runs staged arrays without a second compile, staging
+copies nothing on the host, a member is answered with a view of the
+read-back, and the launch's record carries the bytes it moved and the form
+they came back in."""
 
 import threading
 
@@ -17,7 +21,11 @@ from flyimg_tpu.ops import resample
 from flyimg_tpu.ops import compose
 from flyimg_tpu.ops.compose import flat_pieces, make_program_fn, stage_pieces
 from flyimg_tpu.runtime import tracing
-from flyimg_tpu.runtime.batcher import BatchController, build_batched_program
+from flyimg_tpu.runtime.batcher import (
+    BatchController,
+    _Launch,
+    build_batched_program,
+)
 from flyimg_tpu.runtime.metrics import MetricsRegistry
 from flyimg_tpu.spec.options import OptionsBag
 from flyimg_tpu.spec.plan import build_plan
@@ -103,8 +111,10 @@ _LAUNCHES = {
 @pytest.mark.parametrize("case", sorted(_PLANS))
 def test_staged_form_gives_the_bytes_nhwc_gave(case, launch, request):
     """The batched program fed what ``stage`` makes of the assembled
-    arrays returns byte for byte what ``vmap`` of the single-image program
-    returns fed the assembled NHWC arrays themselves."""
+    arrays returns its output flat, and read through the handle's
+    ``unstage`` (a view of the read-back, C-contiguous) it is byte for byte
+    what ``vmap`` of the single-image program returns fed the assembled
+    NHWC arrays themselves."""
     options, (w, h), mode = _PLANS[case]
     n, sharded, by_frame = _LAUNCHES[launch]
     if by_frame:
@@ -137,14 +147,18 @@ def test_staged_form_gives_the_bytes_nhwc_gave(case, launch, request):
             [(batch // fn.pieces, bh, bw * 3)] * fn.pieces)
         if sharded:
             assert len(staged[0][0].sharding.device_set) == 8
-        got = np.asarray(fn(*staged))
+        raw = np.asarray(fn(*staged))
         inner = make_program_fn(
             group.resample_out, group.pad_canvas, group.pad_offset,
             group.device_plan, rotate_dynamic=group.rotate_dynamic,
             band_taps=group.band_taps,
         )
         want = np.asarray(jax.jit(jax.vmap(inner))(*arrays))
-        assert got.dtype == np.uint8 and got.shape == want.shape
+        oh, ow = want.shape[1:3]
+        assert raw.dtype == np.uint8 and raw.shape == (batch, oh, ow * 3)
+        got = fn.unstage(raw)
+        assert got.shape == want.shape and got.flags.c_contiguous
+        assert np.shares_memory(got, raw)
         np.testing.assert_array_equal(got, want)
     finally:
         _close(ctl)
@@ -233,7 +247,8 @@ def test_handle_warmed_with_image_shaped_specs_runs_staged_arrays(
         out, warm = _compiles_during(
             lambda: jax.block_until_ready(fn(*staged)))
         assert warm == 0
-        assert out.shape == (batch, 28, 44, 3)
+        assert out.shape == (batch, 28, 44 * 3)
+        assert fn.unstage(np.asarray(out)).shape == (batch, 28, 44, 3)
     finally:
         _close(ctl)
 
@@ -340,3 +355,156 @@ def _spans(node):
     yield node
     for child in node["children"]:
         yield from _spans(child)
+
+
+def _run_parked(ctl, program=None):
+    """Pop the parked controller's one group and run it as the executor
+    would (``_execute``: staging, dispatch, the drain thread's read-back
+    and resolve); ``program`` wraps the handle ``_program`` returns.
+    Returns the members' answers."""
+    if program is not None:
+        real = ctl._program
+
+        def wrapped(group, batch):
+            fn, compile_hit = real(group, batch)
+            return program(fn), compile_hit
+
+        ctl._program = wrapped
+    with ctl._lock:
+        group = ctl._pop_ready_group()
+    futures = [m.future for m in group.members]
+    assert ctl._execute(group)
+    return [f.result(timeout=120) for f in futures]
+
+
+def _readbacks(metrics):
+    text = metrics.render_prometheus()
+    return {
+        layout: f'flyimg_batch_readbacks_total{{layout="{layout}"}} 1' in text
+        for layout in ("row_major", "strided")
+    }, "flyimg_batches_total 1" in text
+
+
+def test_an_unsliced_member_is_a_view_of_the_read_back_and_a_sliced_one_a_copy():
+    """A crop-fill member (every member the same static extent) is answered
+    with its slot of the read-back itself; a fit member whose output is
+    bucket-padded gets a C-contiguous copy of its window and nothing of the
+    launch's array."""
+    for options, sliced in (("w_120,h_90,c_1", False), ("w_100", True)):
+        ctl, group, batch, arrays = _queued_launch(
+            options, [(320, 240), (300, 236)])
+        try:
+            assert [m.needs_slice for m in group.members] == [sliced] * 2
+            fn, _ = ctl._program(group, batch)
+            launch = _Launch(1, group.members)
+            launch.open("h2d")
+            launch.dev_args = fn.stage(arrays)
+            out = ctl._await_launch(launch, fn(*launch.dev_args), fn)
+            assert launch.readback == "row_major" and out.flags.c_contiguous
+            futures = [m.future for m in group.members]
+            ctl._resolve_members(group, group.members, out, launch)
+            for i, (future, member) in enumerate(zip(futures, group.members)):
+                got = future.result(timeout=0)
+                th, tw = member.final_true
+                assert got.shape == (th, tw, 3) and got.flags.c_contiguous
+                np.testing.assert_array_equal(got, out[i, :th, :tw])
+                assert np.shares_memory(got, out) == (not sliced)
+        finally:
+            _close(ctl)
+
+
+def test_a_launch_counts_its_read_back_row_major_once():
+    """One launch through the controller's own path: the program's flat
+    output reads back C-contiguous, and the registry counts it once, beside
+    the one launch it belongs to."""
+    metrics = MetricsRegistry()
+    ctl = _Parked(max_batch=4, deadline_ms=0.0, lone_flush=False,
+                  metrics=metrics)
+    try:
+        for seed, (w, h) in enumerate([(320, 240), (300, 236)]):
+            ctl.submit(make_test_image(w, h, seed=seed),
+                       build_plan(OptionsBag("w_120,h_90,c_1"), w, h))
+        outs = _run_parked(ctl)
+        assert [o.shape for o in outs] == [(90, 120, 3)] * 2
+        assert _readbacks(metrics) == (
+            {"row_major": True, "strided": False}, True)
+    finally:
+        _close(ctl)
+
+
+class _PlanarReadBack:
+    """A handle whose output reaches the host in another order than the
+    host's: the same values, a transposed array (what a program whose
+    output the device keeps planar hands numpy)."""
+
+    def __init__(self, handle):
+        self._handle = handle
+
+    def __getattr__(self, name):
+        return getattr(self._handle, name)
+
+    def __call__(self, *args):
+        out = np.asarray(self._handle(*args))
+        planar = np.ascontiguousarray(out.transpose(0, 2, 1)).transpose(0, 2, 1)
+        assert not planar.flags.c_contiguous
+        np.testing.assert_array_equal(planar, out)
+        return planar
+
+
+def test_a_strided_read_back_is_counted_so_and_answers_the_same_bytes():
+    """A read-back that does not arrive in the host's order counts
+    ``layout="strided"`` and its members are still answered with the
+    bytes the row-major read-back gives."""
+    answers = {}
+    for name, program in (("row_major", None), ("strided", _PlanarReadBack)):
+        metrics = MetricsRegistry()
+        ctl = _Parked(max_batch=4, deadline_ms=0.0, lone_flush=False,
+                      metrics=metrics)
+        try:
+            for seed, (w, h) in enumerate([(320, 240), (300, 236)]):
+                ctl.submit(make_test_image(w, h, seed=seed),
+                           build_plan(OptionsBag("w_100"), w, h))
+            answers[name] = _run_parked(ctl, program)
+            assert _readbacks(metrics) == (
+                {"row_major": name == "row_major",
+                 "strided": name == "strided"}, True)
+        finally:
+            _close(ctl)
+    for row_major, strided in zip(answers["row_major"], answers["strided"]):
+        assert strided.flags.c_contiguous
+        assert strided.shape == row_major.shape
+        np.testing.assert_array_equal(strided, row_major)
+
+
+def test_readback_row_major_share_reads_the_counter_the_controller_keeps():
+    """``perfbench/metrics/readback_row_major_share.json`` through the
+    benchmark's own reader, on a controller's registry as the harness
+    scrapes it: 100 where every launch read back row-major, nothing read
+    from a program without the counter."""
+    from perfbench.harness import manifest
+    from perfbench.harness.system import parse_prometheus
+
+    doc = manifest.load_manifest()
+    entry = next(m for m in doc["per_layer"]
+                 if m["name"] == "readback_row_major_share")
+    assert entry["layer"] == "transfer" and entry["moves"] == "images_per_s"
+    assert entry["workloads"] == [c["name"] for c in doc["workloads"]]
+    spec = manifest.load_metric("readback_row_major_share")
+    read = manifest.load_reader(spec["reader"])
+    metrics = MetricsRegistry()
+    ctl = _Parked(max_batch=4, deadline_ms=0.0, lone_flush=False,
+                  metrics=metrics)
+    try:
+        before = parse_prometheus(metrics.render_prometheus())
+        for seed, (w, h) in enumerate([(320, 240), (300, 236)]):
+            ctl.submit(make_test_image(w, h, seed=seed),
+                       build_plan(OptionsBag("w_120,h_90,c_1"), w, h))
+        _run_parked(ctl)
+        after = parse_prometheus(metrics.render_prometheus())
+    finally:
+        _close(ctl)
+    assert read({"counters_before": before, "counters_after": after},
+                **spec["args"]) == pytest.approx(100.0)
+    # the parent's program has no such counter: nothing read, nothing raised
+    assert read({"counters_before": {}, "counters_after": {
+        "flyimg_batches_total": 4.0}}, **spec["args"]) is None
