@@ -19,7 +19,7 @@ from flyimg_tpu.codecs.sniff import MediaInfo, sniff  # noqa: F401
 from flyimg_tpu.codecs import native_codec
 from flyimg_tpu.codecs import pil_codec
 from flyimg_tpu.codecs.exif import apply_orientation, jpeg_orientation
-from flyimg_tpu.codecs.pil_codec import DecodedImage
+from flyimg_tpu.codecs.pil_codec import DecodedImage, png_bit_depth
 
 # lazy ref to the host-pool utilization trackers (runtime/metrics.py):
 # importing flyimg_tpu.runtime at module scope would drag the whole batch
@@ -88,9 +88,14 @@ def decode(
     frame: int = 0,
     info: Optional[MediaInfo] = None,
     roi: Optional[Tuple[int, int, int, int]] = None,
+    call: Optional[native_codec.PngCall] = None,
 ) -> DecodedImage:
-    """Decode bytes -> DecodedImage. JPEG/WebP ride the native codec when
-    built; everything else (and all alpha/animation handling) uses PIL.
+    """Decode bytes -> DecodedImage. JPEG/WebP/PNG ride the native codec
+    when built; everything else (and all animation handling) uses PIL.
+    A 16-bit PNG of grey or RGB decodes to ``uint16`` samples as stored
+    (``native_codec.png_decode16``); one with alpha or a colour key, or
+    read through Pillow, comes out at 8 bits with ``narrowed_from`` 16.
+    ``call`` is filled by a native PNG decode (seconds and bits).
     Alpha sources keep RAW rgb + a separate alpha plane; the handler
     flattens over the bg_ color only where alpha is actually dropped.
     Pass ``info`` when the caller already probed the bytes.
@@ -130,15 +135,34 @@ def decode(
                     _split_alpha(decoded, "image/webp"), data, "webp"
                 )
         elif info.mime == "image/png":
-            decoded = native_codec.png_decode(data)
+            depth = png_bit_depth(data)
+            decoded = None
+            if depth == 16 and data[25] in (0, 2):
+                # the stored 16-bit samples of grey (spread to RGB) or RGB;
+                # the handler's alpha flattening is 8-bit, so colour types
+                # with alpha, and a tRNS colour key (png_decode16 refuses
+                # it), take the 8-bit read below, counted
+                decoded = native_codec.png_decode16(data, call=call)
+            if decoded is None:
+                decoded = native_codec.png_decode(data, call=call)
             if decoded is not None:
-                return _orient_container(
+                image = _orient_container(
                     _split_alpha(decoded, "image/png"), data, "png"
                 )
+                if depth == 16 and image.rgb.dtype == np.uint8:
+                    image.narrowed_from = 16
+                return image
     # NOTE: no orientation here — the PIL fallback already runs
     # ImageOps.exif_transpose (pil_codec.py:76), which honors PNG eXIf
     # and WebP EXIF; applying it again would double-rotate
     return pil_codec.decode(data, target_hint=target_hint, frame=frame)
+
+
+def narrow_to_u8(pixels: np.ndarray) -> np.ndarray:
+    """16-bit samples -> 8, as ImageMagick scales a Q16 quantum to a char
+    (``ScaleQuantumToChar``: about ``round(v / 257)``)."""
+    v = pixels.astype(np.uint32) + 128
+    return ((v - (v >> 8)) >> 8).astype(np.uint8)
 
 
 def _decode_jpeg_roi(
@@ -195,7 +219,7 @@ def _orient_container(
         alpha = np.ascontiguousarray(apply_orientation(alpha, orientation))
     return DecodedImage(
         rgb=rgb, alpha=alpha, mime=decoded.mime, orig_size=decoded.orig_size,
-        n_frames=decoded.n_frames,
+        n_frames=decoded.n_frames, narrowed_from=decoded.narrowed_from,
     )
 
 
@@ -341,14 +365,28 @@ def encode(
     sampling_factor: str = "1x1",
     strip: bool = True,
     alpha: Optional[np.ndarray] = None,
+    call: Optional[native_codec.PngCall] = None,
 ) -> bytes:
     """Encode via the native codec where it covers the case (jpg, webp
-    without alpha; png with or without); PIL otherwise."""
+    without alpha; png with or without); PIL otherwise. ``uint16`` pixels
+    are written as a 16-bit PNG and nothing else: no other format holds
+    16 bits, and Pillow writes no 16-bit RGB (``narrow_to_u8`` first).
+    ``call`` is filled by a native PNG encode (seconds and bits)."""
+    if image.dtype == np.uint16:
+        blob = None
+        if fmt == "png" and alpha is None:
+            blob = native_codec.png_encode16(image, call=call)
+        if blob is None:
+            raise ValueError(
+                f"no encoder writes these 16-bit pixels as {fmt!r}"
+                f"{' with alpha' if alpha is not None else ''}"
+            )
+        return blob
     if native_codec.available() and fmt == "png":
         pixels = image
         if alpha is not None:
             pixels = np.dstack([image, alpha])
-        blob = native_codec.png_encode(pixels)
+        blob = native_codec.png_encode(pixels, call=call)
         if blob is not None:
             return blob
     if native_codec.available() and fmt == "webp":

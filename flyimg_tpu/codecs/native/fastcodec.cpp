@@ -30,6 +30,10 @@
 #include <cstring>
 
 #include <jpeglib.h>
+#include <libdeflate.h>
+#if defined(__SSE2__)
+#include <emmintrin.h>
+#endif
 #include <png.h>
 #if defined(__has_include)
 #if __has_include(<webp/decode.h>)
@@ -52,6 +56,8 @@
 #include <mutex>
 #include <queue>
 #include <thread>
+#include <type_traits>
+#include <utility>
 #include <vector>
 
 extern "C" {
@@ -1057,10 +1063,11 @@ uint8_t* fc_jpeg_encode_trellis(const uint8_t* rgb, int width, int height,
 }
 
 // ---------------------------------------------------------------------------
-// PNG (libpng 1.6 simplified API)
+// PNG at 8 bits (libpng 1.6 simplified API)
 // ---------------------------------------------------------------------------
 
-// Decode PNG to 8-bit RGB or RGBA. channels: pass 3 or 4 to force, or 0 to
+// Decode PNG to 8-bit RGB or RGBA (a 16-bit file too: fc_png_decode16
+// keeps its depth). channels: pass 3 or 4 to force, or 0 to
 // auto-detect (4 iff the file has alpha). Returns malloc'd buffer.
 uint8_t* fc_png_decode(const uint8_t* data, size_t len, int want_channels,
                        int* width, int* height, int* channels) {
@@ -1115,6 +1122,523 @@ uint8_t* fc_png_encode(const uint8_t* pixels, int width, int height,
     return nullptr;
   }
   *out_len = size;
+  return out;
+}
+
+// ---------------------------------------------------------------------------
+// PNG at 16 bits. The simplified API reads 16-bit samples only as
+// PNG_FORMAT_FLAG_LINEAR, which converts them to linear light; these hand
+// over the stored samples, whatever gAMA / sRGB / iCCP chunks the file
+// carries. Samples are native endian in memory.
+//
+// A non-interlaced RGB or RGBA file, the form a master is kept in, is read
+// here without libpng: its IDAT stream is inflated whole by libdeflate
+// (about twice zlib's speed on a master's grainy, barely compressible
+// data) into the frame's own buffer and unfiltered there in place. Grey,
+// grey with alpha and interlaced files take libpng's classic API with no
+// transform. The encoder writes its rows with libpng's filter choice and
+// deflates them whole by libdeflate at the given level.
+// ---------------------------------------------------------------------------
+
+static uint32_t fc_be32(const uint8_t* p) {
+  return (uint32_t{p[0]} << 24) | (uint32_t{p[1]} << 16) |
+         (uint32_t{p[2]} << 8) | uint32_t{p[3]};
+}
+
+static void fc_put_be32(uint8_t* p, uint32_t v) {
+  p[0] = static_cast<uint8_t>(v >> 24);
+  p[1] = static_cast<uint8_t>(v >> 16);
+  p[2] = static_cast<uint8_t>(v >> 8);
+  p[3] = static_cast<uint8_t>(v);
+}
+
+// Swap the bytes of each 16-bit sample of a row of n bytes, in place.
+static void fc_swap16(uint8_t* row, size_t n) {
+  for (size_t i = 0; i + 1 < n; i += 2) {
+    uint16_t v;
+    std::memcpy(&v, row + i, 2);
+    v = __builtin_bswap16(v);
+    std::memcpy(row + i, &v, 2);
+  }
+}
+
+// Samples are native endian in memory and big endian in a PNG.
+static constexpr bool kLittleEndian =
+    __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__;
+
+static int fc_paeth(int a, int b, int c) {
+  const int pa = std::abs(b - c);
+  const int pb = std::abs(a - c);
+  const int pc = std::abs(a + b - 2 * c);
+  return (pa <= pb && pa <= pc) ? a : (pb <= pc ? b : c);
+}
+
+#if defined(__SSE2__)
+extern "C++" {
+// Sub, Average and Paeth a pixel of BPP (6 or 8) bytes at a time, as eight
+// 16-bit lanes: each pixel depends on the one before it, so the bytes of
+// one pixel are the only ones that can be worked on together. A pixel of
+// src is read whole before its pixel of dst is written.
+// (The bytes of a pixel go through registers as a 4-byte and a 2- or
+// 4-byte word: a pixel assembled in memory would be read back across two
+// stores, which the CPU cannot forward, at many times the cost.)
+template <size_t BPP>
+using fc_px_tail = typename std::conditional<BPP == 6, uint16_t, uint32_t>::type;
+
+template <size_t BPP>
+static __m128i fc_load_px(const uint8_t* p) {
+  uint32_t head;
+  fc_px_tail<BPP> tail;
+  std::memcpy(&head, p, 4);
+  std::memcpy(&tail, p + 4, sizeof(tail));
+  const uint64_t v = head | (static_cast<uint64_t>(tail) << 32);
+  return _mm_unpacklo_epi8(_mm_cvtsi64_si128(static_cast<long long>(v)),
+                           _mm_setzero_si128());
+}
+
+template <size_t BPP>
+static void fc_store_px(uint8_t* p, __m128i v) {
+  const __m128i bytes = _mm_and_si128(v, _mm_set1_epi16(0xff));
+  const uint64_t out =
+      static_cast<uint64_t>(_mm_cvtsi128_si64(_mm_packus_epi16(bytes, bytes)));
+  const uint32_t head = static_cast<uint32_t>(out);
+  const fc_px_tail<BPP> tail = static_cast<fc_px_tail<BPP>>(out >> 32);
+  std::memcpy(p, &head, 4);
+  std::memcpy(p + 4, &tail, sizeof(tail));
+}
+
+static __m128i fc_abs16(__m128i v) {
+  return _mm_max_epi16(v, _mm_sub_epi16(_mm_setzero_si128(), v));
+}
+
+template <size_t BPP>
+static void fc_unfilter_px(int ft, const uint8_t* src, uint8_t* dst,
+                           const uint8_t* prev, size_t n) {
+  __m128i a = _mm_setzero_si128();
+  __m128i c = _mm_setzero_si128();
+  for (size_t i = 0; i < n; i += BPP) {
+    const __m128i d = fc_load_px<BPP>(src + i);
+    const __m128i b = fc_load_px<BPP>(prev + i);
+    __m128i pred;
+    if (ft == 1) {
+      pred = a;
+    } else if (ft == 3) {
+      pred = _mm_srli_epi16(_mm_add_epi16(a, b), 1);
+    } else {
+      const __m128i pa = fc_abs16(_mm_sub_epi16(b, c));
+      const __m128i pb = fc_abs16(_mm_sub_epi16(a, c));
+      const __m128i pc =
+          fc_abs16(_mm_sub_epi16(_mm_add_epi16(a, b), _mm_add_epi16(c, c)));
+      const __m128i least = _mm_min_epi16(pa, _mm_min_epi16(pb, pc));
+      const __m128i take_a = _mm_cmpeq_epi16(least, pa);
+      const __m128i take_b = _mm_cmpeq_epi16(least, pb);
+      const __m128i b_or_c = _mm_or_si128(_mm_and_si128(take_b, b),
+                                          _mm_andnot_si128(take_b, c));
+      pred = _mm_or_si128(_mm_and_si128(take_a, a),
+                          _mm_andnot_si128(take_a, b_or_c));
+      c = b;
+    }
+    a = _mm_and_si128(_mm_add_epi16(d, pred), _mm_set1_epi16(0xff));
+    fc_store_px<BPP>(dst + i, a);
+  }
+}
+}  // extern "C++"
+#endif
+
+// Reconstruct one row of filter type ft from src into dst (which may start
+// below src in the same buffer: every byte is read before it is written
+// over), given the reconstructed row above (zeros for the first) and the
+// bytes of a pixel. False for a filter type PNG does not define.
+static bool fc_unfilter_row(int ft, const uint8_t* src, uint8_t* dst,
+                            const uint8_t* prev, size_t n, size_t bpp) {
+#if defined(__SSE2__)
+  if (ft == 1 || ft == 3 || ft == 4) {
+    if (bpp == 6) {
+      fc_unfilter_px<6>(ft, src, dst, prev, n);
+      return true;
+    }
+    if (bpp == 8) {
+      fc_unfilter_px<8>(ft, src, dst, prev, n);
+      return true;
+    }
+  }
+#endif
+  switch (ft) {
+    case 0:
+      std::memmove(dst, src, n);
+      return true;
+    case 1:
+      for (size_t i = 0; i < bpp; ++i) dst[i] = src[i];
+      for (size_t i = bpp; i < n; ++i) {
+        dst[i] = static_cast<uint8_t>(src[i] + dst[i - bpp]);
+      }
+      return true;
+    case 2:
+      for (size_t i = 0; i < n; ++i) {
+        dst[i] = static_cast<uint8_t>(src[i] + prev[i]);
+      }
+      return true;
+    case 3:
+      for (size_t i = 0; i < bpp; ++i) {
+        dst[i] = static_cast<uint8_t>(src[i] + (prev[i] >> 1));
+      }
+      for (size_t i = bpp; i < n; ++i) {
+        dst[i] = static_cast<uint8_t>(src[i] + ((dst[i - bpp] + prev[i]) >> 1));
+      }
+      return true;
+    case 4:
+      for (size_t i = 0; i < bpp; ++i) {
+        dst[i] = static_cast<uint8_t>(src[i] + prev[i]);
+      }
+      for (size_t i = bpp; i < n; ++i) {
+        dst[i] = static_cast<uint8_t>(
+            src[i] + fc_paeth(dst[i - bpp], prev[i], prev[i - bpp]));
+      }
+      return true;
+    default:
+      return false;
+  }
+}
+
+// The stream of a 16-bit, non-interlaced RGB (3) or RGBA (4) PNG without a
+// tRNS chunk: its size and where its IDAT chunks lie. Anything else (or a
+// chunk whose CRC does not match) leaves ok false.
+struct fc_png16_layout {
+  bool ok = false;
+  uint32_t width = 0, height = 0;
+  int channels = 0;
+  std::vector<std::pair<const uint8_t*, size_t>> idat;
+  size_t idat_bytes = 0;
+};
+
+static fc_png16_layout fc_png16_scan(const uint8_t* data, size_t len) {
+  static const uint8_t kSig[8] = {137, 80, 78, 71, 13, 10, 26, 10};
+  fc_png16_layout out;
+  if (len < 8 + 25 || std::memcmp(data, kSig, 8) != 0) return out;
+  size_t pos = 8;
+  bool ended = false;
+  while (!ended && len - pos >= 12) {
+    const size_t n = fc_be32(data + pos);
+    if (n > 0x7fffffffu || len - pos - 12 < n) return out;
+    const uint8_t* type = data + pos + 4;
+    const uint8_t* body = type + 4;
+    const bool critical = !(type[0] & 0x20);
+    if (critical &&
+        libdeflate_crc32(0, type, n + 4) != fc_be32(body + n)) {
+      return out;
+    }
+    if (pos == 8) {
+      // IHDR first: 16 bits, RGB or RGBA, deflate, filter method 0, no
+      // interlace
+      if (std::memcmp(type, "IHDR", 4) != 0 || n != 13) return out;
+      out.width = fc_be32(body);
+      out.height = fc_be32(body + 4);
+      const int color = body[9];
+      if (body[8] != 16 || (color != 2 && color != 6) || body[10] != 0 ||
+          body[11] != 0 || body[12] != 0 || out.width == 0 ||
+          out.height == 0 || out.width > 0x7fffffffu ||
+          out.height > 0x7fffffffu) {
+        return out;
+      }
+      out.channels = color == 6 ? 4 : 3;
+    } else if (std::memcmp(type, "IDAT", 4) == 0) {
+      out.idat.emplace_back(body, n);
+      out.idat_bytes += n;
+    } else if (std::memcmp(type, "tRNS", 4) == 0) {
+      return out;  // a colour key: a transparency the samples do not hold
+    } else if (std::memcmp(type, "IEND", 4) == 0) {
+      ended = true;
+    }
+    pos += 12 + n;
+  }
+  out.ok = ended && !out.idat.empty();
+  return out;
+}
+
+// The layout's samples into a frame buffer (frame_take; *cap as it says),
+// or nullptr for a stream that does not inflate to exactly its rows.
+static uint16_t* fc_png16_read(const fc_png16_layout& png, fc_frame_pool* frames,
+                               size_t* cap) {
+  const size_t bpp = static_cast<size_t>(png.channels) * 2;
+  const size_t stride = static_cast<size_t>(png.width) * bpp;
+  const size_t h = png.height;
+  size_t nbytes = 0;
+  if (__builtin_mul_overflow(stride + 1, h, &nbytes)) return nullptr;
+  // one stream: the chunks' bodies end to end, where there is more than one
+  // (a master's stream is about as large as its frame, so its buffer comes
+  // from the frame pool too, and goes back when the stream is inflated)
+  const uint8_t* stream = png.idat[0].first;
+  uint8_t* joined = nullptr;
+  size_t joined_cap = 0;
+  if (png.idat.size() > 1) {
+    int joined_reused = 0;
+    joined = frame_take(frames, png.idat_bytes, &joined_cap, &joined_reused);
+    if (!joined) return nullptr;
+    size_t at = 0;
+    for (const auto& part : png.idat) {
+      std::memcpy(joined + at, part.first, part.second);
+      at += part.second;
+    }
+    stream = joined;
+  }
+  int reused = 0;
+  // the rows as stored, each behind its filter byte; the frame is unfiltered
+  // toward the front of the same buffer
+  uint8_t* buf = frame_take(frames, nbytes, cap, &reused);
+  libdeflate_decompressor* inflater =
+      buf ? libdeflate_alloc_decompressor() : nullptr;
+  size_t got = 0;
+  bool ok = inflater != nullptr &&
+            libdeflate_zlib_decompress(inflater, stream, png.idat_bytes, buf,
+                                       nbytes, &got) == LIBDEFLATE_SUCCESS &&
+            got == nbytes;
+  if (inflater) libdeflate_free_decompressor(inflater);
+  if (joined) frame_give(frames, joined, joined_cap);
+  if (ok) {
+    std::vector<uint8_t> zeros(stride, 0);
+    const uint8_t* prev = zeros.data();
+    for (size_t y = 0; y < h && ok; ++y) {
+      const uint8_t* row = buf + y * (stride + 1);
+      uint8_t* dst = buf + y * stride;
+      ok = fc_unfilter_row(row[0], row + 1, dst, prev, stride, bpp);
+      // the row above is done with: to native order
+      if (kLittleEndian && y > 0) fc_swap16(buf + (y - 1) * stride, stride);
+      prev = dst;
+    }
+    if (ok && kLittleEndian) {
+      fc_swap16(buf + (h - 1) * stride, stride);
+    }
+  }
+  if (!ok) {
+    if (buf) frame_give(frames, buf, *cap);
+    *cap = 0;
+    return nullptr;
+  }
+  return reinterpret_cast<uint16_t*>(buf);
+}
+
+static void fc_png_error_fn(png_structp png, png_const_charp) {
+  longjmp(png_jmpbuf(png), 1);
+}
+
+static void fc_png_warning_fn(png_structp, png_const_charp) {}
+
+struct fc_png_source {
+  const uint8_t* data;
+  size_t len;
+  size_t pos;
+};
+
+static void fc_png_read_fn(png_structp png, png_bytep out, png_size_t n) {
+  auto* src = static_cast<fc_png_source*>(png_get_io_ptr(png));
+  if (src->len - src->pos < n) png_error(png, "truncated PNG");
+  std::memcpy(out, src->data + src->pos, n);
+  src->pos += n;
+}
+
+// Decode a 16-bit PNG of grey or RGB, with or without alpha, to tightly
+// packed uint16 RGB (channels 3) or RGBA (channels 4): grey is spread to
+// three equal channels, nothing else is changed. Returns nullptr for any
+// other bit depth, a palette, a tRNS colour key (a transparency the
+// samples alone do not hold), or a broken file. The buffer comes from
+// frame_take (``frames`` null: malloc), its capacity back in *cap (0:
+// fc_free releases it, else fc_pool_release).
+uint16_t* fc_png_decode16(const uint8_t* data, size_t len,
+                          fc_frame_pool* frames, int* width, int* height,
+                          int* channels, size_t* cap) {
+  *cap = 0;
+  const fc_png16_layout layout = fc_png16_scan(data, len);
+  if (layout.ok) {
+    uint16_t* pixels = fc_png16_read(layout, frames, cap);
+    if (pixels) {
+      *width = static_cast<int>(layout.width);
+      *height = static_cast<int>(layout.height);
+      *channels = layout.channels;
+    }
+    return pixels;
+  }
+  png_structp png = png_create_read_struct(
+      PNG_LIBPNG_VER_STRING, nullptr, fc_png_error_fn, fc_png_warning_fn);
+  if (!png) return nullptr;
+  png_infop info = png_create_info_struct(png);
+  if (!info) {
+    png_destroy_read_struct(&png, nullptr, nullptr);
+    return nullptr;
+  }
+  fc_png_source src{data, len, 0};
+  // written after setjmp and read by its error branch: volatile, so a
+  // longjmp never finds a stale copy in a register
+  uint8_t* volatile out = nullptr;
+  volatile size_t out_cap = 0;
+  png_bytep* volatile rows = nullptr;
+  if (setjmp(png_jmpbuf(png))) {
+    if (out) frame_give(frames, out, out_cap);
+    std::free(rows);
+    png_destroy_read_struct(&png, &info, nullptr);
+    return nullptr;
+  }
+  png_set_read_fn(png, &src, fc_png_read_fn);
+  png_read_info(png, info);
+  png_uint_32 w = 0, h = 0;
+  int depth = 0, color = 0;
+  png_get_IHDR(png, info, &w, &h, &depth, &color, nullptr, nullptr, nullptr);
+  if (depth != 16 || (color & PNG_COLOR_MASK_PALETTE) ||
+      png_get_valid(png, info, PNG_INFO_tRNS)) {
+    png_destroy_read_struct(&png, &info, nullptr);
+    return nullptr;
+  }
+  if (!(color & PNG_COLOR_MASK_COLOR)) png_set_gray_to_rgb(png);
+#if __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__
+  png_set_swap(png);
+#endif
+  png_set_interlace_handling(png);
+  png_read_update_info(png, info);
+  const int ch = png_get_channels(png, info);
+  const size_t stride = png_get_rowbytes(png, info);
+  if ((ch != 3 && ch != 4) || stride != static_cast<size_t>(w) * ch * 2) {
+    png_destroy_read_struct(&png, &info, nullptr);
+    return nullptr;
+  }
+  size_t taken = 0;
+  int reused = 0;
+  out = frame_take(frames, stride * h, &taken, &reused);
+  out_cap = taken;
+  rows = static_cast<png_bytep*>(std::malloc(sizeof(png_bytep) * h));
+  if (!out || !rows) png_error(png, "out of memory");
+  for (png_uint_32 y = 0; y < h; ++y) rows[y] = out + stride * y;
+  png_read_image(png, rows);
+  png_read_end(png, nullptr);
+  std::free(rows);
+  png_destroy_read_struct(&png, &info, nullptr);
+  *width = static_cast<int>(w);
+  *height = static_cast<int>(h);
+  *channels = ch;
+  *cap = taken;
+  return reinterpret_cast<uint16_t*>(out);
+}
+
+// Filter one row as libpng chooses by default at 8 bits and up: of None,
+// Sub, Up, Average and Paeth, the one whose bytes, read as signed, sum to
+// the least magnitude (the first such in that order). Writes the filter
+// byte and the filtered row to out[0..n].
+static void fc_filter_row(const uint8_t* cur, const uint8_t* prev, size_t n,
+                          size_t bpp, uint8_t* scratch, uint8_t* out) {
+  auto cost = [](const uint8_t* row, size_t n) {
+    size_t sum = 0;
+    for (size_t i = 0; i < n; ++i) {
+      sum += row[i] < 128 ? row[i] : 256 - row[i];
+    }
+    return sum;
+  };
+  uint8_t* sub = scratch;
+  uint8_t* up = scratch + n;
+  uint8_t* avg = scratch + 2 * n;
+  uint8_t* paeth = scratch + 3 * n;
+  for (size_t i = 0; i < bpp; ++i) {
+    sub[i] = cur[i];
+    up[i] = static_cast<uint8_t>(cur[i] - prev[i]);
+    avg[i] = static_cast<uint8_t>(cur[i] - (prev[i] >> 1));
+    paeth[i] = up[i];
+  }
+  // one loop a filter, each free of branches, so that each vectorises
+  for (size_t i = bpp; i < n; ++i) {
+    sub[i] = static_cast<uint8_t>(cur[i] - cur[i - bpp]);
+  }
+  for (size_t i = bpp; i < n; ++i) {
+    up[i] = static_cast<uint8_t>(cur[i] - prev[i]);
+  }
+  for (size_t i = bpp; i < n; ++i) {
+    avg[i] = static_cast<uint8_t>(cur[i] - ((cur[i - bpp] + prev[i]) >> 1));
+  }
+  for (size_t i = bpp; i < n; ++i) {
+    const int a = cur[i - bpp], b = prev[i], c = prev[i - bpp];
+    const int pa = std::abs(b - c), pb = std::abs(a - c);
+    const int pc = std::abs(a + b - 2 * c);
+    const int pred = (pa <= pb && pa <= pc) ? a : (pb <= pc ? b : c);
+    paeth[i] = static_cast<uint8_t>(cur[i] - pred);
+  }
+  const uint8_t* rows[5] = {cur, sub, up, avg, paeth};
+  int best = 0;
+  size_t best_cost = cost(cur, n);
+  for (int f = 1; f < 5; ++f) {
+    const size_t c = cost(rows[f], n);
+    if (c < best_cost) {
+      best = f;
+      best_cost = c;
+    }
+  }
+  out[0] = static_cast<uint8_t>(best);
+  std::memcpy(out + 1, rows[best], n);
+}
+
+static uint8_t* fc_put_chunk(uint8_t* at, const char* type, const uint8_t* body,
+                             size_t n) {
+  fc_put_be32(at, static_cast<uint32_t>(n));
+  std::memcpy(at + 4, type, 4);
+  if (body != at + 8 && n) std::memcpy(at + 8, body, n);
+  fc_put_be32(at + 8 + n, static_cast<uint32_t>(libdeflate_crc32(0, at + 4, n + 4)));
+  return at + 12 + n;
+}
+
+// Encode native-endian uint16 RGB (channels 3) or RGBA (4) as a
+// non-interlaced 16-bit PNG of one IDAT chunk, deflated at `level` (0-9).
+// Returns malloc'd buffer.
+uint8_t* fc_png_encode16(const uint16_t* pixels, int width, int height,
+                         int channels, int level, size_t* out_len) {
+  if ((channels != 3 && channels != 4) || width <= 0 || height <= 0 ||
+      level < 0 || level > 9) {
+    return nullptr;
+  }
+  const size_t bpp = static_cast<size_t>(channels) * 2;
+  const size_t stride = static_cast<size_t>(width) * bpp;
+  const size_t h = static_cast<size_t>(height);
+  size_t raw_bytes = 0;
+  if (__builtin_mul_overflow(stride + 1, h, &raw_bytes)) return nullptr;
+  uint8_t* raw = static_cast<uint8_t*>(std::malloc(raw_bytes));
+  if (!raw) return nullptr;
+  // big-endian rows: this one and the one above, and four filtered forms
+  std::vector<uint8_t> rows(6 * stride, 0);
+  uint8_t* cur = rows.data();
+  uint8_t* prev = cur + stride;
+  const uint8_t* src = reinterpret_cast<const uint8_t*>(pixels);
+  for (size_t y = 0; y < h; ++y) {
+    std::memcpy(cur, src + y * stride, stride);
+    if (kLittleEndian) fc_swap16(cur, stride);
+    fc_filter_row(cur, prev, stride, bpp, rows.data() + 2 * stride,
+                  raw + y * (stride + 1));
+    std::swap(cur, prev);
+  }
+  libdeflate_compressor* deflater = libdeflate_alloc_compressor(level);
+  if (!deflater) {
+    std::free(raw);
+    return nullptr;
+  }
+  const size_t bound = libdeflate_zlib_compress_bound(deflater, raw_bytes);
+  // signature, IHDR, the IDAT's head, its body, its CRC, IEND
+  uint8_t* out = static_cast<uint8_t*>(std::malloc(8 + 25 + 8 + bound + 4 + 12));
+  size_t zlen = 0;
+  if (out) {
+    zlen = libdeflate_zlib_compress(deflater, raw, raw_bytes, out + 8 + 25 + 8,
+                                    bound);
+  }
+  libdeflate_free_compressor(deflater);
+  std::free(raw);
+  if (zlen == 0 || zlen > 0x7fffffffu) {
+    std::free(out);
+    return nullptr;
+  }
+  static const uint8_t kSig[8] = {137, 80, 78, 71, 13, 10, 26, 10};
+  std::memcpy(out, kSig, 8);
+  uint8_t ihdr[13];
+  fc_put_be32(ihdr, static_cast<uint32_t>(width));
+  fc_put_be32(ihdr + 4, static_cast<uint32_t>(height));
+  ihdr[8] = 16;
+  ihdr[9] = channels == 4 ? 6 : 2;
+  ihdr[10] = ihdr[11] = ihdr[12] = 0;
+  uint8_t* at = fc_put_chunk(out + 8, "IHDR", ihdr, 13);
+  at = fc_put_chunk(at, "IDAT", at + 8, zlen);
+  at = fc_put_chunk(at, "IEND", nullptr, 0);
+  *out_len = static_cast<size_t>(at - out);
   return out;
 }
 

@@ -1,8 +1,8 @@
 """ctypes bindings for the native fastcodec library.
 
 Builds libfastcodec.so from the tracked sources on first use (make, g++,
-links libjpeg/libpng/libwebp) and exposes decode/encode entry points with
-numpy in/out. All calls release the GIL (plain ctypes calls do), so the
+links libjpeg/libpng/libdeflate/libwebp) and exposes decode/encode entry
+points with numpy in/out. All calls release the GIL (plain ctypes calls do), so the
 fc_pool batch decode genuinely runs decodes in parallel on multi-core hosts.
 
 ``available()`` is False when the toolchain or libs are missing; callers
@@ -33,8 +33,9 @@ _lib_lock = threading.Lock()
 # layout but decodes full frames only, so ROI requests are not forwarded.
 _roi_supported = False
 # The newest entry point: a binary without it was built from older sources
-# (another batch-item layout), and is rebuilt like one that will not load.
-_REQUIRED_SYMBOL = "fc_pool_workers"
+# (another batch-item layout, no 16-bit PNG), and is rebuilt like one that
+# will not load.
+_REQUIRED_SYMBOL = "fc_png_encode16"
 
 
 class _BatchItem(ctypes.Structure):
@@ -207,6 +208,17 @@ def _load():
         lib.fc_png_encode.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
             ctypes.POINTER(ctypes.c_size_t),
+        ]
+        lib.fc_png_decode16.restype = ctypes.c_void_p
+        lib.fc_png_decode16.argtypes = [
+            ctypes.c_char_p, ctypes.c_size_t, ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_size_t),
+        ]
+        lib.fc_png_encode16.restype = ctypes.c_void_p
+        lib.fc_png_encode16.argtypes = [
+            ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.c_int, ctypes.POINTER(ctypes.c_size_t),
         ]
         lib.fc_probe.restype = ctypes.c_int
         lib.fc_probe.argtypes = [
@@ -470,27 +482,80 @@ def probe(data: bytes) -> Optional[Tuple[str, int, int, int]]:
     )
 
 
+@dataclasses.dataclass
+class PngCall:
+    """What one native PNG call says of itself, for whoever records it (the
+    handler, into its registry): the seconds inside the library and the
+    bits of a sample it read or wrote. ``bits`` stays 0 where no native
+    PNG call finished."""
+
+    seconds: float = 0.0
+    bits: int = 0
+
+
+def _timed(call: Optional[PngCall], bits: int, t0: float) -> None:
+    if call is not None:
+        call.seconds = time.perf_counter() - t0
+        call.bits = bits
+
+
 def png_decode(
-    data: bytes, channels: int = 0
+    data: bytes, channels: int = 0, call: Optional[PngCall] = None
 ) -> Optional[Tuple[np.ndarray, int]]:
-    """Decode PNG -> ([h, w, ch] uint8, ch). channels: 0 auto, 3 RGB, 4 RGBA."""
+    """Decode PNG -> ([h, w, ch] uint8, ch). channels: 0 auto, 3 RGB, 4 RGBA.
+    A 16-bit file comes out at 8 bits here (``png_decode16`` keeps 16)."""
     lib = _load()
     if not lib:
         return None
     w = ctypes.c_int()
     h = ctypes.c_int()
     ch = ctypes.c_int()
+    t0 = time.perf_counter()
     ptr = lib.fc_png_decode(
         data, len(data), channels,
         ctypes.byref(w), ctypes.byref(h), ctypes.byref(ch),
     )
     if not ptr:
         return None
+    _timed(call, 8, t0)
     arr = _adopt_pixels(lib, ptr, w.value * h.value * ch.value)
     return arr.reshape(h.value, w.value, ch.value), ch.value
 
 
-def png_encode(pixels: np.ndarray) -> Optional[bytes]:
+def png_decode16(
+    data: bytes, call: Optional[PngCall] = None
+) -> Optional[Tuple[np.ndarray, int]]:
+    """Decode a 16-bit PNG -> ([h, w, ch] uint16, ch), ch 3 (RGB; grey is
+    spread to three channels) or 4 (with alpha): the stored samples, with
+    no gamma or colour transform. The array owns the native buffer, as a
+    ``uint16`` view of ``_adopt_pixels``'s bytes: nothing is copied. A
+    full frame's buffer is one the process's decode pool keeps
+    (``get_pool``), as a batched JPEG frame's is. None for any other
+    depth, a palette, a tRNS colour key or a broken file."""
+    lib = _load()
+    if not lib:
+        return None
+    pool = get_pool()
+    frames = pool._frames if pool is not None else None
+    w = ctypes.c_int()
+    h = ctypes.c_int()
+    ch = ctypes.c_int()
+    cap = ctypes.c_size_t()
+    t0 = time.perf_counter()
+    ptr = lib.fc_png_decode16(
+        data, len(data), frames, ctypes.byref(w), ctypes.byref(h),
+        ctypes.byref(ch), ctypes.byref(cap),
+    )
+    if not ptr:
+        return None
+    _timed(call, 16, t0)
+    arr = _adopt_pixels(lib, ptr, w.value * h.value * ch.value * 2, frames,
+                        cap.value)
+    return arr.view(np.uint16).reshape(h.value, w.value, ch.value), ch.value
+
+
+def png_encode(pixels: np.ndarray,
+               call: Optional[PngCall] = None) -> Optional[bytes]:
     """Encode [h, w, 3|4] uint8 -> PNG bytes."""
     lib = _load()
     if not lib:
@@ -501,11 +566,41 @@ def png_encode(pixels: np.ndarray) -> Optional[bytes]:
     if channels not in (3, 4):
         return None
     out_len = ctypes.c_size_t()
+    t0 = time.perf_counter()
     ptr = lib.fc_png_encode(
         pixels.tobytes(), w, h, channels, ctypes.byref(out_len)
     )
     if not ptr:
         return None
+    _timed(call, 8, t0)
+    return _copy_bytes(lib, ptr, out_len.value)
+
+
+#: deflate level of a 16-bit PNG answer (libdeflate's): zlib's default
+#: level, which the 8-bit (simplified API) encode uses too
+PNG_LEVEL = 6
+
+
+def png_encode16(pixels: np.ndarray, level: int = PNG_LEVEL,
+                 call: Optional[PngCall] = None) -> Optional[bytes]:
+    """Encode [h, w, 3|4] uint16 -> a 16-bit PNG at zlib ``level``, read
+    in place (no copy of a C-contiguous array)."""
+    lib = _load()
+    if not lib:
+        return None
+    pixels = np.ascontiguousarray(pixels, dtype=np.uint16)
+    if pixels.ndim != 3 or pixels.shape[2] not in (3, 4):
+        return None
+    h, w, channels = pixels.shape
+    out_len = ctypes.c_size_t()
+    t0 = time.perf_counter()
+    ptr = lib.fc_png_encode16(
+        pixels.ctypes.data, w, h, channels, int(level),
+        ctypes.byref(out_len),
+    )
+    if not ptr:
+        return None
+    _timed(call, 16, t0)
     return _copy_bytes(lib, ptr, out_len.value)
 
 

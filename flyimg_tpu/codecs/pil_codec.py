@@ -43,7 +43,7 @@ def set_max_pixels(limit: int) -> None:
 class DecodedImage:
     """Host-side decoded image + metadata the pipeline needs."""
 
-    rgb: np.ndarray                      # [h, w, 3] uint8, alpha flattened
+    rgb: np.ndarray                      # [h, w, 3] uint8 (uint16: a 16-bit PNG)
     alpha: Optional[np.ndarray]          # [h, w] uint8 or None
     mime: str
     orig_size: Tuple[int, int]           # (w, h) BEFORE any draft prescale
@@ -56,6 +56,12 @@ class DecodedImage:
     # None on every full-frame decode path.
     roi_offset: Optional[Tuple[int, int]] = None
     frame_size: Optional[Tuple[int, int]] = None
+    # the bits a sample of the source had where the decode gave fewer
+    # (a 16-bit PNG read at 8 bits: with alpha, a colour key, or through
+    # Pillow); None where the samples kept their depth. The handler counts
+    # it (flyimg_depth_narrowed_total{at="decode"}): a narrowing is never
+    # silent
+    narrowed_from: Optional[int] = None
 
     @property
     def size(self) -> Tuple[int, int]:
@@ -104,8 +110,18 @@ def decode(
         rgb = np.asarray(img.convert("RGB")).copy()
 
     return DecodedImage(
-        rgb=rgb, alpha=alpha, mime=mime, orig_size=orig_size, n_frames=n_frames
+        rgb=rgb, alpha=alpha, mime=mime, orig_size=orig_size, n_frames=n_frames,
+        # Pillow reads a 16-bit PNG at 8 bits
+        narrowed_from=16 if png_bit_depth(data) == 16 else None,
     )
+
+
+def png_bit_depth(data: bytes) -> Optional[int]:
+    """The bit depth in a PNG's IHDR (which the format puts first); None
+    for bytes that are no PNG."""
+    if len(data) < 26 or data[:8] != b"\x89PNG\r\n\x1a\n" or data[12:16] != b"IHDR":
+        return None
+    return data[24]
 
 
 def decode_jpeg_roi(

@@ -8,6 +8,8 @@ except the "command" is a fused XLA program:
     -> [grayscale] -> [monochrome dither] -> [rotate] -> [unsharp]
     -> [sharpen] -> [blur] -> round/clip -> uint8 out
 
+(uint16 in and out for 16-bit samples: ``make_program_fn``'s ``depth``).
+
 Programs are cached by (plan signature, padded input bucket, output shape):
 the per-image geometry (true sizes + source window spans) enters as traced
 scalars, so one executable serves every source size that lands in the same
@@ -17,6 +19,8 @@ application order used by the reference.
 
 from __future__ import annotations
 
+import functools
+import inspect
 import threading
 import time
 from dataclasses import dataclass
@@ -32,6 +36,7 @@ from flyimg_tpu.ops.color import monochrome_dither, to_grayscale
 from flyimg_tpu.ops.filters import gaussian_blur, sharpen as sharpen_op, unsharp_mask
 from flyimg_tpu.ops.pad import extent_pad
 from flyimg_tpu.ops.resample import (
+    SAMPLE_PRECISION,
     kernel_mode,
     resample_image,
     resample_image_banded,
@@ -111,6 +116,7 @@ def make_program_fn(
     plan: TransformPlan,
     rotate_dynamic: bool = False,
     band_taps: Optional[Tuple[int, int]] = None,
+    depth: int = 8,
 ):
     """The raw (unjitted) device program closure for one op config. Shared
     by the single-image path (build_program jits it) and the batch runtime
@@ -126,7 +132,14 @@ def make_program_fn(
     gather-contract (ops/resample.py resample_image_banded) with those
     STATIC per-axis band widths — callers derive them from the plan's
     true geometry via ``select_band_taps`` and carry them in the program
-    cache key (docs/kernels.md)."""
+    cache key (docs/kernels.md).
+
+    ``depth`` is the bits of a sample in and out: 8 (``uint8``) or 16
+    (``uint16``). Every stage works in levels of 255 whatever the depth,
+    so that the constants of the pixel ops (dither thresholds, fills,
+    unsharp thresholds) hold: 16-bit samples are divided by 257 on the way
+    in and multiplied back on the way out, and the resample's contractions
+    keep 16 significant bits (``SAMPLE_PRECISION``)."""
 
     def program(img_u8, in_true, span_y, span_x, out_true):
         # every stage runs under a jax.named_scope: the names reach the
@@ -135,17 +148,21 @@ def make_program_fn(
         # (flyimg.resample_rows / flyimg.resample_cols / flyimg.weights
         # are opened inside ops/resample.py)
         x = img_u8.astype(jnp.float32)
+        if depth == 16:
+            x = x / 257.0
+        precision = SAMPLE_PRECISION[depth]
         cur_true = in_true[:2]
         if resample_out is not None:
             if band_taps is not None:
                 x = resample_image_banded(
                     x, resample_out, span_y, span_x, out_true,
                     in_true[:2], band_taps, method=plan.filter_method,
+                    precision=precision,
                 )
             else:
                 x = resample_image(
                     x, resample_out, span_y, span_x, out_true, in_true[:2],
-                    method=plan.filter_method,
+                    method=plan.filter_method, precision=precision,
                 )
             cur_true = out_true
         if pad_canvas is not None:
@@ -182,6 +199,10 @@ def make_program_fn(
             if plan.blur is not None:
                 r, s = plan.blur
                 x = gaussian_blur(x, r, s)
+            if depth == 16:
+                return jnp.clip(
+                    jnp.round(x * 257.0), 0.0, 65535.0
+                ).astype(jnp.uint16)
             return jnp.clip(jnp.round(x), 0.0, 255.0).astype(jnp.uint8)
 
     return program
@@ -205,7 +226,7 @@ def _ledger():
 def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
                     resample_out=None, pad_canvas=None,
                     pad_offset=(0, 0), rotate_dynamic=False,
-                    band_taps=None) -> Dict[str, object]:
+                    band_taps=None, depth=8) -> Dict[str, object]:
     """Compact human-readable program identity for the cost ledger /
     ``/debug/plans`` — which ops the program fuses and at what static
     shapes, without dumping the whole TransformPlan repr. ``kernel``
@@ -216,7 +237,8 @@ def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
     different keys must never produce identical descriptors (the
     flylint ``program-key-drift`` rule holds this to the cache keys
     mechanically), which is why extent entries carry ``pad_offset`` and
-    the fill ``background`` alongside the canvas."""
+    the fill ``background`` alongside the canvas, and a program of 16-bit
+    samples its ``depth``."""
     ops = []
     if resample_out is not None:
         ops.append("resample")
@@ -254,6 +276,8 @@ def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
             list(plan.background) if plan.background is not None else None
         )
     desc["filter"] = plan.filter_method
+    if depth != 8:
+        desc["depth"] = int(depth)
     return desc
 
 
@@ -270,13 +294,18 @@ def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
 STAGE_PIECE_BYTES = 128 << 20
 
 
-def stage_pieces(batch: int, in_shape: Tuple[int, int]) -> int:
+def stage_pieces(batch: int, in_shape: Tuple[int, int],
+                 sample_bytes: int = 1) -> int:
     """How many pieces a batched program takes its images in: equal runs
     of whole frames along the batch axis, a power of two of them to a
     piece, each piece at most ``STAGE_PIECE_BYTES`` (one frame where a
-    single frame is larger). A function of the program's static shape
-    alone, so it is settled when the program is built."""
-    frames = max(STAGE_PIECE_BYTES // (in_shape[0] * in_shape[1] * 3), 1)
+    single frame is larger) at ``sample_bytes`` a sample. A function of
+    the program's static shape alone, so it is settled when the program
+    is built."""
+    frames = max(
+        STAGE_PIECE_BYTES // (in_shape[0] * in_shape[1] * 3 * sample_bytes),
+        1,
+    )
     frames = 1 << (frames.bit_length() - 1)
     while batch % frames:
         frames //= 2
@@ -510,7 +539,31 @@ def _input_devices(compiled):
         return None
 
 
-@lru_cache(maxsize=256)
+def bound_lru_cache(maxsize: int):
+    """``functools.lru_cache`` keyed on the arguments as the signature
+    binds them, defaults filled in: ``f(a, b)``, ``f(a, b, 8)`` and ``f(a,
+    b, depth=8)`` are one entry, where a plain ``lru_cache`` keys each
+    spelling apart and a caller that omits a default would build (and
+    compile) a second handle of the same program. ``cache_info`` and
+    ``cache_clear`` are the cache's own."""
+    def wrap(fn):
+        signature = inspect.signature(fn)
+        cached = lru_cache(maxsize=maxsize)(fn)
+
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            bound = signature.bind(*args, **kwargs)
+            bound.apply_defaults()
+            return cached(*bound.args)
+
+        call.cache_info = cached.cache_info
+        call.cache_clear = cached.cache_clear
+        return call
+
+    return wrap
+
+
+@bound_lru_cache(maxsize=256)
 def build_program(
     in_shape: Tuple[int, int],
     resample_out: Optional[Tuple[int, int]],
@@ -518,6 +571,7 @@ def build_program(
     pad_offset: Tuple[int, int],
     plan: TransformPlan,
     band_taps: Optional[Tuple[int, int]] = None,
+    depth: int = 8,
 ) -> ProgramHandle:
     """Compile (lazily, on first call) the device program for one op
     config at one padded input shape, as a ``ProgramHandle`` feeding the
@@ -527,10 +581,12 @@ def build_program(
     handle single-shape, which is what lets it hold ONE compiled
     executable. ``band_taps`` is part of the cache AND ledger key:
     dense and banded variants of one plan are distinct programs that
-    must never collide in either table."""
+    must never collide in either table; so is ``depth``, the bits of a
+    sample (``make_program_fn``)."""
     key = (
         "single", in_shape, resample_out, pad_canvas, pad_offset, plan,
         band_taps,
+        depth,
     )
     # fleet warm start (runtime/warmstart.py): note this program's
     # identity for the shared manifest — inside the lru body, so once
@@ -538,18 +594,19 @@ def build_program(
     from flyimg_tpu.runtime import warmstart
 
     warmstart.record_single(
-        in_shape, resample_out, pad_canvas, pad_offset, plan, band_taps
+        in_shape, resample_out, pad_canvas, pad_offset, plan, band_taps,
+        depth,
     )
     return ProgramHandle(
         jax.jit(make_program_fn(
             resample_out, pad_canvas, pad_offset, plan,
-            band_taps=band_taps,
+            band_taps=band_taps, depth=depth,
         )),
         key,
         plan_descriptor(
             plan, in_shape=in_shape, resample_out=resample_out,
             pad_canvas=pad_canvas, pad_offset=pad_offset,
-            band_taps=band_taps,
+            band_taps=band_taps, depth=depth,
         ),
     )
 
@@ -644,7 +701,8 @@ def run_plan(
     plan: TransformPlan,
     src_window: Optional[Tuple[int, int]] = None,
 ) -> np.ndarray:
-    """Execute a plan on one host image [h, w, 3] uint8 -> uint8 output.
+    """Execute a plan on one host image [h, w, 3] uint8 -> uint8 output
+    (uint16 -> uint16 for 16-bit samples).
 
     Pads the input up to a shape bucket so repeated calls with same-signature
     plans and similar sizes reuse one compiled program; the pad region is
@@ -694,9 +752,10 @@ def run_plan(
 
     slice_out = None
     band = None
+    depth = 16 if image.dtype == np.uint16 else 8
     if _needs_resample(plan, layout):
         bh, bw = _bucket_dim(h), _bucket_dim(w)
-        padded = np.zeros((bh, bw, image.shape[2]), dtype=np.uint8)
+        padded = np.zeros((bh, bw, image.shape[2]), dtype=image.dtype)
         padded[:h, :w] = image
         resample_out = layout.resample_out
         in_shape = (bh, bw)
@@ -738,6 +797,7 @@ def run_plan(
         layout.pad_offset,
         plan.device_plan(),
         band,
+        depth,
     )
     t0 = time.perf_counter()
     out = fn(

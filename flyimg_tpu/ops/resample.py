@@ -152,6 +152,19 @@ def select_band_taps(
     return (min(ky, max(in_h, 1)), min(kx, max(in_w, 1)))
 
 
+#: the precision of the resample's two contractions by the bits of a
+#: sample. ``DEFAULT`` is bfloat16 operands on the TPU: 8 significant bits,
+#: under half a level of an 8-bit sample, and out by up to about 128 of
+#: 65,535. A 16-bit sample takes ``HIGHEST``, float32 as the configuration
+#: states it (six bfloat16 MXU passes on the TPU): ``HIGH``'s three passes
+#: were biased by about a third of a level a contraction on a TPU v5e
+#: (PERF.md section 2). The CPU computes float32 either way.
+SAMPLE_PRECISION = {
+    8: jax.lax.Precision.DEFAULT,
+    16: jax.lax.Precision.HIGHEST,
+}
+
+
 def _kernel_fn(method: str, x: jnp.ndarray) -> jnp.ndarray:
     if method == "lanczos3":
         return jnp.where(jnp.abs(x) < 3.0, jnp.sinc(x) * jnp.sinc(x / 3.0), 0.0)
@@ -224,12 +237,15 @@ def resample_image(
     out_true_hw: jnp.ndarray,
     in_true_hw: jnp.ndarray,
     method: str = "lanczos3",
+    precision=jax.lax.Precision.DEFAULT,
 ) -> jnp.ndarray:
     """Resample one [H, W, C] float image to static [out_h, out_w, C].
 
     ``span_y``/``span_x`` are (start, size) source windows per axis;
     ``out_true_hw``/``in_true_hw`` are (h, w) valid extents. All four may be
-    traced. Two einsums -> both land on the MXU.
+    traced. Two einsums -> both land on the MXU. ``precision`` is that of
+    both contractions (``SAMPLE_PRECISION``): 8-bit samples take
+    ``DEFAULT``, 16-bit ones ``HIGHEST``.
     """
     in_h, in_w = image.shape[0], image.shape[1]
     out_h, out_w = out_hw
@@ -242,20 +258,17 @@ def resample_image(
             in_w, out_w, span_x[0], span_x[1], out_true_hw[1], in_true_hw[1],
             method,
         )
-    if RESAMPLE_FORM == "fold2d_bf16":
+    if RESAMPLE_FORM == "fold2d_bf16" and precision == jax.lax.Precision.DEFAULT:
         return _apply_fold2d_bf16(image, wy, wx, out_h, out_w)
-    # DEFAULT precision = bf16 multiplies with f32 accumulation on TPU: 2.3x
-    # the throughput of the f32 path, worst-case error well under one uint8
-    # level for 8-bit imagery (bf16 has 8 mantissa bits). On CPU this is
-    # plain f32, so conformance tests are unaffected.
+    # DEFAULT precision (8-bit samples) = bf16 multiplies with f32
+    # accumulation on TPU: 2.3x the throughput of the f32 path, worst-case
+    # error well under one uint8 level (bf16 has 8 mantissa bits); HIGHEST
+    # (16-bit samples) is float32. On CPU both are plain f32, so
+    # conformance tests are unaffected.
     with jax.named_scope("flyimg.resample_rows"):
-        tmp = jnp.einsum(
-            "oh,hwc->owc", wy, image, precision=jax.lax.Precision.DEFAULT
-        )
+        tmp = jnp.einsum("oh,hwc->owc", wy, image, precision=precision)
     with jax.named_scope("flyimg.resample_cols"):
-        return jnp.einsum(
-            "ow,hwc->hoc", wx, tmp, precision=jax.lax.Precision.DEFAULT
-        )
+        return jnp.einsum("ow,hwc->hoc", wx, tmp, precision=precision)
 
 
 def _band_axis(
@@ -330,10 +343,12 @@ def resample_image_banded(
     in_true_hw: jnp.ndarray,
     taps_hw: Tuple[int, int],
     method: str = "lanczos3",
+    precision=jax.lax.Precision.DEFAULT,
 ) -> jnp.ndarray:
     """Banded K-tap resample of one [H, W, C] float image to static
     [out_h, out_w, C] — the ``resample_image`` contract with a static
-    per-axis band width ``taps_hw`` (Ky, Kx) instead of dense matrices.
+    per-axis band width ``taps_hw`` (Ky, Kx) instead of dense matrices,
+    and the same ``precision``.
 
     Two gather + contract passes: rows are gathered into [out_h, Ky, W, C]
     and contracted over Ky, then columns into [out_h, out_w, Kx, C] and
@@ -354,14 +369,10 @@ def resample_image_banded(
         )
     with jax.named_scope("flyimg.resample_rows"):
         rows = jnp.take(image, iy, axis=0)            # [oh, Ky, w, c]
-        tmp = jnp.einsum(
-            "ok,okwc->owc", wy, rows, precision=jax.lax.Precision.DEFAULT
-        )
+        tmp = jnp.einsum("ok,okwc->owc", wy, rows, precision=precision)
     with jax.named_scope("flyimg.resample_cols"):
         cols = jnp.take(tmp, ix, axis=1)              # [oh, ow, Kx, c]
-        return jnp.einsum(
-            "ok,hokc->hoc", wx, cols, precision=jax.lax.Precision.DEFAULT
-        )
+        return jnp.einsum("ok,hokc->hoc", wx, cols, precision=precision)
 
 
 #: Weight-application formulation. 'einsum' is the shipped two-einsum

@@ -7,12 +7,16 @@ traced geometry scalars, so a group executes as ONE jitted vmapped program:
 
     uint8 [B, Hb, Wb, 3] + per-image spans/true-sizes -> uint8 [B, Ho, Wo, 3]
 
+(uint16 for 16-bit samples: the sample's dtype is part of the identity, so
+8-bit and 16-bit members never share a launch or a host block).
+
 Flush policy (reference-free; this subsystem has no analog in the
 per-request reference): a group flushes when it reaches ``max_batch`` or
 when its oldest member has waited ``deadline_ms`` — the standard
-throughput/latency dial for dynamic batching; a lone request, and an aux
-group whatever else is pending, flushes at once when the executor is idle
-(``_group_ready``). Batch sizes are bucketed to
+throughput/latency dial for dynamic batching; a lone request (unless the
+last transform launch was full), and an aux group whatever else is pending,
+flushes at once when the executor is idle (``_group_ready``). Batch sizes
+are bucketed to
 powers of two (padding repeats the last image) so XLA compiles a handful of
 batch shapes per program, not one per occupancy. A transform group owns its
 launch's padded host block from the moment it is made, and ``submit`` copies
@@ -62,7 +66,6 @@ import time
 from concurrent.futures import Future
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from functools import lru_cache
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -72,6 +75,7 @@ import numpy as np
 from flyimg_tpu.ops.compose import (
     ProgramHandle,
     _bucket_dim,
+    bound_lru_cache,
     bucket_batch,
     final_extent,
     flat_output_format,
@@ -97,6 +101,8 @@ from flyimg_tpu.spec.plan import TransformPlan
 from flyimg_tpu.testing import faults
 
 MAX_BATCH_BUCKET = 64
+# the host blocks' dtype by the bits of a sample (``_Group.depth``)
+_SAMPLE_DTYPES = {8: np.uint8, 16: np.uint16}
 # the name (and `controller` label) of the controller that runs transform
 # launches: ``BatchController``'s default
 TRANSFORM_CONTROLLER = "device"
@@ -137,7 +143,7 @@ def _round_batch(n: int) -> int:
     return min(bucket_batch(n), MAX_BATCH_BUCKET)
 
 
-@lru_cache(maxsize=256)
+@bound_lru_cache(maxsize=256)
 def build_batched_program(
     batch_size: int,
     in_shape: Tuple[int, int],
@@ -148,6 +154,7 @@ def build_batched_program(
     mesh=None,
     rotate_dynamic: bool = False,
     band_taps: Optional[Tuple[int, int]] = None,
+    depth: int = 8,
 ) -> ProgramHandle:
     """vmap of the single-image program over a static batch axis; with a
     mesh, the batch axis is sharded over its 'data' axis (SPMD fan-out, no
@@ -166,15 +173,21 @@ def build_batched_program(
     mapping (ops/compose.py ``flat_pieces``, ``flatten_images``).
     ``band_taps`` (the banded resample's static per-axis K;
     docs/kernels.md) is part of the cache key AND the ledger key — dense
-    and banded variants of one plan must never collide in either."""
+    and banded variants of one plan must never collide in either. So is
+    ``depth``, the bits of a sample in and out (8: ``u8``, 16: ``u16``
+    throughout, staged in pieces of the same bytes: fewer frames a piece);
+    a 16-bit program's XLA module is ``jit_program16``, so that a profile
+    tells it from the 8-bit ``jit_program``."""
     batched = jax.vmap(make_program_fn(
         resample_out, pad_canvas, pad_offset, plan,
-        rotate_dynamic=rotate_dynamic, band_taps=band_taps,
+        rotate_dynamic=rotate_dynamic, band_taps=band_taps, depth=depth,
     ))
 
     # a sharded program takes the images in one piece: the runtime moves
     # one transfer a device, and a piece must not straddle the mesh
-    pieces = stage_pieces(batch_size, in_shape) if mesh is None else 1
+    pieces = (
+        stage_pieces(batch_size, in_shape, depth // 8) if mesh is None else 1
+    )
     frames = batch_size // pieces
 
     def program(flat_pieces_u8, in_true, span_y, span_x, out_true):
@@ -212,6 +225,9 @@ def build_batched_program(
         ))
         return out.reshape(batch_size, *out.shape[2:])
 
+    if depth != 8:
+        program.__name__ = f"program{depth}"
+
     # the output is laid out row-major wherever it lives (the device's
     # default for a flat uint8 array depends on its shape)
     sharding = None
@@ -235,6 +251,7 @@ def build_batched_program(
         pad_offset, plan, rotate_dynamic,
         tuple(mesh.shape.items()) if mesh is not None else None,
         band_taps,
+        depth,
     )
     # fleet warm start (runtime/warmstart.py): note this program's
     # identity for the shared manifest — the mesh stays out (a seeding
@@ -244,7 +261,7 @@ def build_batched_program(
 
     warmstart.record_batched(
         batch_size, in_shape, resample_out, pad_canvas, pad_offset,
-        plan, rotate_dynamic, mesh is not None, band_taps,
+        plan, rotate_dynamic, mesh is not None, band_taps, depth,
     )
     return ProgramHandle(
         jitted,
@@ -253,7 +270,7 @@ def build_batched_program(
             plan, in_shape=in_shape, batch=batch_size,
             resample_out=resample_out, pad_canvas=pad_canvas,
             pad_offset=pad_offset, rotate_dynamic=rotate_dynamic,
-            band_taps=band_taps,
+            band_taps=band_taps, depth=depth,
         ),
         in_sharding=sharding,
         pieces=pieces,
@@ -263,7 +280,7 @@ def build_batched_program(
 @dataclass(eq=False)  # identity equality: generated __eq__ would compare
 class _Pending:       # ndarray fields ("truth value is ambiguous" in any
     # list membership test over in-flight batches)
-    # [h, w, 3] uint8 (or aux payload); None once the member is answered
+    # [h, w, 3] uint8 or uint16 (or aux payload); None once answered
     image: Optional[np.ndarray]
     plan: Optional[TransformPlan]
     future: Future
@@ -335,16 +352,18 @@ class _Launch:
         "seq", "kind", "aux", "images", "capacity", "popped",
         "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
         "block", "transfer_bytes", "readback", "_cursor", "_opened",
-        "prefix", "_gaps", "_at",
+        "prefix", "_gaps", "_at", "depth",
     )
 
     def __init__(self, seq: int, members: List[_Pending], *,
                  kind: str = "primary", aux: bool = False,
                  controller: str = TRANSFORM_CONTROLLER,
-                 gaps: Optional[GapAccount] = None) -> None:
+                 gaps: Optional[GapAccount] = None, depth: int = 8) -> None:
         self.seq = seq
         self.kind = kind
         self.aux = aux
+        # bits of a sample of a transform launch (``_Group.depth``)
+        self.depth = depth
         self.images = self.capacity = len(members)
         self.popped = time.perf_counter()
         self.queue_wait_s = time.monotonic() - min(
@@ -570,6 +589,9 @@ class _Group:
     # banded-resample static per-axis K (None = dense); part of the group
     # key, so members group by K bucket like they group by input shape
     band_taps: Optional[Tuple[int, int]] = None
+    # bits of a sample (8: uint8, 16: uint16); part of the group key, so
+    # 8-bit and 16-bit members never share a launch or a block
+    depth: int = 8
     # aux groups (e.g. batched smart-crop scoring) run this instead of the
     # vmapped transform program: runner(payloads) -> results, one per member
     runner: Optional[callable] = None
@@ -638,6 +660,10 @@ class BatchController:
         # sparse-traffic p99 by deadline_ms; disable for deterministic
         # batch-forming in tests)
         self.lone_flush = lone_flush
+        # the last transform launch popped held max_batch members: the
+        # callers are a saturated stream, and a lone request waits for its
+        # launch to fill (``_group_ready``)
+        self._last_launch_full = False
         # optional data-parallel mesh: batches shard over its 'data' axis
         self.mesh = mesh
         self._n_devices = 1
@@ -718,9 +744,9 @@ class BatchController:
         self._groups: Dict[Tuple, _Group] = {}
         # host blocks of launches that have run, newest first, for the next
         # groups of their shapes (``_take_block`` / ``_keep_block``). Two
-        # at most, whatever their shapes: one filling while one is in
-        # flight is how launches overlap, and a lone launch beside a full
-        # one makes a second block too; a third is let go
+        # at most of each dtype, whatever their shapes: one filling while
+        # one is in flight is how launches overlap, and a lone launch
+        # beside a full one makes a second block too; a third is let go
         self._spare_blocks: List[np.ndarray] = []
         self._lock = threading.Condition()
         self._stop = False
@@ -769,7 +795,8 @@ class BatchController:
         plan: TransformPlan,
         src_window: Optional[Tuple[int, int]] = None,
     ) -> Future:
-        """Queue one image+plan; resolves to the uint8 output array.
+        """Queue one image+plan; resolves to the output array, of the
+        image's dtype (``uint8``, or ``uint16`` for 16-bit samples).
 
         The member's pixels are copied into its launch's padded host block
         HERE, on the caller's thread, before the future is returned: under
@@ -880,9 +907,12 @@ class BatchController:
                 layout.span_y, layout.span_x, layout.out_true,
             )
         device_plan = plan.device_plan()
+        # 16-bit samples keep 16 bits; anything else is copied into a
+        # uint8 block, as it always was
+        depth = 16 if image.dtype == np.uint16 else 8
         key = (
             in_shape, resample_out, layout.pad_canvas, layout.pad_offset,
-            device_plan, rotate_dynamic, band_taps,
+            device_plan, rotate_dynamic, band_taps, depth,
         )
         future: Future = Future()
         submit_span = tracing.current_span()
@@ -923,7 +953,7 @@ class BatchController:
         group_key = key
 
         def make_group() -> _Group:
-            block, kept = self._take_block(in_shape)
+            block, kept = self._take_block(in_shape, _SAMPLE_DTYPES[depth])
             return _Group(
                 key=group_key,
                 in_shape=in_shape,
@@ -933,6 +963,7 @@ class BatchController:
                 device_plan=device_plan,
                 rotate_dynamic=rotate_dynamic,
                 band_taps=band_taps,
+                depth=depth,
                 base_key=base_key,
                 block=block,
                 block_kept=kept,
@@ -943,35 +974,39 @@ class BatchController:
             self._copy_in(*reserved, pending)
         return future
 
-    def _take_block(self, in_shape: Tuple[int, int]):
+    def _take_block(self, in_shape: Tuple[int, int], dtype=np.uint8):
         """The host block of a new transform group (caller holds the lock)
         and whether it has carried a launch before: the spare of exactly
-        this shape where there is one, else a fresh ``np.zeros`` (whose
-        untouched pages cost nothing until a member is copied into them).
-        A spare of another shape is left where it is. Nothing to set: what
-        happens depends only on what launches of this shape have left
-        behind. ``flyimg_batch_blocks_total{from=}`` counts either kind."""
+        this shape and dtype where there is one, else a fresh ``np.zeros``
+        (whose untouched pages cost nothing until a member is copied into
+        them). A spare of another shape or dtype is left where it is.
+        Nothing to set: what happens depends only on what launches of this
+        shape have left behind. ``flyimg_batch_blocks_total{from=}`` counts
+        either kind."""
         shape = (self._padded_batch(self.max_batch), *in_shape, 3)
         for i, spare in enumerate(self._spare_blocks):
-            if spare.shape == shape:
+            if spare.shape == shape and spare.dtype == dtype:
                 del self._spare_blocks[i]
                 self.metrics.record_block("kept")
                 return spare, True
         self.metrics.record_block("fresh")
-        return np.zeros(shape, dtype=np.uint8), False
+        return np.zeros(shape, dtype=dtype), False
 
     def _keep_block(self, launch: _Launch) -> None:
         """A launch's host block becomes the controller's spare: called
         when the program has RUN (``_await_launch``), the one point at
         which nothing reads the block on any backend (the CPU backend's
         ``device_put`` may alias the host array, so there the staged inputs
-        ARE the block until the output is ready). The two newest are kept."""
+        ARE the block until the output is ready). The two newest of its
+        dtype are kept; spares of the other dtype stay as they are."""
         block, launch.block = launch.block, None
         if block is None:
             return
         with self._lock:
             if not self._stop:
-                self._spare_blocks = [block, *self._spare_blocks[:1]]
+                same = [b for b in self._spare_blocks if b.dtype == block.dtype]
+                other = [b for b in self._spare_blocks if b.dtype != block.dtype]
+                self._spare_blocks = [block, *same[:1], *other]
 
     def _copy_in(self, group: _Group, block: np.ndarray,
                  pending: _Pending) -> None:
@@ -1490,6 +1525,15 @@ class BatchController:
         launch, up the power-of-two ladder, and the deadline (which a bulk
         deployment sets to seconds so that a transform launch fills) would
         only hold a post-pass back while the executor has nothing to run.
+        A lone TRANSFORM request takes the fast path only where the last
+        transform launch was not full: after a full one the callers are a
+        saturated stream (a bulk job's workers, in step), the rest of the
+        launch is on its way, and a lone launch would make the next full
+        launch wait for its caller's whole turnaround (on a TPU v5e, a
+        16-bit PNG cycle of 32 callers ran 10.5 s with one and 8.2 s
+        without: PERF.md section 6). The deadline still bounds the wait,
+        and that is its cost: a job's last lone request after a full launch
+        waits out ``deadline_ms``.
         A group with a submit-time copy in flight is not ready whatever
         else holds: no launch reads a slot whose copy has not landed (a copy
         is short, and the landing of the group's last one notifies)."""
@@ -1500,7 +1544,8 @@ class BatchController:
         if now - group.members[0].enqueued_at >= self.deadline_s:
             return True
         return self.lone_flush and (
-            total_pending == 1 or group.runner is not None
+            group.runner is not None
+            or (total_pending == 1 and not self._last_launch_full)
         )
 
     def _ready_group(self) -> bool:
@@ -1577,6 +1622,8 @@ class BatchController:
         group.members = group.members[take_n:]
         if not group.members:
             self._groups.pop(best, None)
+        if group.runner is None:
+            self._last_launch_full = take_n >= self.max_batch
         # the block goes with the launch; what stays queued (a pre-split's
         # remainder, members beyond the block) is copied by _assemble at
         # its own pop
@@ -1591,6 +1638,7 @@ class BatchController:
             members=take,
             rotate_dynamic=group.rotate_dynamic,
             band_taps=group.band_taps,
+            depth=group.depth,
             runner=group.runner,
             base_key=group.base_key,
             mem_cap=mem_cap,
@@ -1772,7 +1820,7 @@ class BatchController:
                         controller=self.name),
             )
             return
-        launch = _Launch(seq, members, gaps=self._gaps)
+        launch = _Launch(seq, members, gaps=self._gaps, depth=group.depth)
         launch.block = block
         span_obj = None
         fn = None
@@ -1976,7 +2024,9 @@ class BatchController:
         if early:
             images = block[:batch]
         else:
-            images = np.zeros((batch, bh, bw, 3), dtype=np.uint8)
+            images = np.zeros(
+                (batch, bh, bw, 3), dtype=_SAMPLE_DTYPES[group.depth]
+            )
         in_true = np.zeros((batch, true_w), dtype=np.float32)
         span_y = np.zeros((batch, 2), dtype=np.float32)
         span_x = np.zeros((batch, 2), dtype=np.float32)
@@ -2033,6 +2083,7 @@ class BatchController:
             self.mesh,
             group.rotate_dynamic,
             group.band_taps,
+            group.depth,
         )
         compile_hit = fn.is_compiled
         self.metrics.record_compile_event(compile_hit)
@@ -2457,7 +2508,7 @@ class BatchController:
         self._touch_busy()  # each recovery launch is wedge-clock progress
         launch = _Launch(
             seq, members, kind="recovery", aux=group.runner is not None,
-            controller=self.name, gaps=self._gaps,
+            controller=self.name, gaps=self._gaps, depth=group.depth,
         )
         try:
             self._run_recovery(group, members, launch)

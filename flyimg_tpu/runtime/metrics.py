@@ -501,10 +501,11 @@ class MetricsRegistry:
         (runtime/batcher.py ``_Launch``: the launch's one record, read
         here by ``seconds(phase)``, ``device_s``, ``queue_wait_s``,
         ``images``, ``capacity``, ``compile_hit``, ``aux``,
-        ``transfer_bytes``, ``readback``). A transform launch observes
-        ``flyimg_device_seconds`` (dispatch to completed read-back, as
-        ever), one histogram per phase, the bytes it moved each way
-        (``flyimg_device_transfer_bytes_total``) and the form its output
+        ``transfer_bytes``, ``readback``, ``depth``). A transform launch
+        observes ``flyimg_device_seconds`` (dispatch to completed
+        read-back, as ever), one histogram per phase, the bytes it moved
+        each way (``flyimg_device_transfer_bytes_total``), the bits of its
+        samples (``flyimg_batch_depth_total``) and the form its output
         was read back in (``flyimg_batch_readbacks_total``); every launch,
         aux included, feeds the per-controller efficiency record
         (``record_batch_launch``) under the label its controller gives
@@ -536,6 +537,12 @@ class MetricsRegistry:
                     "(d2h: the output); over the sum of "
                     "flyimg_device_transfer_seconds, the link's rate",
                 ).inc(nbytes)
+            self.counter(
+                f'flyimg_batch_depth_total{{bits="{launch.depth}"}}',
+                "Transform launches by the bits of a sample they carried: 8 "
+                "(uint8 blocks and programs) or 16 (uint16, 16-bit PNG "
+                "originals answered at their depth)",
+            ).inc()
             if launch.readback is not None:
                 self.counter(
                     f'flyimg_batch_readbacks_total{{layout="{launch.readback}"}}',

@@ -188,8 +188,8 @@ class WarmStartCache:
         return self
 
     def note_single(self, in_shape, resample_out, pad_canvas, pad_offset,
-                    plan, band_taps) -> None:
-        self.recorder.note({
+                    plan, band_taps, depth=8) -> None:
+        self.recorder.note(_with_depth({
             "kind": "single",
             "in_shape": list(in_shape),
             "resample_out": list(resample_out) if resample_out else None,
@@ -197,16 +197,16 @@ class WarmStartCache:
             "pad_offset": list(pad_offset),
             "plan": _plan_to_doc(plan),
             "band_taps": list(band_taps) if band_taps else None,
-        })
+        }, depth))
 
     def note_batched(self, batch_size, in_shape, resample_out, pad_canvas,
                      pad_offset, plan, rotate_dynamic, sharded,
-                     band_taps) -> None:
+                     band_taps, depth=8) -> None:
         # the mesh is ENVIRONMENTAL and stays out of the manifest: a
         # seeding replica compiles against its OWN topology (sharded
         # entries take its local mesh), which is the program it will
         # actually launch
-        self.recorder.note({
+        self.recorder.note(_with_depth({
             "kind": "batched",
             "batch_size": int(batch_size),
             "in_shape": list(in_shape),
@@ -217,7 +217,7 @@ class WarmStartCache:
             "rotate_dynamic": bool(rotate_dynamic),
             "sharded": bool(sharded),
             "band_taps": list(band_taps) if band_taps else None,
-        })
+        }, depth))
 
     # -- publishing --------------------------------------------------------
 
@@ -292,22 +292,22 @@ class WarmStartCache:
         pad_canvas = _tupled(entry.get("pad_canvas"))
         pad_offset = _tupled(entry["pad_offset"])
         band_taps = _tupled(entry.get("band_taps"))
+        depth = int(entry.get("depth", 8))
         f32 = np.dtype("float32")
-        u8 = np.dtype("uint8")
+        sample = np.dtype("uint16" if depth == 16 else "uint8")
         # both builders are called FULLY POSITIONALLY, matching their
-        # production call sites (compose._render/BatchWorker): lru_cache
-        # keys positional and keyword spellings differently, and a
-        # seeded entry only warms the cache if the real render path
-        # lands on the exact same key
+        # production call sites (compose._render/BatchWorker): a seeded
+        # entry only warms the cache if the real render path lands on
+        # the exact same key
         if entry["kind"] == "single":
             from flyimg_tpu.ops.compose import build_program
 
             handle = build_program(
                 in_shape, resample_out, pad_canvas, pad_offset, plan,
-                band_taps,
+                band_taps, depth,
             )
             args = (
-                jax.ShapeDtypeStruct((*in_shape, 3), u8),
+                jax.ShapeDtypeStruct((*in_shape, 3), sample),
                 jax.ShapeDtypeStruct((2,), f32),
                 jax.ShapeDtypeStruct((2,), f32),
                 jax.ShapeDtypeStruct((2,), f32),
@@ -321,11 +321,11 @@ class WarmStartCache:
             handle = build_batched_program(
                 batch, in_shape, resample_out, pad_canvas, pad_offset,
                 plan, mesh if entry.get("sharded") else None,
-                rotate_dynamic, band_taps,
+                rotate_dynamic, band_taps, depth,
             )
             true_w = 4 if rotate_dynamic else 2
             args = (
-                jax.ShapeDtypeStruct((batch, *in_shape, 3), u8),
+                jax.ShapeDtypeStruct((batch, *in_shape, 3), sample),
                 jax.ShapeDtypeStruct((batch, true_w), f32),
                 jax.ShapeDtypeStruct((batch, 2), f32),
                 jax.ShapeDtypeStruct((batch, 2), f32),
@@ -391,6 +391,15 @@ class WarmStartCache:
         )
 
 
+def _with_depth(entry: Dict[str, Any], depth: int) -> Dict[str, Any]:
+    """A manifest entry with its program's sample depth, where it is not
+    8: an 8-bit entry reads (and digests) as it did before depths were
+    recorded, and an entry without one seeds an 8-bit program."""
+    if int(depth) != 8:
+        entry["depth"] = int(depth)
+    return entry
+
+
 # ---------------------------------------------------------------------------
 # process-wide recorder hooks (the faults.install/clear pattern):
 # compose.build_program / batcher.build_batched_program call these inside
@@ -412,14 +421,15 @@ def uninstall() -> None:
 
 
 def record_single(in_shape, resample_out, pad_canvas, pad_offset, plan,
-                  band_taps) -> None:
+                  band_taps, depth=8) -> None:
     """Called by ops/compose.build_program on each lru miss."""
     cache = _active
     if cache is None:
         return
     try:
         cache.note_single(
-            in_shape, resample_out, pad_canvas, pad_offset, plan, band_taps
+            in_shape, resample_out, pad_canvas, pad_offset, plan, band_taps,
+            depth,
         )
     except Exception:  # recording must never fail a compile
         logging.getLogger(LOGGER).debug(
@@ -430,7 +440,7 @@ def record_single(in_shape, resample_out, pad_canvas, pad_offset, plan,
 
 def record_batched(batch_size, in_shape, resample_out, pad_canvas,
                    pad_offset, plan, rotate_dynamic, sharded,
-                   band_taps) -> None:
+                   band_taps, depth=8) -> None:
     """Called by runtime/batcher.build_batched_program on each lru miss."""
     cache = _active
     if cache is None:
@@ -438,7 +448,7 @@ def record_batched(batch_size, in_shape, resample_out, pad_canvas,
     try:
         cache.note_batched(
             batch_size, in_shape, resample_out, pad_canvas, pad_offset,
-            plan, rotate_dynamic, sharded, band_taps,
+            plan, rotate_dynamic, sharded, band_taps, depth,
         )
     except Exception:
         logging.getLogger(LOGGER).debug(

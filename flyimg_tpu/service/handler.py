@@ -24,7 +24,8 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from flyimg_tpu.appconfig import AppParameters
-from flyimg_tpu.codecs import decode, encode, media_info
+from flyimg_tpu.codecs import decode, encode, media_info, narrow_to_u8
+from flyimg_tpu.codecs.native_codec import PngCall
 from flyimg_tpu.codecs.sniff import sniff
 from flyimg_tpu.exceptions import (
     DeadlineExceededException,
@@ -1314,7 +1315,7 @@ class ImageHandler:
         rotate for rotate-only plans, halo-exchange conv for single-filter
         plans. Anything else -> None (batcher / direct path); every branch
         is an allowlist so any new pixel op fails safe to the batcher."""
-        if self.sp_mesh is None:
+        if self.sp_mesh is None or frame.dtype != np.uint8:
             return None
         single = self._tiled_single_op_or_none(frame, plan)
         if single is not None:
@@ -1530,7 +1531,8 @@ class ImageHandler:
         # on the bounded encode pool instead of oversubscribing request
         # threads (the codec-batcher path above already bounds its own
         # native parallelism)
-        return self._stage(
+        png_call = PngCall()
+        blob = self._stage(
             "encode",
             lambda: encode(
                 frame,
@@ -1541,9 +1543,42 @@ class ImageHandler:
                 sampling_factor=sampling_factor,
                 strip=options.truthy("strip"),
                 alpha=alpha,
+                call=png_call,
             ),
             deadline,
         )
+        self._record_png("encode", png_call)
+        return blob
+
+    def _record_png(self, op: str, call: PngCall) -> None:
+        """One native PNG call (``op`` decode or encode): its seconds
+        inside the library and its image, by the bits of a sample."""
+        if self.metrics is None or not call.bits:
+            return
+        self.metrics.counter(
+            f'flyimg_codec_png_seconds_total{{op="{op}",bits="{call.bits}"}}',
+            "Seconds inside the native PNG codec, by operation and the "
+            "bits of a sample (16: the stored samples, no gamma "
+            "transform)",
+        ).inc(call.seconds)
+        self.metrics.counter(
+            f'flyimg_codec_png_images_total{{op="{op}",bits="{call.bits}"}}',
+            "Images through the native PNG codec, by operation and the "
+            "bits of a sample",
+        ).inc()
+
+    def _record_narrowed(self, at: str) -> None:
+        """A 16-bit source carried on at 8 bits: where the codec could
+        not keep 16 (``decode``: alpha, a colour key, the Pillow
+        fallback) or the answer holds 8 (``output``: a JPEG, WebP or GIF,
+        or a post-pass that scores 8-bit renditions)."""
+        if self.metrics is None:
+            return
+        self.metrics.counter(
+            f'flyimg_depth_narrowed_total{{at="{at}"}}',
+            "16-bit sources carried on at 8 bits, by where: decode (the "
+            "codec read 8) or output (the answer holds 8)",
+        ).inc()
 
     def _roi_window(self, options: OptionsBag, info, hint,
                     is_animated_gif_out: bool):
@@ -1761,18 +1796,25 @@ class ImageHandler:
             )
             batched_decode = decoded is not None
             if decoded is None:
+                png_call = PngCall()
                 decoded = self._stage(
                     "decode",
                     lambda: decode(
                         data, target_hint=hint, frame=gif_frame,
-                        info=data_info, roi=roi,
+                        info=data_info, roi=roi, call=png_call,
                     ),
                     deadline,
                 )
+                self._record_png("decode", png_call)
             decode_mode = self._decode_mode(decoded, data_info, hint)
+            if decoded.narrowed_from:
+                self._record_narrowed("decode")
             if decode_span is not None:
                 decode_span.set_attribute("decode.batched", batched_decode)
                 decode_span.set_attribute("decode.mode", decode_mode)
+                decode_span.set_attribute(
+                    "decode.bits", 8 * decoded.rgb.dtype.itemsize
+                )
         # the per-mode stage series feeds the perf-gate's decode-mode
         # legs (tools/perf_gate.py schema 5) and bench_http's
         # decode-split reporting without disturbing the aggregate
@@ -1870,6 +1912,18 @@ class ImageHandler:
                     frames[0].astype(np.float32) * a + bg * (1.0 - a)
                 ).astype(np.uint8)
             ]
+
+        # sample depth: a 16-bit frame stays 16-bit through the device and
+        # the encoder only where the answer is a PNG and no post-pass
+        # scores it (smart crop and faces read 8-bit renditions); any
+        # other answer narrows here, on the host, as ImageMagick writes a
+        # JPEG, WebP or GIF at 8 bits, and takes the 8-bit programs
+        keeps_16 = spec.extension == "png" and not (
+            plan.smart_crop or plan.face_blur or plan.face_crop
+        )
+        if frames[0].dtype == np.uint16 and not keeps_16:
+            frames = [narrow_to_u8(frame) for frame in frames]
+            self._record_narrowed("output")
 
         # submit every frame before waiting on any: coalesced GIF frames
         # share one program identity, so the batcher runs them as a single
@@ -2076,7 +2130,8 @@ class ImageHandler:
         if deadline is not None:
             deadline.check("encode")
         with tracing.stage("encode", timings, self.metrics,
-                           format=spec.extension):
+                           format=spec.extension,
+                           bits=8 * out_frames[0].dtype.itemsize):
             # attach-time decision mirrors keeps_alpha (the flatten
             # decision): attaching alpha to rgb that was already flattened
             # over bg would double-composite semi-transparent pixels
@@ -2145,7 +2200,8 @@ class ImageHandler:
             fmt = spec.extension.upper().replace("JPG", "JPEG")
             spec.identify_repr = (
                 f"{spec.name} {fmt} {out_info.width}x{out_info.height} "
-                f"{out_info.width}x{out_info.height}+0+0 8-bit sRGB "
+                f"{out_info.width}x{out_info.height}+0+0 "
+                f"{8 * out_frames[0].dtype.itemsize}-bit sRGB "
                 f"{len(content)}B"
             )
         return content

@@ -249,6 +249,44 @@ def test_lone_request_flushes_before_deadline():
         ctrl.close()
 
 
+def test_after_a_full_launch_a_lone_request_waits_for_its_launch_to_fill():
+    """Callers in step (a bulk job's workers) come back one by one after a
+    full launch: the first of them does not launch alone, which would hold
+    the next full launch for its whole turnaround; it waits for the launch
+    to fill (here the second request) or for the deadline. After a launch
+    that was not full, a lone request goes at once as before."""
+    import threading
+    import time as _t
+
+    ctrl = BatchController(max_batch=2, deadline_ms=60_000.0)
+    try:
+        rng = np.random.default_rng(9)
+        img = rng.integers(0, 256, size=(64, 64, 3), dtype=np.uint8)
+        plan = _plan("w_32,h_32,rz_1", 64, 64)
+        ctrl.submit(img, plan).result(timeout=60)  # partial: warms batch 1
+        ctrl.pause_launches()
+        pair = [ctrl.submit(img, plan) for _ in range(2)]
+        ctrl.resume_launches()
+        for f in pair:
+            f.result(timeout=60)                   # a full launch of 2
+        lone = ctrl.submit(img, plan)
+        _t.sleep(0.5)
+        assert not lone.done(), "a lone request after a full launch went alone"
+        second = ctrl.submit(img, plan)
+        assert lone.result(timeout=60).shape == second.result(timeout=60).shape
+        # the launch of two was full again; a request after a partial one
+        # goes at once: make one partial launch with the deadline
+        ctrl.deadline_s = 0.2
+        ctrl.submit(img, plan).result(timeout=60)
+        ctrl.deadline_s = 60.0
+        done = threading.Event()
+        t0 = _t.monotonic()
+        ctrl.submit(img, plan).add_done_callback(lambda f: done.set())
+        assert done.wait(30.0) and _t.monotonic() - t0 < 5.0
+    finally:
+        ctrl.close()
+
+
 def test_aux_group_batches_and_orders():
     calls = []
 

@@ -1150,3 +1150,123 @@ def test_host_attribution_metric_files_read_the_recorded_fixture(
                                 "portrait-smartcrop-saturated"]}
     if moves in cells:
         assert entry["workloads"] == cells[moves]
+
+
+# ---------------------------------------------------------------------------
+# 7. the 16-bit cell's metrics: what each file reads, the cell it
+#    lists, and nothing read from a program without 16-bit launches
+
+_DEEP_BEFORE = {
+    "flyimg_batches_total": 4.0,
+    "flyimg_images_processed_total": 97.0,
+    'flyimg_batch_depth_total{bits="16"}': 4.0,
+    'flyimg_codec_png_seconds_total{op="decode",bits="16"}': 300.0,
+    'flyimg_codec_png_images_total{op="decode",bits="16"}': 97.0,
+    'flyimg_codec_png_seconds_total{op="encode",bits="16"}': 190.0,
+    'flyimg_codec_png_images_total{op="encode",bits="16"}': 96.0,
+}
+_DEEP_AFTER = dict(_DEEP_BEFORE, **{
+    "flyimg_batches_total": 9.0,
+    "flyimg_images_processed_total": 257.0,
+    'flyimg_batch_depth_total{bits="16"}': 9.0,
+    'flyimg_codec_png_seconds_total{op="decode",bits="16"}': 780.0,
+    'flyimg_codec_png_images_total{op="decode",bits="16"}': 257.0,
+    'flyimg_codec_png_seconds_total{op="encode",bits="16"}': 510.0,
+    'flyimg_codec_png_images_total{op="encode",bits="16"}': 256.0,
+})
+
+
+@pytest.mark.parametrize("metric,expected,layer", [
+    ("deep_launch_share", 100.0, "batcher"),
+    ("png16_decode_ms_per_image", 3000.0, "host_decode"),
+    ("png16_encode_ms_per_image", 2000.0, "host_encode"),
+])
+def test_16bit_cell_metric_files_read_the_recorded_counters(metric, expected, layer):
+    from perfbench.harness import manifest
+
+    doc = manifest.load_manifest()
+    entry = next(m for m in doc["per_layer"] if m["name"] == metric)
+    assert (entry["layer"], entry["moves"], entry["source"]) == (
+        layer, "images_per_s", "program_counter")
+    assert entry["workloads"] == ["archive-png16-saturated"]
+    spec = manifest.load_metric(metric)
+    read = manifest.load_reader(spec["reader"])
+    ctx = {"counters_before": _DEEP_BEFORE, "counters_after": _DEEP_AFTER}
+    assert read(ctx, **spec["args"]) == pytest.approx(expected)
+    # a launch narrowed to 8 bits on the way lowers the share
+    mixed = dict(_DEEP_AFTER, **{'flyimg_batch_depth_total{bits="16"}': 8.0})
+    if metric == "deep_launch_share":
+        assert read(dict(ctx, counters_after=mixed), **spec["args"]) == pytest.approx(80.0)
+    # a program without 16-bit launches has none of these series: nothing read
+    assert read({"counters_before": {}, "counters_after": {}}, **spec["args"]) is None
+
+
+def test_resample16_roofline_reads_the_16bit_module_alone():
+    """``readers/trace_share.py`` over ``jit_program16``: one full launch
+    of 32 of 0.64 s (a lone launch of 1 beside it counts for nothing), the
+    needed work memory-bound (1.6e8 bytes an image at the v5e's 819 GB/s);
+    an 8-bit ``jit_program`` module is no 16-bit program's."""
+    from perfbench.harness import manifest
+
+    entry = next(m for m in manifest.load_manifest()["per_layer"]
+                 if m["name"] == "resample16_roofline")
+    assert (entry["layer"], entry["moves"], entry["unit"]) == (
+        "device_program", "images_per_s", "%")
+    spec = manifest.load_metric("resample16_roofline")
+    read = manifest.load_reader(spec["reader"])
+    work = {"resample": {"flops": 1.15884e9, "bytes": 1.6077312e8}}
+    events = [["jit_program16(3)", 1e9, 2e7], ["jit_program16(3)", 2e9, 6.4e8]]
+    planes = [{"name": "/device:TPU:0", "lines": [{"name": "XLA Modules", "events": events}]}]
+    ctx = {"trace_planes": planes, "device": {"kind": "TPU v5 lite"},
+           "work_per_image": work, "launch_sizes": {"1": 1, "32": 1},
+           "counters_before": {"flyimg_images_processed_total": 0.0},
+           "counters_after": {"flyimg_images_processed_total": 33.0}}
+    least = 1.6077312e8 / 819e9
+    assert read(ctx, **spec["args"]) == pytest.approx(100.0 * least * 32 / 0.64)
+    eight = [{"name": "/device:TPU:0", "lines": [{"name": "XLA Modules",
+                                                 "events": [["jit_program(3)", 1e9, 6.4e8]]}]}]
+    assert read(dict(ctx, trace_planes=eight), **spec["args"]) is None
+    assert read(dict(ctx, trace_planes=[]), **spec["args"]) is None
+
+
+@pytest.mark.parametrize("twin", [
+    "kept_block_share.png16", "readback_row_major_share.png16",
+    "device_idle_share.png16", "latency_p95_ms.png16",
+    "gap_fill_ms_per_launch.png16", "decode_ms.png16", "encode_ms.png16",
+])
+def test_16bit_cell_twins_read_as_their_originals(twin):
+    """A metric whose list was accepted before the 16-bit cell came is read
+    there through a twin (`<name>.png16`, as the face cell's `<name>.faces`):
+    the same layer and reader, the one cell, moving the rate it reports.
+    The device's idle share reads the 16-bit module alone."""
+    from perfbench.harness import manifest
+
+    per_layer = {m["name"]: m for m in manifest.load_manifest()["per_layer"]}
+    name = twin.rsplit(".", 1)[0]
+    # the tail is end to end where it is bounded: its per-layer reading
+    # is the face cell's twin
+    first_name = name if name in per_layer else f"{name}.faces"
+    entry, original = per_layer[twin], per_layer[first_name]
+    assert entry["workloads"] == ["archive-png16-saturated"]
+    assert entry["moves"] == "images_per_s"
+    assert (entry["layer"], entry["unit"], entry["better"], entry["source"]) == (
+        original["layer"], original["unit"], original["better"], original["source"])
+    spec, first = manifest.load_metric(twin), manifest.load_metric(first_name)
+    assert spec["reader"] == first["reader"]
+    if name != "device_idle_share":
+        assert spec["args"] == first["args"]
+        return
+    assert spec["args"] == dict(first["args"], module="^jit_program16")
+    read = manifest.load_reader(spec["reader"])
+    host = [["flyimg:batch:6:dispatch", 100, 10], ["flyimg:batch:6:d2h", 950, 150]]
+
+    def planes(module):
+        return [{"name": "/host:CPU", "lines": [{"name": "annotations", "events": host}]},
+                {"name": "/device:TPU:0", "lines": [{"name": "XLA Modules",
+                                                     "events": [[module, 300, 250]]}]}]
+
+    # held 100 -> 1100 ns, the module ran 250 of them
+    ctx = {"counters_before": {}, "counters_after": {}}
+    assert read(dict(ctx, trace_planes=planes("jit_program16(3)")),
+                **spec["args"]) == pytest.approx(75.0)
+    assert read(dict(ctx, trace_planes=planes("jit_program(3)")), **spec["args"]) is None

@@ -470,3 +470,18 @@ def test_exact_frame_suppressions_are_justified():
         context = text[max(0, idx - 500):idx]
         assert "DELIBERATE" in context.upper(), relpath
         assert "halo" in context, relpath  # the correctness rationale
+
+
+def test_real_drift_group_key_loses_depth(tmp_path):
+    """The sample depth (8 or 16 bits) is a component of all three
+    identity systems like band_taps: dropping it from the submit() group
+    key, where 8-bit and 16-bit members would then share a launch, is
+    caught as program-key-drift."""
+    result = _scan_real(tmp_path, mutate=(
+        "flyimg_tpu/runtime/batcher.py",
+        "device_plan, rotate_dynamic, band_taps, depth,",
+        "device_plan, rotate_dynamic, band_taps,",
+    ))
+    msgs = _messages(result, "program-key-drift")
+    assert any("group key omits `depth`" in m for m in msgs), \
+        [f.format() for f in result.findings]

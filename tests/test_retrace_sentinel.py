@@ -40,9 +40,9 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _single_key(in_shape=(128, 128), resample_out=(64, 64),
                 pad_canvas=None, pad_offset=(0, 0), plan="planA",
-                band_taps=None):
+                band_taps=None, depth=8):
     return ("single", in_shape, resample_out, pad_canvas, pad_offset,
-            plan, band_taps)
+            plan, band_taps, depth)
 
 
 # ---------------------------------------------------------------------------

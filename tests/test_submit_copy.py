@@ -725,8 +725,18 @@ def test_kept_block_share_reads_the_counter_the_controller_keeps():
     doc = manifest.load_manifest()
     entry = next(m for m in doc["per_layer"] if m["name"] == "kept_block_share")
     assert entry["layer"] == "batcher" and entry["moves"] == "images_per_s"
-    assert entry["workloads"] == [c["name"] for c in doc["workloads"]]
     spec = manifest.load_metric("kept_block_share")
+    # every cell reads it: those of this entry, and those that came after
+    # its list was accepted through a twin of the same counter
+    # (`kept_block_share.png16` for the 16-bit cell)
+    twins = [m for m in doc["per_layer"] if m["name"].startswith("kept_block_share.")]
+    assert twins
+    covered = entry["workloads"] + [c for t in twins for c in t["workloads"]]
+    assert sorted(covered) == sorted(c["name"] for c in doc["workloads"])
+    for twin in twins:
+        assert (twin["layer"], twin["moves"]) == (entry["layer"], entry["moves"])
+        twin_spec = manifest.load_metric(twin["name"])
+        assert (twin_spec["reader"], twin_spec["args"]) == (spec["reader"], spec["args"])
     read = manifest.load_reader(spec["reader"])
     ctl = _waiting_ctl()
     try:

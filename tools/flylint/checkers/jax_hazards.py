@@ -42,7 +42,9 @@ SCOPE_PREFIXES = (
     "flyimg_tpu/ops/", "flyimg_tpu/models/", "flyimg_tpu/parallel/",
 )
 
-_CACHE_DECORATORS = {"lru_cache", "cache"}
+# ops/compose.py's ``bound_lru_cache`` is an lru_cache keyed on the bound
+# arguments
+_CACHE_DECORATORS = {"lru_cache", "cache", "bound_lru_cache"}
 
 
 def _dotted(node: ast.AST) -> str:

@@ -14,7 +14,7 @@ compiles per key *family*.
 
 A family is a program key with ONE component masked out: the key layouts
 are known (``("single", in_shape, resample_out, pad_canvas, pad_offset,
-plan, band_taps)`` and the ``"batched"`` ten-tuple), so every compile
+plan, band_taps, depth)`` and the ``"batched"`` eleven-tuple), so every compile
 feeds len(key) families — "all components fixed except ``in_shape``",
 "all fixed except ``band_taps``", … A compile storm driven by one
 unbucketed value lands every compile in the SAME family, whose distinct-
@@ -64,11 +64,11 @@ DEFAULT_BUDGET = 24
 COMPONENT_NAMES: Dict[str, Tuple[str, ...]] = {
     "single": (
         "kind", "in_shape", "resample_out", "pad_canvas", "pad_offset",
-        "plan", "band_taps",
+        "plan", "band_taps", "depth",
     ),
     "batched": (
         "kind", "batch_size", "in_shape", "resample_out", "pad_canvas",
-        "pad_offset", "plan", "rotate_dynamic", "mesh", "band_taps",
+        "pad_offset", "plan", "rotate_dynamic", "mesh", "band_taps", "depth",
     ),
 }
 
