@@ -226,7 +226,8 @@ def _ledger():
 def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
                     resample_out=None, pad_canvas=None,
                     pad_offset=(0, 0), rotate_dynamic=False,
-                    band_taps=None, depth=8) -> Dict[str, object]:
+                    band_taps=None, depth=8,
+                    word_size=None) -> Dict[str, object]:
     """Compact human-readable program identity for the cost ledger /
     ``/debug/plans`` — which ops the program fuses and at what static
     shapes, without dumping the whole TransformPlan repr. ``kernel``
@@ -237,8 +238,9 @@ def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
     different keys must never produce identical descriptors (the
     flylint ``program-key-drift`` rule holds this to the cache keys
     mechanically), which is why extent entries carry ``pad_offset`` and
-    the fill ``background`` alongside the canvas, and a program of 16-bit
-    samples its ``depth``."""
+    the fill ``background`` alongside the canvas, a program of 16-bit
+    samples its ``depth``, and a batched program whose output crosses the
+    link as ``uint32`` words (``readback_word_size``) its ``d2h_word``."""
     ops = []
     if resample_out is not None:
         ops.append("resample")
@@ -278,6 +280,8 @@ def plan_descriptor(plan: TransformPlan, *, in_shape=None, batch=None,
     desc["filter"] = plan.filter_method
     if depth != 8:
         desc["depth"] = int(depth)
+    if word_size == WORD_BYTES:
+        desc["d2h_word"] = "u32"
     return desc
 
 
@@ -350,27 +354,80 @@ def unflatten_images(flat, in_shape: Tuple[int, int]):
         return flat.reshape(flat.shape[0], *in_shape, 3)
 
 
-def flatten_images(images):
-    """``u8[frames, h, w, 3]`` -> ``u8[frames, h, w * 3]`` inside the device
-    program: the last operation on every piece's output, the mirror of
-    ``unflatten_images``. The device keeps the NHWC output planar, and the
-    host's read-back of a planar array is a strided view whose every
-    member the resolve re-interleaved pixel by pixel; flat, and laid out
-    row-major (``flat_output_format``), the read-back lands in the host's
-    order and ``ProgramHandle.unstage`` re-shapes it without a copy."""
+#: the bytes of the word a batched program's output crosses the link in where
+#: its rows allow (``readback_word_size``): on a TPU v5e the runtime reads a
+#: ``uint8`` or ``uint16`` array back at about 0.7 GB/s and the same bytes as
+#: ``uint32`` at about 3 GB/s (``tools/readback_probe.py``; PERF.md section 6)
+WORD_BYTES = 4
+
+
+class ReadbackByteOrderError(RuntimeError):
+    """A batched program would pack its samples into ``uint32`` words on a
+    host that is not little-endian: the host's byte view of a word would
+    hold its samples in another order. Raised when the program is built,
+    never at an answer."""
+
+
+def readback_word_size(out_width: int, depth: int) -> int:
+    """The bytes of one unit of a batched program's output as it crosses
+    the link: ``WORD_BYTES`` (a ``uint32`` of ``32 // depth`` samples)
+    where a row of ``out_width`` pixels holds a whole number of words, the
+    sample's own ``depth // 8`` where it does not (the program then
+    returns the samples themselves). A function of the program's static
+    output width and depth alone."""
+    per_word = WORD_BYTES * 8 // depth
+    return WORD_BYTES if (out_width * 3) % per_word == 0 else depth // 8
+
+
+def flatten_images(images, word_size: Optional[int] = None):
+    """``[frames, h, w, 3]`` samples -> the batched program's flat output
+    inside the device program: the last operation on every piece's output,
+    the mirror of ``unflatten_images``. The device keeps the NHWC output
+    planar, and the host's read-back of a planar array is a strided view
+    whose every member the resolve re-interleaved pixel by pixel; flat, and
+    laid out row-major (``flat_output_format``), the read-back lands in the
+    host's order and ``ProgramHandle.unstage`` re-shapes it without a copy.
+
+    With ``word_size`` ``WORD_BYTES`` (``readback_word_size``) each row of
+    ``w * 3`` samples leaves as ``w * 3 / k`` ``uint32`` words of ``k = 32 /
+    bits`` samples, sample ``k * i + j`` in byte (or 16-bit half) ``j`` of
+    word ``i``: little-endian, so the host's ``uint8`` (``uint16``) view of
+    the words is the flat samples. The words are made from the pixels in
+    groups of ``k``: the reshape and transpose put pixel ``p`` of every
+    group and channel ``c`` in a plane of their own (``planes[p, c]``, a
+    strided slice of the row), and each word is those planes shifted and
+    or-ed, so no array ever has the word's ``k`` samples as its minor
+    axis (such an intermediate is tiled 32-fold larger on the device).
+    Otherwise the samples as they are, ``[frames, h, w * 3]``."""
     with jax.named_scope("flyimg.flatten"):
-        return images.reshape(*images.shape[:2], -1)
+        n, h, w, c = images.shape
+        sample_bytes = images.dtype.itemsize
+        if word_size is None or word_size == sample_bytes:
+            return images.reshape(n, h, w * c)
+        bits = 8 * sample_bytes
+        per_word = word_size // sample_bytes
+        planes = jnp.transpose(
+            images.astype(jnp.uint32).reshape(n, h, w // per_word, per_word, c),
+            (3, 4, 0, 1, 2),
+        )
+        words = []
+        for k in range(c):
+            word = planes[divmod(k * per_word, c)]
+            for j in range(1, per_word):
+                word = word | (planes[divmod(k * per_word + j, c)] << (bits * j))
+            words.append(word)
+        return jnp.stack(words, axis=-1).reshape(n, h, w * c // per_word)
 
 
 def flat_output_format(sharding) -> jax_layout.Format:
     """The format a batched program returns its flat output in: on
     ``sharding``, laid out row-major (batch, then rows, then the row's
-    bytes), which is the host's order. Pinned, because the device picks a
-    ``uint8`` array's layout by its shape to spare its tiles' padding: on a
-    TPU v5e ``u8[64, 1066, 4800]`` defaults to the batch axis between the
-    rows and the row's bytes (``major_to_minor=(1, 0, 2)``), whose
-    read-back is strided again, and ``u8[64, 1216, 2496]`` to row-major. A
-    function of nothing but the program's static shape."""
+    samples or words), which is the host's order. Pinned, because the
+    device picks a ``uint8`` array's layout by its shape to spare its
+    tiles' padding: on a TPU v5e ``u8[64, 1066, 4800]`` defaults to the
+    batch axis between the rows and the row's bytes (``major_to_minor=(1,
+    0, 2)``), whose read-back is strided again, and ``u8[64, 1216, 2496]``
+    to row-major. A function of nothing but the program's static shape."""
     return jax_layout.Format(
         jax_layout.Layout(major_to_minor=(0, 1, 2)), sharding
     )
@@ -397,20 +454,22 @@ class ProgramHandle:
 
     A batched program (``pieces`` >= 1) takes its image argument in the
     staged form (``flat_pieces``) and returns its output flat (``u8[n,
-    h, w * 3]``, ``flatten_images``), and the handle owns both forms:
-    callers assemble and describe images as ``[n, h, w, 3]``; ``stage``
-    and ``precompile`` map them, so the executable a handle is warmed with
-    is the one its staged arrays run, and ``unstage`` turns the read-back
-    into ``[n, h, w, 3]`` again.
+    h, w * 3]``, or the same bytes as ``uint32`` words: ``flatten_images``),
+    and the handle owns both forms: callers assemble and describe images as
+    ``[n, h, w, 3]``; ``stage`` and ``precompile`` map them, so the
+    executable a handle is warmed with is the one its staged arrays run,
+    and ``unstage`` turns the read-back into ``[n, h, w, 3]`` samples
+    (``samples``: ``uint8`` or ``uint16``) again.
     """
 
     __slots__ = (
         "_jitted", "_compiled", "_lock", "ledger_key", "descriptor",
-        "in_sharding", "pieces",
+        "in_sharding", "pieces", "samples",
     )
 
     def __init__(self, jitted, key, descriptor: Dict[str, object],
-                 in_sharding=None, pieces: int = 0) -> None:
+                 in_sharding=None, pieces: int = 0,
+                 samples=np.uint8) -> None:
         self._jitted = jitted
         self._compiled = None
         self._lock = threading.Lock()
@@ -420,6 +479,9 @@ class ProgramHandle:
         # the program takes its first argument as flat_pieces leaves it,
         # in this many pieces (0: a single-image program, images as given)
         self.pieces = pieces
+        # the dtype of a batched program's output samples, whatever unit
+        # they crossed the link in
+        self.samples = np.dtype(samples)
         if isinstance(key, str):
             self.ledger_key = key
         else:
@@ -457,11 +519,17 @@ class ProgramHandle:
 
     def unstage(self, out):
         """The host read-back of this program's output as the batcher uses
-        it: a batched program's ``u8[n, h, w * 3]`` as ``[n, h, w, 3]``, a
-        view where the read-back is C-contiguous (a copy in the host's
-        order where it is not); a single-image program's as it is."""
+        it: a batched program's flat samples, or words viewed as the
+        samples they hold (little-endian: ``readback_word_size``), as ``[n,
+        h, w, 3]``, a view where the read-back is C-contiguous (a copy in
+        the host's order where it is not); a single-image program's as it
+        is."""
         if not self.pieces:
             return out
+        if out.dtype == np.uint32:
+            if out.strides[-1] != out.itemsize:
+                out = np.ascontiguousarray(out)
+            out = out.view(self.samples)
         n, h, row = out.shape
         return out.reshape(n, h, row // 3, 3)
 

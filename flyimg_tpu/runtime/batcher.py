@@ -61,11 +61,13 @@ from __future__ import annotations
 
 import hashlib
 import itertools
+import sys
 import threading
 import time
 from concurrent.futures import Future
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from functools import lru_cache
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -73,7 +75,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from flyimg_tpu.ops.compose import (
+    WORD_BYTES,
     ProgramHandle,
+    ReadbackByteOrderError,
     _bucket_dim,
     bound_lru_cache,
     bucket_batch,
@@ -83,6 +87,7 @@ from flyimg_tpu.ops.compose import (
     make_program_fn,
     plan_descriptor,
     plan_layout,
+    readback_word_size,
     stage_pieces,
     unflatten_images,
 )
@@ -167,7 +172,12 @@ def build_batched_program(
     pieces, h, w * 3]``; one piece for every launch of small frames),
     un-flattens and transforms them piece by piece, in a loop over one
     traced body, and returns the output flat too, ``u8[batch, H, W *
-    3]``, laid out row-major (``flat_output_format``); callers keep
+    3]``, laid out row-major (``flat_output_format``), and packed four
+    samples (two of 16 bits) to a ``uint32`` word where a row holds a
+    whole number of words (``readback_word_size`` of the output's static
+    width, found by one abstract trace a family of programs, and part of
+    the key as ``word_size``: a read-back of words is some four times
+    faster than of the samples: ``tools/readback_probe.py``); callers keep
     assembling and describing ``[batch, h, w, 3]`` and go through the
     handle's ``stage`` / ``precompile`` / ``unstage``, which own the
     mapping (ops/compose.py ``flat_pieces``, ``flatten_images``).
@@ -189,6 +199,16 @@ def build_batched_program(
         stage_pieces(batch_size, in_shape, depth // 8) if mesh is None else 1
     )
     frames = batch_size // pieces
+    samples = jnp.uint16 if depth == 16 else jnp.uint8
+    word_size = _output_word_size(
+        in_shape, resample_out, pad_canvas, pad_offset, plan,
+        rotate_dynamic, band_taps, depth,
+    )
+    if word_size == WORD_BYTES and sys.byteorder != "little":
+        raise ReadbackByteOrderError(
+            f"a {sys.byteorder}-endian host cannot view the program's "
+            "uint32 words as the samples they pack"
+        )
 
     def program(flat_pieces_u8, in_true, span_y, span_x, out_true):
         # the images arrive as ProgramHandle.stage leaves them (flat, in
@@ -199,7 +219,8 @@ def build_batched_program(
         # in the host's row order
         def one(piece, *scalars):
             return flatten_images(
-                batched(unflatten_images(piece, in_shape), *scalars)
+                batched(unflatten_images(piece, in_shape), *scalars),
+                word_size,
             )
 
         scalars = (in_true, span_y, span_x, out_true)
@@ -252,6 +273,7 @@ def build_batched_program(
         tuple(mesh.shape.items()) if mesh is not None else None,
         band_taps,
         depth,
+        word_size,
     )
     # fleet warm start (runtime/warmstart.py): note this program's
     # identity for the shared manifest — the mesh stays out (a seeding
@@ -270,11 +292,45 @@ def build_batched_program(
             plan, in_shape=in_shape, batch=batch_size,
             resample_out=resample_out, pad_canvas=pad_canvas,
             pad_offset=pad_offset, rotate_dynamic=rotate_dynamic,
-            band_taps=band_taps, depth=depth,
+            band_taps=band_taps, depth=depth, word_size=word_size,
         ),
         in_sharding=sharding,
         pieces=pieces,
+        samples=samples,
     )
+
+
+def _output_word_size(*family) -> int:
+    """``readback_word_size`` of a batched program's static output width,
+    one abstract trace (``jax.eval_shape``: nothing is compiled or run) of
+    the single-image program a family of ``build_batched_program``'s
+    arguments less the batch size: every launch size of a family has the
+    same width, and a warm-up builds them all at once, so the first
+    caller traces and the others wait for its answer."""
+    with _WORD_SIZE_LOCK:
+        return _family_word_size(*family)
+
+
+_WORD_SIZE_LOCK = threading.Lock()
+
+
+@lru_cache(maxsize=256)
+def _family_word_size(in_shape, resample_out, pad_canvas, pad_offset, plan,
+                      rotate_dynamic, band_taps, depth) -> int:
+    f32 = jnp.float32
+    out = jax.eval_shape(
+        make_program_fn(
+            resample_out, pad_canvas, pad_offset, plan,
+            rotate_dynamic=rotate_dynamic, band_taps=band_taps, depth=depth,
+        ),
+        jax.ShapeDtypeStruct(
+            (*in_shape, 3), jnp.uint16 if depth == 16 else jnp.uint8
+        ),
+        # in_true is four wide for a dynamic rotate (``_assemble``)
+        jax.ShapeDtypeStruct((4 if rotate_dynamic else 2,), f32),
+        *(jax.ShapeDtypeStruct((2,), f32),) * 3,
+    )
+    return readback_word_size(out.shape[1], depth)
 
 
 @dataclass(eq=False)  # identity equality: generated __eq__ would compare
@@ -335,7 +391,9 @@ class _Launch:
     staging call that opens ``h2d``: over the phase's seconds, the share
     its thread computed rather than waited; ``transfer_bytes`` the bytes
     staged (``h2d``) and read back (``d2h``); ``readback`` the form the
-    read-back arrived in (``row_major`` or ``strided``). Every phase is
+    read-back arrived in (``row_major`` or ``strided``), ``d2h_word`` the
+    dtype it crossed the link in (``u32`` words or the samples, ``u8`` /
+    ``u16``). Every phase is
     also opened as a ``jax.profiler.TraceAnnotation`` named
     ``flyimg:batch:<seq>:<phase>``
     (an aux launch's: ``flyimg:aux:<controller>:<seq>:<phase>``) on the
@@ -351,8 +409,8 @@ class _Launch:
     __slots__ = (
         "seq", "kind", "aux", "images", "capacity", "popped",
         "queue_wait_s", "marks", "cpu_s", "compile_hit", "dev_args",
-        "block", "transfer_bytes", "readback", "_cursor", "_opened",
-        "prefix", "_gaps", "_at", "depth",
+        "block", "transfer_bytes", "readback", "d2h_word", "_cursor",
+        "_opened", "prefix", "_gaps", "_at", "depth",
     )
 
     def __init__(self, seq: int, members: List[_Pending], *,
@@ -380,6 +438,10 @@ class _Launch:
         # it), "strided" where it is a view in another order (None: not
         # read back)
         self.readback: Optional[str] = None
+        # the dtype the output crossed the link in: "u32" where the program
+        # packs its samples into words, "u8" / "u16" where it returns them
+        # as they are (None: not read back)
+        self.d2h_word: Optional[str] = None
         self.compile_hit: Optional[bool] = None
         # the staged inputs, held only until the h2d wait returns: the
         # drain thread must not keep a launch's inputs alive through the
@@ -498,6 +560,8 @@ class _Launch:
             )
         for direction, nbytes in self.transfer_bytes.items():
             span_obj.set_attribute(f"device.{direction}_bytes", nbytes)
+        if self.d2h_word is not None:
+            span_obj.set_attribute("device.d2h_word", self.d2h_word)
         device_s = self.device_s
         if device_s is not None and not self.aux:
             span_obj.set_attribute("device.seconds", round(device_s, 6))
@@ -2147,8 +2211,10 @@ class BatchController:
         controller here, ``_keep_block``), the output is on the host
         (``d2h``). The inputs are not donated, so waiting on them after the
         dispatch is legal. Returns the output as a host array ``[n, h, w,
-        3]`` (``fn.unstage``: a view of the read-back where it arrived
-        row-major, which ``launch.readback`` records)."""
+        3]`` of samples (``fn.unstage``: a view of the read-back where it
+        arrived row-major, which ``launch.readback`` records, whether it
+        crossed the link as words or as the samples, which
+        ``launch.d2h_word`` records)."""
         with launch.annotate("h2d_wait"):
             jax.block_until_ready(launch.dev_args)
         launch.dev_args = None
@@ -2164,6 +2230,7 @@ class BatchController:
         launch.readback = (
             "row_major" if out.flags.c_contiguous else "strided"
         )
+        launch.d2h_word = f"u{8 * out.dtype.itemsize}"
         return fn.unstage(out)
 
     def _launch_done(self, group: _Group, members: List[_Pending],

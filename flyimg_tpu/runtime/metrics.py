@@ -501,12 +501,15 @@ class MetricsRegistry:
         (runtime/batcher.py ``_Launch``: the launch's one record, read
         here by ``seconds(phase)``, ``device_s``, ``queue_wait_s``,
         ``images``, ``capacity``, ``compile_hit``, ``aux``,
-        ``transfer_bytes``, ``readback``, ``depth``). A transform launch
+        ``transfer_bytes``, ``readback``, ``d2h_word``, ``depth``). A
+        transform launch
         observes ``flyimg_device_seconds`` (dispatch to completed
         read-back, as ever), one histogram per phase, the bytes it moved
         each way (``flyimg_device_transfer_bytes_total``), the bits of its
-        samples (``flyimg_batch_depth_total``) and the form its output
-        was read back in (``flyimg_batch_readbacks_total``); every launch,
+        samples (``flyimg_batch_depth_total``), the form its output
+        was read back in (``flyimg_batch_readbacks_total``) and the dtype
+        it crossed the link in (``flyimg_batch_readback_words_total``);
+        every launch,
         aux included, feeds the per-controller efficiency record
         (``record_batch_launch``) under the label its controller gives
         it: the transform controller's aux launches go under
@@ -551,6 +554,15 @@ class MetricsRegistry:
                     "member a view of it) or strided (a view in another "
                     "order, such as the device's planar one: each member "
                     "copied out of it on the drain thread)",
+                ).inc()
+            if launch.d2h_word is not None:
+                self.counter(
+                    "flyimg_batch_readback_words_total"
+                    f'{{word="{launch.d2h_word}"}}',
+                    "Transform launches by the dtype their output crossed "
+                    "the link in: u32 (the samples packed four, or two of "
+                    "16 bits, to a word, where a row holds whole words) or "
+                    "the samples themselves (u8, u16)",
                 ).inc()
         self.record_batch_launch(
             controller, images=launch.images, capacity=launch.capacity,

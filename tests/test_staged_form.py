@@ -3,7 +3,8 @@ batcher assembles ``u8[batch, h, w, 3]``, ``ProgramHandle.stage`` hands the
 device the same bytes flat and in pieces (``u8[batch / pieces, h, w * 3]``,
 views; one piece for every launch of small frames), the batched program
 un-flattens and transforms them piece by piece, in a loop over one body,
-and returns its output flat, ``u8[batch, H, W * 3]``, which
+and returns its output flat, ``u8[batch, H, W * 3]`` (as ``uint32`` words
+where a row holds whole words: ``tests/test_packed_readback.py``), which
 ``ProgramHandle.unstage`` turns into ``[batch, H, W, 3]`` without a copy.
 Pinned here: the output bytes do not change, a handle warmed with
 image-shaped specs runs staged arrays without a second compile, staging
@@ -19,7 +20,12 @@ import pytest
 
 from flyimg_tpu.ops import resample
 from flyimg_tpu.ops import compose
-from flyimg_tpu.ops.compose import flat_pieces, make_program_fn, stage_pieces
+from flyimg_tpu.ops.compose import (
+    flat_pieces,
+    make_program_fn,
+    readback_word_size,
+    stage_pieces,
+)
 from flyimg_tpu.runtime import tracing
 from flyimg_tpu.runtime.batcher import (
     BatchController,
@@ -155,7 +161,9 @@ def test_staged_form_gives_the_bytes_nhwc_gave(case, launch, request):
         )
         want = np.asarray(jax.jit(jax.vmap(inner))(*arrays))
         oh, ow = want.shape[1:3]
-        assert raw.dtype == np.uint8 and raw.shape == (batch, oh, ow * 3)
+        word = readback_word_size(ow, 8)
+        assert raw.dtype == np.dtype(f"u{word}")
+        assert raw.shape == (batch, oh, ow * 3 // word)
         got = fn.unstage(raw)
         assert got.shape == want.shape and got.flags.c_contiguous
         assert np.shares_memory(got, raw)
@@ -247,7 +255,8 @@ def test_handle_warmed_with_image_shaped_specs_runs_staged_arrays(
         out, warm = _compiles_during(
             lambda: jax.block_until_ready(fn(*staged)))
         assert warm == 0
-        assert out.shape == (batch, 28, 44 * 3)
+        # 44 pixels are 33 words of four samples
+        assert out.dtype == np.uint32 and out.shape == (batch, 28, 44 * 3 // 4)
         assert fn.unstage(np.asarray(out)).shape == (batch, 28, 44, 3)
     finally:
         _close(ctl)

@@ -69,6 +69,7 @@ COMPONENT_NAMES: Dict[str, Tuple[str, ...]] = {
     "batched": (
         "kind", "batch_size", "in_shape", "resample_out", "pad_canvas",
         "pad_offset", "plan", "rotate_dynamic", "mesh", "band_taps", "depth",
+        "word_size",
     ),
 }
 
